@@ -82,7 +82,7 @@ native:
 	$(MAKE) -C elasticdl_tpu/native
 
 lint:
-	env -u PYTHONPATH $(PY) -m elasticdl_tpu.analysis.lint $(LINT_FLAGS) $(LINT_PATHS)
+	$(PY) -m elasticdl_tpu.analysis.lint $(LINT_FLAGS) $(LINT_PATHS)
 	@if command -v ruff >/dev/null 2>&1; then \
 		ruff check $(LINT_PATHS); \
 	elif $(PY) -m ruff --version >/dev/null 2>&1; then \
@@ -92,25 +92,25 @@ lint:
 	fi
 
 lint-changed:
-	env -u PYTHONPATH $(PY) -m elasticdl_tpu.analysis.lint \
+	$(PY) -m elasticdl_tpu.analysis.lint \
 		--changed-only $(LINT_FLAGS) $(LINT_PATHS)
 
 test-fast: native
-	env -u PYTHONPATH $(MESH_ENV) $(PY) -m pytest tests/ -q \
+	$(MESH_ENV) $(PY) -m pytest tests/ -q \
 		-m "not slow and not integration"
 
 test-drills: native
-	env -u PYTHONPATH $(MESH_ENV) $(PY) -m pytest tests/ -q \
+	$(MESH_ENV) $(PY) -m pytest tests/ -q \
 		-m "slow or integration"
 
 drill:
 	bash scripts/run_local_job_drill.sh
-	env -u PYTHONPATH JAX_PLATFORMS=cpu $(PY) scripts/run_master_kill_drill.py
-	env -u PYTHONPATH JAX_PLATFORMS=cpu $(PY) scripts/run_server_kill_drill.py
-	env -u PYTHONPATH JAX_PLATFORMS=cpu $(PY) scripts/run_router_chaos_drill.py
-	env -u PYTHONPATH JAX_PLATFORMS=cpu EDL_KV_CACHE_DTYPE=int8 $(PY) scripts/run_autoscale_drill.py
-	env -u PYTHONPATH JAX_PLATFORMS=cpu $(PY) scripts/run_stall_drill.py
-	env -u PYTHONPATH JAX_PLATFORMS=cpu $(PY) scripts/run_rollout_drill.py
+	JAX_PLATFORMS=cpu $(PY) scripts/run_master_kill_drill.py
+	JAX_PLATFORMS=cpu $(PY) scripts/run_server_kill_drill.py
+	JAX_PLATFORMS=cpu $(PY) scripts/run_router_chaos_drill.py
+	JAX_PLATFORMS=cpu EDL_KV_CACHE_DTYPE=int8 $(PY) scripts/run_autoscale_drill.py
+	JAX_PLATFORMS=cpu $(PY) scripts/run_stall_drill.py
+	JAX_PLATFORMS=cpu $(PY) scripts/run_rollout_drill.py
 
 # Serving smoke: closed-loop load against the real continuous-batching
 # server, one BENCH_*-style JSON line (p50/p99 TTFT, tok/s, goodput).
@@ -138,7 +138,7 @@ drill:
 # the paged+shared leg — the bench FAILS if the enabled plane costs
 # more than 5% tokens/sec ("profiler_overhead" block).
 serve-smoke:
-	env -u PYTHONPATH JAX_PLATFORMS=cpu $(PY) scripts/bench_serving.py \
+	JAX_PLATFORMS=cpu $(PY) scripts/bench_serving.py \
 		--ramp "8:0.8,32:0.5,8:0.5" --compare_paged --kv_block_size 4 \
 		--shared_prefix --prefix_len 16 --suffix_len 1:4 \
 		--out_len 4:12 --draft_k 2 --kv_cache_dtype int8 \
@@ -156,12 +156,12 @@ serve-smoke:
 #   python scripts/bench_int8_scan.py --seq_len 128 --iters 20 \
 #       --out benchmarks/int8_scan_baseline.json
 bench-compare:
-	env -u PYTHONPATH $(PY) scripts/bench_compare.py \
+	$(PY) scripts/bench_compare.py \
 		--fresh BENCH_SERVING.json \
 		--baseline benchmarks/serving_baseline.json
-	env -u PYTHONPATH JAX_PLATFORMS=cpu $(PY) scripts/bench_int8_scan.py \
+	JAX_PLATFORMS=cpu $(PY) scripts/bench_int8_scan.py \
 		--seq_len 128 --iters 20 --out BENCH_INT8_SCAN.json
-	env -u PYTHONPATH $(PY) scripts/bench_compare.py \
+	$(PY) scripts/bench_compare.py \
 		--fresh BENCH_INT8_SCAN.json \
 		--baseline benchmarks/int8_scan_baseline.json
 

@@ -45,12 +45,11 @@ class LocalExecutor(object):
         fault_injector=None,
     ):
         from elasticdl_tpu.common.platform_utils import (
-            honor_jax_platforms_env,
+            configure_compile_cache,
         )
 
-        # before the first backend use (Trainer builds the mesh below):
-        # JAX_PLATFORMS=cpu must win over an ambient plugin's override
-        honor_jax_platforms_env()
+        # before the first backend use (Trainer builds the mesh below)
+        configure_compile_cache()
         self.spec = model_spec
         self.minibatch_size = minibatch_size
         self.num_epochs = num_epochs

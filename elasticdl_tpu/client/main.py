@@ -102,10 +102,10 @@ def build_argument_parser():
 
 def main(argv=None):
     from elasticdl_tpu.common.platform_utils import (
-        honor_jax_platforms_env,
+        configure_compile_cache,
     )
 
-    honor_jax_platforms_env()
+    configure_compile_cache()
     parser = build_argument_parser()
     args, extra = parser.parse_known_args(args=argv)
     return args.func(args, extra) or 0

@@ -22,10 +22,12 @@ identical and unit-testable without a cluster (the reference tests mock
 the same boundary — k8s_instance_manager_test.py).
 """
 
+import os
 import subprocess
 import sys
 import threading
 
+from elasticdl_tpu.common import platform_utils
 from elasticdl_tpu.common.k8s_client import (
     ELASTICDL_REPLICA_INDEX_KEY,
     ELASTICDL_REPLICA_TYPE_KEY,
@@ -287,7 +289,15 @@ class K8sInstanceManager(InstanceManagerBase):
 class LocalInstanceManager(InstanceManagerBase):
     """Workers are local subprocesses running
     `python -m elasticdl_tpu.worker.main` — the no-cluster elastic path
-    (and the fault-injection surface the integration tests use)."""
+    (and the fault-injection surface the integration tests use).
+
+    On a TPU host each worker slot owns one chip (a chip belongs to one
+    process at a time): with more than one worker, slot i's process is
+    confined to chip i through its environment, and a job asking for
+    more workers than the host has chips is refused at start instead of
+    left to fail — or hang — at the second worker's backend start. A
+    relaunched worker keeps its slot, hence its chip. One worker keeps
+    the whole host: it may drive every chip through a mesh."""
 
     def __init__(
         self,
@@ -308,6 +318,17 @@ class LocalInstanceManager(InstanceManagerBase):
         self._worker_args = list(worker_args)
         self._procs = {}
         self._env = env
+        self._chips = (
+            platform_utils.tpu_chip_paths() if num_workers > 1 else []
+        )
+        if self._chips and num_workers > len(self._chips):
+            raise ValueError(
+                "%d workers asked for, but this host has %d TPU chip(s) "
+                "(%s) and a chip belongs to one process: lower "
+                "--num_workers, or give one worker several chips with "
+                "--distribution_strategy AllreduceStrategy --mesh_spec"
+                % (num_workers, len(self._chips), ", ".join(self._chips))
+            )
 
     def _launch(self, worker_id, original_index):
         cmd = (
@@ -315,7 +336,14 @@ class LocalInstanceManager(InstanceManagerBase):
             + self._worker_args
             + ["--worker_id", str(worker_id)]
         )
-        proc = subprocess.Popen(cmd, env=self._env)
+        env = self._env
+        if self._chips:
+            chip = self._chips[original_index]
+            env = dict(os.environ if env is None else env,
+                       **platform_utils.one_chip_env(chip))
+            logger.info("Worker %d (slot %d) is given chip %s",
+                        worker_id, original_index, chip)
+        proc = subprocess.Popen(cmd, env=env)
         with self._lock:
             self._procs[worker_id] = proc
         threading.Thread(
@@ -342,6 +370,20 @@ class LocalInstanceManager(InstanceManagerBase):
             proc = self._procs.get(worker_id)
         if proc is not None and proc.poll() is None:
             proc.kill()
+
+    def stop(self):
+        """Kill the workers and wait until they are gone: a worker
+        holds its chip until its process has exited, and whoever runs
+        next on this host needs the chip."""
+        super().stop()
+        with self._lock:
+            procs = list(self._procs.values())
+        for proc in procs:
+            try:
+                proc.wait(timeout=30)
+            except subprocess.TimeoutExpired:
+                logger.warning("worker pid %d did not exit after kill",
+                               proc.pid)
 
 
 # ---------------------------------------------------------------- helpers

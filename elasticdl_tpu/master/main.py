@@ -108,10 +108,12 @@ def create_instance_manager(args, task_d, master_port):
 
 def main(argv=None):
     from elasticdl_tpu.common.platform_utils import (
-        honor_jax_platforms_env,
+        configure_compile_cache,
     )
 
-    honor_jax_platforms_env()
+    # the master itself never touches a device; this fixes the
+    # directory its worker subprocesses inherit
+    configure_compile_cache()
     # SIGUSR2 -> all-thread stack dump: a live wedged master can
     # always be interrogated without killing the job
     from elasticdl_tpu.observability.runtime_health import (

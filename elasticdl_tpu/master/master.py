@@ -204,6 +204,10 @@ class Master(object):
                     if not self.task_d.invoke_deferred_callback():
                         break
                 time.sleep(poll_interval)
+            logger.info(
+                "All tasks finished at model version %d",
+                self.task_d.model_version,
+            )
             if self.state_store:
                 # durable completion marker: a relaunched master (or the
                 # drill supervisor) must not redo a finished job

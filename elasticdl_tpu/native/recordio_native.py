@@ -10,6 +10,8 @@ semantics, faster scan.
 import ctypes
 import os
 
+from elasticdl_tpu.common.log_utils import default_logger as logger
+
 _LIB = None
 _TRIED = False
 
@@ -22,6 +24,9 @@ def _load():
     here = os.path.dirname(os.path.abspath(__file__))
     path = os.path.join(here, "libtrecio.so")
     if not os.path.exists(path):
+        # the .so is a build product (git-ignored): a fresh checkout
+        # reads records with the Python codec until someone runs make
+        logger.info("TRec codec: python (libtrecio.so not built)")
         return None
     try:
         lib = ctypes.CDLL(path)
@@ -38,7 +43,10 @@ def _load():
         lib.trec_free_buf.argtypes = [ctypes.c_char_p]
         lib.trec_close.argtypes = [ctypes.c_void_p]
         _LIB = lib
-    except OSError:
+        logger.info("TRec codec: native (%s)", path)
+    except OSError as e:
+        logger.warning("TRec codec: python (%s failed to load: %s)",
+                       path, e)
         _LIB = None
     return _LIB
 

@@ -24,7 +24,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.common.log_utils import default_logger as logger
 from elasticdl_tpu.ops.dispatch import (
-    CompilerParams,
     interpret_mode,
     is_tpu_backend,
     use_cond_mask,
@@ -43,8 +42,8 @@ NEG_INF = _NEG_INF  # masking constant shared with context_parallel
 _LOG2E = float(np.log2(np.e))
 _LN2 = float(np.log(2.0))
 
-# Tuned flash block defaults: hardware sweeps (scripts/bench_attention.py
-# via scripts/hw_session.py) persist their winner here so every call site
+# Tuned flash block defaults: hardware sweeps (scripts/bench_attention.py)
+# persist their winner here so every call site
 # that leaves block sizes unset — the model zoo, ring attention — picks
 # it up. Resolution order: explicit argument > EDL_FLASH_BLOCK_Q/K env >
 # ops/flash_tuning.json > 128.
@@ -116,6 +115,20 @@ def resolve_paged_rows(explicit=None):
         return _align8(value) if value else 8
     except (TypeError, ValueError):
         return 8
+
+
+def dispatch_summary():
+    """The attention policy this process traces with, for the one
+    start-up log line (common/platform_utils.log_startup). Per-shape
+    exceptions are logged where they are decided: flash_attention
+    warns when a sequence does not tile, the server names its paged
+    decode implementation (paged_decode_impl)."""
+    if not use_pallas():
+        return {"attention": "xla-blockwise (kernels off)"}
+    return {"attention": "pallas-flash%s, blocks (%d, %d)" % (
+        " interpreted" if interpret_mode() else "",
+        resolve_block(None, "q"), resolve_block(None, "k"),
+    )}
 
 
 def softmax_merge(o, l, m, s, v_blk, w_scale=None):
@@ -532,9 +545,7 @@ def paged_decode_attention(q, k_cur, v_cur, k_pool, v_pool, block_table,
                              w_scale=w_scale), None
 
     if use_kernel is None:
-        use_kernel = use_paged_kernel() and _paged_kernel_supported(
-            d, block_size, m
-        )
+        use_kernel = use_paged_kernel() and _paged_kernel_supported(m)
     if use_kernel:
         o, l, mx = _paged_decode_fused(
             qf, k_pool, v_pool, block_table, length, t, window=window,
@@ -572,26 +583,43 @@ def paged_decode_attention(q, k_cur, v_cur, k_pool, v_pool, block_table,
 # ------------------------------------------------- fused paged kernel
 
 
-def _paged_kernel_supported(d, block_size, m):
-    """Shape gate for the fused paged decode kernel. Interpret mode
-    (CPU tests, FORCE_INTERPRET debugging) takes any shape — no tiling
-    constraints apply. COMPILED Mosaic streams (1, block_size, 1, d)
-    arena tiles, so the arena's lane dim d must be a 128 multiple and
-    the block_size sublane dim 8-aligned: unlike q (a [b,h,t,d]-sized
-    array, padded for free in _paged_decode_fused), padding the SHARED
-    arenas would copy the whole pool every step — misaligned pools
-    keep the scan. m == 0 (no table slots) has no pool to stream."""
-    if m < 1:
-        return False
-    if interpret_mode():
-        return True
-    return d % 128 == 0 and block_size % 8 == 0
+def _paged_kernel_supported(m):
+    """Shape gate for the fused paged decode kernel — the ONE place
+    that decides kernel-or-scan by shape. m == 0 (no table slots) has
+    no pool to stream; every other shape takes the kernel.
+
+    The rule Mosaic applies to every block
+    (jax/_src/pallas/mosaic/lowering.py::_check_block_mappings) is that
+    each of the last two block dims equals the array's or is a multiple
+    of (8, 128). _paged_decode_fused streams whole blocks of the arenas
+    viewed as [num_blocks, block_size*hkv, d] — tiles
+    (1, block_size*hkv, d) and, for the scale leaves,
+    (1, block_size*hkv, 1) — so both of a tile's last two dims EQUAL
+    the array's and the rule holds for every hkv, block_size and d;
+    Mosaic pads a tile that is not (8, 128)-aligned in VMEM, the shared
+    arenas are never padded or copied. Compiled on the v5e against the
+    scan for d in {40, 64, 72, 80, 96, 112, 128, 192, 256}, tile rows
+    (block_size*hkv) from 1 to 512, bf16 / int8 / f32 arenas, t in
+    {1, 8}, bare and under vmap (CHANGES.md, PR 21); the flagship pool
+    shape is in tests/test_tpu_smoke.py."""
+    return m >= 1
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                  hkv, m, t, bs, window, quantized, n_rows):
+def paged_decode_impl(m):
+    """Name of the implementation paged_decode_attention's auto
+    dispatch picks for a pool with `m` table slots per sequence — what
+    the server logs at start."""
+    if not use_paged_kernel():
+        return "scan (kernels off)"
+    if not _paged_kernel_supported(m):
+        return "scan (no table slots)"
+    return "pallas-interpret" if interpret_mode() else "pallas"
+
+
+def _paged_kernel(tbl_ref, len_ref, q_ref, rows_ref, cols_ref, k_ref,
+                  v_ref, *rest, m, bs, window, quantized):
     """Fused paged decode attention, one Mosaic program per
-    (batch·kv_head, table slot) grid point.
+    (sequence, table slot) grid point, ALL kv heads per program.
 
     Scalar-prefetch operands (the vLLM PagedAttention shape): the
     flattened [b*m] block table and the [b] lengths land in SMEM before
@@ -599,22 +627,29 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     block HBM->VMEM by TABLE INDIRECTION — `tbl[batch*m + j]` IS the
     index map, -1 slots clamped to resident block 0 and masked here.
 
-    Per step: the (1, bs, 1, d) k/v tiles collapse to (bs, d); int8
-    rows dequantize IN-REGISTER by the (bs, 1) scale-leaf column
-    broadcast (one multiply per row element in VMEM — algebraically
-    the scan's score-tile/weight folding, chosen because the sublane
-    broadcast needs no transpose of the scale column). Scores run in
-    the exp2 domain like the flash kernels (log2e pre-folded into q's
-    scale multiply), masked by the SAME _paged_valid predicate the
-    scan uses, and accumulate into the fp32 VMEM scratch (o, l, m)
-    online-softmax triple; the last slot writes the raw partials out
-    (m converted back to natural log) for the shared current-tile
-    merge + finalize in paged_decode_attention."""
+    Per step the whole block arrives as one (bs*hkv, d) tile — arena
+    row r of head g sits at tile row r*hkv + g — and ONE matmul scores
+    every query row against every tile row; the cross-head products
+    are masked with the same select that applies _paged_valid, so the
+    weights of a foreign head are exactly 0 and the p @ v matmul needs
+    no per-head split either. That trades hkv-fold MXU work on a tile
+    of a few dozen rows (decode is bound by the pool stream, not the
+    MXU) for whole-block DMAs and no in-kernel relayout. The row/column
+    head ids and offsets ride in as two small int32 operands: vector
+    integer div/mod is not something to ask of the VPU.
+
+    int8 rows dequantize IN-REGISTER by the (bs*hkv, 1) scale-leaf
+    column broadcast. Scores run in the exp2 domain like the flash
+    kernels (log2e pre-folded into q's scale multiply) and accumulate
+    into the fp32 VMEM scratch (o, l, m) online-softmax triple; the
+    last slot writes the raw partials out (m converted back to natural
+    log) for the shared current-tile merge + finalize in
+    paged_decode_attention."""
     if quantized:
         ks_ref, vs_ref = rest[:2]
         rest = rest[2:]
     o_ref, l_ref, m_ref, acc_o, acc_l, acc_m = rest
-    i = pl.program_id(0)
+    batch = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -623,34 +658,33 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         acc_l[:] = jnp.zeros_like(acc_l)
         acc_m[:] = jnp.full_like(acc_m, _NEG_INF)
 
-    batch = i // hkv
     bid = tbl_ref[batch * m + j]
     seq_len = len_ref[batch]
 
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)  # (bs, d)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32)
+    kb = k_ref[0].astype(jnp.float32)  # (bs*hkv, d)
+    vb = v_ref[0].astype(jnp.float32)
     if quantized:
-        kb = kb * ks_ref[0, :, 0, :]  # (bs, 1) sublane broadcast
-        vb = vb * vs_ref[0, :, 0, :]
+        kb = kb * ks_ref[0]  # (bs*hkv, 1) lane broadcast
+        vb = vb * vs_ref[0]
 
-    q = q_ref[0, 0]  # (n_rows, d), exp2-domain prescaled f32
+    q = q_ref[0]  # (hkv*n_rows, d), exp2-domain prescaled f32
     s = jax.lax.dot_general(
         q, kb, dimension_numbers=_dims(1, 1),
         preferred_element_type=jnp.float32,
-    )  # (n_rows, bs), log2 units
+    )  # (hkv*n_rows, bs*hkv), log2 units
 
-    k_pos = j * bs + jax.lax.broadcasted_iota(
-        jnp.int32, (n_rows, bs), 1
-    )
-    # row r of the padded tile is tile token r % t (group-major
-    # [group, t] flatten; pad rows alias real positions and are
-    # sliced off by the caller)
-    row_pos = seq_len + (
-        jax.lax.broadcasted_iota(jnp.int32, (n_rows, bs), 0) % t
-    )
-    s = jnp.where(
-        _paged_valid(k_pos, bid, seq_len, row_pos, window), s, _NEG_INF
-    )
+    # every mask operand is broadcast to the full score tile as int32
+    # BEFORE it is compared: Mosaic broadcasts integers along either
+    # axis, a (1, n) or scalar i1 it may not
+    zeros = jnp.zeros(s.shape, jnp.int32)
+    row_head = zeros + rows_ref[:, 0:1]  # (hkv*n_rows, 1) columns
+    row_tok = zeros + rows_ref[:, 1:2]
+    col_head = zeros + cols_ref[0:1, :]  # (1, bs*hkv) rows
+    col_off = zeros + cols_ref[1:2, :]
+    valid = _paged_valid(
+        j * bs + col_off, zeros + bid, seq_len, seq_len + row_tok, window
+    ) & (row_head == col_head)
+    s = jnp.where(valid, s, _NEG_INF)
 
     m_prev = acc_m[:]
     m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
@@ -665,11 +699,11 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(j == m - 1)
     def _():
-        o_ref[0, 0] = acc_o[:]
-        l_ref[0, 0] = acc_l[:]
+        o_ref[0] = acc_o[:]
+        l_ref[0] = acc_l[:]
         # natural-log units at the boundary, like the flash epilogue:
         # nothing outside the kernel ever sees base-2 values
-        m_ref[0, 0] = acc_m[:] * _LN2
+        m_ref[0] = acc_m[:] * _LN2
 
 
 def _paged_decode_fused(qf, k_pool, v_pool, block_table, length, t,
@@ -682,10 +716,14 @@ def _paged_decode_fused(qf, k_pool, v_pool, block_table, length, t,
 
     qf is the scan's query layout: [b, hkv, group*t, d], already scale-
     multiplied, f32. The row axis pads up to resolve_paged_rows() (the
-    tuned sublane tile); k/v pools stream untouched — int8 arenas stay
-    int8 through the DMA, scale leaves ride as (1, bs, 1, 1) tiles."""
+    tuned sublane tile) and the heads fold into it. The pools stream
+    untouched: [num_blocks, bs, hkv, last] is VIEWED as
+    [num_blocks, bs*hkv, last] (a reshape of contiguous dims, no copy)
+    so a block is one (bs*hkv, last) tile whose last two dims equal
+    the array's — int8 arenas stay int8 through the DMA and the scale
+    leaves ride as (bs*hkv, 1) columns."""
     b, hkv, gt, d = qf.shape
-    bs = k_pool.shape[1]
+    num_blocks, bs = k_pool.shape[:2]
     m = block_table.shape[1]
     quantized = k_scale_pool is not None
     rows = resolve_paged_rows(rows)
@@ -695,65 +733,91 @@ def _paged_decode_fused(qf, k_pool, v_pool, block_table, length, t,
         q2 = jnp.pad(
             q2, ((0, 0), (0, 0), (0, n_rows - gt), (0, 0))
         )
+    q_rows, kv_rows = hkv * n_rows, bs * hkv
+    q2 = q2.reshape(b, q_rows, d)
     tbl = jnp.asarray(block_table, jnp.int32).reshape(b * m)
     ln = jnp.asarray(length, jnp.int32)
+    # query row R is head R // n_rows; row r of a head's padded tile is
+    # tile token r % t (group-major [group, t] flatten; pad rows alias
+    # real positions and are sliced off below). Tile column c is arena
+    # row c // hkv of head c % hkv.
+    r_idx = np.arange(q_rows)
+    c_idx = np.arange(kv_rows)
+    row_meta = np.stack(
+        [r_idx // n_rows, (r_idx % n_rows) % t], axis=1
+    ).astype(np.int32)  # [q_rows, 2]
+    col_meta = np.stack(
+        [c_idx % hkv, c_idx // hkv], axis=0
+    ).astype(np.int32)  # [2, kv_rows]
 
-    def _bh_spec(last):
-        """Per-(batch, kv-head) tile, revisited across the j stream."""
+    def _seq_spec(last):
+        """Per-sequence tile, revisited across the j stream."""
         return pl.BlockSpec(
-            (1, 1, n_rows, last),
-            lambda i, j, tbl_ref, len_ref: (i // hkv, i % hkv, 0, 0),
+            (1, q_rows, last),
+            lambda i, j, tbl_ref, len_ref: (i, 0, 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    def _const_spec(shape):
+        return pl.BlockSpec(
+            shape, lambda i, j, tbl_ref, len_ref: (0, 0),
             memory_space=pltpu.VMEM,
         )
 
     def _pool_spec(last):
         """THE tentpole index map: the scalar-prefetched block table
-        routes the HBM->VMEM DMA — slot j of sequence i//hkv names the
+        routes the HBM->VMEM DMA — slot j of sequence i names the
         arena block to stream; -1 (unallocated) clamps to block 0,
         whose rows _paged_valid masks. Same-index revisits (clamped
         runs) elide the copy like the flash stream clamps."""
         return pl.BlockSpec(
-            (1, bs, 1, last),
+            (1, kv_rows, last),
             lambda i, j, tbl_ref, len_ref: (
-                jnp.maximum(tbl_ref[(i // hkv) * m + j], 0),
-                0, i % hkv, 0,
+                jnp.maximum(tbl_ref[i * m + j], 0), 0, 0,
             ),
             memory_space=pltpu.VMEM,
         )
 
-    in_specs = [_bh_spec(d), _pool_spec(d), _pool_spec(d)]
-    inputs = [q2, k_pool, v_pool]
+    def _view(pool):
+        return pool.reshape(num_blocks, kv_rows, pool.shape[-1])
+
+    in_specs = [_seq_spec(d), _const_spec(row_meta.shape),
+                _const_spec(col_meta.shape), _pool_spec(d),
+                _pool_spec(d)]
+    inputs = [q2, row_meta, col_meta, _view(k_pool), _view(v_pool)]
     if quantized:
         in_specs += [_pool_spec(1), _pool_spec(1)]
-        inputs += [k_scale_pool, v_scale_pool]
+        inputs += [_view(k_scale_pool), _view(v_scale_pool)]
     kernel = functools.partial(
-        _paged_kernel, hkv=hkv, m=m, t=t, bs=bs, window=window,
-        quantized=quantized, n_rows=n_rows,
+        _paged_kernel, m=m, bs=bs, window=window, quantized=quantized,
     )
     o, l, mx = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b * hkv, m),
+            grid=(b, m),
             in_specs=in_specs,
-            out_specs=(_bh_spec(d), _bh_spec(1), _bh_spec(1)),
+            out_specs=(_seq_spec(d), _seq_spec(1), _seq_spec(1)),
             scratch_shapes=[
-                pltpu.VMEM((n_rows, d), jnp.float32),
-                pltpu.VMEM((n_rows, 1), jnp.float32),
-                pltpu.VMEM((n_rows, 1), jnp.float32),
+                pltpu.VMEM((q_rows, d), jnp.float32),
+                pltpu.VMEM((q_rows, 1), jnp.float32),
+                pltpu.VMEM((q_rows, 1), jnp.float32),
             ],
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((b, hkv, n_rows, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, n_rows, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, n_rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, q_rows, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, q_rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, q_rows, 1), jnp.float32),
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret_mode(),
     )(tbl, ln, *inputs)
-    return o[:, :, :gt], l[:, :, :gt, 0], mx[:, :, :gt, 0]
+    o = o.reshape(b, hkv, n_rows, d)[:, :, :gt]
+    l = l.reshape(b, hkv, n_rows)[:, :, :gt]
+    mx = mx.reshape(b, hkv, n_rows)[:, :, :gt]
+    return o, l, mx
 
 
 def _check_window(window, lq, lk):
@@ -1134,10 +1198,8 @@ def _dkv_q_spec(block, d, h, hkv, n_q, clamp=None):
 def _mosaic_params():
     """Grid semantics for all three flash kernels: (bh, output-block,
     streamed-block) = two parallel dims + one arbitrary (sequential
-    accumulation over scratch). Lets Mosaic pipeline the parallel dims.
-    `CompilerParams` comes from ops.dispatch — the one place the
-    jax-0.4.37 `TPUCompilerParams` rename is resolved."""
-    return CompilerParams(
+    accumulation over scratch). Lets Mosaic pipeline the parallel dims."""
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary")
     )
 
@@ -1529,9 +1591,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     tiles = _flash_tiles(lq, lk, block_q, block_k)
     if not (use_pallas() and tiles):
         if use_pallas():
-            logger.debug(
-                "flash_attention falling back to blockwise: seq (%d, %d) "
-                "does not tile into (%d, %d) blocks",
+            # trace-time, so once per compiled program, not per step
+            logger.warning(
+                "flash_attention takes the XLA blockwise path: seq "
+                "(%d, %d) does not tile into (%d, %d) blocks",
                 lq, lk, block_q, block_k,
             )
         out = blockwise_attention(q, k, v, causal=causal, scale=scale,

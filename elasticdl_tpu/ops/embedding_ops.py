@@ -28,11 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.ops import update_math as um
-from elasticdl_tpu.ops.dispatch import (
-    MemorySpace,
-    interpret_mode,
-    use_pallas,
-)
+from elasticdl_tpu.ops.dispatch import interpret_mode, use_pallas
 
 PADDING_ID = -1
 
@@ -123,8 +119,8 @@ def embedding_gather(table, ids, interpret=None):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=MemorySpace.HBM)],
-            out_specs=pl.BlockSpec(memory_space=MemorySpace.HBM),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
             scratch_shapes=[pltpu.SemaphoreType.DMA((_ID_CHUNK,))],
         ),
         out_shape=jax.ShapeDtypeStruct(
@@ -153,7 +149,7 @@ def _row_update_call(kernel, ids, hyper, tables, grads, interpret):
     grid = flat_ids.shape[0] // _ID_CHUNK
     hyper = jnp.stack([jnp.asarray(h, jnp.float32) for h in hyper])
     n_tables = len(tables)
-    hbm = pl.BlockSpec(memory_space=MemorySpace.HBM)
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

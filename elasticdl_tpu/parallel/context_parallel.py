@@ -413,6 +413,43 @@ def ring_attention(q, k, v, mesh, causal=False, scale=None,
     return fn(q, k, v, jnp.asarray(segments, jnp.int32))
 
 
+def sharded_flash_attention(q, k, v, mesh, causal=False, window=None,
+                            segments=None,
+                            batch_axes=(MeshAxis.DP, MeshAxis.FSDP),
+                            head_axis=MeshAxis.TP):
+    """flash_attention under a multi-device mesh with no sp axis: q/k/v
+    are global [batch, heads, seq, dim] arrays. XLA cannot partition a
+    Mosaic kernel ("wrap the call in a shard_map"), so the call is one
+    shard_map program: batch over `batch_axes`, heads over `head_axis`,
+    each device running the bare kernel on its shard — no collective,
+    attention rows are independent. An axis whose size does not divide
+    the dimension it would shard is left out (that dimension is then
+    computed redundantly on every device along it, which is what the
+    partitioner does with a replicated operand); both head counts must
+    divide for the head axis, or the GQA group map breaks."""
+    def fits(dim, axes):
+        size = 1
+        for ax in axes:
+            size *= mesh.shape.get(ax, 1)
+        return size > 1 and dim % size == 0
+
+    b_ax = tuple(batch_axes) if fits(q.shape[0], batch_axes) else None
+    h_ax = (
+        head_axis
+        if fits(q.shape[1], (head_axis,)) and fits(k.shape[1], (head_axis,))
+        else None
+    )
+    spec = P(b_ax, h_ax, None, None)
+    local = functools.partial(flash_attention, causal=causal,
+                              window=window)
+    if segments is None:
+        return shard_map(local, mesh, (spec, spec, spec), spec)(q, k, v)
+    return shard_map(
+        lambda qq, kk, vv, ss: local(qq, kk, vv, segments=ss),
+        mesh, (spec, spec, spec, P(b_ax, None)), spec,
+    )(q, k, v, jnp.asarray(segments, jnp.int32))
+
+
 # Local full-sequence attention per Ulysses impl choice; "jax_flash" is
 # jax's bundled TPU kernel (ops/attention.jax_flash_attention). Unknown
 # values are validated in ulysses_attention before tracing.

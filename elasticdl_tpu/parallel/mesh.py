@@ -86,12 +86,13 @@ def build_mesh(mesh_spec=None, devices=None):
             % (sizes, total, n)
         )
     shape = tuple(sizes[ax] for ax in MeshAxis.ALL)
-    try:
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        # create_device_mesh optimizes ICI adjacency; fall back to a plain
-        # reshape for virtual/CPU device sets where it can bail out.
+    if devices[0].platform == "cpu":
+        # virtual/CPU device sets have no interconnect to lay out
         dev_array = np.asarray(devices).reshape(shape)
+    else:
+        # orders devices by ICI adjacency; on an accelerator a topology
+        # it cannot lay out is an error, not something to paper over
+        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     mesh = Mesh(dev_array, MeshAxis.ALL)
     logger.info("Built mesh %s over %d devices", dict(sizes), n)
     return mesh
@@ -116,10 +117,10 @@ def current_mesh():
     """The Mesh active via `with mesh:` (how model code — e.g. the
     transformer's attention — discovers the sp axis at trace time inside
     the Trainer's compiled step), or None outside any mesh context."""
-    try:
-        from jax._src.mesh import thread_resources
-    except ImportError:  # older jax
-        from jax.interpreters.pxla import thread_resources
+    # `with mesh:` sets the legacy thread-local resource env, which has
+    # no public reader: jax.sharding.get_abstract_mesh() only sees
+    # jax.set_mesh / use_mesh contexts, and carries no devices.
+    from jax._src.mesh import thread_resources
 
     mesh = thread_resources.env.physical_mesh
     if mesh is None or mesh.empty:

@@ -45,27 +45,19 @@ is where the real bubble shrink lives.
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.8
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        # manual-collectives mode: the body mixes per-stage values with
-        # replicated ones, which the varying-manual-axes checker rejects
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common.constants import MeshAxis
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    # manual-collectives mode: the body mixes per-stage values with
+    # replicated ones, which the varying-manual-axes checker rejects
+    return _shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def stage_size(mesh):

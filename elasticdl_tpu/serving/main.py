@@ -128,6 +128,17 @@ def parse_serving_args(args=None):
     return parser.parse_args(args)
 
 
+def _paged_decode_choice(engine):
+    """Which paged-decode implementation dispatch picks for this
+    engine (the start-up log line)."""
+    from elasticdl_tpu.ops.attention import paged_decode_impl
+
+    kv = getattr(engine, "kv", None)
+    if kv is None:
+        return "none (dense per-slot KV)"
+    return paged_decode_impl(kv.max_blocks_per_slot)
+
+
 def build_server(args):
     # imports deferred so --help works without jax initialized
     import jax
@@ -136,6 +147,10 @@ def build_server(args):
         get_latest_checkpoint_version,
         restore_state_from_checkpoint,
     )
+    from elasticdl_tpu.common.platform_utils import (
+        configure_compile_cache,
+        log_startup,
+    )
     from elasticdl_tpu.parallel import mesh as mesh_lib
     from elasticdl_tpu.serving.server import (
         GenerationServer,
@@ -143,6 +158,7 @@ def build_server(args):
     )
     from elasticdl_tpu.training.trainer import Trainer
 
+    configure_compile_cache()
     spec = get_model_spec(args.model_zoo, args.model_def)
     mesh = mesh_lib.build_mesh({"dp": 1}, devices=jax.devices()[:1])
     trainer = Trainer(spec, mesh=mesh, model_params=args.model_params)
@@ -212,6 +228,8 @@ def build_server(args):
     server.engine.model_version = version
     if server.watcher is not None:
         server.watcher.version = version
+    log_startup("Serving", mesh.devices.flat,
+                paged_decode=_paged_decode_choice(server.engine))
     return server
 
 

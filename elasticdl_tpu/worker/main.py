@@ -69,10 +69,12 @@ def build_worker(args):
 
 def main(argv=None):
     from elasticdl_tpu.common.platform_utils import (
-        honor_jax_platforms_env,
+        configure_compile_cache,
+        log_device_memory,
+        log_startup,
     )
 
-    honor_jax_platforms_env()
+    configure_compile_cache()
     # SIGUSR2 -> all-thread stack dump: a live wedged worker can
     # always be interrogated without killing its task
     from elasticdl_tpu.observability.runtime_health import (
@@ -90,7 +92,16 @@ def main(argv=None):
 
     configure(service="worker:%d" % args.worker_id)
     worker = build_worker(args)
+    role = "Worker %d" % args.worker_id
+    devices = list(worker.trainer.mesh.devices.flat)
+    # the default strategy trains on the first visible device only
+    # (Trainer -> local_mesh()); the line below is where that shows
+    log_startup(
+        role, devices,
+        mesh={k: v for k, v in worker.trainer.mesh.shape.items() if v > 1},
+    )
     worker.run()
+    log_device_memory(role, devices)
     return 0
 
 

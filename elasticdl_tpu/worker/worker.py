@@ -19,6 +19,7 @@ What's preserved, behavior-for-behavior:
 """
 
 import os
+import time
 import traceback
 
 import numpy as np
@@ -239,7 +240,8 @@ class Worker(object):
             self._call_master(
                 "register_worker",
                 pb.RegisterWorkerRequest(
-                    worker_id=self.worker_id, address="", num_devices=1
+                    worker_id=self.worker_id, address="",
+                    num_devices=self.trainer.mesh.size,
                 ),
             )
         except Exception:
@@ -362,6 +364,28 @@ class Worker(object):
                     version, self._checkpoint_dir_for_init,
                 )
 
+    def _log_batch_placement(self, labels):
+        """Once, before the first step: where the assembled global
+        batch landed — one shard per device of the mesh's batch axes."""
+        shards = labels.addressable_shards
+        logger.info(
+            "Worker %d global batch of %d rows placed as %d shard(s) "
+            "of %d rows on devices %s", self.worker_id, labels.shape[0],
+            len(shards), shards[0].data.shape[0],
+            [s.device.id for s in shards],
+        )
+
+    def _record_loss(self, loss, t0):
+        """Keep and log one step's loss. float() is where the host
+        waits for the step, so the seconds are the step's own — the
+        first one carries state init and the compile."""
+        loss = float(loss)
+        self.losses.append(loss)
+        logger.info(
+            "Worker %d step %d loss %.6f (%.3f s)", self.worker_id,
+            len(self.losses), loss, time.perf_counter() - t0,
+        )
+
     def _maybe_checkpoint(self):
         """Save on the checkpoint_steps cadence. Never raises: a transient
         save failure must not fail (or retry) the already-applied step."""
@@ -379,11 +403,12 @@ class Worker(object):
         err = ""
         for attempt in range(MAX_MINIBATCH_RETRY_NUM):
             try:
+                t0 = time.perf_counter()
                 self._ensure_state(batch)
                 self.state, loss = self.trainer.train_step(
                     self.state, batch, true_count
                 )
-                self.losses.append(float(loss))
+                self._record_loss(loss, t0)
                 break
             except (ValueError, TypeError):
                 # deterministic failures don't heal with retries
@@ -485,8 +510,6 @@ class Worker(object):
             task_pb = self.get_task()
             if not task_pb.shard_name:
                 if task_pb.type == pb.WAIT:
-                    import time
-
                     time.sleep(self._task_data_service._wait_sleep_secs)
                     continue
                 break
@@ -628,6 +651,9 @@ class Worker(object):
         # every host is in this call).
         prepped = self.trainer._host_prepare(features)
         gf, gl, gw = self._spmd_ctx.assemble((prepped, labels, weights))
+        if not self.losses:
+            self._log_batch_placement(gl)
+        t0 = time.perf_counter()
         self._ensure_state(padded)
         self.state, loss = self.trainer.train_step_assembled(
             self.state, gf, gl, gw
@@ -635,7 +661,7 @@ class Worker(object):
         self._maybe_checkpoint()
         if n > 0:
             self._template_batch = (features, labels)
-            self.losses.append(float(loss))
+            self._record_loss(loss, t0)
             if self._spmd_ctx.process_index == 0:
                 self.report_version(int(self.state.step))
             self._task_data_service.report_record_done(n, "")

@@ -34,6 +34,7 @@ from elasticdl_tpu.ops.losses import chunked_softmax_xent
 from elasticdl_tpu.parallel import mesh as mesh_lib
 from elasticdl_tpu.parallel.context_parallel import (
     ring_attention,
+    sharded_flash_attention,
     ulysses_attention,
 )
 
@@ -317,7 +318,12 @@ class CausalSelfAttention(nn.Module):
             out = jax_flash_attention(
                 q, k, v, causal=self.causal, window=window
             )
-        else:  # "auto" (validated above)
+        elif mesh is not None and mesh.size > 1:
+            out = sharded_flash_attention(
+                q, k, v, mesh, causal=self.causal, window=window,
+                segments=segments,
+            )
+        else:  # "auto" (validated above) on one device
             out = flash_attention(
                 q, k, v, causal=self.causal, window=window,
                 segments=segments,

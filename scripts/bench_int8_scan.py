@@ -2,7 +2,7 @@
 """Microbench pin: the paged blockwise INT8 scan vs the dense
 deferred-dequantize int8 decode step.
 
-BENCHNOTES round 6 explained the residual offline `decode_kv_int8`
+An earlier round explained the residual offline `decode_kv_int8`
 gap (int8 ~0.85-0.95x fp after the deferred-dequantize fix: the
 per-step int8->f32 cast feeding the score matmul plus the two [*, L]
 scale multiplies). This PR folds the SAME deferral into the paged

@@ -3,7 +3,7 @@ transformer_pp family on a virtual pp mesh.
 
 The interleaved (circular, Megatron-style) schedule runs vM + P - 1
 ticks of 1/v-size chunk bodies vs GPipe's M + P - 1 full-stage ticks —
-total stage-work (M + (P-1)/v) vs (M + P - 1). At the VERDICT-r04
+total stage-work (M + (P-1)/v) vs (M + P - 1). At the round-4
 comparison point (M=8, P=4, v=2) that is 9.5 vs 11 stage-times: ~14%
 less work on an oversubscribed virtual mesh (where wall-clock tracks
 TOTAL work, all virtual devices timesharing the host) and the same

@@ -77,7 +77,7 @@ def main():
     trace_dir = sys.argv[1] if len(sys.argv) > 1 else "/tmp/edl-trace"
     import bench as bench_mod
 
-    bench_mod.require_accelerator_or_exit()
+    bench_mod.require_tpu()
     capture(trace_dir)
     summarize(trace_dir)
 

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Replayable worker-kill recovery drill (VERDICT round-3 item #5).
+"""Replayable worker-kill recovery drill.
 
 Runs the REAL distributed stack — master gRPC server, task dispatcher,
 LocalInstanceManager spawning worker subprocesses — SIGKILLs a worker

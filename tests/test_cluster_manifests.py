@@ -1,4 +1,4 @@
-"""manifests/ validated by machinery (VERDICT round-2 missing #5):
+"""manifests/ validated by machinery:
 
 * always: YAML parses; the master manifest's labels match the selector
   keys the k8s client generates services against, its args parse with

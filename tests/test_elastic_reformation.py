@@ -1,4 +1,4 @@
-"""Elastic mesh re-formation drill (VERDICT.md round-1 item #6, SURVEY
+"""Elastic mesh re-formation drill (SURVEY
 hard part #1): a 2-host SPMD job loses a host mid-training (preemption
 SIGKILL, exit 137), the sharded checkpoint carries continuity, and the
 job finishes on a RE-FORMED, SMALLER mesh — re-jit, re-shard restore —

@@ -1,4 +1,4 @@
-"""Export parity for the host-resident embedding tier (VERDICT.md weak
+"""Export parity for the host-resident embedding tier (review weak
 #6): the exported artifact carries host rows, serving reproduces
 training-time predictions exactly, and the mesh handler validates the
 artifact (the reference's model_handler_test export-parity coverage)."""

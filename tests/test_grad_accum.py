@@ -112,7 +112,7 @@ def test_accum_sparse_row_parity():
     """Sparse-tapped tables under accumulation: k microbatches stage
     their dedup'd row grads and apply once per macro step — the final
     table, dense params, AND row-optimizer slots must equal the one
-    big-batch update (VERDICT round-2 item #6; reference local-update
+    big-batch update (reference local-update
     semantics, worker.py:822-828)."""
     rs = np.random.RandomState(0)
     ids = rs.randint(0, 16, size=(8, 4)).astype(np.int32)

@@ -1,4 +1,4 @@
-"""Host-spill embedding tier, integrated end-to-end (VERDICT.md round-1
+"""Host-spill embedding tier, integrated end-to-end (review round-1
 item #5): deepfm trains with tables in the host store, loss matches the
 HBM path on the same data, and engine state rides the checkpoint."""
 

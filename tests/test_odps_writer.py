@@ -1,4 +1,4 @@
-"""ODPS write path + k-v table tools (VERDICT.md round-1 missing #3):
+"""ODPS write path + k-v table tools:
 writer round-trips a table through the reader; flattening tools match the
 reference UDTF protocol."""
 

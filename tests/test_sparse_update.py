@@ -1,6 +1,6 @@
 """Row-sparse embedding update engine (embedding/sparse_update.py).
 
-Covers VERDICT round-1 item #4: the per-step cost of training a model with
+Covers the per-step cost of training a model with
 a big embedding table must not scale with vocab (the reference's whole
 point: only touched rows move, ps/optimizer_wrapper.py:70-351 /
 go/pkg/ps/optimizer.go per-row kernels), while the numerics must match the
@@ -189,7 +189,7 @@ def _vocab_sized_compute_ops(hlo, vocab, dim=16):
 
 
 def test_cost_does_not_scale_with_vocab():
-    """The whole point (VERDICT #4): the compiled step's only
+    """The whole point: the compiled step's only
     vocab-sized operations are the in-place row scatters into the
     donated table + slot buffers — every other op is O(touched rows).
     The dense-masked oracle by contrast runs vocab-sized compute every

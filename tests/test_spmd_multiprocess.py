@@ -33,7 +33,7 @@ def _spec():
 
 @pytest.mark.slow
 def test_two_process_host_embedding_parity(tmp_path):
-    """VERDICT round-2 item #5: host-spill embedding tables partitioned
+    """Host-spill embedding tables partitioned
     over 2 real processes (4 virtual devices each) train to parity with
     a single-process run of the identical global batch stream — the
     reference's PS capacity-scales-with-fleet property, TPU-style."""

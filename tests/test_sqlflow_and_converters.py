@@ -1,4 +1,4 @@
-"""census_model_sqlflow zoo family + real-dataset converters (VERDICT.md
+"""census_model_sqlflow zoo family + real-dataset converters (review
 round-1 missing #4/#5): the transform-op graph interpreter, both sqlflow
 variants training e2e, and the image/CSV -> TRec converters."""
 

@@ -1,4 +1,4 @@
-"""Tensor parallelism is real (VERDICT.md round-1 weak #7): transformer
+"""Tensor parallelism is real: transformer
 kernels annotated with nn.with_partitioning over `tp` actually shard over
 a tp>1 mesh, the compiled train step contains the Megatron all-reduces,
 and the math matches the single-device model."""

@@ -6,8 +6,8 @@ JAX_PLATFORMS=cpu). Run on hardware with:
 
     EDL_TPU_TEST_PLATFORM=tpu python -m pytest tests/test_tpu_smoke.py -q
 
-VERDICT.md round-1 item #3: Mosaic lowering can reject shapes the Pallas
-interpreter accepts, so interpreter-mode coverage (tests/test_ops.py)
+Mosaic can reject shapes the Pallas interpreter accepts, so
+interpreter-mode coverage (tests/test_ops.py, tests/test_attention.py)
 does not prove these kernels run where it counts. This module is that
 proof; scripts/build_and_test.sh runs it when a TPU is reachable.
 """
@@ -133,8 +133,12 @@ def test_sliding_window_compiled(causal):
     grads = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
     refs = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for g, r in zip(grads, refs):
+        # the squared loss makes O(5) gradients, and fp32 operands go
+        # through the MXU as bf16 passes on both sides: a relative term
+        # for the large entries, the absolute one for those near zero
+        # (on the v5e: 28 of 65536 entries up to 0.094 off, 1.5%)
         np.testing.assert_allclose(
-            np.asarray(g), np.asarray(r), atol=5e-2, rtol=0
+            np.asarray(g), np.asarray(r), atol=5e-2, rtol=2e-2
         )
 
 
@@ -159,6 +163,141 @@ def test_rope_flash_compiled():
         np.asarray(out, np.float32), np.asarray(oracle),
         atol=2e-2, rtol=2e-2,
     )
+
+
+# ----------------------------------------------------------- paged decode
+
+
+def _paged_case(rng, b, t, int8, hkv=8, group=1, d=128, bs=16, m=6,
+                num_blocks=32):
+    """Operands for paged_decode_attention at the flagship serving
+    shape: every sequence owns a distinct run of table slots, the last
+    slots are unallocated (-1) and the cached lengths end mid-block."""
+    h = hkv * group
+    dtype = jnp.bfloat16
+
+    def rows(*shape):
+        x = _rand(rng, *shape)
+        if not int8:
+            return jnp.asarray(x, dtype), None
+        scale = np.abs(x).max(-1, keepdims=True) / 127.0
+        return (jnp.asarray(np.round(x / scale), jnp.int8),
+                jnp.asarray(scale, jnp.float32))
+
+    q = jnp.asarray(_rand(rng, b, h, t, d), dtype)
+    k_cur, k_cur_scale = rows(b, hkv, t, d)
+    v_cur, v_cur_scale = rows(b, hkv, t, d)
+    k_pool, k_scale_pool = rows(num_blocks, bs, hkv, d)
+    v_pool, v_scale_pool = rows(num_blocks, bs, hkv, d)
+    table = np.full((b, m), -1, np.int32)
+    length = np.zeros((b,), np.int32)
+    ids = rng.permutation(num_blocks)
+    for i in range(b):
+        used = m - 2 - (i % 2)
+        table[i, :used] = ids[i * m:i * m + used]
+        length[i] = used * bs - 5 - i
+    args = (q, k_cur, v_cur, k_pool, v_pool, jnp.asarray(table),
+            jnp.asarray(length))
+    kwargs = {}
+    if int8:
+        kwargs = dict(k_scale_pool=k_scale_pool,
+                      v_scale_pool=v_scale_pool,
+                      k_cur_scale=k_cur_scale, v_cur_scale=v_cur_scale)
+    return args, kwargs
+
+
+@pytest.mark.parametrize("vmapped", [False, True])
+@pytest.mark.parametrize("t", [1, 8])
+@pytest.mark.parametrize("int8", [False, True])
+def test_paged_decode_compiled(int8, t, vmapped):
+    """The fused paged kernel, compiled, against the lax.scan oracle at
+    hkv 8 / d 128 / block 16 — bare over a batch, and under jax.vmap
+    over slots with the pools closed over, which is how the serving
+    engine calls it (serving/engine.py _build_paged_step)."""
+    rng = np.random.default_rng(11)
+    args, kwargs = _paged_case(rng, b=4, t=t, int8=int8)
+
+    def run(use_kernel):
+        def attend(*a, **kw):
+            return attention.paged_decode_attention(
+                *a, use_kernel=use_kernel, **kw)
+
+        if not vmapped:
+            return jax.jit(attend)(*args, **kwargs)
+        q, k_cur, v_cur, k_pool, v_pool, table, length = args
+        cur = {k: v for k, v in kwargs.items() if "cur" in k}
+        pools = {k: v for k, v in kwargs.items() if "pool" in k}
+
+        def one(q1, k1, v1, tbl1, len1, cur1):
+            return attend(
+                q1[None], k1[None], v1[None], k_pool, v_pool,
+                tbl1[None], len1[None], **pools,
+                **{k: v[None] for k, v in cur1.items()},
+            )[0]
+
+        return jax.jit(jax.vmap(one))(q, k_cur, v_cur, table, length,
+                                      cur)
+
+    out = run(True)
+    oracle = run(False)
+    assert out.shape == oracle.shape and np.isfinite(
+        np.asarray(out)).all()
+    # both accumulate in fp32 over the same rows; they differ by the
+    # MXU's default-precision passes and the order of the block merge
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(oracle), atol=2e-2, rtol=2e-2
+    )
+
+
+def test_flash_under_four_device_mesh():
+    """Flash forward + backward inside a jit over a dp=4 mesh: XLA
+    cannot partition a Mosaic kernel, so the model routes through
+    context_parallel.sharded_flash_attention (one shard_map program).
+    Checked against the same call on one device."""
+    if len(jax.devices()) < 4:
+        pytest.skip(
+            "test_flash_under_four_device_mesh needs 4 chips, this "
+            "machine has %d" % len(jax.devices())
+        )
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from elasticdl_tpu.parallel import mesh as mesh_lib
+    from elasticdl_tpu.parallel.context_parallel import (
+        sharded_flash_attention,
+    )
+
+    mesh = mesh_lib.build_mesh("dp=4", devices=jax.devices()[:4])
+    rng = np.random.default_rng(12)
+    b, h, seq, d = 8, 4, 256, 128
+    q, k, v = (jnp.asarray(_rand(rng, b, h, seq, d), jnp.bfloat16)
+               for _ in range(3))
+
+    def loss_mesh(q, k, v):
+        return (sharded_flash_attention(
+            q, k, v, mesh, causal=True
+        ).astype(jnp.float32) ** 2).sum()
+
+    def loss_one(q, k, v):
+        return (attention.flash_attention(
+            q, k, v, causal=True, interpret=False
+        ).astype(jnp.float32) ** 2).sum()
+
+    batch_sh = NamedSharding(mesh, P(("dp", "fsdp")))
+    with mesh:
+        val, grads = jax.jit(
+            jax.value_and_grad(loss_mesh, argnums=(0, 1, 2)),
+            in_shardings=(batch_sh,) * 3,
+        )(q, k, v)
+    assert len(grads[0].sharding.device_set) == 4
+    ref_val, ref_grads = jax.jit(
+        jax.value_and_grad(loss_one, argnums=(0, 1, 2))
+    )(q, k, v)
+    np.testing.assert_allclose(float(val), float(ref_val), rtol=1e-3)
+    for g, r in zip(grads, ref_grads):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(r, np.float32),
+            atol=1e-2, rtol=0,
+        )
 
 
 # ------------------------------------------------- dense optimizer kernels

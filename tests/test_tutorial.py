@@ -74,7 +74,7 @@ def test_tutorial_references_exist():
         "elasticdl_tpu/api/local_executor.py",
         "common/tb_events.py",
         "docs/designs",
-        "BENCHNOTES.md",
+        "PERF.md",
         "tests/test_finetune.py",
     ):
         assert rel in text, "tutorial no longer mentions %s" % rel
@@ -82,5 +82,5 @@ def test_tutorial_references_exist():
                                        "common", "tb_events.py"))
     for rel in ("manifests/elasticdl-tpu-rbac.yaml",
                 "scripts/validate_job_status.py",
-                "docs/designs", "BENCHNOTES.md"):
+                "docs/designs", "PERF.md"):
         assert os.path.exists(os.path.join(REPO, rel)), rel
