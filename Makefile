@@ -131,12 +131,14 @@ drill:
 # KV bytes) and records the "host_vs_evict" ratio block: what share
 # of the baseline's re-paid prefill tokens the host tier recovers by
 # revival upload, with steady-state post-eviction TTFT. --profile
-# records the per-step decode profiler breakdown (p50/p99 per phase:
-# prefill/suffix_tile/decode/draft/verify_commit/scatter/
-# revive_upload) under "profile" plus a validated /metrics scrape,
-# and --overhead_ab runs the metrics+profiler plane OFF-vs-ON A/B on
-# the paged+shared leg — the bench FAILS if the enabled plane costs
-# more than 5% tokens/sec ("profiler_overhead" block).
+# records the loop's phase spans (observability/tracing.py `phase`,
+# always on in the server; p50/p99 per phase: tick.upload/
+# tick.dispatch/tick.fetch/prefill/prompt_write/suffix_tile/draft/
+# revive_upload ...) under "profile" plus a validated /metrics scrape,
+# and --overhead_ab runs the observability plane (exposition,
+# forensics, runtime health) OFF-vs-ON A/B on the paged+shared leg —
+# the bench FAILS if the enabled plane costs more than 5% tokens/sec
+# ("profiler_overhead" block).
 serve-smoke:
 	JAX_PLATFORMS=cpu $(PY) scripts/bench_serving.py \
 		--ramp "8:0.8,32:0.5,8:0.5" --compare_paged --kv_block_size 4 \

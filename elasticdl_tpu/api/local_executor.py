@@ -15,6 +15,7 @@ from elasticdl_tpu.data.dataset import Dataset, pad_batch
 from elasticdl_tpu.common.model_utils import resolve_dataset_fn
 from elasticdl_tpu.data.reader.data_reader_factory import create_data_reader
 from elasticdl_tpu.master.task_dispatcher import TaskDispatcher, TaskType
+from elasticdl_tpu.observability import tracing
 from elasticdl_tpu.training.metrics import MetricsAggregator
 from elasticdl_tpu.training.trainer import Trainer
 
@@ -170,35 +171,49 @@ class LocalExecutor(object):
         )
         stop = False
         while not stop:
-            if self._fault_injector is not None:
-                self._fault_injector.intercept("local_get_task")
-            task_id, task = dispatcher.get("local")
+            with tracing.phase("train.task_get"):
+                if self._fault_injector is not None:
+                    self._fault_injector.intercept("local_get_task")
+                task_id, task = dispatcher.get("local")
             if task is None:
                 break
-            for batch in self._task_dataset(reader, task, Mode.TRAINING):
-                padded, n = pad_batch(batch, self.minibatch_size)
-                self._ensure_state(padded)
-                self.state, loss = self.trainer.train_step(
-                    self.state, padded, n
-                )
-                self.losses.append(float(loss))
+            batches = iter(self._task_dataset(reader, task, Mode.TRAINING))
+            while True:
+                # the loop's phases carry the step they lead up to
+                seq = len(self.losses)
+                with tracing.phase("train.next_batch", seq=seq):
+                    batch = next(batches, None)
+                if batch is None:
+                    break
+                with tracing.phase("train.pad", seq=seq):
+                    padded, n = pad_batch(batch, self.minibatch_size)
+                    self._ensure_state(padded)
+                with tracing.phase("train.step", seq=seq):
+                    self.state, loss = self.trainer.train_step(
+                        self.state, padded, n
+                    )
+                with tracing.phase("train.loss_fetch", seq=seq):
+                    self.losses.append(float(loss))
                 if self._checkpoint_saver is not None:
-                    self._checkpoint_saver.maybe_save(self.state)
+                    with tracing.phase("train.checkpoint", seq=seq):
+                        self._checkpoint_saver.maybe_save(self.state)
                 step = int(self.state.step)
                 if (
                     self.evaluation_steps
                     and eval_reader
                     and step % self.evaluation_steps == 0
                 ):
-                    metrics = self._evaluate_with_reader(eval_reader)
+                    with tracing.phase("train.eval", seq=seq):
+                        metrics = self._evaluate_with_reader(eval_reader)
                     logger.info("Eval at step %d: %s", step, metrics)
                 if self.max_steps and step >= self.max_steps:
                     dispatcher.stop_training = True
                     stop = True
                     break
-            if self._fault_injector is not None:
-                self._fault_injector.intercept("local_report")
-            dispatcher.report(task_id, True)
+            with tracing.phase("train.task_report"):
+                if self._fault_injector is not None:
+                    self._fault_injector.intercept("local_report")
+                dispatcher.report(task_id, True)
         final_metrics = (
             self._evaluate_with_reader(eval_reader) if eval_reader else {}
         )

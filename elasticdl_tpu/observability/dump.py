@@ -33,7 +33,14 @@ def merge_dir(trace_dir):
     under `trace_dir`. Unreadable files are reported in meta, not
     fatal: a SIGKILLed process's missing/partial export must never
     block merging the survivors."""
-    spans, meta = [], []
+    spans, meta, _phases = _merge(trace_dir)
+    return spans, meta
+
+
+def _merge(trace_dir):
+    """merge_dir plus the exports' phase rings (tracing.phase): (span
+    dicts, meta, phase dicts)."""
+    spans, meta, phases = [], [], []
     for path in sorted(glob.glob(
             os.path.join(trace_dir, "spans-*.json"))):
         try:
@@ -42,6 +49,7 @@ def merge_dir(trace_dir):
         except (OSError, ValueError) as e:
             meta.append({"path": path, "error": str(e)})
             continue
+        phases.extend(doc.get("phases", ()))
         meta.append({
             "path": path,
             "service": doc.get("service", "?"),
@@ -54,9 +62,12 @@ def merge_dir(trace_dir):
             "retained": doc.get("retained", 0),
             "retained_dropped": doc.get("retained_dropped", 0),
             "sampled_out": doc.get("sampled_out", 0),
+            # the phase ring's own count and drop-oldest evictions
+            "phases": len(doc.get("phases", ())),
+            "phases_dropped": doc.get("phases_dropped", 0),
         })
         spans.extend(doc.get("spans", ()))
-    return spans, meta
+    return spans, meta, phases
 
 
 def drops_by_service(meta):
@@ -89,9 +100,9 @@ def main(argv=None):
         print("dump: no --dir and no $%s set" % TRACE_DIR_ENV,
               file=sys.stderr)
         return 2
-    spans, meta = merge_dir(args.dir)
+    spans, meta, phases = _merge(args.dir)
     drops = drops_by_service(meta)
-    doc = chrome_trace(spans)
+    doc = chrome_trace(spans, phases)
     # Chrome-trace "otherData" rides unknown keys through Perfetto
     # untouched: the merged evidence accounting lives IN the artifact,
     # so a trace file can say its own evidence is incomplete
@@ -105,9 +116,9 @@ def main(argv=None):
         json.dump(doc, f)
     errors = [m for m in meta if "error" in m]
     print(
-        "dump: merged %d spans across %d traces from %d exports -> %s"
-        " (%d unreadable exports)"
-        % (len(spans), len(group_by_trace(spans)),
+        "dump: merged %d spans across %d traces and %d phases from "
+        "%d exports -> %s (%d unreadable exports)"
+        % (len(spans), len(group_by_trace(spans)), len(phases),
            len(meta) - len(errors), args.out, len(errors))
     )
     if drops:

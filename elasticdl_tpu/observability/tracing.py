@@ -55,6 +55,32 @@ backstop), and ``python -m elasticdl_tpu.observability.dump`` merges
 every per-process export into one Chrome-trace JSON that loads in
 Perfetto (ui.perfetto.dev) or chrome://tracing.
 
+PHASE SPANS: below the request there is the loop. `phase(name)` (or
+the `begin`/`end` pair) times one host-visible region of a hot loop —
+a scheduler tick's upload, dispatch, fetch and bookkeeping, a train
+step's batch wait, dispatch and loss fetch — from a CLOSED set of
+names (`PHASES`; an unknown name raises). It is always on and never
+touches the device: no `block_until_ready`, no second program. Each
+phase is recorded twice, on two clocks that agree:
+
+* as ``(name, start_ns, end_ns, seq, parent, trace_id, attrs)`` with
+  ``time.time_ns()`` in a SECOND bounded ring of the same recorder
+  (`phases()`; its own ``phases_dropped``), so a long window of ticks
+  never evicts request spans and request spans never evict phases.
+  ``seq`` is the tick or step number (children inherit their
+  parent's), ``parent`` the enclosing phase's name on this thread;
+* as ``jax.profiler.TraceAnnotation("edl/" + name)`` when jax is
+  already imported: with a profiler session running the phase is an
+  event of the calling thread in the xplane, beside the device's
+  lines; with none it costs a flag check.
+
+Every phase end also feeds that name's cumulative log-linear histogram
+(`phase_snapshot()`, the ``edl_serving_phase_ms{phase=}`` family): the
+ring drops its oldest, a cumulative family cannot. `count(name, n)`
+records work done where it happens (`COUNTERS`, closed too) as a
+zero-length entry of the same ring, so a reader can cut counts to a
+window like phases.
+
 Timestamps are ``time.time()`` (wall clock): spans from different
 processes must land on one timeline, which monotonic clocks cannot
 give across processes. Good enough for the single-host drills this
@@ -63,12 +89,16 @@ process's spans.
 """
 
 import atexit
+import collections
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
+
+from elasticdl_tpu.observability.histogram import LogLinearHistogram
 
 TRACE_DIR_ENV = "EDL_TRACE_DIR"
 
@@ -77,6 +107,34 @@ _DEFAULT_CAPACITY = 4096
 #: smaller than the ring — retention is for the tail, not a second
 #: copy of everything
 _DEFAULT_RETAINED_CAPACITY = 2048
+#: the phase ring's bound: a 51 s window of 10 ticks/s x ~10 phases,
+#: its warm-up and its drain fit several times over
+_DEFAULT_PHASE_CAPACITY = 65536
+
+#: the closed set of phase names, declared once: `begin` raises on
+#: anything else. One line per loop, outermost first.
+PHASES = (
+    # serving/server.py _Scheduler._iterate
+    "tick", "tick.admit", "tick.prefill_tile", "tick.stream", "idle",
+    # serving/engine.py step() / _spec_step()
+    "tick.ensure", "tick.upload", "tick.dispatch", "tick.fetch",
+    "tick.commit",
+    # serving/engine.py insert() and friends, serving/kv_pool.py
+    "prefill", "prefill_tile", "suffix_tile", "draft", "reload_swap",
+    "prompt_write", "revive_upload",
+    # api/local_executor.py train()
+    "train.task_get", "train.next_batch", "train.pad", "train.step",
+    "train.loss_fetch", "train.checkpoint", "train.eval",
+    "train.task_report",
+    # training/trainer.py train_step()
+    "trainer.host_prepare", "trainer.dispatch", "trainer.post_tiers",
+)
+#: the closed set of `count` names
+COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
+            "prompts_prefilled")
+
+Phase = collections.namedtuple(
+    "Phase", "name start_ns end_ns seq parent trace_id attrs")
 
 
 def new_trace_id():
@@ -169,7 +227,8 @@ class SpanRecorder(object):
     def __init__(self, service="proc", capacity=_DEFAULT_CAPACITY,
                  clock=time.time,
                  retained_capacity=_DEFAULT_RETAINED_CAPACITY,
-                 sample_rate=1.0, seed=None):
+                 sample_rate=1.0, seed=None,
+                 phase_capacity=_DEFAULT_PHASE_CAPACITY):
         self.service = service
         self.capacity = int(capacity)
         self.clock = clock
@@ -185,6 +244,79 @@ class SpanRecorder(object):
         self._retained_traces = set()
         self._classifiers = []
         self._rand = random.Random(seed)
+        # the phase ring (module docstring, PHASE SPANS): its own
+        # bound, lock and drop count, so neither ring evicts the other
+        self.phase_capacity = int(phase_capacity)
+        self.phases_dropped = 0
+        self._phase_lock = threading.Lock()
+        self._phases = deque(maxlen=self.phase_capacity)
+        self._phase_hists = {p: LogLinearHistogram() for p in PHASES}
+        self._counts = dict.fromkeys(COUNTERS, 0)
+
+    # ------------------------------------------------------- phase ring
+
+    def _record_phase(self, record):
+        """Seal one phase or count: one short critical section."""
+        with self._phase_lock:
+            if len(self._phases) == self.phase_capacity:
+                self.phases_dropped += 1
+            self._phases.append(record)
+            hist = self._phase_hists.get(record[0])
+            if hist is not None:
+                hist.record((record[2] - record[1]) * 1e-6)
+            else:
+                self._counts[record[0]] += record[6]["n"]
+
+    def phases(self, since_ns=None, until_ns=None):
+        """The raw ring, oldest first, as `Phase` tuples (counts are
+        zero-length entries whose attrs hold ``n``); with bounds, the
+        entries that overlap [since_ns, until_ns]."""
+        with self._phase_lock:
+            raw = list(self._phases)
+        return [
+            Phase(*r) for r in raw
+            if (since_ns is None or r[2] >= since_ns)
+            and (until_ns is None or r[1] <= until_ns)
+        ]
+
+    def counts(self):
+        """{counter: cumulative total} since start (or clear)."""
+        with self._phase_lock:
+            return dict(self._counts)
+
+    def phase_snapshot(self):
+        """{phase: {count, p50_ms, p99_ms, total_ms}} for phases that
+        recorded anything, cumulative (the bench's BENCH_SERVING.json
+        ``profile`` shape)."""
+        with self._phase_lock:
+            return {
+                name: {
+                    "count": h.count,
+                    "p50_ms": round(h.percentile(50), 3),
+                    "p99_ms": round(h.percentile(99), 3),
+                    "total_ms": round(h.sum, 3),
+                }
+                for name, h in self._phase_hists.items() if h.count
+            }
+
+    def phase_hist_series(self):
+        """[({"phase": name}, bucket counts, sum)] per phase that
+        recorded samples: the series of one labeled histogram family
+        (metrics.hist_family)."""
+        with self._phase_lock:
+            return [
+                ({"phase": name}, h.to_counts(), h.sum)
+                for name, h in self._phase_hists.items() if h.count
+            ]
+
+    def clear_phases(self):
+        with self._phase_lock:
+            self._phases.clear()
+            self.phases_dropped = 0
+            self._phase_hists = {p: LogLinearHistogram() for p in PHASES}
+            self._counts = dict.fromkeys(COUNTERS, 0)
+
+    # ---------------------------------------------------- request spans
 
     def add_classifier(self, fn):
         """Register a verdict hook `fn(span) -> True | False | None`:
@@ -308,6 +440,8 @@ class SpanRecorder(object):
             dropped = self.dropped
             retained_dropped = self.retained_dropped
             sampled_out = self.sampled_out
+        with self._phase_lock:
+            phases_dropped = self.phases_dropped
         return {
             "service": self.service,
             "pid": os.getpid(),
@@ -316,6 +450,9 @@ class SpanRecorder(object):
             "retained_dropped": retained_dropped,
             "sampled_out": sampled_out,
             "spans": [s.to_dict() for s in spans],
+            "phases_dropped": phases_dropped,
+            "phases": [dict(p._asdict(), service=self.service)
+                       for p in self.phases()],
         }
 
     def write(self, path):
@@ -373,6 +510,107 @@ def configure(service=None, capacity=None):
     return _RECORDER
 
 
+# ------------------------------------------------------------ phase spans
+
+_LABELS = {p: "edl/" + p for p in PHASES}  # the xplane's event names
+_COUNTERS = frozenset(COUNTERS)
+_tls = threading.local()
+_annotation = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+class _OpenPhase(object):
+    """A phase between `begin` and `end`; also the context manager
+    `phase()` returns."""
+
+    __slots__ = ("name", "seq", "parent", "trace_id", "attrs",
+                 "start_ns", "_ann", "_stack")
+
+    def __init__(self, name, seq, parent, trace_id, attrs, stack):
+        self.name, self.seq, self.parent = name, seq, parent
+        self.trace_id, self.attrs, self._stack = trace_id, attrs, stack
+        self._ann = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, _exc_type, _exc, _tb):
+        end(self)
+        return False
+
+
+def begin(name, seq=None, trace_id="", **attrs):
+    """Open the phase `name` on this thread; close it with `end`.
+    `seq` (the tick or step number) and `trace_id` (the request's,
+    where the phase serves one request) are inherited from the
+    enclosing phase when not given."""
+    global _annotation
+    label = _LABELS.get(name)
+    if label is None:
+        raise ValueError(
+            "unknown phase %r (declared: %s)" % (name, ", ".join(PHASES))
+        )
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+    if stack:
+        top = stack[-1]
+        ph = _OpenPhase(name, top.seq if seq is None else seq, top.name,
+                        trace_id or top.trace_id, attrs, stack)
+    else:
+        ph = _OpenPhase(name, seq, "", trace_id, attrs, stack)
+    stack.append(ph)
+    if _annotation is None and "jax" in sys.modules:
+        _annotation = sys.modules["jax"].profiler.TraceAnnotation
+    # with no profiler session the annotation is this flag check
+    if _annotation is not None and _annotation.is_enabled():
+        ph._ann = _annotation(label)
+        ph._ann.__enter__()
+    ph.start_ns = time.time_ns()
+    return ph
+
+
+def end(ph, **attrs):
+    """Close a phase opened by `begin` (with what is only known now as
+    `attrs`) and seal it into the recorder's phase ring. Phases left
+    open above it on its thread's stack (an exception skipped their
+    `end`) are dropped, so one failure cannot mis-parent what
+    follows."""
+    end_ns = time.time_ns()
+    if ph._ann is not None:
+        ph._ann.__exit__(None, None, None)
+    stack = ph._stack
+    if stack and stack[-1] is ph:
+        stack.pop()
+    elif ph in stack:
+        while stack.pop() is not ph:
+            pass
+    if attrs:
+        ph.attrs.update(attrs)
+    _RECORDER._record_phase((ph.name, ph.start_ns, end_ns, ph.seq,
+                             ph.parent, ph.trace_id, ph.attrs))
+
+
+#: `with phase("tick.upload"): ...` — `begin` and `end` around a block
+phase = begin
+
+
+def count(name, n=1):
+    """Count `n` units of work under the closed counter `name`, where
+    the work happens."""
+    if name not in _COUNTERS:
+        raise ValueError(
+            "unknown counter %r (declared: %s)"
+            % (name, ", ".join(COUNTERS))
+        )
+    stack = getattr(_tls, "stack", None)
+    top = stack[-1] if stack else None
+    now = time.time_ns()
+    _RECORDER._record_phase((
+        name, now, now, top.seq if top else None,
+        top.name if top else "", "", {"n": n},
+    ))
+
+
 # ------------------------------------------------------ chrome conversion
 
 
@@ -399,7 +637,34 @@ def children_of(span_dicts, parent_span_id):
             if s["parent_span_id"] == parent_span_id]
 
 
-def chrome_trace(span_dicts):
+def _chrome_phases(phase_dicts, pid_of):
+    """Phase dicts (`SpanRecorder.export()["phases"]`) as slices on
+    one "phases" row per service — they nest by time, as they did on
+    the thread that ran them — named as in the xplane (``edl/<name>``);
+    counts become instant events."""
+    events = []
+    for pid in sorted({pid_of[p["service"]] for p in phase_dicts}):
+        events.append({
+            "name": "thread_name", "ph": "M", "pid": pid, "tid": 0,
+            "args": {"name": "phases"},
+        })
+    for p in sorted(phase_dicts, key=lambda d: (d["start_ns"],
+                                                -d["end_ns"])):
+        args = dict(p["attrs"], seq=p["seq"], parent=p["parent"])
+        if p["trace_id"]:
+            args["trace_id"] = p["trace_id"]
+        ev = {"name": "edl/" + p["name"], "cat": p["service"],
+              "pid": pid_of[p["service"]], "tid": 0,
+              "ts": p["start_ns"] / 1e3, "args": args}
+        if p["name"] in _COUNTERS:
+            ev.update(ph="i", s="t")
+        else:
+            ev.update(ph="X", dur=(p["end_ns"] - p["start_ns"]) / 1e3)
+        events.append(ev)
+    return events
+
+
+def chrome_trace(span_dicts, phase_dicts=()):
     """Convert merged span dicts into Chrome-trace JSON (the "JSON
     Array Format" both chrome://tracing and Perfetto ingest).
 
@@ -407,8 +672,11 @@ def chrome_trace(span_dicts):
     one "thread" per trace within it — so opening the file shows each
     request's spans stacked on one row, per tier. Every slice carries
     trace_id/span_id/parent_span_id (plus the span attrs and status)
-    in ``args``; span events become instant events on the same row."""
-    services = sorted({s["service"] for s in span_dicts})
+    in ``args``; span events become instant events on the same row.
+    `phase_dicts` (the same processes' phase rings, on the same wall
+    clock) land on a "phases" row of their service."""
+    services = sorted({s["service"] for s in span_dicts}
+                      | {p["service"] for p in phase_dicts})
     pid_of = {svc: i + 1 for i, svc in enumerate(services)}
     tid_of = {}
     events = []
@@ -445,4 +713,5 @@ def chrome_trace(span_dicts):
                              trace_id=s["trace_id"],
                              span_id=s["span_id"]),
             })
+    events.extend(_chrome_phases(phase_dicts, pid_of))
     return {"traceEvents": events, "displayTimeUnit": "ms"}

@@ -791,7 +791,7 @@ def _paged_decode_fused(qf, k_pool, v_pool, block_table, length, t,
     kernel = functools.partial(
         _paged_kernel, m=m, bs=bs, window=window, quantized=quantized,
     )
-    o, l, mx = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -813,7 +813,12 @@ def _paged_decode_fused(qf, k_pool, v_pool, block_table, length, t,
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret_mode(),
-    )(tbl, ln, *inputs)
+    )
+    # the scope names the kernel's HLO instruction and so its event in
+    # a device trace (`paged_decode.N`); pallas_call's own `name=` would
+    # do the same but also replace the custom call's `kernel_name`
+    with jax.named_scope("paged_decode"):
+        o, l, mx = call(tbl, ln, *inputs)
     o = o.reshape(b, hkv, n_rows, d)[:, :, :gt]
     l = l.reshape(b, hkv, n_rows)[:, :, :gt]
     mx = mx.reshape(b, hkv, n_rows)[:, :, :gt]
@@ -1272,7 +1277,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             q_seg.reshape(b, lq, 1),
             k_seg.reshape(b, 1, lk),
         ]
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_k),
         in_specs=in_specs,
@@ -1293,7 +1298,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         ],
         compiler_params=_mosaic_params(),
         interpret=interpret_mode() if interpret is None else interpret,
-    )(*inputs)
+    )
+    with jax.named_scope("flash_fwd"):  # its event in a device trace
+        out, lse = call(*inputs)
     out = out.reshape(b, h, lq, d)
     if with_residuals:
         return out, lse.reshape(b, h, lq, 1)
@@ -1474,7 +1481,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
     if segments is not None:
         dq_in_specs += list(_seg_specs(block_q, block_k, h,
                                        clamp=kv_clamp))
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, scale=scale, causal=causal,
             window=window, block_q=block_q, block_k=block_k, n_k=n_k,
@@ -1487,7 +1494,9 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_mosaic_params(),
         interpret=interp,
-    )(q3, k3, v3, do3, lse3, delta3, *seg_inputs)
+    )
+    with jax.named_scope("flash_bwd_dq"):
+        dq = dq_call(q3, k3, v3, do3, lse3, delta3, *seg_inputs)
 
     # key-block-parallel pass: q-side inputs stream over the inner dim
     # (all (group, q_block) pairs under GQA)
@@ -1505,7 +1514,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
             _seg_specs(block_q, block_k, hkv, dkv=True, n_q=n_q,
                        clamp=q_clamp)
         )
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, scale=scale, causal=causal,
             window=window, block_q=block_q, block_k=block_k, n_q=n_q,
@@ -1525,7 +1534,9 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
         ],
         compiler_params=_mosaic_params(),
         interpret=interp,
-    )(q3, k3, v3, do3, lse3, delta3, *seg_inputs)
+    )
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = dkv_call(q3, k3, v3, do3, lse3, delta3, *seg_inputs)
     return (
         dq.reshape(b, h, lq, d),
         dk.reshape(b, hkv, lk, d),

@@ -59,7 +59,10 @@ def chunked_softmax_xent(hidden, kernel, labels, num_chunks=8):
         h, y = hy
         return None, chunk_fn(h, kernel, y)
 
-    _, ce = jax.lax.scan(body, None, (h_chunks, y_chunks))
+    # the scope puts "fused_head" into the op_name of the loop and of
+    # everything in its body, for whoever reads a device trace
+    with jax.named_scope("fused_head"):
+        _, ce = jax.lax.scan(body, None, (h_chunks, y_chunks))
     return ce.swapaxes(0, 1).reshape(b, num_chunks * c)[:, :s]
 
 

@@ -57,7 +57,6 @@ and HBM bandwidth, not latency, bounds the step).
 """
 
 import os
-import threading
 import time
 
 import jax
@@ -73,8 +72,7 @@ from elasticdl_tpu.api.generation import (
     serving_next_token,
 )
 from elasticdl_tpu.common.log_utils import default_logger as logger
-from elasticdl_tpu.observability.histogram import LogLinearHistogram
-from elasticdl_tpu.observability.metrics import hist_family
+from elasticdl_tpu.observability import tracing
 from elasticdl_tpu.observability.runtime_health import tracked_jit
 
 
@@ -145,113 +143,10 @@ def role_default():
     return role
 
 
-def profile_default():
-    """EDL_PROFILE resolves the per-step decode profiler when the
-    config leaves it unset (off by default: the disabled engine does
-    no timing work at all)."""
-    return os.environ.get("EDL_PROFILE", "") not in ("", "0")
-
-
-class StepProfiler(object):
-    """Per-step decode profiler: where inside a serving step does time
-    go? Each PHASE is one host-visible region of the engine's work,
-    timed wall-clock with the produced device values blocked on (so
-    async dispatch can't smear a phase into its successor) and
-    recorded into a per-phase log-linear histogram — the same bucket
-    scheme as every latency surface, so phase p99s are comparable
-    with TTFT/step percentiles and render as one more histogram
-    family on /metrics (`edl_serving_phase_ms{phase=...}`).
-
-    Phase taxonomy (closed set — observe() raises on anything else,
-    the telemetry-counter contract):
-
-        prefill        full-prompt prefill forward + cache/block write
-        suffix_tile    shared-prefix suffix tile over resident blocks
-        prefill_tile   one chunked-prefill tile: a fixed-token chunk
-                       of a long prompt run between decode ticks (the
-                       scheduler prices its per-tick chunk budget off
-                       this phase's percentiles)
-        decode         the plain vmapped single-token step (model
-                       apply + sample; paged: minus the row scatter,
-                       which times separately)
-        draft          draft-model work: draft prefill at seat time +
-                       the k-token draft scan each speculative tick
-        verify_commit  the target's (k+1)-tile verify + accept/commit
-                       math of the speculative tick
-        scatter        row scatter into the paged arenas (plain and
-                       speculative ticks)
-        revive_upload  host->device batched revival scatter of spilled
-                       prefix chains (tiered KV)
-        reload_swap    hot checkpoint swap (set_params, dequantize
-                       included)
-
-    Enabled, the PAGED step runs as SPLIT compiled functions (decode |
-    scatter; draft | verify | scatter) — mathematically identical to
-    the fused step (the splits pass the same arrays through the host
-    boundary; the e2e battery pins token parity with the profiler ON),
-    trading only cross-phase fusion for attribution. Disabled
-    (engine.profiler is None) the engine keeps the fused executables
-    and does NO timing work — the serve-smoke overhead A/B bounds the
-    enabled cost at 5%.
-
-    Thread-safety: the scheduler thread records, the metrics HTTP
-    thread snapshots — one lock, record is O(1)."""
-
-    PHASES = ("prefill", "suffix_tile", "prefill_tile", "decode",
-              "draft", "verify_commit", "scatter", "revive_upload",
-              "reload_swap")
-
-    def __init__(self, clock=time.perf_counter):
-        self._clock = clock
-        self._lock = threading.Lock()
-        self.hists = {p: LogLinearHistogram() for p in self.PHASES}
-
-    def t(self):
-        """The profiler's clock (engine call sites time around their
-        own block_until_ready, so the clock is part of the API)."""
-        return self._clock()
-
-    def observe(self, phase, secs):
-        with self._lock:
-            if phase not in self.hists:
-                raise ValueError(
-                    "unknown profiler phase %r (declared: %s)"
-                    % (phase, ", ".join(self.PHASES))
-                )
-            self.hists[phase].record(secs * 1000.0)
-
-    def snapshot(self):
-        """{phase: {count, p50_ms, p99_ms, total_ms}} for phases that
-        recorded anything — the bench's BENCH_SERVING.json shape."""
-        with self._lock:
-            out = {}
-            for phase in self.PHASES:
-                h = self.hists[phase]
-                if not h.count:
-                    continue
-                out[phase] = {
-                    "count": h.count,
-                    "p50_ms": round(h.percentile(50), 3),
-                    "p99_ms": round(h.percentile(99), 3),
-                    "total_ms": round(h.sum, 3),
-                }
-            return out
-
-    def prometheus(self):
-        """One labeled histogram family: edl_serving_phase_ms with a
-        `phase` label per declared phase that recorded samples."""
-        with self._lock:
-            series = [
-                ({"phase": phase}, self.hists[phase].to_counts(),
-                 self.hists[phase].sum)
-                for phase in self.PHASES if self.hists[phase].count
-            ]
-            return [hist_family(
-                "edl_serving_phase_ms",
-                "per-step decode profiler: wall ms per phase (shared "
-                "log-linear scheme)",
-                series,
-            )]
+def _trace_id(request):
+    """The request's trace id for a phase that serves it ("" for the
+    bare requests of tests and benches)."""
+    return getattr(request, "trace_id", "") or ""
 
 
 class _Slot(object):
@@ -311,10 +206,6 @@ class ContinuousBatchingEngine(object):
         # the engine reports prefix-share / CoW / draft-accept events
         # it alone can see; None costs nothing (tests, benches)
         self.telemetry = None
-        # optional per-step decode profiler (StepProfiler; the server
-        # wires it under ServingConfig.profile / EDL_PROFILE). None =
-        # fused executables, no timing work at all
-        self.profiler = None
         # optional recompile sentry (runtime_health.RecompileSentry;
         # the server attaches it under ServingConfig.runtime_health).
         # Every jit site below compiles through _tjit, which resolves
@@ -390,11 +281,10 @@ class ContinuousBatchingEngine(object):
         the ONE place the weights dequantize: the cached float tree in
         `_exec_variables` serves every prefill/decode step until the
         next reload invalidates it here."""
-        # reload_swap phase: the profiler attribute only exists after
-        # __init__ assigns it, and the FIRST set_params (construction)
-        # is not a reload — getattr keeps both true
-        prof = getattr(self, "profiler", None)
-        t0 = prof.t() if prof is not None else 0.0
+        # the FIRST set_params (construction, before the slots exist)
+        # is not a reload and records no phase
+        swap = (tracing.begin("reload_swap", version=int(version))
+                if hasattr(self, "_slots") else None)
         self.variables = {"params": state.params, **state.model_state}
         from elasticdl_tpu.api.quantization import is_quantized
 
@@ -421,9 +311,8 @@ class ContinuousBatchingEngine(object):
                 self._exec_variables = self._dequant_fn(self.variables)
         else:
             self._exec_variables = self.variables
-        if prof is not None:
-            jax.block_until_ready(self._exec_variables)
-            prof.observe("reload_swap", prof.t() - t0)
+        if swap is not None:
+            tracing.end(swap)
 
     # ------------------------------------------------------------- slots
 
@@ -500,20 +389,17 @@ class ContinuousBatchingEngine(object):
             self._prefill_fns[p_pad] = fn
         buf = np.zeros((1, self.seq_len), np.int32)
         buf[0, :p] = request.prompt
-        prof = self.profiler
-        t0 = prof.t() if prof is not None else 0.0
-        with self.trainer.mesh:
-            kv, first = fn(
-                self._exec_variables, jnp.asarray(buf),
-                jnp.asarray(p, jnp.int32),
-                jnp.asarray(request.seed, jnp.int32),
-                jnp.asarray(request.temperature, jnp.float32),
-            )
-            self._pool = self._write_slot(kv, slot)
-        if prof is not None:
-            jax.block_until_ready(self._pool)
-            prof.observe("prefill", prof.t() - t0)
-        first = int(first)
+        with tracing.phase("prefill", trace_id=_trace_id(request),
+                           prompt_tokens=p, bucket=p_pad):
+            with self.trainer.mesh:
+                kv, first = fn(
+                    self._exec_variables, jnp.asarray(buf),
+                    jnp.asarray(p, jnp.int32),
+                    jnp.asarray(request.seed, jnp.int32),
+                    jnp.asarray(request.temperature, jnp.float32),
+                )
+                self._pool = self._write_slot(kv, slot)
+            first = int(first)  # the host waits for the first token
         # lifecycle annotation on the request's serve span (no-op for
         # untraced requests): which prefill bucket this paid for
         if hasattr(request, "trace_event"):
@@ -560,31 +446,31 @@ class ContinuousBatchingEngine(object):
             return []
         if self._step_fn is None:
             self._step_fn = self._build_step()
-        prof = self.profiler
-        t0 = prof.t() if prof is not None else 0.0
         with self.trainer.mesh:
-            self._pool, nxt = self._step_fn(
-                self._exec_variables, self._pool,
-                jnp.asarray(self._last_tokens),
-                jnp.asarray(self._seeds),
-                jnp.asarray(self._temps),
-            )
-            nxt = np.asarray(nxt)  # blocks on the step
-        if prof is not None:
-            prof.observe("decode", prof.t() - t0)
+            with tracing.phase("tick.upload"):
+                args = (jnp.asarray(self._last_tokens),
+                        jnp.asarray(self._seeds),
+                        jnp.asarray(self._temps))
+            with tracing.phase("tick.dispatch"):
+                self._pool, nxt = self._step_fn(
+                    self._exec_variables, self._pool, *args
+                )
+            with tracing.phase("tick.fetch"):
+                nxt = np.asarray(nxt)  # blocks on the step
         out = []
-        for slot, st in active:
-            token = int(nxt[slot])
-            st.request.generated.append(token)
-            st.request.model_version = self.model_version
-            self._last_tokens[slot] = token
-            finished = (
-                len(st.request.prompt) + len(st.request.generated)
-                >= st.max_total
-            )
-            if finished:
-                self.evict(slot)
-            out.append((slot, st.request, [token], finished))
+        with tracing.phase("tick.commit"):
+            for slot, st in active:
+                token = int(nxt[slot])
+                st.request.generated.append(token)
+                st.request.model_version = self.model_version
+                self._last_tokens[slot] = token
+                finished = (
+                    len(st.request.prompt) + len(st.request.generated)
+                    >= st.max_total
+                )
+                if finished:
+                    self.evict(slot)
+                out.append((slot, st.request, [token], finished))
         return out
 
     # ------------------------------------------------------- compiled fns
@@ -786,8 +672,6 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._positions = np.zeros(self.num_slots, np.int32)
         self._suffix_fns = {}  # suffix bucket -> compiled tile prefill
         self._spec_fn = None
-        self._step_fns_split = None  # (decode, scatter) when profiling
-        self._spec_fns_split = None  # (draft, verify, scatter)
         # last-forwarded pool counters: the engine mirrors the pool's
         # monotone spill/revival counters into the closed telemetry
         # set by DELTA, so the event file stays in lockstep with the
@@ -860,18 +744,6 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._d_write_fn = None
 
     # ------------------------------------------------------------ params
-
-    @property
-    def profiler(self):
-        return self._profiler
-
-    @profiler.setter
-    def profiler(self, value):
-        # the paged pool times its own revive uploads (the one phase
-        # only it can see), so the profiler forwards to it
-        self._profiler = value
-        if hasattr(self, "kv"):
-            self.kv.profiler = value
 
     @property
     def sentry(self):
@@ -985,21 +857,18 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 self._prefill_fns[p_pad] = fn
             buf = np.zeros((1, self.seq_len), np.int32)
             buf[0, :p] = request.prompt
-            prof = self.profiler
-            t0 = prof.t() if prof is not None else 0.0
-            with self.trainer.mesh:
-                kv, first = fn(
-                    self._exec_variables, jnp.asarray(buf),
-                    jnp.asarray(p, jnp.int32),
-                    jnp.asarray(request.seed, jnp.int32),
-                    jnp.asarray(request.temperature, jnp.float32),
-                )
-                if decoding:
-                    self.kv.write_prompt(kv, slot, p)
-            if prof is not None:
-                jax.block_until_ready(self.kv.pools if decoding else first)
-                prof.observe("prefill", prof.t() - t0)
-            first = int(first)
+            with tracing.phase("prefill", trace_id=_trace_id(request),
+                               prompt_tokens=p, bucket=p_pad):
+                with self.trainer.mesh:
+                    kv, first = fn(
+                        self._exec_variables, jnp.asarray(buf),
+                        jnp.asarray(p, jnp.int32),
+                        jnp.asarray(request.seed, jnp.int32),
+                        jnp.asarray(request.temperature, jnp.float32),
+                    )
+                    if decoding:
+                        self.kv.write_prompt(kv, slot, p)
+                first = int(first)  # the host waits for the token
             if hasattr(request, "trace_event"):
                 request.trace_event("prefill", bucket=p_pad, slot=slot,
                                     paged=True)
@@ -1052,21 +921,19 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             self._suffix_fns[t_pad] = fn
         chunk = np.zeros((1, t_pad), np.int32)
         chunk[0, :t] = request.prompt[start:]
-        prof = self.profiler
-        t0 = prof.t() if prof is not None else 0.0
-        with self.trainer.mesh:
-            self.kv.pools, first = fn(
-                self._exec_variables, self.kv.pools,
-                jnp.asarray(self.kv.tables[slot]),
-                jnp.asarray(chunk),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(t, jnp.int32),
-                jnp.asarray(request.seed, jnp.int32),
-                jnp.asarray(request.temperature, jnp.float32),
-            )
-        if prof is not None:
-            jax.block_until_ready(self.kv.pools)
-            prof.observe("suffix_tile", prof.t() - t0)
+        with tracing.phase("suffix_tile", trace_id=_trace_id(request),
+                           suffix_tokens=t, bucket=t_pad):
+            with self.trainer.mesh:
+                self.kv.pools, first = fn(
+                    self._exec_variables, self.kv.pools,
+                    jnp.asarray(self.kv.tables[slot]),
+                    jnp.asarray(chunk),
+                    jnp.asarray(start, jnp.int32),
+                    jnp.asarray(t, jnp.int32),
+                    jnp.asarray(request.seed, jnp.int32),
+                    jnp.asarray(request.temperature, jnp.float32),
+                )
+            first = int(first)  # the host waits for the first token
         if self.telemetry is not None:
             # count the allocator-reported shared tokens so this stays
             # in lockstep with BlockAllocator.prefix_hit_tokens (start
@@ -1075,7 +942,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         if hasattr(request, "trace_event"):
             request.trace_event("prefix_hit", slot=slot,
                                 shared_tokens=start, suffix_tokens=t)
-        return int(first)
+        return first
 
     # --------------------------------------------------- chunked prefill
 
@@ -1173,9 +1040,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             self._suffix_fns[t_pad] = fn
         chunk = np.zeros((1, t_pad), np.int32)
         chunk[0, :t] = request.prompt[job.pos:job.pos + t]
-        prof = self.profiler
-        t0 = prof.t() if prof is not None else 0.0
-        with self.trainer.mesh:
+        with tracing.phase("prefill_tile", trace_id=_trace_id(request),
+                           tile_tokens=t, bucket=t_pad), \
+                self.trainer.mesh:
             self.kv.pools, first = fn(
                 self._exec_variables, self.kv.pools,
                 jnp.asarray(self.kv.tables[slot]),
@@ -1185,9 +1052,6 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 jnp.asarray(request.seed, jnp.int32),
                 jnp.asarray(request.temperature, jnp.float32),
             )
-        if prof is not None:
-            jax.block_until_ready(self.kv.pools)
-            prof.observe("prefill_tile", prof.t() - t0)
         job.pos += t
         job.tiles += 1
         if not final:
@@ -1249,15 +1113,12 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             self._d_prefill_fns[p_pad] = fn
         buf = np.zeros((1, self.seq_len), np.int32)
         buf[0, :p] = request.prompt
-        prof = self.profiler
-        t0 = prof.t() if prof is not None else 0.0
-        with self.trainer.mesh:
+        with tracing.phase("draft", trace_id=_trace_id(request),
+                           prompt_tokens=p, bucket=p_pad), \
+                self.trainer.mesh:
             d_kv = fn(self._d_variables, jnp.asarray(buf),
                       jnp.asarray(p, jnp.int32))
             self._write_draft_slot(d_kv, slot)
-        if prof is not None:
-            jax.block_until_ready(self._d_pool)
-            prof.observe("draft", prof.t() - t0)
 
     def _suffix_bucket(self, t):
         """Static tile widths for the suffix prefill, in steps of 8 so
@@ -1289,42 +1150,45 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             return []
         if self.draft_k:
             return self._spec_step(active)
-        for i, _st in active:
-            # the block this step writes (position = the slot's pos);
-            # drawn from the slot's reservation, so it cannot fail
-            self.kv.ensure_blocks(i, int(self._positions[i]))
-        # an extend's pop can spill under pressure: keep the telemetry
-        # mirror current even on decode-only ticks
-        self._sync_host_telemetry()
-        if self.profiler is not None:
-            nxt = self._profiled_step()
-        else:
-            if self._step_fn is None:
-                self._step_fn = self._build_paged_step()
-            with self.trainer.mesh:
+        with tracing.phase("tick.ensure"):
+            for i, _st in active:
+                # the block this step writes (position = the slot's
+                # pos); drawn from the slot's reservation, so it
+                # cannot fail
+                self.kv.ensure_blocks(i, int(self._positions[i]))
+            # an extend's pop can spill under pressure: keep the
+            # telemetry mirror current even on decode-only ticks
+            self._sync_host_telemetry()
+        if self._step_fn is None:
+            self._step_fn = self._build_paged_step()
+        with self.trainer.mesh:
+            with tracing.phase("tick.upload"):
+                args = (self.kv.tables_device(),
+                        jnp.asarray(self._positions),
+                        jnp.asarray(self._last_tokens),
+                        jnp.asarray(self._seeds),
+                        jnp.asarray(self._temps))
+            with tracing.phase("tick.dispatch"):
                 self.kv.pools, nxt = self._step_fn(
-                    self._exec_variables, self.kv.pools,
-                    self.kv.tables_device(),
-                    jnp.asarray(self._positions),
-                    jnp.asarray(self._last_tokens),
-                    jnp.asarray(self._seeds),
-                    jnp.asarray(self._temps),
+                    self._exec_variables, self.kv.pools, *args
                 )
-                nxt = np.asarray(nxt)
+            with tracing.phase("tick.fetch"):
+                nxt = np.asarray(nxt)  # the host waits for the device
         out = []
-        for slot, st in active:
-            self._positions[slot] += 1
-            token = int(nxt[slot])
-            st.request.generated.append(token)
-            st.request.model_version = self.model_version
-            self._last_tokens[slot] = token
-            finished = (
-                len(st.request.prompt) + len(st.request.generated)
-                >= st.max_total
-            )
-            if finished:
-                self.evict(slot)
-            out.append((slot, st.request, [token], finished))
+        with tracing.phase("tick.commit"):
+            for slot, st in active:
+                self._positions[slot] += 1
+                token = int(nxt[slot])
+                st.request.generated.append(token)
+                st.request.model_version = self.model_version
+                self._last_tokens[slot] = token
+                finished = (
+                    len(st.request.prompt) + len(st.request.generated)
+                    >= st.max_total
+                )
+                if finished:
+                    self.evict(slot)
+                out.append((slot, st.request, [token], finished))
         return out
 
     def _spec_step(self, active):
@@ -1335,59 +1199,61 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         the draft's rollback is counter-only."""
         k = self.draft_k
         budgets = np.ones(self.num_slots, np.int32)
-        for i, st in active:
-            pos = int(self._positions[i])
-            # materialize every block this tick MIGHT write (rows
-            # pos..pos+k, capped at the slot's last needed row) —
-            # reservation-backed, cannot fail for a seated request
-            self.kv.ensure_blocks(i, min(pos + k, st.max_total - 2))
-            budgets[i] = st.max_total - (
-                len(st.request.prompt) + len(st.request.generated)
-            )
-        self._sync_host_telemetry()  # ensure_blocks pops can spill
-        if self.profiler is not None:
-            toks, counts = self._profiled_spec_step(budgets)
-        else:
-            if self._spec_fn is None:
-                self._spec_fn = self._build_spec_step()
-            with self.trainer.mesh:
-                self.kv.pools, self._d_pool, toks, counts = (
-                    self._spec_fn(
-                        self._exec_variables, self._d_variables,
-                        self.kv.pools, self._d_pool,
-                        self.kv.tables_device(),
+        with tracing.phase("tick.ensure"):
+            for i, st in active:
+                pos = int(self._positions[i])
+                # materialize every block this tick MIGHT write (rows
+                # pos..pos+k, capped at the slot's last needed row) —
+                # reservation-backed, cannot fail for a seated request
+                self.kv.ensure_blocks(i, min(pos + k, st.max_total - 2))
+                budgets[i] = st.max_total - (
+                    len(st.request.prompt) + len(st.request.generated)
+                )
+            self._sync_host_telemetry()  # ensure_blocks pops can spill
+        if self._spec_fn is None:
+            self._spec_fn = self._build_spec_step()
+        with self.trainer.mesh:
+            with tracing.phase("tick.upload"):
+                args = (self.kv.tables_device(),
                         jnp.asarray(self._positions),
                         jnp.asarray(self._last_tokens),
                         jnp.asarray(self._seeds),
                         jnp.asarray(self._temps),
-                        jnp.asarray(budgets),
+                        jnp.asarray(budgets))
+            with tracing.phase("tick.dispatch"):
+                self.kv.pools, self._d_pool, toks, counts = (
+                    self._spec_fn(
+                        self._exec_variables, self._d_variables,
+                        self.kv.pools, self._d_pool, *args
                     )
                 )
+            with tracing.phase("tick.fetch"):
                 toks = np.asarray(toks)
                 counts = np.asarray(counts)
         out = []
         accepted = 0
-        for slot, st in active:
-            c = int(counts[slot])
-            committed = [int(x) for x in toks[slot, :c]]
-            st.request.generated.extend(committed)
-            st.request.model_version = self.model_version
-            self._positions[slot] += c
-            self._last_tokens[slot] = committed[-1]
-            accepted += c - 1
-            finished = (
-                len(st.request.prompt) + len(st.request.generated)
-                >= st.max_total
-            )
-            if finished:
-                self.evict(slot)
-            out.append((slot, st.request, committed, finished))
-        self.draft_proposed += k * len(active)
-        self.draft_accepted += accepted
-        if self.telemetry is not None:
-            self.telemetry.count("draft_proposed", k * len(active))
-            if accepted:
-                self.telemetry.count("draft_accepted", accepted)
+        with tracing.phase("tick.commit"):
+            for slot, st in active:
+                c = int(counts[slot])
+                committed = [int(x) for x in toks[slot, :c]]
+                st.request.generated.extend(committed)
+                st.request.model_version = self.model_version
+                self._positions[slot] += c
+                self._last_tokens[slot] = committed[-1]
+                accepted += c - 1
+                finished = (
+                    len(st.request.prompt) + len(st.request.generated)
+                    >= st.max_total
+                )
+                if finished:
+                    self.evict(slot)
+                out.append((slot, st.request, committed, finished))
+            self.draft_proposed += k * len(active)
+            self.draft_accepted += accepted
+            if self.telemetry is not None:
+                self.telemetry.count("draft_proposed", k * len(active))
+                if accepted:
+                    self.telemetry.count("draft_accepted", accepted)
         return out
 
     # ------------------------------------------------------- compiled fns
@@ -1409,14 +1275,16 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 # token lands at pos + 1. The cache collection carries
                 # ONLY the counter — the rows live in the shared
                 # arenas, read through this slot's table and written
-                # back via the sown "kv_out" rows.
-                logits, aux = model.apply(
-                    dict(variables, cache={"pos": pos}),
-                    {"tokens": tok[None, None]},
-                    training=False, decode=True,
-                    mutable=["cache", "kv_out"],
-                    paged={"pools": pools, "table": table[None]},
-                )
+                # back via the sown "kv_out" rows. The scope is the
+                # per-slot body's op_name in a device trace.
+                with jax.named_scope("paged_slot"):
+                    logits, aux = model.apply(
+                        dict(variables, cache={"pos": pos}),
+                        {"tokens": tok[None, None]},
+                        training=False, decode=True,
+                        mutable=["cache", "kv_out"],
+                        paged={"pools": pools, "table": table[None]},
+                    )
                 nxt = serving_next_token(
                     logits[0, 0], seed, pos + 1, temp, top_k, top_p
                 )
@@ -1445,219 +1313,6 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             self.block_size,
         )
         return self._tjit("paged_step", step)
-
-    # ------------------------------------------- profiled (split) steps
-
-    def _profiled_step(self):
-        """The plain paged tick with the profiler on: the SAME math as
-        the fused step, split at the decode|scatter boundary so each
-        phase times against blocked outputs. Returns the sampled
-        tokens as a numpy array (the fused path's contract)."""
-        prof = self.profiler
-        if self._step_fns_split is None:
-            self._step_fns_split = self._build_paged_step_split()
-        decode_fn, scatter_fn = self._step_fns_split
-        with self.trainer.mesh:
-            tables = self.kv.tables_device()
-            positions = jnp.asarray(self._positions)
-            t0 = prof.t()
-            nxt, rows = decode_fn(
-                self._exec_variables, self.kv.pools, tables,
-                positions, jnp.asarray(self._last_tokens),
-                jnp.asarray(self._seeds), jnp.asarray(self._temps),
-            )
-            jax.block_until_ready(nxt)
-            prof.observe("decode", prof.t() - t0)
-            t0 = prof.t()
-            self.kv.pools = scatter_fn(
-                self.kv.pools, rows, tables, positions
-            )
-            jax.block_until_ready(self.kv.pools)
-            prof.observe("scatter", prof.t() - t0)
-            return np.asarray(nxt)
-
-    def _profiled_spec_step(self, budgets):
-        """The speculative tick with the profiler on, split at the
-        draft|verify|scatter boundaries (same arrays cross the host
-        boundary that the fused step keeps on device — token streams
-        are identical, pinned by the e2e battery)."""
-        prof = self.profiler
-        if self._spec_fns_split is None:
-            self._spec_fns_split = self._build_spec_step_split()
-        draft_fn, verify_fn, scatter_fn = self._spec_fns_split
-        with self.trainer.mesh:
-            tables = self.kv.tables_device()
-            positions = jnp.asarray(self._positions)
-            t0 = prof.t()
-            self._d_pool, d_toks, chunk = draft_fn(
-                self._d_variables, self._d_pool, positions,
-                jnp.asarray(self._last_tokens),
-            )
-            jax.block_until_ready(chunk)
-            prof.observe("draft", prof.t() - t0)
-            t0 = prof.t()
-            toks, counts, rows, bids, offs = verify_fn(
-                self._exec_variables, self.kv.pools, tables,
-                positions, chunk, d_toks,
-                jnp.asarray(self._seeds), jnp.asarray(self._temps),
-                jnp.asarray(budgets),
-            )
-            jax.block_until_ready(toks)
-            prof.observe("verify_commit", prof.t() - t0)
-            t0 = prof.t()
-            self.kv.pools = scatter_fn(self.kv.pools, rows, bids, offs)
-            jax.block_until_ready(self.kv.pools)
-            prof.observe("scatter", prof.t() - t0)
-            return np.asarray(toks), np.asarray(counts)
-
-    def _build_paged_step_split(self):
-        """The fused `_build_paged_step` math as two executables:
-        decode (model apply + sample, rows sown out) and scatter (row
-        write into the arenas). Only cross-phase fusion is given up —
-        every op and every mask is the fused step's."""
-        from elasticdl_tpu.serving.kv_pool import scatter_rows
-
-        model = self.model
-        top_k, top_p, qz = self.top_k, self.top_p, self._exec_qz
-        block_size, num_blocks = self.block_size, self.num_blocks
-
-        def decode(variables, pools, tables, positions, last_tokens,
-                   seeds, temps):
-            variables = _maybe_dequantize(variables, qz)
-
-            def one(table, pos, tok, seed, temp):
-                logits, aux = model.apply(
-                    dict(variables, cache={"pos": pos}),
-                    {"tokens": tok[None, None]},
-                    training=False, decode=True,
-                    mutable=["cache", "kv_out"],
-                    paged={"pools": pools, "table": table[None]},
-                )
-                nxt = serving_next_token(
-                    logits[0, 0], seed, pos + 1, temp, top_k, top_p
-                )
-                rows = jax.tree.map(
-                    lambda t: t[0][0, :, 0, :], aux["kv_out"],
-                    is_leaf=lambda x: isinstance(x, tuple),
-                )
-                return nxt, rows
-
-            return jax.vmap(one)(
-                tables, positions, last_tokens, seeds, temps
-            )
-
-        def scatter(pools, rows, tables, positions):
-            bids = jnp.take_along_axis(
-                tables, (positions // block_size)[:, None], axis=1
-            )[:, 0]
-            bids = jnp.where(bids < 0, num_blocks, bids)
-            return scatter_rows(pools, rows, bids,
-                                positions % block_size)
-
-        logger.info(
-            "serving: compiling SPLIT (profiled) paged decode step "
-            "for %d slots", self.num_slots,
-        )
-        return (self._tjit("paged_decode.split", decode),
-                self._tjit("paged_scatter.split", scatter))
-
-    def _build_spec_step_split(self):
-        """The fused `_build_spec_step` math as three executables —
-        draft scan | target verify + accept/commit | row scatter —
-        for phase attribution under the profiler."""
-        from elasticdl_tpu.serving.kv_pool import scatter_rows
-
-        model, d_model = self.model, self._d_model
-        top_k, top_p, qz = self.top_k, self.top_p, self._exec_qz
-        block_size, num_blocks = self.block_size, self.num_blocks
-        max_blocks = self.kv.max_blocks_per_slot
-        k = self.draft_k
-
-        def draft(d_variables, d_pool, positions, last_tokens):
-            d_pool_f = dict(d_pool, pos=positions)
-
-            def d_one(cache, tok):
-                lg, upd = d_model.apply(
-                    dict(d_variables, cache=cache),
-                    {"tokens": tok[None, None]},
-                    training=False, decode=True, mutable=["cache"],
-                )
-                nxt = jnp.argmax(lg[0, 0], axis=-1).astype(jnp.int32)
-                return upd["cache"], nxt
-
-            def d_scan(carry, _):
-                cache, tok = carry
-                cache, nxt = jax.vmap(d_one)(cache, tok)
-                return (cache, nxt), nxt
-
-            (d_pool_out, _), d_seq = jax.lax.scan(
-                d_scan, (d_pool_f, last_tokens), None, length=k
-            )
-            d_toks = jnp.moveaxis(d_seq, 0, 1)
-            chunk = jnp.concatenate(
-                [last_tokens[:, None], d_toks], axis=1
-            )
-            return d_pool_out, d_toks, chunk
-
-        def verify(variables, pools, tables, positions, chunk, d_toks,
-                   seeds, temps, budgets):
-            variables = _maybe_dequantize(variables, qz)
-
-            def v_one(table, pos, toks):
-                logits, aux = model.apply(
-                    dict(variables, cache={"pos": pos}),
-                    {"tokens": toks[None]},
-                    training=False, decode=True,
-                    mutable=["cache", "kv_out"],
-                    paged={"pools": pools, "table": table[None]},
-                )
-                g = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
-                rows = jax.tree.map(
-                    lambda s: s[0][0].transpose(1, 0, 2),
-                    aux["kv_out"],
-                    is_leaf=lambda x: isinstance(x, tuple),
-                )
-                return logits[0], g, rows
-
-            logits, g, rows = jax.vmap(v_one)(tables, positions, chunk)
-            match = jnp.cumprod(
-                (d_toks == g[:, :k]).astype(jnp.int32), axis=1
-            )
-            a = jnp.where(temps > 0.0, 0, match.sum(axis=1))
-            c = jnp.minimum(a + 1, jnp.maximum(budgets, 1))
-
-            def pick(lg, aa, seed, pos, temp):
-                return serving_next_token(
-                    lg[aa], seed, pos + 1 + aa, temp, top_k, top_p
-                )
-
-            bonus = jax.vmap(pick)(logits, a, seeds, positions, temps)
-            out_toks = jnp.where(
-                jnp.arange(k + 1)[None, :] == a[:, None],
-                bonus[:, None], g,
-            )
-            wpos = positions[:, None] + jnp.arange(k + 1)[None, :]
-            bids = jnp.take_along_axis(
-                tables,
-                jnp.minimum(wpos // block_size, max_blocks - 1),
-                axis=1,
-            )
-            keep = (
-                (jnp.arange(k + 1)[None, :] < c[:, None]) & (bids >= 0)
-            )
-            bids = jnp.where(keep, bids, num_blocks)
-            return out_toks, c, rows, bids, wpos % block_size
-
-        def scatter(pools, rows, bids, offs):
-            return scatter_rows(pools, rows, bids, offs)
-
-        logger.info(
-            "serving: compiling SPLIT (profiled) speculative step "
-            "(k=%d) for %d slots", k, self.num_slots,
-        )
-        return (self._tjit("spec_draft.split", draft),
-                self._tjit("spec_verify.split", verify),
-                self._tjit("spec_scatter.split", scatter))
 
     def _build_suffix_prefill(self, t_pad):
         """Compiled shared-prefix suffix prefill: decode a tile of up
@@ -1785,13 +1440,14 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             )  # [S, k+1]; row j = the token at stream position pos+j
 
             def v_one(table, pos, toks):
-                logits, aux = model.apply(
-                    dict(variables, cache={"pos": pos}),
-                    {"tokens": toks[None]},
-                    training=False, decode=True,
-                    mutable=["cache", "kv_out"],
-                    paged={"pools": pools, "table": table[None]},
-                )  # logits [1, k+1, V]: row j predicts pos + j + 1
+                with jax.named_scope("paged_slot"):
+                    logits, aux = model.apply(
+                        dict(variables, cache={"pos": pos}),
+                        {"tokens": toks[None]},
+                        training=False, decode=True,
+                        mutable=["cache", "kv_out"],
+                        paged={"pools": pools, "table": table[None]},
+                    )  # logits [1, k+1, V]: row j predicts pos + j + 1
                 g = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
                 rows = jax.tree.map(
                     lambda s: s[0][0].transpose(1, 0, 2),

@@ -108,6 +108,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from elasticdl_tpu.api.generation import kv_row_leaf
+from elasticdl_tpu.observability import tracing
 
 
 class OutOfBlocks(Exception):
@@ -761,12 +762,13 @@ def scatter_rows(pools, rows, bids, offs):
         for p, leaf in jax.tree_util.tree_flatten_with_path(rows)[0]
     }
     out = []
-    for path, pool in flat:
-        row = rmap.get(jax.tree_util.keystr(path))
-        if row is None:
-            out.append(pool)
-        else:
-            out.append(pool.at[bids, offs].set(row, mode="drop"))
+    with jax.named_scope("row_scatter"):  # op_name, for a device trace
+        for path, pool in flat:
+            row = rmap.get(jax.tree_util.keystr(path))
+            if row is None:
+                out.append(pool)
+            else:
+                out.append(pool.at[bids, offs].set(row, mode="drop"))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -867,9 +869,6 @@ class PagedKVPool(object):
         self.chain_import_tokens = 0
         self._gather_fn = None
         self._upload_fns = {}  # padded batch size -> compiled scatter
-        # optional StepProfiler (serving/engine.py): the pool times its
-        # revive uploads — the one decode phase only it can see
-        self.profiler = None
         # recompile sentry (runtime health): the engine forwards its
         # sentry so the pool's own executables (spill gather, revival
         # upload buckets, prompt write, CoW copy) count into the same
@@ -937,8 +936,7 @@ class PagedKVPool(object):
         every upload size. Revival and chain import both land here —
         the import path is the revival upload pointed at a sibling
         replica's bytes instead of this host's spill store."""
-        prof = self.profiler
-        t0 = prof.t() if prof is not None else 0.0
+        span = tracing.begin("revive_upload", blocks=len(staged))
         k = len(staged)
         k_pad = 1
         while k_pad < k:
@@ -977,9 +975,7 @@ class PagedKVPool(object):
             jnp.asarray(bids),
         )
         self.revive_uploads += 1
-        if prof is not None:
-            jax.block_until_ready(self.pools)
-            prof.observe("revive_upload", prof.t() - t0)
+        tracing.end(span)
 
     def _apply_revivals(self):
         """Upload the rows of every chain entry the last seat revived
@@ -1136,13 +1132,21 @@ class PagedKVPool(object):
                 static_argnames=("block_size",),
             )
         table = self.allocator.table(slot)
-        for j in range(start_block,
-                       blocks_for(prompt_tokens, self.block_size)):
-            self.pools = self._write_fn(
-                self.pools, kv, jnp.asarray(j, jnp.int32),
-                jnp.asarray(table[j], jnp.int32),
-                block_size=self.block_size,
-            )
+        blocks = range(start_block,
+                       blocks_for(prompt_tokens, self.block_size))
+        with tracing.phase("prompt_write", blocks=len(blocks)):
+            for j in blocks:
+                self.pools = self._write_fn(
+                    self.pools, kv, jnp.asarray(j, jnp.int32),
+                    jnp.asarray(table[j], jnp.int32),
+                    block_size=self.block_size,
+                )
+        # work done, counted where it happens: one launch per block,
+        # and the prompt tokens those blocks now hold
+        tracing.count("prompt_write.launches", len(blocks))
+        tracing.count("prompt_write.tokens",
+                      prompt_tokens - start_block * self.block_size)
+        tracing.count("prompts_prefilled")
 
     def ensure_blocks(self, slot, pos):
         """Make sure the block covering cache position `pos` exists

@@ -84,12 +84,6 @@ def parse_serving_args(args=None):
     # EDL_METRICS_PORT (unset = off), 0 = ephemeral port — the bound
     # port prints as `METRICS_READY port=N` next to the serving line
     parser.add_argument("--metrics_port", type=int, default=-1)
-    # per-step decode profiler (engine.StepProfiler): phase timers
-    # around prefill / suffix tile / draft / verify / scatter / revive
-    # upload / reload swap; -1 resolves from EDL_PROFILE, default off
-    # (disabled = zero timing work)
-    parser.add_argument("--profile", type=int, default=-1,
-                        choices=(-1, 0, 1))
     # tail-forensics plane (histogram exemplars + tail-based trace
     # retention + slow-cause attribution): -1 resolves from
     # EDL_FORENSICS, default ON — priced by the bench overhead A/B
@@ -210,7 +204,6 @@ def build_server(args):
             draft_k=draft_k if draft is not None else 0,
             metrics_port=(None if args.metrics_port < 0
                           else args.metrics_port),
-            profile=None if args.profile < 0 else bool(args.profile),
             forensics=(None if args.forensics < 0
                        else bool(args.forensics)),
             runtime_health=(None if args.runtime_health < 0

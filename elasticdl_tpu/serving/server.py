@@ -37,7 +37,7 @@ from elasticdl_tpu.common.fault_injection import (
     maybe_wrap_servicer,
 )
 from elasticdl_tpu.common.log_utils import default_logger as logger
-from elasticdl_tpu.observability import forensics
+from elasticdl_tpu.observability import forensics, tracing
 from elasticdl_tpu.observability.tracing import recorder
 from elasticdl_tpu.proto import elasticdl_pb2 as pb
 from elasticdl_tpu.serving.admission import (
@@ -47,18 +47,18 @@ from elasticdl_tpu.serving.admission import (
 )
 from elasticdl_tpu.observability.metrics import (
     MetricsServer,
+    gauge_family,
+    hist_family,
     metrics_port_default,
 )
 from elasticdl_tpu.serving.engine import (
     ContinuousBatchingEngine,
     PagedContinuousBatchingEngine,
-    StepProfiler,
     kv_host_bytes_default,
     kv_paged_default,
     kv_shared_default,
     prefill_budget_default,
     prefill_chunk_default,
-    profile_default,
     role_default,
 )
 from elasticdl_tpu.observability.runtime_health import (
@@ -127,12 +127,9 @@ class ServingConfig(object):
     metrics_port (None resolves from EDL_METRICS_PORT; unset = OFF)
     arms the Prometheus-text /metrics exposition on a stdlib HTTP
     thread (observability/metrics.py): the closed telemetry sets, the
-    latency histograms and the per-step profiler phases, scrapeable by
-    anything that speaks the text format (0 = ephemeral port, for
-    drills/tests). profile (None resolves from EDL_PROFILE, default
-    off) arms the per-step decode profiler (engine.StepProfiler) —
-    phase-split compiled steps, <5% bound serve-smoke overhead; off,
-    the engine does no timing work at all."""
+    latency histograms and the loop's phase spans (tracing.phase,
+    always on), scrapeable by anything that speaks the text format
+    (0 = ephemeral port, for drills/tests)."""
 
     def __init__(self, num_slots=4, queue_capacity=64, top_k=0,
                  top_p=1.0, checkpoint_dir="", reload_poll_secs=2.0,
@@ -141,7 +138,7 @@ class ServingConfig(object):
                  port=0, max_workers=64, kv_paged=None,
                  kv_block_size=16, kv_num_blocks=0, kv_shared=None,
                  draft_k=0, kv_host_bytes=None, metrics_port=None,
-                 profile=None, forensics=None, runtime_health=None,
+                 forensics=None, runtime_health=None,
                  stall_after_secs=None, health_reconcile_secs=2.0,
                  health_dir=None, role=None, prefill_chunk_tokens=None,
                  prefill_budget_ms=None):
@@ -174,9 +171,6 @@ class ServingConfig(object):
         self.metrics_port = (
             metrics_port_default() if metrics_port is None
             else int(metrics_port)
-        )
-        self.profile = (
-            profile_default() if profile is None else bool(profile)
         )
         # the tail-forensics plane (None resolves from EDL_FORENSICS,
         # default on): histogram exemplars at the latency record
@@ -263,6 +257,7 @@ class _Scheduler(threading.Thread):
         self.prefill_budget_ms = float(prefill_budget_ms)
         self._pending_prefills = []
         self._tile_ms = 0.0  # EWMA tile cost; prices the budget check
+        self._tick_seq = 0  # the `tick` phase span's seq
         # scheduler-thread work submitted by gRPC handlers (chain
         # export/import touch the jax pool, and ALL jax work belongs
         # to this thread); submit_job blocks with a liveness bound
@@ -367,6 +362,17 @@ class _Scheduler(threading.Thread):
         return int(self.engine.model_version)
 
     def _iterate(self):
+        """One tick, under its root phase span (`tick`: seq = the tick
+        number; active = slots still seated when it ends)."""
+        tick = tracing.begin("tick", seq=self._tick_seq)
+        self._tick_seq += 1
+        try:
+            self._tick()
+        finally:
+            tracing.end(tick, active=self.engine.active_count(),
+                        queue_depth=len(self.queue))
+
+    def _tick(self):
         self._run_jobs()
         if self.watcher is not None:
             reloaded = self.watcher.poll()
@@ -390,8 +396,11 @@ class _Scheduler(threading.Thread):
             self._count_slow(req)
             req.push(("error", "DEADLINE_EXCEEDED",
                       "deadline expired mid-decode"))
-        self._fill_slots()
-        self._advance_prefills()
+        with tracing.phase("tick.admit"):
+            self._fill_slots()
+        if self._pending_prefills:
+            with tracing.phase("tick.prefill_tile"):
+                self._advance_prefills()
         if self.engine.active_count():
             if self._injector is not None:
                 # the stall drill's injection point: a delay rule
@@ -407,25 +416,27 @@ class _Scheduler(threading.Thread):
             results = self.engine.step()
             dt = self._clock() - t0
             committed = 0
-            for _slot, req, tokens, finished in results:
-                req.push(("tokens", list(tokens), req.model_version))
-                committed += len(tokens)
-                if finished:
-                    self._complete(req)
-            kv = self.engine.kv_stats()
-            self.telemetry.record_step(
-                len(self.queue), len(results), dt, committed,
-                kv_bytes_in_use=kv["kv_bytes_in_use"],
-                kv_blocks_free=kv["kv_blocks_free"],
-                kv_host_blocks=kv.get("kv_host_blocks"),
-                kv_host_bytes=kv.get("kv_host_bytes"),
-            )
-            if self.health is not None:
-                self.health.record_tick(
-                    len(self.queue), len(results), dt, committed
+            with tracing.phase("tick.stream"):
+                for _slot, req, tokens, finished in results:
+                    req.push(("tokens", list(tokens), req.model_version))
+                    committed += len(tokens)
+                    if finished:
+                        self._complete(req)
+                kv = self.engine.kv_stats()
+                self.telemetry.record_step(
+                    len(self.queue), len(results), dt, committed,
+                    kv_bytes_in_use=kv["kv_bytes_in_use"],
+                    kv_blocks_free=kv["kv_blocks_free"],
+                    kv_host_blocks=kv.get("kv_host_blocks"),
+                    kv_host_bytes=kv.get("kv_host_bytes"),
                 )
+                if self.health is not None:
+                    self.health.record_tick(
+                        len(self.queue), len(results), dt, committed
+                    )
         elif not self._pending_prefills:
-            self.queue.wait_for_work(self.idle_wait_secs)
+            with tracing.phase("idle"):
+                self.queue.wait_for_work(self.idle_wait_secs)
         # pending prefills and no decode: loop again immediately —
         # the next tick runs another budget's worth of tiles and
         # still polls admission between them
@@ -435,8 +446,8 @@ class _Scheduler(threading.Thread):
         per-tick budget. The budget bites only while decode slots are
         waiting (that is the latency being protected); at least one
         tile always runs, so prefill can never starve. Tile cost is
-        priced by an EWMA of measured tile time — the same number the
-        profiler's prefill_tile phase exports when armed."""
+        priced by an EWMA of measured tile time — the host's, as the
+        `prefill_tile` phase span records it."""
         budget = self.prefill_budget_ms
         spent, ran = 0.0, 0
         while self._pending_prefills:
@@ -1076,10 +1087,6 @@ class GenerationServer(object):
         # the engine reports the events only it can see (prefix hits,
         # CoW faults, draft accepts) through the same closed counters
         self.engine.telemetry = self.telemetry
-        # per-step decode profiler (phase-split compiled steps); the
-        # paged engine forwards it to the KV pool for revive timing
-        if cfg.profile:
-            self.engine.profiler = StepProfiler()
         # one injector serves the servicer wrapper AND the health/
         # scheduler hooks, so a single EDL_FAULT_SPEC drives a drill
         # end-to-end (rule state is shared, as it must be)
@@ -1147,12 +1154,21 @@ class GenerationServer(object):
 
     def _metrics_families(self):
         """One replica scrape: the closed telemetry sets + latency
-        histograms, plus the profiler's phase histogram when armed
-        (called on the exposition HTTP thread; each collector locks
-        itself)."""
+        histograms, plus the loop's phase spans as one labeled
+        histogram family and their ring's drop count (called on the
+        exposition HTTP thread; each collector locks itself)."""
         fams = self.telemetry.prometheus()
-        if self.engine.profiler is not None:
-            fams.extend(self.engine.profiler.prometheus())
+        fams.append(hist_family(
+            "edl_serving_phase_ms",
+            "phase spans of the scheduler tick (tracing.phase): wall "
+            "ms per phase, cumulative (shared log-linear scheme)",
+            recorder().phase_hist_series(),
+        ))
+        fams.append(gauge_family(
+            "edl_serving_phase_ring_dropped",
+            "phase spans evicted from the bounded phase ring",
+            [({}, recorder().phases_dropped)],
+        ))
         if self.health is not None:
             # the per-fn recompile family (the scalar health gauges/
             # counters already ride the closed telemetry sets)

@@ -30,6 +30,7 @@ from flax import struct
 from flax.core import FrozenDict
 
 from elasticdl_tpu.common.log_utils import default_logger as logger
+from elasticdl_tpu.observability import tracing
 from elasticdl_tpu.parallel import mesh as mesh_lib
 from elasticdl_tpu.parallel.sharding import (
     infer_state_pspec,
@@ -469,20 +470,24 @@ class Trainer(object):
         bsz = _leading_dim(features)
         weights = _make_weights(bsz, true_count)
         self._reject_spmd_host_local_path("train_step")
-        features = self._host_prepare(features)
-        # int(state.step) forces a host sync (blocks on the previous
-        # step's output); only pay it when a host/sparse tier actually
-        # consumes it, so dense models keep async dispatch overlap
-        tiers = self._host_manager is not None or self._defer_sparse
-        pre_step = int(state.step) if tiers else 0
-        scale = self._host_lr_scale(pre_step) if tiers else 1.0
-        state, loss, host_grads, sparse_aux = self._run_train_step(
-            state, features, labels, weights
-        )
-        if tiers:
-            state = self._post_step_tiers(
-                pre_step, state, host_grads, sparse_aux, scale
+        with tracing.phase("trainer.host_prepare"):
+            features = self._host_prepare(features)
+            # int(state.step) forces a host sync (blocks on the previous
+            # step's output); only pay it when a host/sparse tier
+            # actually consumes it, so dense models keep async dispatch
+            # overlap
+            tiers = self._host_manager is not None or self._defer_sparse
+            pre_step = int(state.step) if tiers else 0
+            scale = self._host_lr_scale(pre_step) if tiers else 1.0
+        with tracing.phase("trainer.dispatch"):
+            state, loss, host_grads, sparse_aux = self._run_train_step(
+                state, features, labels, weights
             )
+        if tiers:
+            with tracing.phase("trainer.post_tiers"):
+                state = self._post_step_tiers(
+                    pre_step, state, host_grads, sparse_aux, scale
+                )
         return state, loss
 
     def _host_lr_scale(self, pre_step):

@@ -143,15 +143,18 @@ def parse_args(argv=None):
     # quantized model)
     p.add_argument("--kv_cache_dtype", default="",
                    choices=("", "int8"))
-    # per-step decode profiler (serving/engine.py StepProfiler): the
-    # run records each phase's p50/p99/count under "profile" —
-    # prefill / suffix_tile / decode / draft / verify_commit /
-    # scatter / revive_upload / reload_swap
+    # the loop's phase spans (observability/tracing.py `phase`; always
+    # on in the server): the run records each phase's p50/p99/count
+    # under "profile" — tick.upload / tick.dispatch / tick.fetch /
+    # prefill / prompt_write / suffix_tile / draft / revive_upload ...
+    # — and scrapes /metrics once
     p.add_argument("--profile", action="store_true")
-    # metrics+profiler overhead A/B: run the paged+shared leg twice —
-    # plane OFF (no profiler, no /metrics server) vs ON (profiler +
-    # live exposition being scraped is the serve path under test) —
-    # and assert the ON leg's tokens/sec within OVERHEAD_BOUND of OFF
+    # observability overhead A/B: run the paged+shared leg twice —
+    # plane OFF (no /metrics server, no forensics, no runtime health)
+    # vs ON (all three; the live exposition being scraped is the serve
+    # path under test) — and assert the ON leg's tokens/sec within
+    # OVERHEAD_BOUND of OFF. The phase spans ride both legs: they have
+    # no switch
     p.add_argument("--overhead_ab", action="store_true")
     # tiered host spill (serving/kv_pool.py): host-tier capacity in
     # BLOCKS (converted to bytes at the serving rig's exact
@@ -356,11 +359,18 @@ def run_load(args, trainer, state, plan, num_slots, kv_paged,
              metrics_port=None, forensics=True, runtime_health=True):
     import jax
 
-    from elasticdl_tpu.observability.tracing import new_trace_id
+    from elasticdl_tpu.observability.tracing import (
+        new_trace_id,
+        recorder,
+    )
     from elasticdl_tpu.proto import elasticdl_pb2 as pb
     from elasticdl_tpu.proto.service import ServingStub, build_channel
     from elasticdl_tpu.serving import GenerationServer, ServingConfig
 
+    if profile:
+        # the phase histograms are the process's, cumulative: this
+        # leg's block starts from zero
+        recorder().clear_phases()
     server = GenerationServer(
         trainer, state,
         ServingConfig(
@@ -372,7 +382,6 @@ def run_load(args, trainer, state, plan, num_slots, kv_paged,
             kv_shared=kv_shared,
             draft_k=draft_k if draft is not None else 0,
             kv_host_bytes=kv_host_bytes,
-            profile=profile,
             metrics_port=metrics_port,
             forensics=forensics,
             runtime_health=runtime_health,
@@ -444,9 +453,7 @@ def run_load(args, trainer, state, plan, num_slots, kv_paged,
     status = stub.server_status(pb.ServerStatusRequest(), timeout=30)
     health_snap = (server.health.snapshot()
                    if server.health is not None else None)
-    profile_snap = None
-    if profile and server.engine.profiler is not None:
-        profile_snap = server.engine.profiler.snapshot()
+    profile_snap = recorder().phase_snapshot() if profile else None
     scrape = None
     if server.metrics is not None:
         # one real scrape through the stdlib HTTP server, validated by
@@ -560,8 +567,8 @@ def run_load(args, trainer, state, plan, num_slots, kv_paged,
                 health_snap["memory_unaccounted_bytes"],
         }
     if profile_snap is not None:
-        # the per-step decode profiler breakdown: p50/p99/count per
-        # phase (serving/engine.py StepProfiler.snapshot shape)
+        # the loop's phase spans: p50/p99/count per phase
+        # (tracing.SpanRecorder.phase_snapshot shape)
         record["profile"] = profile_snap
     if scrape is not None:
         record["metrics_scrape"] = scrape
@@ -1314,22 +1321,24 @@ def run_disagg_ab(args):
     }
 
 
-#: the enabled metrics+profiler plane may cost at most this fraction
-#: of the disabled plane's tokens/sec (the PR 6 tracing bound, kept)
+#: the enabled observability plane may cost at most this fraction of
+#: the disabled plane's tokens/sec (the PR 6 tracing bound, kept)
 OVERHEAD_BOUND = 0.05
 
 
 def run_overhead_ab(args, trainer, state, plan, num_slots,
                     num_blocks, draft):
     """The observability overhead A/B: the SAME arrival plan on the
-    paged+shared pool, plane OFF (no profiler, no exposition, no
-    forensics — exemplars, tail retention and slow-cause attribution
-    all disarmed — and no runtime health: sentry, accountant and
-    watchdog all absent) vs ON (profiler armed — split compiled
-    steps — plus a live /metrics server that gets scraped at the end,
-    the full forensics plane AND the runtime health plane: recompile
-    sentry on every executable, ledger reconciliation, progress
-    watchdog). tokens/sec must stay within OVERHEAD_BOUND; one
+    paged+shared pool, plane OFF (no exposition, no forensics —
+    exemplars, tail retention and slow-cause attribution all disarmed
+    — and no runtime health: sentry, accountant and watchdog all
+    absent) vs ON (a live /metrics server that gets scraped at the
+    end, the full forensics plane AND the runtime health plane:
+    recompile sentry on every executable, ledger reconciliation,
+    progress watchdog). The phase spans have no switch and ride both
+    legs (the ON leg records their block). The key stays
+    "profiler_overhead": bench_compare.py reads it from older
+    records. tokens/sec must stay within OVERHEAD_BOUND; one
     retry forgives a scheduler hiccup on a noisy CI box, but two
     misses fail the bench (a >5% observability tax is a regression,
     not noise)."""
@@ -1413,7 +1422,7 @@ def run_bench(args):
             results, parse_ramp(args.ramp)
         )
     if args.overhead_ab:
-        # metrics+profiler overhead A/B on the paged+shared shape (the
+        # observability overhead A/B on the paged+shared shape (the
         # path with the most instrumented phases)
         record["profiler_overhead"] = run_overhead_ab(
             args, trainer, state, plan,
@@ -1497,9 +1506,6 @@ def run_bench(args):
             kv_shared=True,
             draft=draft,
             draft_k=args.draft_k,
-            # profiling the headline leg: its greedy-match rate below
-            # then ALSO pins the SPLIT (profiled) step path against
-            # the int8 dense oracle in a real serve
             profile=args.profile,
             metrics_port=0 if args.profile else None,
         )

@@ -260,27 +260,6 @@ def test_collective_rejects_bad_op():
     assert status == CollectiveCommunicatorStatus.FAILED
 
 
-# ------------------------------------------------------------ profiler
-
-
-def test_profile_trace_writes_trace(tmp_path):
-    import glob as _glob
-
-    import jax.numpy as jnp
-
-    from elasticdl_tpu.common.profiler import (
-        profile_trace,
-        step_annotation,
-    )
-
-    with profile_trace(str(tmp_path)):
-        with step_annotation(0):
-            jnp.dot(jnp.ones((8, 8)), jnp.ones((8, 8))).block_until_ready()
-    files = _glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
-                       recursive=True)
-    assert files, "no xplane trace written"
-
-
 def test_validate_job_status_fake_api():
     from scripts.validate_job_status import validate
 

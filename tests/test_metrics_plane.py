@@ -10,8 +10,8 @@ documents), the stdlib scrape server, the closed GAUGE sets (the
 counter-set contract, extended), the snapshot()/close() vs ring
 window-boundary regression (identical totals on both paths), the
 windowed prefix-hit-rate, the SLO burn-rate math (multi-window rule,
-finiteness), the per-step profiler's closed phase set, and the
-router's SloObjective blocks + /metrics endpoint."""
+finiteness), and the router's SloObjective blocks + /metrics
+endpoint."""
 
 import math
 import os
@@ -483,27 +483,6 @@ def test_slo_spec_validation():
         SloSpec("x", "latency", 0.0, hist="h", threshold_ms=1.0)
     with pytest.raises(ValueError):
         SloSpec("x", "nonsense", 0.01)
-
-
-# ------------------------------------------------------ profiler (unit)
-
-
-def test_step_profiler_closed_phase_set_and_exposition():
-    from elasticdl_tpu.serving.engine import StepProfiler
-
-    p = StepProfiler()
-    p.observe("prefill", 0.002)
-    p.observe("scatter", 0.0001)
-    with pytest.raises(ValueError, match="unknown profiler phase"):
-        p.observe("prefil", 0.002)
-    snap = p.snapshot()
-    assert set(snap) == {"prefill", "scatter"}
-    assert snap["prefill"]["count"] == 1
-    assert snap["prefill"]["p50_ms"] == pytest.approx(2.0, rel=0.05)
-    fams = parse_prometheus_text(render_prometheus(p.prometheus()))
-    phases = {lab["phase"] for n, lab, v in
-              fams["edl_serving_phase_ms"]["samples"]}
-    assert phases == {"prefill", "scatter"}
 
 
 # --------------------------------------------- router SLO + /metrics

@@ -1,0 +1,428 @@
+"""Phase spans (observability/tracing.py `phase` / `begin` / `end` /
+`count`): the closed set, nesting, the second ring and its bound, the
+cumulative histograms behind /metrics, the profiler's clock, and the
+two hot loops that record them (the scheduler tick, the local train
+loop) — with the program's outputs unchanged."""
+
+import glob
+import json
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from elasticdl_tpu.observability import tracing
+from elasticdl_tpu.observability.metrics import (
+    hist_family,
+    render_prometheus,
+)
+from elasticdl_tpu.observability.promparse import parse_prometheus_text
+from elasticdl_tpu.observability.tracing import SpanRecorder
+
+
+@pytest.fixture(autouse=True)
+def fresh_ring():
+    tracing.recorder().clear_phases()
+    yield
+    tracing.recorder().clear_phases()
+
+
+def _names(phases):
+    return [p.name for p in phases]
+
+
+# ----------------------------------------------------------- the primitive
+
+
+def test_nesting_records_parent_and_inherits_seq_and_trace_id():
+    tick = tracing.begin("tick", seq=7)
+    with tracing.phase("tick.admit"):
+        with tracing.phase("prefill", trace_id="abc", prompt_tokens=5):
+            with tracing.phase("prompt_write", blocks=2):
+                pass
+    with tracing.phase("tick.stream"):
+        pass
+    tracing.end(tick, active=3, queue_depth=1)
+    got = {p.name: p for p in tracing.recorder().phases()}
+    # children seal before their parent: the root is last
+    assert _names(tracing.recorder().phases()) == [
+        "prompt_write", "prefill", "tick.admit", "tick.stream", "tick"]
+    assert got["tick"].parent == "" and got["tick"].seq == 7
+    assert got["tick"].attrs == {"active": 3, "queue_depth": 1}
+    assert got["tick.admit"].parent == "tick"
+    assert got["prefill"].parent == "tick.admit"
+    assert got["prompt_write"].parent == "prefill"
+    assert {p.seq for p in got.values()} == {7}
+    assert got["prefill"].trace_id == got["prompt_write"].trace_id == "abc"
+    assert got["tick.stream"].trace_id == ""  # a sibling inherits nothing
+    assert got["prefill"].attrs == {"prompt_tokens": 5}
+    for child, parent in (("prompt_write", "prefill"),
+                          ("prefill", "tick.admit"), ("tick.admit", "tick")):
+        assert got[parent].start_ns <= got[child].start_ns
+        assert got[child].end_ns <= got[parent].end_ns
+
+
+def test_names_are_a_closed_set():
+    with pytest.raises(ValueError, match="unknown phase"):
+        tracing.begin("tick.uplaod")
+    with pytest.raises(ValueError, match="unknown counter"):
+        tracing.count("prompt_write.launch")
+    assert tracing.recorder().phases() == []
+    assert len(set(tracing.PHASES)) == len(tracing.PHASES)
+    assert not set(tracing.PHASES) & set(tracing.COUNTERS)
+
+
+def test_an_exception_between_begin_and_end_cannot_misparent_the_next():
+    tick = tracing.begin("tick", seq=1)
+    tracing.begin("tick.admit")  # never ended: its body raised
+    tracing.end(tick)
+    with tracing.phase("idle"):
+        pass
+    got = {p.name: p for p in tracing.recorder().phases()}
+    assert set(got) == {"tick", "idle"}
+    assert got["idle"].parent == "" and got["idle"].seq is None
+
+
+def test_counts_are_windowable_entries_and_cumulative_totals():
+    with tracing.phase("prefill", seq=3):
+        tracing.count("prompt_write.launches", 4)
+        tracing.count("prompt_write.tokens", 61)
+    tracing.count("prompts_prefilled")
+    rec = tracing.recorder()
+    assert rec.counts() == {"prompt_write.launches": 4,
+                            "prompt_write.tokens": 61,
+                            "prompts_prefilled": 1}
+    launches = [p for p in rec.phases() if p.name == "prompt_write.launches"]
+    assert len(launches) == 1 and launches[0].attrs == {"n": 4}
+    assert launches[0].start_ns == launches[0].end_ns
+    assert launches[0].parent == "prefill" and launches[0].seq == 3
+    # `since`/`until` keep what overlaps the interval
+    t = launches[0].start_ns
+    assert launches[0] in rec.phases(since_ns=t, until_ns=t)
+    assert rec.phases(since_ns=t + 10**12) == []
+    assert rec.phases(until_ns=t - 10**12) == []
+
+
+def test_the_phase_ring_is_bounded_and_counts_what_it_drops(monkeypatch):
+    small = SpanRecorder(phase_capacity=8)
+    monkeypatch.setattr(tracing, "_RECORDER", small)
+    for i in range(20):
+        with tracing.phase("idle", seq=i):
+            pass
+    assert len(small.phases()) == 8
+    assert small.phases_dropped == 12
+    assert [p.seq for p in small.phases()] == list(range(12, 20))
+    # the histogram is cumulative: it saw all twenty
+    assert small.phase_snapshot()["idle"]["count"] == 20
+
+
+def test_neither_ring_evicts_the_other(monkeypatch):
+    small = SpanRecorder(capacity=4, phase_capacity=4)
+    monkeypatch.setattr(tracing, "_RECORDER", small)
+    for _ in range(3):
+        small.start_span("serve").finish()
+    for i in range(50):
+        with tracing.phase("idle", seq=i):
+            pass
+    assert len(small.snapshot()) == 3 and small.dropped == 0
+    for _ in range(50):
+        small.start_span("serve").finish()
+    assert len(small.phases()) == 4 and small.phases_dropped == 46
+    assert len(small.snapshot()) == 4 and small.dropped == 49
+    small.clear()  # request spans only
+    assert len(small.phases()) == 4
+
+
+def test_histograms_back_the_snapshot_and_the_metrics_family():
+    for ms in (1, 2, 40):
+        ph = tracing.begin("tick.upload")
+        ph.start_ns -= ms * 10**6  # a phase of about `ms` ms
+        tracing.end(ph)
+    with tracing.phase("tick.fetch"):
+        pass
+    rec = tracing.recorder()
+    snap = rec.phase_snapshot()
+    assert set(snap) == {"tick.upload", "tick.fetch"}
+    assert snap["tick.upload"]["count"] == 3
+    assert snap["tick.upload"]["p50_ms"] == pytest.approx(2.0, rel=0.05)
+    assert snap["tick.upload"]["total_ms"] == pytest.approx(43.0, rel=0.05)
+    text = render_prometheus([hist_family(
+        "edl_serving_phase_ms", "phases", rec.phase_hist_series())])
+    fams = parse_prometheus_text(text)  # raises on malformation
+    labels = {lab["phase"] for _n, lab, _v in
+              fams["edl_serving_phase_ms"]["samples"]}
+    assert labels == {"tick.upload", "tick.fetch"}
+
+
+def test_export_and_chrome_trace_carry_phases_beside_request_spans(
+        monkeypatch, tmp_path):
+    from elasticdl_tpu.observability import dump
+
+    rec = SpanRecorder(service="replica:1")
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    rec.start_span("serve", trace_id="t1").finish()
+    with tracing.phase("tick", seq=0):
+        with tracing.phase("prefill", trace_id="t1"):
+            tracing.count("prompts_prefilled")
+    rec.flush(str(tmp_path))
+    assert dump.main(["--dir", str(tmp_path),
+                      "--out", str(tmp_path / "trace.json")]) == 0
+    doc = json.load(open(tmp_path / "trace.json"))
+    events = doc["traceEvents"]
+    slices = {e["name"]: e for e in events if e["ph"] == "X"}
+    assert {"serve", "edl/tick", "edl/prefill"} <= set(slices)
+    assert slices["edl/prefill"]["args"]["trace_id"] == "t1"
+    assert slices["edl/prefill"]["pid"] == slices["serve"]["pid"]
+    assert slices["edl/tick"]["ts"] <= slices["edl/prefill"]["ts"]
+    assert [e["name"] for e in events if e["ph"] == "i"] == [
+        "edl/prompts_prefilled"]
+    assert doc["otherData"]["exports"][0]["phases"] == 3
+    assert doc["otherData"]["exports"][0]["phases_dropped"] == 0
+    spans, meta = dump.merge_dir(str(tmp_path))  # its old contract
+    assert len(spans) == 1 and meta[0]["spans"] == 1
+
+
+# ------------------------------------------------------ the profiler's clock
+
+
+def test_annotations_land_in_the_xplane_on_the_rings_clock(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for seq in range(3):
+            with tracing.phase("train.step", seq=seq):
+                with tracing.phase("trainer.dispatch"):
+                    jnp.ones((64, 64)).sum().block_until_ready()
+                    time.sleep(0.003)
+    finally:
+        jax.profiler.stop_trace()
+    with tracing.phase("train.step", seq=99):  # no session: ring only
+        pass
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("edl/"):
+                    events.setdefault(ev.name, []).append(
+                        (line.name, ev.start_ns, ev.duration_ns))
+    assert set(events) == {"edl/train.step", "edl/trainer.dispatch"}
+    assert len(events["edl/train.step"]) == 3
+    # one thread line holds them all: they nest there
+    assert len({line for evs in events.values() for line, _, _ in evs}) == 1
+    ring = [p for p in tracing.recorder().phases()
+            if p.name == "train.step" and p.seq != 99]
+    # the xplane counts from its session's start, the ring from the
+    # epoch: one origin apart, and the same clock — the offset is the
+    # same for every event to within 1 ms, and so is every duration
+    traced = sorted(events["edl/train.step"], key=lambda e: e[1])
+    origin = ring[0].start_ns - traced[0][1]
+    for (_, start, dur), p in zip(traced, ring):
+        assert abs(start + origin - p.start_ns) < 1e6
+        assert abs(dur - (p.end_ns - p.start_ns)) < 1e6
+        assert dur >= 3e6
+
+
+# ------------------------------------------------------- the scheduler tick
+
+
+def test_a_scripted_iterate_yields_the_expected_tree():
+    from test_serving import FakeClock, _req, _rig
+
+    clock = FakeClock()
+    engine, queue, _telemetry, sched = _rig(clock)
+    sched._iterate()  # nothing to do: admit polls, then the idle wait
+    queue.submit(_req(clock=clock))
+    sched._iterate()  # seats the request, one decode step, streams it
+    sched._iterate()  # decode only
+    by_seq = {}
+    for p in tracing.recorder().phases():
+        by_seq.setdefault(p.seq, []).append(p)
+    assert sorted(by_seq) == [0, 1, 2]
+    assert _names(by_seq[0]) == ["tick.admit", "idle", "tick"]
+    for seq in (1, 2):
+        assert _names(by_seq[seq]) == ["tick.admit", "tick.stream", "tick"]
+        assert all(p.parent == "tick" for p in by_seq[seq][:-1])
+    roots = [ps[-1] for _seq, ps in sorted(by_seq.items())]
+    assert [r.attrs["active"] for r in roots] == [0, 1, 1]
+    assert [r.attrs["queue_depth"] for r in roots] == [0, 0, 0]
+    assert all(r.parent == "" for r in roots)
+
+
+def test_a_tick_that_raises_still_seals_its_root():
+    from test_serving import FakeClock, _rig
+
+    _engine, _queue, _telemetry, sched = _rig(FakeClock())
+    sched._fill_slots = lambda: 1 / 0
+    with pytest.raises(ZeroDivisionError):
+        sched._iterate()
+    sched._fill_slots = lambda: None
+    sched._iterate()
+    roots = [p for p in tracing.recorder().phases() if p.name == "tick"]
+    assert [r.seq for r in roots] == [0, 1]
+    assert all(r.parent == "" for r in roots)
+
+
+# ------------------------------------------- a tiny paged server, for real
+
+
+@pytest.fixture(scope="module")
+def paged_server():
+    import jax
+
+    from elasticdl_tpu.common.model_utils import get_model_spec
+    from elasticdl_tpu.parallel import mesh as mesh_lib
+    from elasticdl_tpu.serving.server import (
+        GenerationServer,
+        ServingConfig,
+    )
+    from elasticdl_tpu.training.trainer import Trainer
+
+    spec = get_model_spec("model_zoo",
+                          "transformer_lm.transformer_lm.custom_model")
+    mesh = mesh_lib.build_mesh({"dp": 1}, devices=jax.devices()[:1])
+    trainer = Trainer(
+        spec, mesh=mesh,
+        model_params="vocab_size=16; seq_len=32; embed_dim=32; "
+                     "num_heads=2; num_layers=1",
+    )
+    dummy = np.zeros((1, 32), np.int32)
+    state = trainer.init_state(({"tokens": dummy}, dummy))
+    server = GenerationServer(
+        trainer, state,
+        ServingConfig(num_slots=3, kv_paged=True, kv_block_size=4,
+                      kv_shared=False, idle_wait_secs=0.01,
+                      handler_poll_secs=0.05),
+    ).start(grpc_server=False)
+    yield trainer, state, server
+    server.stop()
+
+
+def _serve(server, specs):
+    from elasticdl_tpu.proto import elasticdl_pb2 as pb
+
+    results = {}
+
+    def call(i, prompt, new):
+        r = server.raw_servicer.generate(
+            pb.GenerateRequest(prompt=prompt, max_new_tokens=new))
+        results[i] = list(r.tokens)
+
+    threads = [threading.Thread(target=call, args=(i, p, n))
+               for i, (p, n) in enumerate(specs)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    return results
+
+
+SPECS = [([1, 2, 3, 4, 5, 6], 5), ([7, 8, 9], 4), ([3, 1], 6),
+         ([2, 4, 6, 8, 10, 12, 14, 1, 3], 3), ([5], 7)]
+
+
+def test_tokens_equal_the_offline_oracle_with_the_spans_recording(
+        paged_server):
+    from elasticdl_tpu.api.generation import autoregressive_generate
+
+    trainer, state, server = paged_server
+    results = _serve(server, SPECS)
+    assert len(results) == len(SPECS)
+    for i, (prompt, new) in enumerate(SPECS):
+        want = np.asarray(autoregressive_generate(
+            trainer, state, np.asarray([prompt], np.int32), new,
+            use_cache=True))[0]
+        assert list(want) == results[i], (i, prompt)
+    rec = tracing.recorder()
+    assert rec.phases_dropped == 0
+    ticks = {}
+    for p in rec.phases():
+        if p.parent == "tick":
+            ticks.setdefault(p.seq, set()).add(p.name)
+    decode = [names for names in ticks.values() if "tick.dispatch" in names]
+    assert decode and all(
+        {"tick.admit", "tick.ensure", "tick.upload", "tick.dispatch",
+         "tick.fetch", "tick.commit", "tick.stream"} <= names
+        for names in decode)
+    prefills = [p for p in rec.phases() if p.name == "prefill"]
+    assert len(prefills) == len(SPECS)
+    assert all(p.parent == "tick.admit" and p.trace_id for p in prefills)
+    assert sorted(p.attrs["prompt_tokens"] for p in prefills) == sorted(
+        len(prompt) for prompt, _ in SPECS)
+    writes = [p for p in rec.phases() if p.name == "prompt_write"]
+    assert {p.parent for p in writes} == {"prefill"}
+    counts = rec.counts()
+    assert counts["prompts_prefilled"] == len(SPECS)
+    assert counts["prompt_write.tokens"] == sum(len(p) for p, _ in SPECS)
+    assert counts["prompt_write.launches"] == sum(
+        -(-len(p) // 4) for p, _ in SPECS)  # one per 4-token block
+    assert sum(p.attrs["blocks"] for p in writes) == counts[
+        "prompt_write.launches"]
+    # the engine compiled one step program: there is no split twin
+    assert not hasattr(server.engine, "_step_fns_split")
+    assert not hasattr(server.engine, "profiler")
+
+
+def test_metrics_exposition_still_carries_the_phase_family(paged_server):
+    _trainer, _state, server = paged_server
+    _serve(server, SPECS[:2])
+    fams = parse_prometheus_text(
+        render_prometheus(server._metrics_families()))
+    phases = {lab["phase"] for _n, lab, _v in
+              fams["edl_serving_phase_ms"]["samples"]}
+    assert {"tick", "tick.upload", "tick.dispatch", "tick.fetch",
+            "tick.commit", "tick.stream", "prefill",
+            "prompt_write"} <= phases
+    assert phases <= set(tracing.PHASES)
+    dropped = fams["edl_serving_phase_ring_dropped"]["samples"]
+    assert [v for _n, _lab, v in dropped] == [0]
+    assert "edl_serving_ttft_ms" in fams
+
+
+# ---------------------------------------------------------- the train loop
+
+
+def test_local_executor_train_yields_one_train_step_per_step(tmp_path):
+    from elasticdl_tpu.api.local_executor import LocalExecutor
+    from elasticdl_tpu.common.model_utils import get_model_spec
+    from elasticdl_tpu.data import recordio_gen
+
+    train_dir = str(tmp_path / "train")
+    recordio_gen.gen_mnist_like(train_dir, num_files=1, records_per_file=48)
+    executor = LocalExecutor(
+        get_model_spec(
+            "model_zoo",
+            "mnist_functional_api.mnist_functional_api.custom_model"),
+        training_data=train_dir, minibatch_size=16, num_epochs=1,
+        records_per_task=32,
+    )
+    state, _ = executor.run()
+    steps = int(state.step)
+    assert steps == 3 and len(executor.losses) == 3
+    rec = tracing.recorder()
+    by_name = {}
+    for p in rec.phases():
+        by_name.setdefault(p.name, []).append(p)
+    assert [p.seq for p in by_name["train.step"]] == [0, 1, 2]
+    assert [p.seq for p in by_name["train.loss_fetch"]] == [0, 1, 2]
+    assert [p.seq for p in by_name["train.pad"]] == [0, 1, 2]
+    # two tasks (32 + 16 records): each ends with the wait that found
+    # its iterator empty, and is fetched and reported once; the third
+    # task_get finds the queue empty
+    assert len(by_name["train.next_batch"]) == 3 + 2
+    assert len(by_name["train.task_report"]) == 2
+    assert len(by_name["train.task_get"]) == 3
+    for p in by_name["trainer.dispatch"] + by_name["trainer.host_prepare"]:
+        assert p.parent == "train.step"
+    assert [p.seq for p in by_name["trainer.dispatch"]] == [0, 1, 2]
+    assert "trainer.post_tiers" not in by_name  # a dense model has none
+    # the loop's phases do not overlap: they tile the thread's time
+    loop = sorted((p for p in rec.phases()
+                   if p.parent == "" and p.name.startswith("train.")),
+                  key=lambda p: p.start_ns)
+    for a, b in zip(loop, loop[1:]):
+        assert a.end_ns <= b.start_ns

@@ -917,22 +917,25 @@ def test_shared_prefix_speculative_matches_dense_greedy_32way(rig):
         assert dense_results[i] == shared_results[i], (i, s)
 
 
-def test_profiled_split_step_matches_offline_int8_32way():
-    """The metrics-plane parity pin: with the per-step decode profiler
-    ENABLED the paged engine runs SPLIT compiled steps (decode|scatter
-    and draft|verify|scatter instead of the fused executables) — the
-    token streams must STILL equal the offline int8 oracle at 32-way
-    paged + shared + speculative + int8 concurrency (mismatched draft,
-    so rollback exercises the split verify path). Also pins that every
-    speculative-path phase actually recorded, and that the /metrics
-    exposition of a live replica parses through the INDEPENDENT
-    text-format parser with the phase histogram present — the
-    acceptance criterion's "live replica serves Prometheus text"."""
+def test_fused_spec_step_matches_offline_int8_32way_with_phases():
+    """The metrics-plane parity pin, on the one program there is: the
+    fused speculative step's token streams equal the offline int8
+    oracle at 32-way paged + shared + speculative + int8 concurrency
+    (mismatched draft, so rollback exercises the verify path) while
+    the phase spans record. Also pins that every phase of the
+    speculative tick and of seating actually recorded, and that the
+    /metrics exposition of a live replica parses through the
+    INDEPENDENT text-format parser with the phase histogram present —
+    the acceptance criterion's "live replica serves Prometheus
+    text"."""
     import urllib.request
 
+    from elasticdl_tpu.observability import tracing
     from elasticdl_tpu.observability.promparse import (
         parse_prometheus_text,
     )
+
+    tracing.recorder().clear_phases()
 
     int8_params = PARAMS + "; kv_cache_dtype='int8'"
     mesh = mesh_lib.build_mesh({"dp": 1}, devices=jax.devices()[:1])
@@ -953,15 +956,12 @@ def test_profiled_split_step_matches_offline_int8_32way():
     cfg = ServingConfig(
         num_slots=6, queue_capacity=64, kv_paged=True,
         kv_block_size=4, kv_num_blocks=24, kv_shared=True, draft_k=2,
-        profile=True, metrics_port=0,
+        metrics_port=0,
     )
     server = GenerationServer(
         trainer, state, cfg, draft=(draft_trainer, draft_state)
     ).start()
     try:
-        assert server.engine.profiler is not None
-        # the pool shares the profiler (revive-upload attribution)
-        assert server.engine.kv.profiler is server.engine.profiler
         stub = ServingStub(build_channel("localhost:%d" % server.port))
         results, errors = {}, {}
 
@@ -995,13 +995,15 @@ def test_profiled_split_step_matches_offline_int8_32way():
         assert 0.0 <= st.prefix_hit_rate_window <= 1.0
         assert st.kv_blocks_free == st.kv_blocks_total == 24
 
-        snap = server.engine.profiler.snapshot()
+        snap = tracing.recorder().phase_snapshot()
         # every phase the speculative+shared workload exercises
-        for phase in ("prefill", "suffix_tile", "draft",
-                      "verify_commit", "scatter"):
+        for phase in ("prefill", "suffix_tile", "draft", "prompt_write",
+                      "tick.ensure", "tick.upload", "tick.dispatch",
+                      "tick.fetch", "tick.commit", "tick.stream"):
             assert phase in snap and snap[phase]["count"] > 0, (
                 phase, snap,
             )
+        assert tracing.recorder().phases_dropped == 0
 
         text = urllib.request.urlopen(
             "http://127.0.0.1:%d/metrics" % server.metrics.port,
