@@ -131,7 +131,11 @@ PHASES = (
 )
 #: the closed set of `count` names
 COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
-            "prompts_prefilled")
+            "prompts_prefilled",
+            # serving/engine.py step() / _spec_step(), a decode tick:
+            # table slots in reach of the lanes' sequences (what the
+            # paged kernel streams a layer) of lanes x table width
+            "paged.blocks_streamed", "paged.table_slots")
 
 Phase = collections.namedtuple(
     "Phase", "name start_ns end_ns seq parent trace_id attrs")

@@ -387,6 +387,29 @@ def _paged_valid(k_pos, bid, length, row_pos, window):
     return valid
 
 
+def paged_live_blocks(length, window, block_size, m, xp=jnp):
+    """The half-open range [j_lo, j_hi) of table slots that can hold a
+    key some row of a query tile at `length` sees — what the fused
+    kernel streams, and what serving counts as streamed.
+
+    It follows _paged_valid, which stays the source of truth for each
+    key: keys live at k_pos < length, so j_hi = min(m, ceil(length /
+    block_size)); tile row 0 (position `length`) reaches furthest back,
+    to k_pos = length - window + 1, so j_lo is that key's block (0
+    without a window) — later rows and the keys of block j_lo before
+    the reach are masked per key. The tile width does not enter: every
+    row sees the pool up to `length` and none further back than row 0.
+    length == 0 (a free lane) gives the empty range (0, 0).
+
+    `xp` is the array module: jnp inside the kernel (scalars from
+    SMEM), np on the host (the engine's per-lane counters)."""
+    j_hi = xp.minimum((length + block_size - 1) // block_size, m)
+    if window is None:
+        return j_hi * 0, j_hi
+    j_lo = xp.maximum(length - window + 1, 0) // block_size
+    return xp.minimum(j_lo, j_hi), j_hi
+
+
 def _tile_causal_mask(group, t, window):
     """[group*t, t] visibility of the query tile's OWN keys, shared by
     the scan and fused paths (both merge the tile outside the pool
@@ -545,7 +568,8 @@ def paged_decode_attention(q, k_cur, v_cur, k_pool, v_pool, block_table,
                              w_scale=w_scale), None
 
     if use_kernel is None:
-        use_kernel = use_paged_kernel() and _paged_kernel_supported(m)
+        use_kernel = use_paged_kernel() and _paged_kernel_supported(
+            m, k_pool, quantized)
     if use_kernel:
         o, l, mx = _paged_decode_fused(
             qf, k_pool, v_pool, block_table, length, t, window=window,
@@ -583,127 +607,235 @@ def paged_decode_attention(q, k_cur, v_cur, k_pool, v_pool, block_table,
 # ------------------------------------------------- fused paged kernel
 
 
-def _paged_kernel_supported(m):
+def _paged_kernel_supported(m, pool=None, quantized=False):
     """Shape gate for the fused paged decode kernel — the ONE place
     that decides kernel-or-scan by shape. m == 0 (no table slots) has
-    no pool to stream; every other shape takes the kernel.
+    no pool to stream. `pool` is a row arena
+    [num_blocks, block_size, hkv, d]; without one only m is judged.
 
-    The rule Mosaic applies to every block
-    (jax/_src/pallas/mosaic/lowering.py::_check_block_mappings) is that
-    each of the last two block dims equals the array's or is a multiple
-    of (8, 128). _paged_decode_fused streams whole blocks of the arenas
-    viewed as [num_blocks, block_size*hkv, d] — tiles
-    (1, block_size*hkv, d) and, for the scale leaves,
-    (1, block_size*hkv, 1) — so both of a tile's last two dims EQUAL
-    the array's and the rule holds for every hkv, block_size and d;
-    Mosaic pads a tile that is not (8, 128)-aligned in VMEM, the shared
-    arenas are never padded or copied. Compiled on the v5e against the
-    scan for d in {40, 64, 72, 80, 96, 112, 128, 192, 256}, tile rows
-    (block_size*hkv) from 1 to 512, bf16 / int8 / f32 arenas, t in
-    {1, 8}, bare and under vmap (CHANGES.md, PR 21); the flagship pool
-    shape is in tests/test_tpu_smoke.py."""
-    return m >= 1
+    _paged_kernel copies whole blocks of the arenas viewed as
+    [num_blocks, block_size*hkv, d] out of HBM itself, one
+    (block_size*hkv, d) slab a copy, and Mosaic moves only whole tiles
+    of a tiled memref: d must be a multiple of 128 lanes and a slab a
+    whole number of 32-bit sublanes (an even number of bf16 rows, a
+    multiple of 4 int8 rows). The f32 scale leaves of int8 arenas ride
+    as 128-lane rows of block_size*hkv scales a block, which must
+    divide 128 or be a multiple of it. Every other pool decodes
+    through the scan; the interpreter takes any shape. Compiled for a
+    described v5e over d in {64, 128, 256}, slabs of 1 to 512 rows and
+    bf16 / int8 / f32 arenas (PERF.md §6, PR 25), and on the v5e
+    against the scan at the shapes of tests/test_tpu_smoke.py."""
+    if m < 1:
+        return False
+    if pool is None or interpret_mode():
+        return True
+    _, bs, hkv, d = pool.shape
+    kv_rows = bs * hkv
+    return (
+        d % 128 == 0
+        and kv_rows * jnp.dtype(pool.dtype).itemsize % 4 == 0
+        and (not quantized or kv_rows % 128 == 0 or 128 % kv_rows == 0)
+    )
 
 
-def paged_decode_impl(m):
+def paged_decode_impl(m, pool=None, quantized=False):
     """Name of the implementation paged_decode_attention's auto
-    dispatch picks for a pool with `m` table slots per sequence — what
-    the server logs at start."""
+    dispatch picks for a pool (a row arena) with `m` table slots per
+    sequence — what the server logs at start."""
     if not use_paged_kernel():
         return "scan (kernels off)"
-    if not _paged_kernel_supported(m):
+    if m < 1:
         return "scan (no table slots)"
+    if not _paged_kernel_supported(m, pool, quantized):
+        return "scan (the pool's blocks are not whole tiles)"
     return "pallas-interpret" if interpret_mode() else "pallas"
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, rows_ref, cols_ref, k_ref,
-                  v_ref, *rest, m, bs, window, quantized):
-    """Fused paged decode attention, one Mosaic program per
-    (sequence, table slot) grid point, ALL kv heads per program.
+#: a trip of the pool stream scores at least this many arena rows
+#: (block_size * hkv a block) in one matmul, where the table is that
+#: wide. Decided by a sweep at the sc2-3b serving shape on the v5e
+#: (scripts/bench_attention.py --paged-trip; PERF.md §6, PR 25): a
+#: call is launch-bound and flat in it (14 us) while a sequence has a
+#: few dozen blocks in reach; over a full table of 1024 blocks it
+#: takes 119 us at 256 rows, 78 at 1024 and 73 at 2048.
+_PAGED_TRIP_ROWS = 1024
+#: ... and at most this many score elements (query rows x arena rows,
+#: fp32), so a wide suffix-prefill tile keeps a score tile that fits
+_PAGED_TRIP_SCORES = 1 << 19
+
+
+def _paged_trip_blocks(q_rows, kv_rows, m):
+    """Blocks a trip of the pool stream moves and scores at once, from
+    shapes alone: enough to reach _PAGED_TRIP_ROWS arena rows, no more
+    than the table has, fewer where the query tile is tall."""
+    want = -(-_PAGED_TRIP_ROWS // kv_rows)
+    fit = _PAGED_TRIP_SCORES // (q_rows * kv_rows)
+    return max(1, min(want, fit, m))
+
+
+def _paged_kernel(tbl_ref, len_ref, q_ref, rows_ref, cols_ref, *rest,
+                  m, bs, window, quantized, kblk, wblk, sgrp):
+    """Fused paged decode attention, one Mosaic program per sequence,
+    ALL kv heads per program, streaming only the blocks in reach.
 
     Scalar-prefetch operands (the vLLM PagedAttention shape): the
     flattened [b*m] block table and the [b] lengths land in SMEM before
-    the grid runs, so the K/V BlockSpec index maps gather each slot's
-    block HBM->VMEM by TABLE INDIRECTION — `tbl[batch*m + j]` IS the
-    index map, -1 slots clamped to resident block 0 and masked here.
+    the grid runs. The arenas stay in HBM (`pl.ANY`); the program reads
+    its sequence's live range [j_lo, j_hi) (paged_live_blocks) and
+    loops over ceil((j_hi - j_lo) / kblk) TRIPS — none for a free lane.
+    A trip copies `kblk` blocks HBM->VMEM by TABLE INDIRECTION
+    (`tbl[batch*m + j]` names the arena block; -1 clamps to block 0 and
+    is masked), into one of two buffers, so trip c+1's copies fly under
+    trip c's matmuls. Slots past j_hi in the last trip copy block
+    j_hi - 1 again: its rows are finite and their columns sit at
+    k_pos >= length, which _paged_valid masks — what lies outside the
+    range is never read, so it cannot reach the sum.
 
-    Per step the whole block arrives as one (bs*hkv, d) tile — arena
-    row r of head g sits at tile row r*hkv + g — and ONE matmul scores
-    every query row against every tile row; the cross-head products
-    are masked with the same select that applies _paged_valid, so the
-    weights of a foreign head are exactly 0 and the p @ v matmul needs
-    no per-head split either. That trades hkv-fold MXU work on a tile
-    of a few dozen rows (decode is bound by the pool stream, not the
-    MXU) for whole-block DMAs and no in-kernel relayout. The row/column
-    head ids and offsets ride in as two small int32 operands: vector
-    integer div/mod is not something to ask of the VPU.
+    `wblk` of a trip's blocks are scored at once, as one
+    (wblk*bs*hkv, d) tile — arena row r of head g of its block i sits
+    at tile row (i*bs + r)*hkv + g — and ONE matmul scores every query
+    row against every tile row (wblk == kblk, the whole trip, but for
+    int8 arenas); the cross-head products are masked with the same
+    select that applies _paged_valid, so the weights of a foreign head
+    are exactly 0 and the p @ v matmul needs no per-head split either.
+    That trades hkv-fold MXU work on a few hundred rows (decode is
+    bound by the pool stream, not the MXU) for whole-block DMAs and no
+    in-kernel relayout. The row/column head ids and offsets ride in as
+    two small int32 operands: vector integer div/mod is not something
+    to ask of the VPU.
 
-    int8 rows dequantize IN-REGISTER by the (bs*hkv, 1) scale-leaf
-    column broadcast. Scores run in the exp2 domain like the flash
-    kernels (log2e pre-folded into q's scale multiply) and accumulate
-    into the fp32 VMEM scratch (o, l, m) online-softmax triple; the
-    last slot writes the raw partials out (m converted back to natural
-    log) for the shared current-tile merge + finalize in
-    paged_decode_attention."""
-    if quantized:
-        ks_ref, vs_ref = rest[:2]
-        rest = rest[2:]
-    o_ref, l_ref, m_ref, acc_o, acc_l, acc_m = rest
+    int8 arenas stream int8 and dequantize DEFERRED, as in the scan: a
+    block's bs*hkv k scales multiply its score columns and its v scales
+    the weights, each a (1, bs*hkv) row, so a block is scored on its
+    own (wblk == 1). A copy moves whole 128-lane rows: where a block
+    has fewer scales, `sgrp` blocks share a row of the scale view and
+    the block's lanes are rolled to the front.
+
+    Scores run in the exp2 domain like the flash kernels (log2e
+    pre-folded into q's scale multiply) and accumulate in the fp32
+    output tiles (o, l, m) themselves, an online-softmax triple; the
+    epilogue converts m back to natural log for the shared
+    current-tile merge + finalize in paged_decode_attention."""
+    n_pools = 4 if quantized else 2
+    pools, rest = rest[:n_pools], rest[n_pools:]
+    o_ref, l_ref, m_ref = rest[:3]
+    bufs, sem = rest[3:3 + n_pools], rest[3 + n_pools]
     batch = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        acc_o[:] = jnp.zeros_like(acc_o)
-        acc_l[:] = jnp.zeros_like(acc_l)
-        acc_m[:] = jnp.full_like(acc_m, _NEG_INF)
-
-    bid = tbl_ref[batch * m + j]
     seq_len = len_ref[batch]
+    j_lo, j_hi = paged_live_blocks(seq_len, window, bs, m)
+    trips = (j_hi - j_lo + kblk - 1) // kblk
 
-    kb = k_ref[0].astype(jnp.float32)  # (bs*hkv, d)
-    vb = v_ref[0].astype(jnp.float32)
-    if quantized:
-        kb = kb * ks_ref[0]  # (bs*hkv, 1) lane broadcast
-        vb = vb * vs_ref[0]
+    def block_id(j):
+        """Table entry of slot j, -1 = unallocated; slots past the live
+        range read its last one."""
+        return tbl_ref[batch * m + jnp.minimum(j, j_hi - 1)]
 
-    q = q_ref[0]  # (hkv*n_rows, d), exp2-domain prescaled f32
-    s = jax.lax.dot_general(
-        q, kb, dimension_numbers=_dims(1, 1),
-        preferred_element_type=jnp.float32,
-    )  # (hkv*n_rows, bs*hkv), log2 units
+    def copies(c, slot, wait):
+        """Start, or wait for, trip c's block copies into buffer
+        `slot`: one DMA semaphore a buffer, waited once per copy. A
+        rolled loop: a wide trip costs no time to trace (unrolled, 32
+        blocks a trip took 4 s a layer)."""
+        def one(i, carry):
+            bid = jnp.maximum(block_id(j_lo + c * kblk + i), 0)
+            # (k, v[, k scales, v scales]): `sgrp` blocks a scale row
+            for pool, buf, grp in zip(pools, bufs, (1, 1, sgrp, sgrp)):
+                copy = pltpu.make_async_copy(
+                    pool.at[bid // grp], buf.at[slot, i], sem.at[slot])
+                if wait:
+                    copy.wait()
+                else:
+                    copy.start()
+            return carry
 
-    # every mask operand is broadcast to the full score tile as int32
-    # BEFORE it is compared: Mosaic broadcasts integers along either
-    # axis, a (1, n) or scalar i1 it may not
-    zeros = jnp.zeros(s.shape, jnp.int32)
-    row_head = zeros + rows_ref[:, 0:1]  # (hkv*n_rows, 1) columns
-    row_tok = zeros + rows_ref[:, 1:2]
-    col_head = zeros + cols_ref[0:1, :]  # (1, bs*hkv) rows
-    col_off = zeros + cols_ref[1:2, :]
-    valid = _paged_valid(
-        j * bs + col_off, zeros + bid, seq_len, seq_len + row_tok, window
-    ) & (row_head == col_head)
-    s = jnp.where(valid, s, _NEG_INF)
+        jax.lax.fori_loop(0, kblk, one, 0)
 
-    m_prev = acc_m[:]
-    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-    p = jnp.exp2(s - m_new)
-    corr = jnp.exp2(m_prev - m_new)
-    acc_l[:] = acc_l[:] * corr + p.sum(-1, keepdims=True)
-    acc_o[:] = acc_o[:] * corr + jax.lax.dot_general(
-        p, vb, dimension_numbers=_dims(1, 0),
-        preferred_element_type=jnp.float32,
-    )
-    acc_m[:] = m_new
+    def scales(buf, slot, u, j0):
+        """Block j0's (1, bs*hkv) row of scales out of the row of
+        `sgrp` blocks' scales that was copied for it."""
+        row = buf[slot, u]
+        if sgrp == 1:
+            return row
+        kv_rows = row.shape[1] // sgrp
+        lane = (jnp.maximum(block_id(j0), 0) % sgrp) * kv_rows
+        return pltpu.roll(row, (row.shape[1] - lane) % row.shape[1],
+                          1)[:, :kv_rows]
 
-    @pl.when(j == m - 1)
+    @pl.when(trips > 0)
     def _():
-        o_ref[0] = acc_o[:]
-        l_ref[0] = acc_l[:]
-        # natural-log units at the boundary, like the flash epilogue:
-        # nothing outside the kernel ever sees base-2 values
-        m_ref[0] = acc_m[:] * _LN2
+        copies(0, 0, wait=False)
+
+    o_ref[0] = jnp.zeros_like(o_ref[0])
+    l_ref[0] = jnp.zeros_like(l_ref[0])
+    m_ref[0] = jnp.full_like(m_ref[0], _NEG_INF)
+    q = q_ref[0]  # (hkv*n_rows, d), exp2-domain prescaled f32
+    cols = cols_ref.shape[1]  # wblk*bs*hkv
+
+    def merge(slot, u, j0):
+        """Score blocks [u*wblk, (u+1)*wblk) of buffer `slot` — table
+        slots j0 .. j0 + wblk - 1 — in one matmul and fold them into
+        the (o, l, m) triple."""
+        kb = bufs[0][slot, pl.ds(u * wblk, wblk)].reshape(cols, -1)
+        vb = bufs[1][slot, pl.ds(u * wblk, wblk)].reshape(cols, -1)
+        s = jax.lax.dot_general(
+            q, kb.astype(jnp.float32), dimension_numbers=_dims(1, 1),
+            preferred_element_type=jnp.float32,
+        )  # (hkv*n_rows, wblk*bs*hkv), log2 units
+        if quantized:
+            s = s * scales(bufs[2], slot, u, j0)  # k scales, a row
+
+        # the blocks' ids, one table read a block, laid along the
+        # columns on a single row before anything is broadcast
+        col_blk = cols_ref[2:3, :]
+        bid = jax.lax.fori_loop(
+            0, wblk,
+            lambda i, bid: jnp.where(col_blk == i, block_id(j0 + i), bid),
+            jnp.full(col_blk.shape, -1, jnp.int32))
+        # every mask operand is broadcast to the full score tile as
+        # int32 BEFORE it is compared: Mosaic broadcasts integers along
+        # either axis, a (1, n) or scalar i1 it may not
+        zeros = jnp.zeros(s.shape, jnp.int32)
+        row_head = zeros + rows_ref[:, 0:1]  # (hkv*n_rows, 1) columns
+        row_tok = zeros + rows_ref[:, 1:2]
+        col_head = zeros + cols_ref[0:1, :]  # (1, wblk*bs*hkv) rows
+        col_off = zeros + cols_ref[1:2, :]
+        valid = _paged_valid(
+            j0 * bs + col_off, zeros + bid, seq_len, seq_len + row_tok,
+            window,
+        ) & (row_head == col_head)
+        s = jnp.where(valid, s, _NEG_INF)
+
+        m_prev = m_ref[0]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.exp2(s - m_new)
+        corr = jnp.exp2(m_prev - m_new)
+        l_ref[0] = l_ref[0] * corr + p.sum(-1, keepdims=True)
+        if quantized:
+            p = p * scales(bufs[3], slot, u, j0)  # v's: the weights
+        o_ref[0] = o_ref[0] * corr + jax.lax.dot_general(
+            p, vb.astype(jnp.float32), dimension_numbers=_dims(1, 0),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[0] = m_new
+
+    def trip(c, carry):
+        slot = c % 2
+
+        @pl.when(c + 1 < trips)
+        def _():
+            copies(c + 1, 1 - slot, wait=False)
+
+        copies(c, slot, wait=True)
+
+        def part(u, carry):
+            merge(slot, u, j_lo + c * kblk + u * wblk)
+            return carry
+
+        return jax.lax.fori_loop(0, kblk // wblk, part, carry)
+
+    jax.lax.fori_loop(0, trips, trip, 0)
+    # natural-log units at the boundary, like the flash epilogue:
+    # nothing outside the kernel ever sees base-2 values
+    m_ref[0] = m_ref[0] * _LN2
 
 
 def _paged_decode_fused(qf, k_pool, v_pool, block_table, length, t,
@@ -716,12 +848,14 @@ def _paged_decode_fused(qf, k_pool, v_pool, block_table, length, t,
 
     qf is the scan's query layout: [b, hkv, group*t, d], already scale-
     multiplied, f32. The row axis pads up to resolve_paged_rows() (the
-    tuned sublane tile) and the heads fold into it. The pools stream
-    untouched: [num_blocks, bs, hkv, last] is VIEWED as
+    tuned sublane tile) and the heads fold into it. The pools are never
+    tiled by a BlockSpec: [num_blocks, bs, hkv, last] is VIEWED as
     [num_blocks, bs*hkv, last] (a reshape of contiguous dims, no copy)
-    so a block is one (bs*hkv, last) tile whose last two dims equal
-    the array's — int8 arenas stay int8 through the DMA and the scale
-    leaves ride as (bs*hkv, 1) columns."""
+    and handed to the kernel whole, in HBM; the kernel copies the
+    blocks its sequence has in reach, _paged_trip_blocks of them a
+    trip, into two VMEM buffers a pool — int8 arenas stay int8 through
+    the DMA; their scale leaves are regrouped (one XLA copy a call, not
+    a slot: the pools are not batched) into rows of 128 lanes."""
     b, hkv, gt, d = qf.shape
     num_blocks, bs = k_pool.shape[:2]
     m = block_table.shape[1]
@@ -734,75 +868,66 @@ def _paged_decode_fused(qf, k_pool, v_pool, block_table, length, t,
             q2, ((0, 0), (0, 0), (0, n_rows - gt), (0, 0))
         )
     q_rows, kv_rows = hkv * n_rows, bs * hkv
+    kblk = _paged_trip_blocks(q_rows, kv_rows, m)
     q2 = q2.reshape(b, q_rows, d)
     tbl = jnp.asarray(block_table, jnp.int32).reshape(b * m)
     ln = jnp.asarray(length, jnp.int32)
     # query row R is head R // n_rows; row r of a head's padded tile is
     # tile token r % t (group-major [group, t] flatten; pad rows alias
-    # real positions and are sliced off below). Tile column c is arena
-    # row c // hkv of head c % hkv.
+    # real positions and are sliced off below). Trip column c is arena
+    # row (c // hkv) % bs of head c % hkv in the trip's block
+    # c // kv_rows, at position offset c // hkv from the trip's start.
     r_idx = np.arange(q_rows)
-    c_idx = np.arange(kv_rows)
+    wblk = 1 if quantized else kblk
+    c_idx = np.arange(wblk * kv_rows)
     row_meta = np.stack(
         [r_idx // n_rows, (r_idx % n_rows) % t], axis=1
     ).astype(np.int32)  # [q_rows, 2]
     col_meta = np.stack(
-        [c_idx % hkv, c_idx // hkv], axis=0
-    ).astype(np.int32)  # [2, kv_rows]
+        [c_idx % hkv, c_idx // hkv, c_idx // kv_rows], axis=0
+    ).astype(np.int32)  # [3, wblk*kv_rows]
 
     def _seq_spec(last):
-        """Per-sequence tile, revisited across the j stream."""
+        """Per-sequence tile."""
         return pl.BlockSpec(
-            (1, q_rows, last),
-            lambda i, j, tbl_ref, len_ref: (i, 0, 0),
+            (1, q_rows, last), lambda i, tbl_ref, len_ref: (i, 0, 0),
             memory_space=pltpu.VMEM,
         )
 
     def _const_spec(shape):
         return pl.BlockSpec(
-            shape, lambda i, j, tbl_ref, len_ref: (0, 0),
+            shape, lambda i, tbl_ref, len_ref: (0, 0),
             memory_space=pltpu.VMEM,
         )
 
-    def _pool_spec(last):
-        """THE tentpole index map: the scalar-prefetched block table
-        routes the HBM->VMEM DMA — slot j of sequence i names the
-        arena block to stream; -1 (unallocated) clamps to block 0,
-        whose rows _paged_valid masks. Same-index revisits (clamped
-        runs) elide the copy like the flash stream clamps."""
-        return pl.BlockSpec(
-            (1, kv_rows, last),
-            lambda i, j, tbl_ref, len_ref: (
-                jnp.maximum(tbl_ref[i * m + j], 0), 0, 0,
-            ),
-            memory_space=pltpu.VMEM,
-        )
-
-    def _view(pool):
-        return pool.reshape(num_blocks, kv_rows, pool.shape[-1])
-
-    in_specs = [_seq_spec(d), _const_spec(row_meta.shape),
-                _const_spec(col_meta.shape), _pool_spec(d),
-                _pool_spec(d)]
-    inputs = [q2, row_meta, col_meta, _view(k_pool), _view(v_pool)]
+    pools = [p.reshape(num_blocks, kv_rows, d) for p in (k_pool, v_pool)]
+    sgrp = max(1, 128 // kv_rows)
     if quantized:
-        in_specs += [_pool_spec(1), _pool_spec(1)]
-        inputs += [_view(k_scale_pool), _view(v_scale_pool)]
+        # a copy moves whole 128-lane rows: `sgrp` blocks' scales share
+        # one where a block has fewer than 128 arena rows
+        pad = -num_blocks % sgrp
+        pools += [
+            jnp.pad(p.reshape(num_blocks, kv_rows), ((0, pad), (0, 0)))
+            .reshape(-1, 1, sgrp * kv_rows)
+            for p in (k_scale_pool, v_scale_pool)
+        ]
     kernel = functools.partial(
         _paged_kernel, m=m, bs=bs, window=window, quantized=quantized,
+        kblk=kblk, wblk=wblk, sgrp=sgrp,
     )
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, m),
-            in_specs=in_specs,
+            grid=(b,),
+            in_specs=[_seq_spec(d), _const_spec(row_meta.shape),
+                      _const_spec(col_meta.shape)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=(_seq_spec(d), _seq_spec(1), _seq_spec(1)),
             scratch_shapes=[
-                pltpu.VMEM((q_rows, d), jnp.float32),
-                pltpu.VMEM((q_rows, 1), jnp.float32),
-                pltpu.VMEM((q_rows, 1), jnp.float32),
-            ],
+                pltpu.VMEM((2, kblk) + p.shape[1:], p.dtype)
+                for p in pools
+            ] + [pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, q_rows, d), jnp.float32),
@@ -810,7 +935,7 @@ def _paged_decode_fused(qf, k_pool, v_pool, block_table, length, t,
             jax.ShapeDtypeStruct((b, q_rows, 1), jnp.float32),
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("parallel",)
         ),
         interpret=interpret_mode(),
     )
@@ -818,7 +943,7 @@ def _paged_decode_fused(qf, k_pool, v_pool, block_table, length, t,
     # a device trace (`paged_decode.N`); pallas_call's own `name=` would
     # do the same but also replace the custom call's `kernel_name`
     with jax.named_scope("paged_decode"):
-        o, l, mx = call(tbl, ln, *inputs)
+        o, l, mx = call(tbl, ln, q2, row_meta, col_meta, *pools)
     o = o.reshape(b, hkv, n_rows, d)[:, :, :gt]
     l = l.reshape(b, hkv, n_rows)[:, :, :gt]
     mx = mx.reshape(b, hkv, n_rows)[:, :, :gt]

@@ -74,6 +74,7 @@ from elasticdl_tpu.api.generation import (
 from elasticdl_tpu.common.log_utils import default_logger as logger
 from elasticdl_tpu.observability import tracing
 from elasticdl_tpu.observability.runtime_health import tracked_jit
+from elasticdl_tpu.ops.attention import paged_live_blocks
 
 
 def kv_paged_default():
@@ -1134,6 +1135,19 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._positions[slot] = 0
         self.kv.release(slot)
 
+    def _count_paged_stream(self):
+        """Count what this tick's paged decode streams, a layer: the
+        table slots in reach of each lane's sequence (the kernel's own
+        live range — a free lane sits at position 0 and has none) of
+        all the table slots the lanes have."""
+        j_lo, j_hi = paged_live_blocks(
+            self._positions, getattr(self.model, "attn_window", 0) or None,
+            self.block_size, self.kv.max_blocks_per_slot, xp=np,
+        )
+        tracing.count("paged.blocks_streamed", int((j_hi - j_lo).sum()))
+        tracing.count("paged.table_slots",
+                      self.num_slots * self.kv.max_blocks_per_slot)
+
     def step(self):
         """One vmapped decode step over the whole pool, paged: block
         tables and positions enter as device arrays, each active slot
@@ -1159,6 +1173,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             # an extend's pop can spill under pressure: keep the
             # telemetry mirror current even on decode-only ticks
             self._sync_host_telemetry()
+            self._count_paged_stream()
         if self._step_fn is None:
             self._step_fn = self._build_paged_step()
         with self.trainer.mesh:
@@ -1210,6 +1225,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                     len(st.request.prompt) + len(st.request.generated)
                 )
             self._sync_host_telemetry()  # ensure_blocks pops can spill
+            self._count_paged_stream()
         if self._spec_fn is None:
             self._spec_fn = self._build_spec_step()
         with self.trainer.mesh:

@@ -130,7 +130,12 @@ def _paged_decode_choice(engine):
     kv = getattr(engine, "kv", None)
     if kv is None:
         return "none (dense per-slot KV)"
-    return paged_decode_impl(kv.max_blocks_per_slot)
+    import jax
+
+    arena = max((leaf for leaf in jax.tree.leaves(kv.pools)
+                 if leaf.ndim == 4), key=lambda leaf: leaf.shape[-1])
+    return paged_decode_impl(kv.max_blocks_per_slot, arena,
+                             kv.kv_cache_dtype == "int8")
 
 
 def build_server(args):

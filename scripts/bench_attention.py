@@ -20,6 +20,16 @@ kernel interprets and the timings only rank interpreter overhead.
 
 Usage:  python scripts/bench_attention.py --paged [--write] \\
             [b h hkv d L bs t]
+
+`--paged-trip` sweeps how many arena rows a trip of the paged kernel's
+pool stream scores at once (attention._PAGED_TRIP_ROWS, a constant:
+the winner is written into the code by hand) at a serving shape, one
+call a slot under jax.vmap as the engine makes them, over three sets
+of lengths: a steady generation mix with free lanes, every lane free,
+and every lane at the table's end with no window.
+
+Usage:  python scripts/bench_attention.py --paged-trip \\
+            [slots hkv group d bs m window]
 """
 
 import os
@@ -127,6 +137,82 @@ def paged_sweep(argv, write):
         print("wrote paged_rows=%d to %s" % (best, path))
 
 
+def paged_trip_sweep(argv):
+    """Time the vmapped per-slot paged decode at each trip width; the
+    per-call time is the step's over the slots."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.ops import attention
+
+    try:
+        shape = [int(a) for a in argv] or [16, 2, 12, 128, 16, 1024, 4096]
+        slots, hkv, group, d, bs, m, window = shape
+    except ValueError:
+        sys.exit("usage: bench_attention.py --paged-trip "
+                 "[slots hkv group d bs m window]")
+    rs = np.random.RandomState(0)
+    nb = slots * m + 1
+    free = slots * 5 // 16
+    mixes = {
+        # the benchmark's gen-steady lengths, scaled to the table: a
+        # few per cent of it in reach, a third of the lanes free
+        "steady": [0] * free + [
+            int(x) for x in np.minimum(
+                m * bs // 7, m * bs // 200 + 1 + rs.exponential(
+                    m * bs / 38, size=slots - free))],
+        "all free": [0] * slots,
+        "table's end": [m * bs] * slots,
+    }
+
+    def rows(*s):
+        return jnp.asarray(rs.randn(*s).astype(np.float32), jnp.bfloat16)
+
+    q, k_cur, v_cur = (rows(slots, n, 1, d)
+                       for n in (hkv * group, hkv, hkv))
+    k_pool, v_pool = rows(nb, bs, hkv, d), rows(nb, bs, hkv, d)
+
+    def step(use_kernel, win):
+        def one(q1, k1, v1, tbl1, len1):
+            return attention.paged_decode_attention(
+                q1[None], k1[None], v1[None], k_pool, v_pool, tbl1[None],
+                len1[None], window=win, use_kernel=use_kernel)[0]
+
+        return jax.jit(jax.vmap(one))
+
+    for name, lengths in mixes.items():
+        win = None if name == "table's end" else window
+        table = np.full((slots, m), -1, np.int32)
+        taken = 1
+        for i, ln in enumerate(lengths):
+            used = -(-int(ln) // bs)
+            table[i, :used] = np.arange(taken, taken + used)
+            taken += used
+        args = (q, k_cur, v_cur, jnp.asarray(table),
+                jnp.asarray(lengths, jnp.int32))
+        live = sum(int(hi - lo) for lo, hi in zip(
+            *attention.paged_live_blocks(
+                np.asarray(lengths), win, bs, m, xp=np)))
+        print("%s: %d slots, %d of %d table slots in reach"
+              % (name, slots, live, slots * m))
+        t_scan = timed(step(False, win), args, iters=5)
+        print("  scan                      %9.1f us a call"
+              % (t_scan / slots * 1e6))
+        for trip_rows in (32, 64, 128, 256, 512, 1024, 2048):
+            attention._PAGED_TRIP_ROWS = trip_rows
+            rows = attention.resolve_paged_rows()
+            kblk = attention._paged_trip_blocks(
+                hkv * -(-group // rows) * rows, bs * hkv, m)
+            try:
+                t_fused = timed(step(True, win), args, iters=50)
+            except Exception as e:  # noqa: BLE001
+                print("  trip rows %-5d blocks %-3d FAILED: %r"
+                      % (trip_rows, kblk, repr(e)[:200]))
+                continue
+            print("  trip rows %-5d blocks %-3d %9.1f us a call"
+                  % (trip_rows, kblk, t_fused / slots * 1e6))
+
+
 def main():
     import jax
     import jax.numpy as jnp
@@ -210,5 +296,8 @@ if __name__ == "__main__":
         if _write:
             _argv.remove("--write")
         paged_sweep(_argv, _write)
+    elif "--paged-trip" in _argv:
+        _argv.remove("--paged-trip")
+        paged_trip_sweep(_argv)
     else:
         main()

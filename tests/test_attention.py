@@ -1247,3 +1247,198 @@ def test_paged_fused_matches_naive(window, quantized):
             fused[i], ref, rtol=2e-5, atol=2e-5,
             err_msg="row %d window=%r int8=%r" % (i, window, quantized),
         )
+
+
+# --------------------------------- the stream follows the live range
+#
+# _paged_kernel streams the table slots [j_lo, j_hi) in reach of a
+# sequence (paged_live_blocks), _paged_trip_blocks of them a trip. The
+# battery pins a trip at 3 blocks over a table of 8 (4-token blocks) so
+# that lengths fall short of, on and past a trip's end, and a window
+# starts the range inside a trip and on a trip's first block.
+
+_S_BS, _S_M, _S_HKV, _S_GROUP, _S_D, _S_TRIP = 4, 8, 2, 2, 8, 3
+
+
+@pytest.fixture
+def three_block_trips(monkeypatch):
+    from elasticdl_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_PAGED_TRIP_ROWS",
+                        _S_TRIP * _S_BS * _S_HKV)
+    assert attention._paged_trip_blocks(8, _S_BS * _S_HKV, _S_M) == _S_TRIP
+
+
+def _stream_case(lengths, t, arena, seed=0):
+    """Operands for paged_decode_attention over ragged `lengths`: each
+    sequence owns ceil(length / bs) scattered blocks, the rest of its
+    table row is -1, and no sequence owns block 0 (where -1 clamps).
+    `arena` is "bf16" or "int8"."""
+    rs = np.random.RandomState(seed)
+    b, h = len(lengths), _S_HKV * _S_GROUP
+    nb = 1 + b * _S_M
+    q = rs.randn(b, h, t, _S_D).astype(np.float32)
+    cur = [rs.randn(b, _S_HKV, t, _S_D).astype(np.float32)
+           for _ in range(2)]
+    pools = [rs.randn(nb, _S_BS, _S_HKV, _S_D).astype(np.float32)
+             for _ in range(2)]
+    table = np.full((b, _S_M), -1, np.int32)
+    order = 1 + rs.permutation(nb - 1)
+    for i, ln in enumerate(lengths):
+        used = -(-int(ln) // _S_BS)
+        table[i, :used] = order[i * _S_M:i * _S_M + used]
+    kwargs = {}
+    if arena == "int8":
+        (k_cur, kcs), (v_cur, vcs) = (_rowquant(x) for x in cur)
+        (k_pool, ksp), (v_pool, vsp) = (_rowquant(x) for x in pools)
+        kwargs = dict(
+            k_scale_pool=jnp.asarray(ksp), v_scale_pool=jnp.asarray(vsp),
+            k_cur_scale=jnp.asarray(kcs), v_cur_scale=jnp.asarray(vcs),
+        )
+        arrays = (q, k_cur, v_cur, k_pool, v_pool)
+    else:
+        arrays = tuple(jnp.asarray(x, jnp.bfloat16)
+                       for x in (q, *cur, *pools))
+    args = tuple(jnp.asarray(a) for a in arrays) + (
+        jnp.asarray(table), jnp.asarray(lengths, jnp.int32))
+    return args, kwargs
+
+
+def _both_paths(args, kwargs, window, vmapped=False):
+    """(scan, fused) results, bare over the batch or — as the serving
+    engine calls it — one sequence a call under jax.vmap over slots,
+    the pools closed over."""
+    from elasticdl_tpu.ops.attention import paged_decode_attention
+
+    def attend(use_kernel, *a, **kw):
+        return paged_decode_attention(*a, use_kernel=use_kernel,
+                                      window=window, **kw)
+
+    if not vmapped:
+        return tuple(attend(use, *args, **kwargs) for use in (False, True))
+    q, k_cur, v_cur, k_pool, v_pool, table, length = args
+    cur = {k: v for k, v in kwargs.items() if "cur" in k}
+    pools = {k: v for k, v in kwargs.items() if "pool" in k}
+
+    def lanes(use_kernel):
+        def one(q1, k1, v1, tbl1, len1, cur1):
+            return attend(
+                use_kernel, q1[None], k1[None], v1[None], k_pool, v_pool,
+                tbl1[None], len1[None], **pools,
+                **{k: v[None] for k, v in cur1.items()})[0]
+
+        return jax.vmap(one)(q, k_cur, v_cur, table, length, cur)
+
+    return lanes(False), lanes(True)
+
+
+def _assert_paths_agree(args, kwargs, window, vmapped=False):
+    scan, fused = _both_paths(args, kwargs, window, vmapped)
+    assert fused.shape == scan.shape
+    assert np.isfinite(np.asarray(fused)).all()
+    np.testing.assert_allclose(np.asarray(fused), np.asarray(scan),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("arena", ("bf16", "int8"))
+@pytest.mark.parametrize("t", (1, 8))
+@pytest.mark.parametrize("length", (
+    0, 1, _S_BS - 1, _S_BS, _S_TRIP * _S_BS - 1, _S_TRIP * _S_BS,
+    _S_TRIP * _S_BS + 1, _S_M * _S_BS))
+def test_paged_stream_ends_where_the_sequence_does(
+        length, t, arena, three_block_trips):
+    """No trip, part of one, exactly one, one and a block, and the
+    whole table: a sequence beside one of another length."""
+    args, kwargs = _stream_case([length, 2 * _S_TRIP * _S_BS - 2], t,
+                                arena, seed=length)
+    _assert_paths_agree(args, kwargs, window=None)
+
+
+@pytest.mark.parametrize("arena", ("bf16", "int8"))
+@pytest.mark.parametrize("t", (1, 8))
+@pytest.mark.parametrize("length,window,j_lo", (
+    (30, 9, 5), (30, 18, 3), (32, 32, 0), (17, 2, 4), (9, 1, 2)),
+    ids=("inside-a-trip", "a-trips-first-block", "window-is-the-table",
+         "two-keys", "no-key"))
+def test_paged_stream_starts_where_the_window_does(
+        length, window, j_lo, t, arena, three_block_trips):
+    from elasticdl_tpu.ops.attention import paged_live_blocks
+
+    lo, hi = paged_live_blocks(np.int32(length), window, _S_BS, _S_M,
+                               xp=np)
+    assert (lo, hi) == (j_lo, -(-length // _S_BS))
+    args, kwargs = _stream_case([length, 21], t, arena, seed=window)
+    _assert_paths_agree(args, kwargs, window=window)
+
+
+@pytest.mark.parametrize("arena", ("bf16", "int8"))
+@pytest.mark.parametrize("t", (1, 8))
+@pytest.mark.parametrize("window", (None, 9))
+def test_paged_stream_under_vmap_over_ragged_slots(
+        window, t, arena, three_block_trips):
+    """One call a slot, as the engine's step makes them: ragged
+    lengths, a free lane (length 0, table row all -1) among them."""
+    args, kwargs = _stream_case([13, 0, 32, 5, 24], t, arena, seed=t)
+    assert (np.asarray(args[5])[1] == -1).all()
+    _assert_paths_agree(args, kwargs, window, vmapped=True)
+
+
+@pytest.mark.parametrize("window", (None, 9))
+@pytest.mark.parametrize("vmapped", (False, True), ids=("bare", "vmap"))
+def test_paged_stream_never_reads_outside_the_live_range(
+        window, vmapped, three_block_trips):
+    """Every arena block outside its sequence's [j_lo, j_hi) holds
+    NaN — the blocks a window has left behind, block 0 (where a -1
+    clamps) and every block no table names: the fused result must
+    equal the clean pool's, the scan's turns NaN (it streams the whole
+    table and masks)."""
+    from elasticdl_tpu.ops.attention import paged_live_blocks
+
+    lengths = [30, 0, 13, 32]
+    args, kwargs = _stream_case(lengths, 1, "bf16", seed=3)
+    clean, _ = _both_paths(args, kwargs, window, vmapped)
+    table = np.asarray(args[5])
+    lo, hi = paged_live_blocks(np.asarray(lengths), window, _S_BS, _S_M,
+                               xp=np)
+    live = np.concatenate([table[i, lo[i]:hi[i]] for i in range(len(lengths))])
+    assert (live > 0).all()
+    dead = np.setdiff1d(np.arange(args[3].shape[0]), live)
+    assert 0 in dead and len(dead) > len(lengths)
+    poisoned = [np.array(pool, np.float32) for pool in args[3:5]]
+    for pool in poisoned:
+        pool[dead] = np.nan
+    args = args[:3] + tuple(
+        jnp.asarray(pool, jnp.bfloat16) for pool in poisoned) + args[5:]
+    scan, fused = _both_paths(args, kwargs, window, vmapped)
+    np.testing.assert_allclose(np.asarray(fused), np.asarray(clean),
+                               rtol=2e-5, atol=2e-5)
+    assert np.isnan(np.asarray(scan)).any()
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_paged_live_blocks_against_the_predicate(seed):
+    """Brute force over random (length, tile, window, block size, table
+    width): every block in which _paged_valid admits a key for some
+    tile row lies inside [j_lo, j_hi), and — with no window or one of
+    at least 2 — the range's first and last blocks each hold one."""
+    from elasticdl_tpu.ops.attention import _paged_valid, paged_live_blocks
+
+    rs = np.random.RandomState(seed)
+    for _ in range(300):
+        bs, m = int(rs.randint(1, 9)), int(rs.randint(1, 12))
+        length = int(rs.randint(0, m * bs + 1))
+        t = int(rs.randint(1, 10))
+        window = None if rs.rand() < 0.3 else int(rs.randint(1, m * bs + 3))
+        k_pos = np.arange(m * bs)[None, :]
+        row_pos = length + np.arange(t)[:, None]
+        seen = np.asarray(_paged_valid(k_pos, 1, length, row_pos, window))
+        seen = seen.any(0).reshape(m, bs).any(1)  # [m]: a visible key
+        j_lo, j_hi = (int(j) for j in paged_live_blocks(
+            np.int32(length), window, bs, m, xp=np))
+        case = (length, t, window, bs, m, j_lo, j_hi)
+        assert 0 <= j_lo <= j_hi <= m, case
+        assert not seen[:j_lo].any() and not seen[j_hi:].any(), case
+        if j_hi > j_lo and (window is None or window >= 2):
+            assert seen[j_lo] and seen[j_hi - 1], case
+        if length == 0:
+            assert j_lo == j_hi == 0, case

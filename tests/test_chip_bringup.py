@@ -272,6 +272,40 @@ def test_paged_gate_agrees_with_the_compilers_block_rule(hkv, int8):
     assert not attention._paged_kernel_supported(0)
 
 
+@pytest.mark.parametrize("bs,hkv,d,dtype,quantized,ok", [
+    (16, 2, 128, "bfloat16", False, True),    # the benchmark's serve cell
+    (16, 2, 128, "int8", True, True),         # 4 blocks' scales a row
+    (16, 8, 128, "int8", True, True),         # 128 scales: a row a block
+    (16, 8, 256, "float32", False, True),
+    (16, 8, 64, "bfloat16", False, False),    # half a lane tile
+    (16, 8, 192, "bfloat16", False, False),
+    (1, 1, 128, "bfloat16", False, False),    # half a 32-bit sublane
+    (2, 1, 128, "int8", True, False),
+    (1, 1, 128, "float32", False, True),
+    (8, 3, 128, "bfloat16", False, True),
+    (8, 3, 128, "int8", True, False),         # 24 scales do not tile 128
+    (16, 5, 128, "int8", True, False),
+], ids=lambda v: str(v))
+def test_paged_gate_is_what_the_compiler_took_for_a_described_v5e(
+        bs, hkv, d, dtype, quantized, ok):
+    """The compiled kernel copies blocks out of HBM itself; Mosaic
+    moves whole tiles (compiled for a described v5e over these shapes:
+    PERF.md §6, PR 25). The interpreter takes every shape, and so does
+    a call that names no pool."""
+    from elasticdl_tpu.ops import attention
+
+    pool = jax.ShapeDtypeStruct((32, bs, hkv, d), jnp.dtype(dtype))
+    with mock.patch.object(attention, "interpret_mode", lambda: False):
+        assert attention._paged_kernel_supported(8, pool, quantized) is ok
+        assert not attention._paged_kernel_supported(0, pool, quantized)
+        with mock.patch.object(attention, "use_paged_kernel", lambda: True):
+            impl = attention.paged_decode_impl(8, pool, quantized)
+    assert impl == ("pallas" if ok else
+                    "scan (the pool's blocks are not whole tiles)")
+    with mock.patch.object(attention, "interpret_mode", lambda: True):
+        assert attention._paged_kernel_supported(8, pool, quantized)
+
+
 def test_flash_under_a_mesh_lowers_only_inside_shard_map():
     from elasticdl_tpu.ops import attention
     from elasticdl_tpu.parallel import mesh as mesh_lib

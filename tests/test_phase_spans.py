@@ -90,9 +90,9 @@ def test_counts_are_windowable_entries_and_cumulative_totals():
         tracing.count("prompt_write.tokens", 61)
     tracing.count("prompts_prefilled")
     rec = tracing.recorder()
-    assert rec.counts() == {"prompt_write.launches": 4,
-                            "prompt_write.tokens": 61,
-                            "prompts_prefilled": 1}
+    assert rec.counts() == dict(dict.fromkeys(tracing.COUNTERS, 0), **{
+        "prompt_write.launches": 4, "prompt_write.tokens": 61,
+        "prompts_prefilled": 1})
     launches = [p for p in rec.phases() if p.name == "prompt_write.launches"]
     assert len(launches) == 1 and launches[0].attrs == {"n": 4}
     assert launches[0].start_ns == launches[0].end_ns
@@ -365,6 +365,87 @@ def test_tokens_equal_the_offline_oracle_with_the_spans_recording(
     # the engine compiled one step program: there is no split twin
     assert not hasattr(server.engine, "_step_fns_split")
     assert not hasattr(server.engine, "profiler")
+
+
+@pytest.mark.parametrize("window,want", [(0, 4), (6, 3)],
+                         ids=("full", "window6"))
+def test_a_decode_tick_counts_the_blocks_its_lanes_have_in_reach(
+        window, want):
+    """One tick of a 3-lane engine over 4-token blocks (table width
+    8) with lanes at positions 9, 3 and free: the paged kernel's live
+    range is [0, 3) / [0, 1) / empty, and a window of 6 moves the
+    first to [1, 3)."""
+    import jax
+
+    from elasticdl_tpu.common.model_utils import get_model_spec
+    from elasticdl_tpu.ops.attention import paged_live_blocks
+    from elasticdl_tpu.parallel import mesh as mesh_lib
+    from elasticdl_tpu.serving.admission import ServingRequest
+    from elasticdl_tpu.serving.engine import (
+        PagedContinuousBatchingEngine,
+    )
+    from elasticdl_tpu.training.trainer import Trainer
+
+    spec = get_model_spec("model_zoo",
+                          "transformer_lm.transformer_lm.custom_model")
+    trainer = Trainer(
+        spec, mesh=mesh_lib.build_mesh({"dp": 1},
+                                       devices=jax.devices()[:1]),
+        model_params="vocab_size=16; seq_len=32; embed_dim=32; "
+                     "num_heads=2; num_layers=2; attn_window=%d" % window,
+    )
+    dummy = np.zeros((1, 32), np.int32)
+    state = trainer.init_state(({"tokens": dummy}, dummy))
+    eng = PagedContinuousBatchingEngine(
+        trainer, state, num_slots=3, block_size=4, share_prefix=False)
+    eng.insert(ServingRequest(list(range(1, 10)), 4))
+    eng.insert(ServingRequest([3, 1, 2], 4))
+    assert list(eng._positions) == [9, 3, 0]
+    tracing.recorder().clear_phases()
+    assert len(eng.step()) == 2
+    lo, hi = paged_live_blocks(np.array([9, 3, 0]), window or None, 4, 8,
+                               xp=np)
+    assert int((hi - lo).sum()) == want
+    counts = tracing.recorder().counts()
+    entries = {p.name: p for p in tracing.recorder().phases()
+               if p.name.startswith("paged.")}
+    assert entries["paged.blocks_streamed"].attrs == {"n": want}
+    assert entries["paged.table_slots"].attrs == {"n": 3 * 8}
+    assert {p.parent for p in entries.values()} == {"tick.ensure"}
+    # once a tick whatever the depth, and cumulative like the others
+    before = counts["paged.table_slots"]
+    eng.step()  # positions 10, 4, 0: lane 1 now reaches its second block
+    counts = tracing.recorder().counts()
+    assert counts["paged.table_slots"] - before == 3 * 8
+    with pytest.raises(ValueError, match="unknown counter"):
+        tracing.count("paged.blocks")
+
+
+def test_the_queued_stream_share_file_reads_the_two_counters(monkeypatch):
+    """chipbench/layers/paged.stream_share.json (a file with no
+    BENCHMARK.json entry yet): blocks streamed over table slots, from
+    the ring, inside the window; nothing where the program counts
+    neither (the parent commit)."""
+    import importlib
+    import os
+
+    from chipbench import run as cb_run
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "layers",
+                           "paged.stream_share.json")) as f:
+        spec = json.load(f)
+    module, _, fn = spec["reader"].rpartition(":")
+    read = getattr(importlib.import_module(module), fn)
+    monkeypatch.setattr(cb_run, "_T0", time.time() - 1.0, raising=False)
+    measured = {"counters": {"setup_s": 0.5}, "window_s": 60.0}
+    with tracing.phase("tick", seq=1):
+        pass
+    assert read(measured, **spec["args"]) is None
+    for streamed in (3, 5):
+        tracing.count("paged.blocks_streamed", streamed)
+        tracing.count("paged.table_slots", 24)
+    assert read(measured, **spec["args"]) == 8 / 48
 
 
 def test_metrics_exposition_still_carries_the_phase_family(paged_server):

@@ -169,10 +169,11 @@ def test_rope_flash_compiled():
 
 
 def _paged_case(rng, b, t, int8, hkv=8, group=1, d=128, bs=16, m=6,
-                num_blocks=32):
+                num_blocks=32, lengths=None):
     """Operands for paged_decode_attention at the flagship serving
     shape: every sequence owns a distinct run of table slots, the last
-    slots are unallocated (-1) and the cached lengths end mid-block."""
+    slots are unallocated (-1) and the cached lengths end mid-block.
+    With `lengths` each sequence owns the blocks its length needs."""
     h = hkv * group
     dtype = jnp.bfloat16
 
@@ -192,10 +193,16 @@ def _paged_case(rng, b, t, int8, hkv=8, group=1, d=128, bs=16, m=6,
     table = np.full((b, m), -1, np.int32)
     length = np.zeros((b,), np.int32)
     ids = rng.permutation(num_blocks)
+    taken = 0
     for i in range(b):
-        used = m - 2 - (i % 2)
-        table[i, :used] = ids[i * m:i * m + used]
-        length[i] = used * bs - 5 - i
+        if lengths is None:
+            used = m - 2 - (i % 2)
+            length[i] = used * bs - 5 - i
+        else:
+            length[i] = lengths[i]
+            used = -(-lengths[i] // bs)
+        table[i, :used] = ids[taken:taken + used]
+        taken += used
     args = (q, k_cur, v_cur, k_pool, v_pool, jnp.asarray(table),
             jnp.asarray(length))
     kwargs = {}
@@ -206,21 +213,34 @@ def _paged_case(rng, b, t, int8, hkv=8, group=1, d=128, bs=16, m=6,
     return args, kwargs
 
 
+#: the benchmark's serving cell (sc2-3b-serve): 2 kv heads x 12 query
+#: heads each, 16,384 positions in 16-token blocks, window 4096; a free
+#: lane, lengths inside one trip of the stream, past one, past the
+#: window (the range starts inside the table)
+CELL = dict(hkv=2, group=12, m=1024, num_blocks=704,
+            lengths=(0, 72, 500, 2312, 8264))
+
+
 @pytest.mark.parametrize("vmapped", [False, True])
 @pytest.mark.parametrize("t", [1, 8])
 @pytest.mark.parametrize("int8", [False, True])
-def test_paged_decode_compiled(int8, t, vmapped):
+@pytest.mark.parametrize("shape", [{}, CELL], ids=["hkv8-m6", "cell"])
+def test_paged_decode_compiled(shape, int8, t, vmapped):
     """The fused paged kernel, compiled, against the lax.scan oracle at
-    hkv 8 / d 128 / block 16 — bare over a batch, and under jax.vmap
-    over slots with the pools closed over, which is how the serving
-    engine calls it (serving/engine.py _build_paged_step)."""
+    hkv 8 / d 128 / block 16 and at the serving cell's shape — bare
+    over a batch, and under jax.vmap over slots with the pools closed
+    over, which is how the serving engine calls it (serving/engine.py
+    _build_paged_step)."""
     rng = np.random.default_rng(11)
-    args, kwargs = _paged_case(rng, b=4, t=t, int8=int8)
+    args, kwargs = _paged_case(
+        rng, b=len(shape.get("lengths", range(4))), t=t, int8=int8,
+        **shape)
+    window = 4096 if shape else None
 
     def run(use_kernel):
         def attend(*a, **kw):
             return attention.paged_decode_attention(
-                *a, use_kernel=use_kernel, **kw)
+                *a, use_kernel=use_kernel, window=window, **kw)
 
         if not vmapped:
             return jax.jit(attend)(*args, **kwargs)
