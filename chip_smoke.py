@@ -684,7 +684,9 @@ def child_kernels(sizes):
     with engine.trainer.mesh:
         report["paged_step"] = describe(
             "paged decode step", engine._build_paged_step().trace(
-                engine._exec_variables, engine.kv.pools,
+                # traced and compiled, never run: the step donates
+                # the pool it is handed, and this one stays the engine's
+                engine.kv.pools, engine._exec_variables,
                 engine.kv.tables_device(),
                 jnp.asarray(engine._positions),
                 jnp.asarray(engine._last_tokens),

@@ -332,6 +332,9 @@ class DeviceMemoryAccountant(object):
         pool = getattr(self._engine, "_d_pool", None)
         if pool is None:
             return 0
+        # `nbytes` is shape x itemsize, never a buffer read: the
+        # scheduler thread donates this tree to the speculative step,
+        # and a leaf seen here may already be deleted
         return sum(int(getattr(leaf, "nbytes", 0))
                    for leaf in jax.tree.leaves(pool))
 

@@ -135,7 +135,13 @@ COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             # serving/engine.py step() / _spec_step(), a decode tick:
             # table slots in reach of the lanes' sequences (what the
             # paged kernel streams a layer) of lanes x table width
-            "paged.blocks_streamed", "paged.table_slots")
+            "paged.blocks_streamed", "paged.table_slots",
+            # serving/kv_pool.py run_inplace(): calls of a program that
+            # takes a KV pool and hands one back, and those of them
+            # that consumed the pool they were handed (donation: the
+            # update ran in place). The ratio is 1.0 or some caller
+            # kept the pool from being donated
+            "pool.launches", "pool.inplace_launches")
 
 Phase = collections.namedtuple(
     "Phase", "name start_ns end_ns seq parent trace_id attrs")

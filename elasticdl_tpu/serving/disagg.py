@@ -80,10 +80,7 @@ def proto_to_blocks(msg, pool):
     block_size) surfaces as a ValueError the servicer downgrades to
     ok=False. Returns ``(blocks, leaf_dtypes)`` in import_chain's
     argument shape."""
-    import jax
-
-    shapes = [leaf.shape[1:] for leaf in jax.tree.leaves(pool.pools)
-              if leaf.ndim == 4]
+    shapes = pool.row_shapes  # recorded at construction: no buffer read
     dtypes = list(msg.leaf_dtypes)
     if len(dtypes) != len(shapes):
         raise ValueError(

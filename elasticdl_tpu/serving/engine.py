@@ -45,6 +45,14 @@ Two pool layouts share this scheduler surface:
   so short requests pack densely. Token streams are identical to the
   dense engine (the parity the e2e tests lock); only the memory
   geometry differs. Select with ServingConfig.kv_paged / EDL_KV_PAGED.
+  Its pool is UPDATED IN PLACE: the decode step, the speculative step,
+  the suffix / tile prefill and the pool's own block writes all donate
+  the pool tree they take (kv_pool.py's module docstring has the
+  contract). The engine holds the arenas only as `self.kv.pools`, the
+  draft's dense pool only as `self._d_pool`, and rebinds each from the
+  call's result in the same statement (`kv.update`, `run_inplace`); a
+  donating call that raises after consuming the pool is KVPoolLost,
+  which ends the scheduler like any step that raises.
 
 Weight-only int8 params (api/quantization): by default the engine
 dequantizes ONCE per set_params (initial load and every hot reload)
@@ -925,8 +933,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         with tracing.phase("suffix_tile", trace_id=_trace_id(request),
                            suffix_tokens=t, bucket=t_pad):
             with self.trainer.mesh:
-                self.kv.pools, first = fn(
-                    self._exec_variables, self.kv.pools,
+                first = self.kv.update(
+                    fn, self._exec_variables,
                     jnp.asarray(self.kv.tables[slot]),
                     jnp.asarray(chunk),
                     jnp.asarray(start, jnp.int32),
@@ -1044,8 +1052,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         with tracing.phase("prefill_tile", trace_id=_trace_id(request),
                            tile_tokens=t, bucket=t_pad), \
                 self.trainer.mesh:
-            self.kv.pools, first = fn(
-                self._exec_variables, self.kv.pools,
+            first = self.kv.update(
+                fn, self._exec_variables,
                 jnp.asarray(self.kv.tables[slot]),
                 jnp.asarray(chunk),
                 jnp.asarray(job.pos, jnp.int32),
@@ -1184,8 +1192,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                         jnp.asarray(self._seeds),
                         jnp.asarray(self._temps))
             with tracing.phase("tick.dispatch"):
-                self.kv.pools, nxt = self._step_fn(
-                    self._exec_variables, self.kv.pools, *args
+                nxt = self.kv.update(
+                    self._step_fn, self._exec_variables, *args
                 )
             with tracing.phase("tick.fetch"):
                 nxt = np.asarray(nxt)  # the host waits for the device
@@ -1237,11 +1245,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                         jnp.asarray(self._temps),
                         jnp.asarray(budgets))
             with tracing.phase("tick.dispatch"):
-                self.kv.pools, self._d_pool, toks, counts = (
-                    self._spec_fn(
-                        self._exec_variables, self._d_variables,
-                        self.kv.pools, self._d_pool, *args
-                    )
+                self._d_pool, toks, counts = self.kv.update(
+                    self._spec_fn, self._d_pool,
+                    self._exec_variables, self._d_variables, *args
                 )
             with tracing.phase("tick.fetch"):
                 toks = np.asarray(toks)
@@ -1281,7 +1287,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         top_k, top_p, qz = self.top_k, self.top_p, self._exec_qz
         block_size, num_blocks = self.block_size, self.num_blocks
 
-        def step(variables, pools, tables, positions, last_tokens,
+        def step(pools, variables, tables, positions, last_tokens,
                  seeds, temps):
             variables = _maybe_dequantize(variables, qz)
 
@@ -1328,7 +1334,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             "%d x %d-token blocks", self.num_slots, self.num_blocks,
             self.block_size,
         )
-        return self._tjit("paged_step", step)
+        return self._tjit("paged_step", step, donate_argnums=(0,))
 
     def _build_suffix_prefill(self, t_pad):
         """Compiled shared-prefix suffix prefill: decode a tile of up
@@ -1344,7 +1350,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         block_size, num_blocks = self.block_size, self.num_blocks
         max_blocks = self.kv.max_blocks_per_slot
 
-        def fn(variables, pools, table, chunk, start, t_real, seed,
+        def fn(pools, variables, table, chunk, start, t_real, seed,
                temp):
             variables = _maybe_dequantize(variables, qz)
             logits, aux = model.apply(
@@ -1375,7 +1381,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             "serving: compiling shared-prefix suffix prefill for "
             "tile %d", t_pad,
         )
-        return self._tjit("suffix_prefill[%d]" % t_pad, fn)
+        return self._tjit("suffix_prefill[%d]" % t_pad, fn,
+                          donate_argnums=(0,))
 
     def _build_draft_prefill(self, p_pad):
         d_model, d_kv_shapes = self._d_model, self._d_kv_shapes
@@ -1392,6 +1399,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         return self._tjit("draft_prefill[%d]" % p_pad, prefill)
 
     def _write_draft_slot(self, kv, slot):
+        from elasticdl_tpu.serving.kv_pool import run_inplace
+
         if self._d_write_fn is None:
             def write(pool, kv, idx):
                 def upd(p, n):
@@ -1402,9 +1411,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
                 return jax.tree.map(upd, pool, kv)
 
-            self._d_write_fn = self._tjit("draft_slot_write", write)
-        self._d_pool = self._d_write_fn(
-            self._d_pool, kv, jnp.asarray(slot, jnp.int32)
+            self._d_write_fn = self._tjit("draft_slot_write", write,
+                                          donate_argnums=(0,))
+        self._d_pool = run_inplace(
+            self._d_write_fn, self._d_pool, kv,
+            jnp.asarray(slot, jnp.int32),
         )
 
     def _build_spec_step(self):
@@ -1426,7 +1437,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         max_blocks = self.kv.max_blocks_per_slot
         k = self.draft_k
 
-        def step(variables, d_variables, pools, d_pool, tables,
+        def step(pools, d_pool, variables, d_variables, tables,
                  positions, last_tokens, seeds, temps, budgets):
             variables = _maybe_dequantize(variables, qz)
             # force the draft counters to the committed truth — the
@@ -1504,10 +1515,10 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             keep = (jnp.arange(k + 1)[None, :] < c[:, None]) & (bids >= 0)
             bids = jnp.where(keep, bids, num_blocks)
             pools = scatter_rows(pools, rows, bids, wpos % block_size)
-            return pools, d_pool_out, out_toks, c
+            return pools, (d_pool_out, out_toks, c)
 
         logger.info(
             "serving: compiling speculative draft-verify step "
             "(k=%d) for %d slots", k, self.num_slots,
         )
-        return self._tjit("spec_step", step)
+        return self._tjit("spec_step", step, donate_argnums=(0, 1))

@@ -91,6 +91,36 @@ latency rather than prefill compute. Invariants:
 * virtual ids are never reused, so a recycled device block id can
   never collide with a spilled entry's key.
 
+UPDATED IN PLACE: every compiled program that takes the pool tree and
+hands one back DONATES it (`donate_argnums=(0,)`: the pool is the
+program's first argument and its first result), so the scatter or
+`dynamic_update_slice` writes its few rows into the arenas themselves
+— a program that did not donate would first copy every arena to make
+its output (1.7 GB a launch at the serving cell's size). What that
+obliges the callers to:
+
+* after a donating call the tree that went in is DELETED. The pool is
+  held in exactly one place, `PagedKVPool.pools` (the engine's draft
+  pool in `_d_pool`), and `PagedKVPool.update` rebinds it from the
+  call's result before it returns; nobody else keeps a pool tree
+  across a call. Readers on the scheduler thread between calls (the
+  spill gather, `export_chain`) get NEW arrays out of a program that
+  donates nothing; readers on other threads (`disagg.proto_to_blocks`,
+  the memory accountant) read shapes and dtypes recorded at
+  construction (`row_shapes`, `leaf_dtypes()`, `bytes_total`), never a
+  buffer;
+* a donating call that raises AFTER it consumed its argument leaves no
+  pool at all: `update` then drops the tree and raises `KVPoolLost`,
+  and so does every later use — fail fast, never a "buffer has been
+  deleted" out of a later tick. The scheduler treats it as any step
+  that raises (`server.run`: crashed, every request aborted). A call
+  that raises BEFORE consuming it (a trace or shape error) leaves the
+  pool as it was;
+* `run_inplace` counts `pool.launches` once a call and
+  `pool.inplace_launches` when the tree that went in was in fact
+  consumed (one leaf's `is_deleted()`, a host-side flag): their ratio
+  is the share of pool updates that ran in place.
+
 Block ids enter the compiled decode step as DEVICE arrays (the tables),
 so slot churn and sequence growth never recompile anything — the same
 zero-recompile contract the dense pool holds, at block granularity.
@@ -116,6 +146,84 @@ class OutOfBlocks(Exception):
     scheduler treats this as backpressure: the request stays queued
     until completions free blocks (admission rejects outright only
     requests that could NEVER fit)."""
+
+
+class KVPoolLost(RuntimeError):
+    """A pool-updating program raised after it had consumed (donation)
+    the pool it was handed: the arenas are gone and every sequence's
+    rows with them. Raised by that call and by every later use of the
+    pool; the scheduler dies of it like of any step that raises and
+    aborts what is seated and queued (server.run)."""
+
+
+def run_inplace(program, pools, *args, **kwargs):
+    """Call ONE pool-updating program: `program(pools, *args)` donates
+    the pool tree and returns the updated tree, alone or first of a
+    `(pools, extra)` pair. `pools` is deleted when this returns — the
+    caller rebinds its one reference from the result in the same
+    statement. Counts the launch, and counts it as in place when the
+    tree was consumed. A program that raises with the tree consumed
+    raises KVPoolLost (from its error); one that raises with the tree
+    intact re-raises as it is."""
+    # one leaf stands for the tree, the largest: jit hands a leaf the
+    # program returns untouched (the zero-d position placeholder)
+    # straight back, never donated
+    probe = max(jax.tree.leaves(pools), key=lambda leaf: leaf.size)
+    try:
+        out = program(pools, *args, **kwargs)
+    except Exception as e:
+        if probe.is_deleted():
+            raise KVPoolLost(
+                "a pool-updating program raised after consuming the "
+                "KV pool: %r" % (e,)
+            ) from e
+        raise
+    tracing.count("pool.launches")
+    if probe.is_deleted():  # a flag on the host: no sync
+        tracing.count("pool.inplace_launches")
+    return out
+
+
+_HLO_DTYPE = {"bfloat16": "bf16", "float16": "f16", "float32": "f32",
+              "int8": "s8", "int32": "s32"}
+
+
+def _leaf_bytes(leaf):
+    """A leaf's bytes from its shape and dtype (an array or a shape):
+    sizes never come from a buffer."""
+    return int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+
+
+def pool_aliasing(compiled, *pool_trees):
+    """What a compiled pool-updating program does with the pool trees
+    it takes (arrays or shapes): `pool_bytes` they hold, `alias_bytes`
+    of its arguments that its results reuse
+    (`memory_analysis().alias_size_in_bytes`), and `pool_shaped_copies`,
+    the `copy` instructions of its optimized HLO whose result has an
+    arena's shape. In place means alias_bytes >= pool_bytes (the chip
+    gives the zero-d position placeholder a 512-byte tile) and no such
+    copy. For scripts/check_pool_donation.py and the tests: compiled,
+    nothing runs."""
+    import re
+
+    leaves = [leaf for tree in pool_trees
+              for leaf in jax.tree.leaves(tree)]
+    arenas = {
+        "%s[%s]" % (_HLO_DTYPE[np.dtype(leaf.dtype).name],
+                    ",".join(str(n) for n in leaf.shape))
+        for leaf in leaves if len(leaf.shape) == 4
+    }
+    copies = 0
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = (.*?) copy(?:-start)?\(", line)
+        if m and any(a in m.group(1) for a in arenas):
+            copies += 1
+    return {
+        "pool_bytes": sum(_leaf_bytes(leaf) for leaf in leaves),
+        "alias_bytes": int(
+            compiled.memory_analysis().alias_size_in_bytes),
+        "pool_shaped_copies": copies,
+    }
 
 
 def blocks_for(tokens, block_size):
@@ -785,7 +893,7 @@ def _pool_tjit(pool, name, fn, **jit_kwargs):
     """jax.jit with recompile-sentry adoption for the pool's compiled
     helpers — lazy like the engine's _tjit, so executables built
     before the server attaches the sentry still count later
-    compiles."""
+    compiles. The pool-updating ones pass `donate_argnums=(0,)`."""
     from elasticdl_tpu.observability.runtime_health import tracked_jit
 
     return tracked_jit(
@@ -805,7 +913,12 @@ class PagedKVPool(object):
     re-uploads only after a mutation (alloc/extend/CoW/release), so a
     decode step that crosses no block boundary costs zero host->device
     table traffic — the per-step assembly is one cached handle, not
-    per-slot work."""
+    per-slot work.
+
+    `pools` is the ONE reference to the arenas: every program that
+    updates them runs through `update`, which donates the tree and
+    rebinds it (module docstring, UPDATED IN PLACE). Never keep
+    `pool.pools` in a variable across such a call."""
 
     def __init__(self, kv_shapes, cache_len, num_slots, num_blocks,
                  block_size, share_prefix=False, host_bytes=0):
@@ -836,8 +949,13 @@ class PagedKVPool(object):
             leaf for leaf in jax.tree.leaves(self.pools)
             if leaf.ndim == 4
         ]
-        self.bytes_total = int(sum(leaf.nbytes for leaf in row_leaves))
+        self.bytes_total = sum(_leaf_bytes(leaf) for leaf in row_leaves)
         self.block_bytes = self.bytes_total // max(1, self.num_blocks)
+        # one block's row shape and the dtype of every row leaf, in
+        # jax.tree.leaves order, recorded here so that no reader needs
+        # a buffer for them (a handler thread may ask mid-update)
+        self.row_shapes = [tuple(leaf.shape[1:]) for leaf in row_leaves]
+        self._leaf_dtypes = [str(leaf.dtype) for leaf in row_leaves]
         # the arenas' storage format, advertised on stats/ServerStatus:
         # any int8 row leaf means the quantized format (its f32 scale
         # leaves ride along)
@@ -875,6 +993,34 @@ class PagedKVPool(object):
         # edl_serving_recompiles_total{fn=} family. None = plain jit.
         self.sentry = None
 
+    # ----------------------------------------------------- in-place update
+
+    def _live_pools(self):
+        if self.pools is None:
+            raise KVPoolLost(
+                "the KV pool was lost to a pool-updating program that "
+                "raised after consuming it; this engine serves no more"
+            )
+        return self.pools
+
+    def update(self, program, *args, **kwargs):
+        """Run one pool-updating program over the arenas, in place:
+        `program(pools, *args)` donates the tree (run_inplace) and the
+        result is bound to `self.pools` before this returns. Returns
+        what the program returned beside the pool (None for a program
+        that returns the pool alone). Scheduler thread only."""
+        try:
+            out = run_inplace(program, self._live_pools(), *args,
+                              **kwargs)
+        except KVPoolLost:
+            self.pools = None  # nothing may point at deleted arenas
+            raise
+        if isinstance(out, tuple):  # a pool tree is a mapping
+            self.pools, extra = out
+            return extra
+        self.pools = out
+        return None
+
     # ----------------------------------------------------------- lifecycle
 
     def can_seat(self, prompt, prompt_tokens, commit_tokens):
@@ -904,7 +1050,10 @@ class PagedKVPool(object):
         compiled gather with a traced bid. The spill sink and the
         chain export both read through here, so an exported chain is
         byte-identical to what the host spill tier would hold for the
-        same blocks."""
+        same blocks. The gather donates nothing and returns NEW
+        arrays, fetched before this returns — safe beside the donating
+        programs because it runs on the scheduler thread, between
+        them."""
         if self._gather_fn is None:
             def gather(pools, b):
                 return [leaf[b] for leaf in jax.tree.leaves(pools)
@@ -913,7 +1062,8 @@ class PagedKVPool(object):
             self._gather_fn = _pool_tjit(
                 self, "kv_spill_gather", gather
             )
-        rows = self._gather_fn(self.pools, jnp.asarray(bid, jnp.int32))
+        rows = self._gather_fn(self._live_pools(),
+                               jnp.asarray(bid, jnp.int32))
         return [np.asarray(r) for r in rows]
 
     def _spill_block(self, bid, vid):
@@ -930,18 +1080,18 @@ class PagedKVPool(object):
 
     def _upload_rows(self, staged):
         """Upload staged `(bid, [np rows per leaf])` row sets into
-        their device blocks: ONE batched scatter over the block axis,
-        padded to a power-of-two bucket (pad lanes carry the
-        out-of-bounds drop id), so a handful of executables serve
-        every upload size. Revival and chain import both land here —
-        the import path is the revival upload pointed at a sibling
-        replica's bytes instead of this host's spill store."""
+        their device blocks: ONE launch, its rows padded to a
+        power-of-two bucket (the program writes the real lanes only),
+        so a handful of executables serve every upload size. Revival
+        and chain import both land here — the import path is the
+        revival upload pointed at a sibling replica's bytes instead of
+        this host's spill store."""
         span = tracing.begin("revive_upload", blocks=len(staged))
         k = len(staged)
         k_pad = 1
         while k_pad < k:
             k_pad *= 2
-        bids = np.full(k_pad, self.num_blocks, np.int32)  # drop lanes
+        bids = np.zeros(k_pad, np.int32)  # lanes past k are not run
         per_leaf = None
         for i, (bid, rows) in enumerate(staged):
             bids[i] = bid
@@ -951,31 +1101,59 @@ class PagedKVPool(object):
                 ]
             for j, r in enumerate(rows):
                 per_leaf[j][i] = r
-        fn = self._upload_fns.get(k_pad)
-        if fn is None:
-            def upload(pools, rows_list, b):
-                flat, treedef = jax.tree_util.tree_flatten(pools)
-                out, it = [], iter(rows_list)
-                for leaf in flat:
-                    if leaf.ndim == 4:
-                        out.append(
-                            leaf.at[b].set(next(it), mode="drop")
-                        )
-                    else:
-                        out.append(leaf)
-                return jax.tree_util.tree_unflatten(treedef, out)
-
-            fn = _pool_tjit(
-                self, "kv_revive_upload[%d]" % k_pad, upload
-            )
-            self._upload_fns[k_pad] = fn
-        self.pools = fn(
-            self.pools,
-            [jnp.asarray(r) for r in per_leaf],
-            jnp.asarray(bids),
-        )
+        self.update(self._upload_program(k_pad),
+                    [jnp.asarray(r) for r in per_leaf],
+                    jnp.asarray(bids), jnp.asarray(k, jnp.int32))
         self.revive_uploads += 1
         tracing.end(span)
+
+    # the pool's own three updating programs, compiled on first use;
+    # each takes the pool first and donates it (module docstring)
+
+    def _upload_program(self, k_pad):
+        fn = self._upload_fns.get(k_pad)
+        if fn is None:
+            def upload(pools, rows_list, b, k):
+                # one `dynamic_update_slice` a block and leaf, like
+                # the prompt write, over the `k` real lanes only: a
+                # scatter over the block axis makes the chip's
+                # compiler re-lay the whole arena out, there and back
+                flat, treedef = jax.tree_util.tree_flatten(pools)
+                at = [i for i, leaf in enumerate(flat) if leaf.ndim == 4]
+
+                def one(i, flat):
+                    flat = list(flat)
+                    for rows, j in zip(rows_list, at):
+                        flat[j] = jax.lax.dynamic_update_slice(
+                            flat[j],
+                            jax.lax.dynamic_index_in_dim(rows, i),
+                            (b[i], 0, 0, 0),
+                        )
+                    return flat
+
+                flat = jax.lax.fori_loop(0, k, one, flat)
+                return jax.tree_util.tree_unflatten(treedef, flat)
+
+            fn = self._upload_fns[k_pad] = _pool_tjit(
+                self, "kv_revive_upload[%d]" % k_pad, upload,
+                donate_argnums=(0,),
+            )
+        return fn
+
+    def _write_program(self):
+        if self._write_fn is None:
+            self._write_fn = _pool_tjit(
+                self, "kv_prompt_write", write_prompt_block,
+                static_argnames=("block_size",), donate_argnums=(0,),
+            )
+        return self._write_fn
+
+    def _copy_program(self):
+        if self._copy_fn is None:
+            self._copy_fn = _pool_tjit(
+                self, "kv_cow_copy", copy_block, donate_argnums=(0,),
+            )
+        return self._copy_fn
 
     def _apply_revivals(self):
         """Upload the rows of every chain entry the last seat revived
@@ -994,8 +1172,7 @@ class PagedKVPool(object):
         """Row-leaf dtype names in jax.tree.leaves order — the arena
         format fingerprint a chain transfer carries so an importer can
         refuse a mismatched payload."""
-        return [str(leaf.dtype) for leaf in jax.tree.leaves(self.pools)
-                if leaf.ndim == 4]
+        return list(self._leaf_dtypes)
 
     def export_chain(self, prompt):
         """Export the longest indexed chain covering `prompt` as a
@@ -1126,18 +1303,15 @@ class PagedKVPool(object):
         into the slot's allocated blocks — block-granular, no
         whole-slot copy (shared blocks below start_block are already
         resident and must not be re-written)."""
-        if self._write_fn is None:
-            self._write_fn = _pool_tjit(
-                self, "kv_prompt_write", write_prompt_block,
-                static_argnames=("block_size",),
-            )
+        write = self._write_program()
         table = self.allocator.table(slot)
         blocks = range(start_block,
                        blocks_for(prompt_tokens, self.block_size))
         with tracing.phase("prompt_write", blocks=len(blocks)):
             for j in blocks:
-                self.pools = self._write_fn(
-                    self.pools, kv, jnp.asarray(j, jnp.int32),
+                # `kv` is NOT donated: every block's launch reads it
+                self.update(
+                    write, kv, jnp.asarray(j, jnp.int32),
                     jnp.asarray(table[j], jnp.int32),
                     block_size=self.block_size,
                 )
@@ -1166,14 +1340,8 @@ class PagedKVPool(object):
         if moved is None:
             return None
         old, new = moved
-        if self._copy_fn is None:
-            self._copy_fn = _pool_tjit(
-                self, "kv_cow_copy", copy_block
-            )
-        self.pools = self._copy_fn(
-            self.pools, jnp.asarray(old, jnp.int32),
-            jnp.asarray(new, jnp.int32),
-        )
+        self.update(self._copy_program(), jnp.asarray(old, jnp.int32),
+                    jnp.asarray(new, jnp.int32))
         self._sync_row(slot)
         return moved
 

@@ -49,6 +49,7 @@ from elasticdl_tpu.observability.metrics import (
     MetricsServer,
     gauge_family,
     hist_family,
+    labeled_counter_family,
     metrics_port_default,
 )
 from elasticdl_tpu.serving.engine import (
@@ -1155,7 +1156,8 @@ class GenerationServer(object):
     def _metrics_families(self):
         """One replica scrape: the closed telemetry sets + latency
         histograms, plus the loop's phase spans as one labeled
-        histogram family and their ring's drop count (called on the
+        histogram family, its work counters as one labeled counter
+        family, and their ring's drop count (called on the
         exposition HTTP thread; each collector locks itself)."""
         fams = self.telemetry.prometheus()
         fams.append(hist_family(
@@ -1163,6 +1165,12 @@ class GenerationServer(object):
             "phase spans of the scheduler tick (tracing.phase): wall "
             "ms per phase, cumulative (shared log-linear scheme)",
             recorder().phase_hist_series(),
+        ))
+        fams.append(labeled_counter_family(
+            "edl_serving_work_total",
+            "work counted where it happens (tracing.count), cumulative",
+            [({"counter": name}, n)
+             for name, n in sorted(recorder().counts().items())],
         ))
         fams.append(gauge_family(
             "edl_serving_phase_ring_dropped",

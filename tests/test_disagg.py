@@ -53,8 +53,7 @@ def _exported_chain(pool, prompt):
     for name, leaf in pool.pools.items():
         if getattr(leaf, "ndim", 0) == 4:
             arenas[name] = jnp.asarray(
-                rs.randint(-127, 128, size=leaf.shape)
-                .astype(np.asarray(leaf).dtype)
+                rs.randint(-127, 128, size=leaf.shape).astype(leaf.dtype)
             )
     pool.pools = dict(pool.pools, **arenas)
     pool.register_prefix(0, prompt)

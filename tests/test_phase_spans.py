@@ -461,6 +461,10 @@ def test_metrics_exposition_still_carries_the_phase_family(paged_server):
     assert phases <= set(tracing.PHASES)
     dropped = fams["edl_serving_phase_ring_dropped"]["samples"]
     assert [v for _n, _lab, v in dropped] == [0]
+    work = {lab["counter"]: v for _n, lab, v in
+            fams["edl_serving_work_total"]["samples"]}
+    assert set(work) == set(tracing.COUNTERS)
+    assert work["pool.inplace_launches"] == work["pool.launches"] > 0
     assert "edl_serving_ttft_ms" in fams
 
 
