@@ -59,13 +59,6 @@
 #                   30 s lease heuristic — with zero accepted-request
 #                   loss; a deliberate device-buffer leak is convicted
 #                   by the memory accountant)
-#   serve-smoke   — closed-loop load vs the generation server; emits
-#                   the BENCH_SERVING.json serving-throughput record
-#   bench-compare — gate a fresh serve-smoke record against the
-#                   committed benchmarks/serving_baseline.json with
-#                   per-metric tolerances (tok/s, goodput, bytes/
-#                   token, the overhead-A/B ratio, zero steady
-#                   recompiles); exit nonzero on regression
 #   cluster-smoke — kind/minikube manifests smoke, env-gated
 #                   (EDL_CLUSTER_FULL=1 + a reachable cluster)
 
@@ -75,8 +68,8 @@ MESH_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 RUFF_VERSION = 0.8.4
 LINT_PATHS = elasticdl_tpu scripts tests
 
-.PHONY: native lint lint-changed test-fast test-drills drill serve-smoke \
-	bench-compare ci ci-fast cluster-smoke clean
+.PHONY: native lint lint-changed test-fast test-drills drill ci ci-fast \
+	cluster-smoke clean
 
 native:
 	$(MAKE) -C elasticdl_tpu/native
@@ -111,61 +104,6 @@ drill:
 	JAX_PLATFORMS=cpu EDL_KV_CACHE_DTYPE=int8 $(PY) scripts/run_autoscale_drill.py
 	JAX_PLATFORMS=cpu $(PY) scripts/run_stall_drill.py
 	JAX_PLATFORMS=cpu $(PY) scripts/run_rollout_drill.py
-
-# Serving smoke: closed-loop load against the real continuous-batching
-# server, one BENCH_*-style JSON line (p50/p99 TTFT, tok/s, goodput).
-# The shared-prefix workload (a pool of common system prompts + random
-# suffixes) runs FIVE ways at EQUAL KV bytes: dense, block-paged
-# (private), paged + refcounted prefix sharing, paged + sharing +
-# speculative decode (draft_k), and paged + sharing + spec over INT8
-# arenas (quantized block storage, ~3x the blocks in the same bytes) —
-# bytes-per-token, prefix-hit tokens, CoW copies, the draft accept
-# rate and the int8 greedy-match rate vs the int8 dense oracle
-# recorded under "kv"/"paged"/"paged_shared"/"paged_shared_spec"/
-# "paged_int8"/"int8_vs_shared". Arrivals follow a
-# --ramp piecewise-Poisson profile (the SAME generator the autoscale
-# drill uses), so every record also carries per-phase percentiles
-# under "phases". --kv_host_blocks additionally runs the tiered-KV
-# eviction-pressure A/B (its own long-prefix int8 rig, device pool
-# below the prefix working set, host tier off vs on at equal DEVICE
-# KV bytes) and records the "host_vs_evict" ratio block: what share
-# of the baseline's re-paid prefill tokens the host tier recovers by
-# revival upload, with steady-state post-eviction TTFT. --profile
-# records the loop's phase spans (observability/tracing.py `phase`,
-# always on in the server; p50/p99 per phase: tick.upload/
-# tick.dispatch/tick.fetch/prefill/prompt_write/suffix_tile/draft/
-# revive_upload ...) under "profile" plus a validated /metrics scrape,
-# and --overhead_ab runs the observability plane (exposition,
-# forensics, runtime health) OFF-vs-ON A/B on the paged+shared leg —
-# the bench FAILS if the enabled plane costs more than 5% tokens/sec
-# ("profiler_overhead" block).
-serve-smoke:
-	JAX_PLATFORMS=cpu $(PY) scripts/bench_serving.py \
-		--ramp "8:0.8,32:0.5,8:0.5" --compare_paged --kv_block_size 4 \
-		--shared_prefix --prefix_len 16 --suffix_len 1:4 \
-		--out_len 4:12 --draft_k 2 --kv_cache_dtype int8 \
-		--kv_host_blocks 84 --profile --overhead_ab --disagg \
-		--out BENCH_SERVING.json
-
-# the bench-trajectory gate: run AFTER serve-smoke has written a
-# fresh BENCH_SERVING.json; tolerances live in scripts/bench_compare.py
-# (override per metric with --tol). Update the baseline deliberately,
-# with the PR that improves it:
-#   make serve-smoke && cp BENCH_SERVING.json benchmarks/serving_baseline.json
-# The second leg re-runs the paged-attention microbench (scan + fused
-# Pallas kernel, smoke-sized) and gates its ratio blocks against
-# benchmarks/int8_scan_baseline.json the same way; refresh with
-#   python scripts/bench_int8_scan.py --seq_len 128 --iters 20 \
-#       --out benchmarks/int8_scan_baseline.json
-bench-compare:
-	$(PY) scripts/bench_compare.py \
-		--fresh BENCH_SERVING.json \
-		--baseline benchmarks/serving_baseline.json
-	JAX_PLATFORMS=cpu $(PY) scripts/bench_int8_scan.py \
-		--seq_len 128 --iters 20 --out BENCH_INT8_SCAN.json
-	$(PY) scripts/bench_compare.py \
-		--fresh BENCH_INT8_SCAN.json \
-		--baseline benchmarks/int8_scan_baseline.json
 
 ci-fast: lint test-fast
 

@@ -93,7 +93,7 @@ class Sizes(object):
             "--model_def", MODEL_DEF,
             "--model_params", self.model_params,
             "--port", "0", "--num_slots", str(self.num_slots),
-            "--kv_paged", "1", "--kv_block_size", str(self.kv_block),
+            "--kv_block_size", str(self.kv_block),
         ]
 
 

@@ -6,8 +6,9 @@ three replicas, the serving bench's client-side samples — are
 mergeable by elementwise bucket-count addition and comparable without
 unit negotiation. Replacing point-gauges/EWMAs with these is what lets
 `ServerStatus`/`router_status` answer "what is p99 right now" and lets
-`bench_serving.py` and the live telemetry compute percentiles from the
-SAME code path (definitionally identical numbers).
+the drills' client-side samples and the live telemetry compute
+percentiles from the SAME code path (definitionally identical
+numbers).
 
 Scheme (values are non-negative floats; the system records
 milliseconds): the value is scaled by ``1/RESOLUTION`` to an integer
@@ -224,9 +225,9 @@ class LogLinearHistogram(object):
 
 def percentiles(values, qs=(50, 90, 99)):
     """Percentiles of `values` through the shared histogram — THE
-    entry point bench_serving.py and the tests use, so offline bench
+    entry point the drills and the tests use, so a drill's client-side
     numbers and live status-RPC numbers come from one definition.
-    {"p50": ...} with None entries when `values` is empty (a bench
+    {"p50": ...} with None entries when `values` is empty (a run
     with no completions has no percentile, unlike a live histogram
     where 0 means "no data yet")."""
     if not values:

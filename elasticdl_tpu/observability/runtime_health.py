@@ -22,7 +22,7 @@ layers behind one `ServingConfig.runtime_health` switch:
   prefill/suffix bucket); a SECOND compile of the same name is a
   RECOMPILE, and after `mark_steady()` (the post-warmup boundary) a
   recompile is a counted, trace-evented ANOMALY — the invariant
-  serve-smoke asserts at zero. Exposed as the closed labeled family
+  tests/test_runtime_health.py holds a live server to at zero. Exposed as the closed labeled family
   `edl_serving_recompiles_total{fn=...}`.
 
 * **DeviceMemoryAccountant** — periodic reconciliation of the
@@ -96,7 +96,7 @@ BUNDLE_SCHEMA = "edl-health-bundle/1"
 def runtime_health_default():
     """EDL_RUNTIME_HEALTH resolves the health plane when the config
     leaves it unset: on unless explicitly '0' (the plane's cost is
-    bounded by the serve-smoke overhead A/B, like forensics)."""
+    inside the benchmark's `tick.host_ms`, like forensics)."""
     return os.environ.get(HEALTH_ENV, "1") != "0"
 
 
@@ -129,8 +129,8 @@ class RecompileSentry(object):
     RECOMPILE is a compile of a name that was already compiled once
     (the engine's call sites all carry fixed shapes per name, so a
     recompile is never legitimate); a STEADY RECOMPILE is a recompile
-    after `mark_steady()` — the anomaly class serve-smoke pins at
-    zero. First compiles of a NEW name after the boundary are fine:
+    after `mark_steady()` — the anomaly class the live-server test
+    pins at zero. First compiles of a NEW name after the boundary are fine:
     a prefill bucket first exercised mid-serve is the cold path
     working as designed, not churn recompiling."""
 

@@ -296,8 +296,7 @@ class SpanRecorder(object):
 
     def phase_snapshot(self):
         """{phase: {count, p50_ms, p99_ms, total_ms}} for phases that
-        recorded anything, cumulative (the bench's BENCH_SERVING.json
-        ``profile`` shape)."""
+        recorded anything, cumulative."""
         with self._phase_lock:
             return {
                 name: {

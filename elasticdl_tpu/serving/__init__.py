@@ -7,9 +7,9 @@ the inference half — it turns the offline decode library
 
 * admission.py   bounded request queue with backpressure + deadlines
 * engine.py      continuous-batching decode scheduler over a fixed
-                 pool of KV-cache slots (one jit step, no recompiles
-                 on membership change); dense per-slot stripes or the
-                 block-paged pool (EDL_KV_PAGED / ServingConfig)
+                 pool of slots whose KV rows live in the block-paged
+                 pool (one jit step, no recompiles on membership
+                 change)
 * kv_pool.py     block-paged KV storage: free-list allocator, per-slot
                  block tables, shared per-layer block arenas, and the
                  tiered host-spill cache (evicted prefix chains park
@@ -37,7 +37,6 @@ from elasticdl_tpu.serving.admission import (  # noqa: F401
     ServingRequest,
 )
 from elasticdl_tpu.serving.engine import (  # noqa: F401
-    ContinuousBatchingEngine,
     PagedContinuousBatchingEngine,
 )
 from elasticdl_tpu.serving.kv_pool import (  # noqa: F401

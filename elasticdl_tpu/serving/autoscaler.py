@@ -165,14 +165,13 @@ class AutoscalerConfig(object):
         # fleet (serving/disagg.py): free+cached paged-KV headroom
         # across decode-capable replicas below this floor is pressure,
         # even while queues look healthy — imported chains and new
-        # seats will soon stop fitting. 0 disables (dense pools report
-        # no block counts; unified fleets scale on queue-wait alone).
+        # seats will soon stop fitting. 0 disables (unified fleets
+        # scale on queue-wait alone).
         self.up_free_kv_blocks = int(up_free_kv_blocks)
         self.idle_queue_wait_ms = float(idle_queue_wait_ms)
         self.down_window_secs = float(down_window_secs)
         # scale-down additionally requires this much free paged-KV
-        # headroom across the fleet (0 disables the gate — the dense
-        # pool reports no block counts)
+        # headroom across the fleet (0 disables the gate)
         self.down_free_kv_blocks = int(down_free_kv_blocks)
         self.cooldown_secs = float(cooldown_secs)
         self.ready_timeout_secs = float(ready_timeout_secs)

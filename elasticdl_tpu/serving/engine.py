@@ -1,4 +1,4 @@
-"""Continuous-batching decode engine over a fixed pool of KV slots.
+"""Continuous-batching decode engine over a block-paged KV pool.
 
 The offline decoder (api/generation.py) compiles one program per
 (batch, lengths, sampling) combination and runs each request cohort to
@@ -8,21 +8,22 @@ leaves the pool idle while the longest member finishes. This engine
 instead runs ONE jit-compiled single-token decode step over a fixed
 pool of `num_slots` batch slots, every step, forever:
 
-* each slot owns a batch-1 KV-cache tree (the same per-layer caches the
-  model's decode mode builds — including its scalar position counter),
-  stacked leaf-wise into a pool with leading axis [S, ...];
+* the KV rows of every layer live in shared block arenas
+  (serving/kv_pool.py); a slot holds a block table and a position, and
+  admission works against a token/block budget, so short requests pack
+  densely instead of pinning `seq_len` rows each;
 * the step `jax.vmap`s the model's decode over the slot axis, so every
-  slot advances at its OWN position — the per-slot cache counter drives
-  each layer's cache write, RoPE rotation and position-embedding lookup
-  exactly as in offline decode;
+  slot advances at its OWN position — the per-slot counter drives each
+  layer's RoPE rotation and position-embedding lookup exactly as in
+  offline decode, and the new token's rows scatter into the slot's own
+  block;
 * prompt insertion = one batched prefill (the offline `_run_prefill`,
-  bucketed to 64 like offline decode) + a `lax.dynamic_update_slice`
-  of the slot's cache rows at a TRACED slot index — membership changes
-  never recompile anything;
-* finished/expired slots are simply marked free host-side; their stale
-  cache rows are dead weight until the next insertion overwrites them
-  (free slots still ride through the vmapped step as masked work — the
-  static-shape price of zero recompiles).
+  bucketed to 64 like offline decode) + block-granular writes of the
+  prompt's rows at TRACED block ids — membership changes never
+  recompile anything;
+* finished/expired slots are marked free host-side and their blocks go
+  back to the free list (free slots still ride through the vmapped
+  step as masked work — the static-shape price of zero recompiles).
 
 Token parity: a request's output depends only on (params, prompt, seed,
 temperature) — never on what else shares the pool. Greedy and sampled
@@ -34,25 +35,14 @@ Single-threaded by design: only the scheduler thread may call
 insert/step/set_params (jax computations stay serialized; the gRPC
 threads touch only the admission queue and event plumbing).
 
-Two pool layouts share this scheduler surface:
-
-* ContinuousBatchingEngine — the DENSE pool: every slot owns a
-  contiguous `seq_len` KV stripe per layer. Simple, but decode HBM
-  scales as `num_slots x seq_len` no matter how short requests run.
-* PagedContinuousBatchingEngine — the BLOCK-PAGED pool
-  (serving/kv_pool.py): KV rows live in shared block arenas, slots
-  hold block tables, and admission works against a token/block budget
-  so short requests pack densely. Token streams are identical to the
-  dense engine (the parity the e2e tests lock); only the memory
-  geometry differs. Select with ServingConfig.kv_paged / EDL_KV_PAGED.
-  Its pool is UPDATED IN PLACE: the decode step, the speculative step,
-  the suffix / tile prefill and the pool's own block writes all donate
-  the pool tree they take (kv_pool.py's module docstring has the
-  contract). The engine holds the arenas only as `self.kv.pools`, the
-  draft's dense pool only as `self._d_pool`, and rebinds each from the
-  call's result in the same statement (`kv.update`, `run_inplace`); a
-  donating call that raises after consuming the pool is KVPoolLost,
-  which ends the scheduler like any step that raises.
+The pool is UPDATED IN PLACE: the decode step, the speculative step,
+the suffix / tile prefill and the pool's own block writes all donate
+the pool tree they take (kv_pool.py's module docstring has the
+contract). The engine holds the arenas only as `self.kv.pools`, the
+draft's dense per-slot pool only as `self._d_pool`, and rebinds each
+from the call's result in the same statement (`kv.update`,
+`run_inplace`); a donating call that raises after consuming the pool
+is KVPoolLost, which ends the scheduler like any step that raises.
 
 Weight-only int8 params (api/quantization): by default the engine
 dequantizes ONCE per set_params (initial load and every hot reload)
@@ -72,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from elasticdl_tpu.api.generation import (
+    _decode_cache,
     _kv_shapes_for,
     _maybe_dequantize,
     _prefill_bucket,
@@ -79,16 +70,14 @@ from elasticdl_tpu.api.generation import (
     _run_prefill,
     serving_next_token,
 )
+from elasticdl_tpu.api.quantization import (
+    dequantize_params,
+    is_quantized,
+)
 from elasticdl_tpu.common.log_utils import default_logger as logger
 from elasticdl_tpu.observability import tracing
 from elasticdl_tpu.observability.runtime_health import tracked_jit
 from elasticdl_tpu.ops.attention import paged_live_blocks
-
-
-def kv_paged_default():
-    """EDL_KV_PAGED resolves the pool layout when the config leaves it
-    unset — the env toggle the drills/CI use to prove both modes."""
-    return os.environ.get("EDL_KV_PAGED", "") not in ("", "0")
 
 
 def kv_shared_default():
@@ -191,385 +180,17 @@ class _PrefillJob(object):
         return self.first is not None
 
 
-class ContinuousBatchingEngine(object):
-    """The decode pool. `top_k`/`top_p` are server-level static sampling
-    filters (part of the compiled step); temperature and seed ride per
-    request as traced values."""
-
-    def __init__(self, trainer, state, num_slots, top_k=0, top_p=1.0):
-        model = trainer.model
-        _require_kv_convention(model)
-        if not getattr(model, "causal", True):
-            raise ValueError("serving needs a causal sequence model")
-        if num_slots < 1:
-            raise ValueError("num_slots must be >= 1")
-        if not 0.0 < top_p <= 1.0:
-            raise ValueError("top_p must be in (0, 1], got %r" % (top_p,))
-        self.trainer = trainer
-        self.model = model
-        self.num_slots = int(num_slots)
-        self.seq_len = int(model.seq_len)
-        self.top_k = int(top_k)
-        self.top_p = float(top_p)
-        # optional ServingTelemetry hook (GenerationServer wires it):
-        # the engine reports prefix-share / CoW / draft-accept events
-        # it alone can see; None costs nothing (tests, benches)
-        self.telemetry = None
-        # optional recompile sentry (runtime_health.RecompileSentry;
-        # the server attaches it under ServingConfig.runtime_health).
-        # Every jit site below compiles through _tjit, which resolves
-        # this LAZILY — executables built before the server attaches
-        # the sentry still count their later compiles. None = plain
-        # jax.jit, zero counting work.
-        self.sentry = None
-        self.draft_k = 0        # speculative decode off (paged engine
-        self.draft_proposed = 0  # overrides when a draft is seated)
-        self.draft_accepted = 0
-        # chunked prefill tile width (0 = monolithic); the dense pool
-        # never chunks — only the paged engine overrides this
-        self.prefill_chunk_tokens = 0
-        # cumulative wall ms this engine has spent inside insert()
-        # (prefill / suffix tile / draft prefill) — the scheduler
-        # advances it; the servicer stamps it at admission so seating
-        # can report how long OTHER requests' prefills held the
-        # single-threaded scheduler while this one waited
-        # (forensics: prefill_blocked_by_other). Written only by the
-        # scheduler thread, read racily by handler threads — a stale
-        # read under-reports blocking by at most one prefill, which
-        # the attribution tolerates by design.
-        self.prefill_busy_ms = 0.0
-
-        from elasticdl_tpu.api.quantization import is_quantized
-
-        self._qz = is_quantized(state.params)
-        # in-jit dequantize is opt-in (see the module docstring); the
-        # default path serves float weights cached by set_params
-        self._exec_qz = self._qz and _fused_dequant()
-        self._dequant_fn = None
-        self.set_params(state, version=getattr(state, "version", 0))
-
-        # batch-1 cache template -> pooled leaves [S, ...]; shares the
-        # trainer's compile cache so offline callers reuse the shapes
-        from elasticdl_tpu.api.generation import _decode_cache
-
-        self._kv_shapes = _kv_shapes_for(_decode_cache(trainer), model, 1)
-        self._init_pool()
-        self._slots = [None] * self.num_slots  # _Slot or None
-        self._last_tokens = np.zeros(self.num_slots, np.int32)
-        self._seeds = np.zeros(self.num_slots, np.int32)
-        self._temps = np.zeros(self.num_slots, np.float32)
-        self._prefill_fns = {}  # bucket -> compiled prefill
-        self._step_fn = None
-        self._write_fn = None
-
-    def _init_pool(self):
-        from elasticdl_tpu.api.generation import kv_row_leaf
-
-        self._pool = jax.tree.map(
-            lambda sh: jnp.zeros((self.num_slots,) + sh.shape, sh.dtype),
-            self._kv_shapes,
-        )
-        # KV ROW bytes only (the position counters are noise and would
-        # break the paged pool's equal-bytes comparison)
-        self._kv_bytes_total = self.num_slots * int(sum(
-            int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-            for leaf in jax.tree.leaves(self._kv_shapes)
-            if kv_row_leaf(leaf, self.seq_len)
-        ))
-
-    # ------------------------------------------------------------ params
-
-    def set_params(self, state, version):
-        """Swap the serving params (hot reload). Runs BETWEEN decode
-        steps (scheduler thread), so in-flight sequences simply continue
-        on the new weights — their KV caches, positions and pending
-        tokens are untouched. Shapes/dtypes must match the compiled
-        executables; a changed architecture needs a new server.
-
-        With int8 params (and the default non-fused path) this is also
-        the ONE place the weights dequantize: the cached float tree in
-        `_exec_variables` serves every prefill/decode step until the
-        next reload invalidates it here."""
-        # the FIRST set_params (construction, before the slots exist)
-        # is not a reload and records no phase
-        swap = (tracing.begin("reload_swap", version=int(version))
-                if hasattr(self, "_slots") else None)
-        self.variables = {"params": state.params, **state.model_state}
-        from elasticdl_tpu.api.quantization import is_quantized
-
-        if is_quantized(state.params) != self._qz and hasattr(
-                self, "_pool"):
-            raise ValueError(
-                "hot reload cannot change quantization (compiled "
-                "executables bake the dequantize path)"
-            )
-        self.model_version = int(version)
-        if self._qz and not self._exec_qz:
-            if self._dequant_fn is None:
-                from elasticdl_tpu.api.quantization import (
-                    dequantize_params,
-                )
-
-                self._dequant_fn = self._tjit(
-                    "dequant",
-                    lambda v: dict(
-                        v, params=dequantize_params(v["params"])
-                    ),
-                )
-            with self.trainer.mesh:
-                self._exec_variables = self._dequant_fn(self.variables)
-        else:
-            self._exec_variables = self.variables
-        if swap is not None:
-            tracing.end(swap)
-
-    # ------------------------------------------------------------- slots
-
-    def free_slots(self):
-        return [i for i, s in enumerate(self._slots) if s is None]
-
-    def active_count(self):
-        return sum(1 for s in self._slots if s is not None)
-
-    def active_requests(self):
-        return [s.request for s in self._slots if s is not None]
-
-    def can_seat(self, request):
-        """Whether `request` can be seated RIGHT NOW beyond needing a
-        free slot (the scheduler checks slots separately). The dense
-        pool has no other resource; the paged pool answers from its
-        block budget."""
-        return True
-
-    def max_cached_tokens(self):
-        """Largest prompt+decode cache footprint a request may ever
-        need — the admission queue's never-fits bound."""
-        return self.seq_len
-
-    def kv_stats(self):
-        """KV memory accounting for telemetry / ServerStatus. The
-        dense pool's total is resident whether slots are active or
-        not — exactly the pressure the paged pool relieves; in_use
-        reports the stripes live requests actually pin."""
-        per_slot = self._kv_bytes_total // max(1, self.num_slots)
-        return {
-            "kv_paged": False,
-            "kv_shared": False,
-            "kv_cache_dtype": getattr(
-                self.model, "kv_cache_dtype", "") or "",
-            "kv_block_size": 0,
-            "kv_blocks_total": 0,
-            "kv_blocks_free": 0,
-            "kv_blocks_cached": 0,
-            "kv_blocks_shared": 0,
-            "kv_bytes_total": self._kv_bytes_total,
-            "kv_bytes_in_use": self.active_count() * per_slot,
-            "prefix_hit_tokens": 0,
-            "cow_copies": 0,
-            "kv_host_blocks": 0,
-            "kv_host_bytes": 0,
-            "kv_host_bytes_budget": 0,
-            "revive_uploads": 0,
-            "prefill_tokens_revived": 0,
-            "host_drops": 0,
-        }
-
-    def insert(self, request):
-        """Seat `request` in a free slot: one prefill forward fills the
-        slot's per-layer caches for the prompt and produces the FIRST
-        generated token (pushed by the caller — this is the TTFT
-        boundary). Returns (slot_idx, first_token, finished); raises
-        RuntimeError when no slot is free (callers check free_slots)."""
-        free = self.free_slots()
-        if not free:
-            raise RuntimeError("no free slot")
-        slot = free[0]
-        p = len(request.prompt)
-        total = p + request.max_new_tokens
-        if total > self.seq_len:
-            raise ValueError(
-                "request needs %d positions > seq_len %d"
-                % (total, self.seq_len)
-            )
-        p_pad = _prefill_bucket(p, self.seq_len)
-        fn = self._prefill_fns.get(p_pad)
-        if fn is None:
-            fn = self._build_prefill(p_pad)
-            self._prefill_fns[p_pad] = fn
-        buf = np.zeros((1, self.seq_len), np.int32)
-        buf[0, :p] = request.prompt
-        with tracing.phase("prefill", trace_id=_trace_id(request),
-                           prompt_tokens=p, bucket=p_pad):
-            with self.trainer.mesh:
-                kv, first = fn(
-                    self._exec_variables, jnp.asarray(buf),
-                    jnp.asarray(p, jnp.int32),
-                    jnp.asarray(request.seed, jnp.int32),
-                    jnp.asarray(request.temperature, jnp.float32),
-                )
-                self._pool = self._write_slot(kv, slot)
-            first = int(first)  # the host waits for the first token
-        # lifecycle annotation on the request's serve span (no-op for
-        # untraced requests): which prefill bucket this paid for
-        if hasattr(request, "trace_event"):
-            request.trace_event("prefill", bucket=p_pad, slot=slot)
-        request.generated.append(first)
-        request.model_version = self.model_version
-        finished = request.max_new_tokens == 1
-        if not finished:
-            self._slots[slot] = _Slot(request, total)
-            self._last_tokens[slot] = first
-            self._seeds[slot] = request.seed
-            self._temps[slot] = request.temperature
-        return slot, first, finished
-
-    def evict(self, slot):
-        """Free a slot (completion or deadline eviction). The stale
-        cache rows stay until the next insert overwrites them."""
-        self._slots[slot] = None
-
-    def evict_expired(self, now):
-        """Evict every active request whose deadline has passed;
-        returns the evicted requests (the scheduler fails them with
-        DEADLINE_EXCEEDED — partial tokens already streamed stand).
-        Routed through evict() so the paged pool reclaims blocks."""
-        out = []
-        for i, st in enumerate(self._slots):
-            if st is not None and st.request.expired(now):
-                self.evict(i)
-                out.append(st.request)
-        return out
-
-    def step(self):
-        """One vmapped decode step over the WHOLE pool. Every active
-        slot advances one token at its own position; free slots run the
-        same compute against stale caches and are ignored (static shape,
-        zero recompiles). Returns [(slot, request, tokens, finished)]
-        for slots that were active — `tokens` is the LIST of tokens the
-        step committed for that slot (one here; the speculative paged
-        step can commit several). Finished slots are freed."""
-        active = [
-            (i, s) for i, s in enumerate(self._slots) if s is not None
-        ]
-        if not active:
-            return []
-        if self._step_fn is None:
-            self._step_fn = self._build_step()
-        with self.trainer.mesh:
-            with tracing.phase("tick.upload"):
-                args = (jnp.asarray(self._last_tokens),
-                        jnp.asarray(self._seeds),
-                        jnp.asarray(self._temps))
-            with tracing.phase("tick.dispatch"):
-                self._pool, nxt = self._step_fn(
-                    self._exec_variables, self._pool, *args
-                )
-            with tracing.phase("tick.fetch"):
-                nxt = np.asarray(nxt)  # blocks on the step
-        out = []
-        with tracing.phase("tick.commit"):
-            for slot, st in active:
-                token = int(nxt[slot])
-                st.request.generated.append(token)
-                st.request.model_version = self.model_version
-                self._last_tokens[slot] = token
-                finished = (
-                    len(st.request.prompt) + len(st.request.generated)
-                    >= st.max_total
-                )
-                if finished:
-                    self.evict(slot)
-                out.append((slot, st.request, [token], finished))
-        return out
-
-    # ------------------------------------------------------- compiled fns
-
-    def _tjit(self, name, fn, **jit_kwargs):
-        """jax.jit with recompile-sentry adoption: one fixed NAME per
-        call site (buckets included), so a second compile of any name
-        is, by construction, the churn-recompiles failure the sentry
-        exists to catch."""
-        return tracked_jit(
-            fn, name, lambda: getattr(self, "sentry", None),
-            **jit_kwargs,
-        )
-
-    def _build_prefill(self, p_pad):
-        model, kv_shapes = self.model, self._kv_shapes
-        top_k, top_p, qz = self.top_k, self.top_p, self._exec_qz
-
-        def prefill(variables, buf, p_len, seed, temperature):
-            variables = _maybe_dequantize(variables, qz)
-            kv, last = _run_prefill(
-                model, variables, kv_shapes, buf, p_len, p_pad
-            )
-            first = serving_next_token(
-                last[0], seed, p_len, temperature, top_k, top_p
-            )
-            return kv, first
-
-        logger.info("serving: compiling prefill for bucket %d", p_pad)
-        return self._tjit("prefill[%d]" % p_pad, prefill)
-
-    def _build_step(self):
-        model = self.model
-        top_k, top_p, qz = self.top_k, self.top_p, self._exec_qz
-
-        def step(variables, pool, last_tokens, seeds, temps):
-            variables = _maybe_dequantize(variables, qz)
-
-            def one(cache, tok, seed, temp):
-                # pre-advance counter: the model writes this token's
-                # k/v at `pos` and the sampled token lands at pos + 1
-                # (the offline loop's `_next_token(..., i + 1)`)
-                pos = cache["pos"]
-                logits, upd = model.apply(
-                    dict(variables, cache=cache),
-                    {"tokens": tok[None, None]},
-                    training=False, decode=True, mutable=["cache"],
-                )
-                nxt = serving_next_token(
-                    logits[0, 0], seed, pos + 1, temp, top_k, top_p
-                )
-                return upd["cache"], nxt
-
-            return jax.vmap(one)(pool, last_tokens, seeds, temps)
-
-        logger.info(
-            "serving: compiling decode step for %d slots", self.num_slots
-        )
-        return self._tjit("decode_step", step)
-
-    def _write_slot(self, kv, slot):
-        """Insert a batch-1 cache tree into the pool at a TRACED slot
-        index (one compiled write serves every slot)."""
-        if self._write_fn is None:
-            def write(pool, kv, idx):
-                def upd(p, n):
-                    start = (idx,) + (0,) * n.ndim
-                    return jax.lax.dynamic_update_slice(
-                        p, n[None], start
-                    )
-
-                return jax.tree.map(upd, pool, kv)
-
-            self._write_fn = self._tjit("slot_write", write)
-        return self._write_fn(
-            self._pool, kv, jnp.asarray(slot, jnp.int32)
-        )
-
-
-class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
+class PagedContinuousBatchingEngine(object):
     """The decode pool over BLOCK-PAGED KV storage (serving/kv_pool.py).
-
-    Same scheduler surface and token streams as the dense engine; the
-    differences are all memory geometry:
+    `top_k`/`top_p` are server-level static sampling filters (part of
+    the compiled step); temperature and seed ride per request as traced
+    values.
 
     * per-layer KV rows live in shared `[num_blocks, block_size, hkv,
       d]` arenas — total KV HBM is the BLOCK BUDGET, decoupled from
       `num_slots x seq_len`, so more concurrent slots fit in the same
       bytes when requests run short of `seq_len`;
-    * insert = the SAME batched prefill, then block-granular writes of
+    * insert = one batched prefill, then block-granular writes of
       the prompt's blocks into blocks allocated from the free list
       (never a whole-slot copy), with the request's full token budget
       RESERVED so decode growth cannot strand mid-flight;
@@ -640,11 +261,16 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         import inspect
 
         model = trainer.model
+        _require_kv_convention(model)
+        if not getattr(model, "causal", True):
+            raise ValueError("serving needs a causal sequence model")
         if "paged" not in inspect.signature(
                 type(model).__call__).parameters:
             raise ValueError(
-                "model %r lacks the paged-decode convention (`paged` "
-                "kwarg); serve it with the dense engine"
+                "model %r cannot be served: its __call__ must take the "
+                "`paged` argument (decode over {'pools', 'table'}: "
+                "attend through the block table and sow the new k/v "
+                "rows to \"kv_out\", as model_zoo/transformer_lm does)"
                 % type(model).__name__
             )
         if getattr(model, "kv_cache_dtype", "") not in ("", "int8"):
@@ -653,13 +279,22 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 "formats (kv_cache_dtype=%r)"
                 % (getattr(model, "kv_cache_dtype", ""),)
             )
+        if num_slots < 1:
+            raise ValueError("num_slots must be >= 1")
+        if not 0.0 < top_p <= 1.0:
+            raise ValueError("top_p must be in (0, 1], got %r" % (top_p,))
+        self.trainer = trainer
+        self.model = model
+        self.num_slots = int(num_slots)
+        self.seq_len = int(model.seq_len)
+        self.top_k = int(top_k)
+        self.top_p = float(top_p)
         self.block_size = int(block_size)
-        # 0 = dense-equivalent budget: the same KV bytes the dense
-        # pool would pin for this slot count
+        # 0 = the same-bytes budget: the KV rows of num_slots
+        # sequences of seq_len tokens
         self.num_blocks = int(num_blocks) or (
-            int(num_slots) * -(-int(model.seq_len) // self.block_size)
+            self.num_slots * -(-self.seq_len // self.block_size)
         )
-        self._share = bool(share_prefix)
         # host spill tier (None resolves from EDL_KV_HOST_BYTES): the
         # byte budget for chains demoted to host RAM on eviction,
         # revived by upload instead of re-prefill
@@ -667,8 +302,6 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             kv_host_bytes_default() if host_bytes is None
             else int(host_bytes)
         )
-        super().__init__(trainer, state, num_slots, top_k=top_k,
-                         top_p=top_p)
         # chunked prefill (None resolves from EDL_PREFILL_CHUNK_TOKENS;
         # 0 = monolithic): long prompts run as fixed-token tiles via
         # begin_insert/advance_prefill so the scheduler can interleave
@@ -677,9 +310,57 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             prefill_chunk_default() if prefill_chunk_tokens is None
             else int(prefill_chunk_tokens)
         )
+        # optional ServingTelemetry hook (GenerationServer wires it):
+        # the engine reports prefix-share / CoW / draft-accept events
+        # it alone can see; None costs nothing (tests, benches)
+        self.telemetry = None
+        self.draft_proposed = 0
+        self.draft_accepted = 0
+        # cumulative wall ms this engine has spent inside insert()
+        # (prefill / suffix tile / draft prefill) — the scheduler
+        # advances it; the servicer stamps it at admission so seating
+        # can report how long OTHER requests' prefills held the
+        # single-threaded scheduler while this one waited
+        # (forensics: prefill_blocked_by_other). Written only by the
+        # scheduler thread, read racily by handler threads — a stale
+        # read under-reports blocking by at most one prefill, which
+        # the attribution tolerates by design.
+        self.prefill_busy_ms = 0.0
+
+        from elasticdl_tpu.serving.kv_pool import PagedKVPool
+
+        # batch-1 cache template (shares the trainer's compile cache
+        # so offline callers reuse the shapes) -> the block arenas
+        self._kv_shapes = _kv_shapes_for(_decode_cache(trainer), model, 1)
+        self.kv = PagedKVPool(
+            self._kv_shapes, self.seq_len, self.num_slots,
+            self.num_blocks, self.block_size,
+            share_prefix=bool(share_prefix),
+            host_bytes=self.host_bytes,
+        )
+        # optional recompile sentry (runtime_health.RecompileSentry;
+        # the server attaches it under ServingConfig.runtime_health).
+        # Every jit site below compiles through _tjit, which resolves
+        # this LAZILY — executables built before the server attaches
+        # the sentry still count their later compiles. None = plain
+        # jax.jit, zero counting work.
+        self.sentry = None
+        self._qz = is_quantized(state.params)
+        # in-jit dequantize is opt-in (see the module docstring); the
+        # default path serves float weights cached by _load_params
+        self._exec_qz = self._qz and _fused_dequant()
+        self._dequant_fn = None
+        self._load_params(state, getattr(state, "version", 0))
+
+        self._slots = [None] * self.num_slots  # _Slot or None
         self._prefilling = {}  # slot -> _PrefillJob (chunked, pending)
         self._positions = np.zeros(self.num_slots, np.int32)
+        self._last_tokens = np.zeros(self.num_slots, np.int32)
+        self._seeds = np.zeros(self.num_slots, np.int32)
+        self._temps = np.zeros(self.num_slots, np.float32)
+        self._prefill_fns = {}  # bucket -> compiled prefill
         self._suffix_fns = {}  # suffix bucket -> compiled tile prefill
+        self._step_fn = None
         self._spec_fn = None
         # last-forwarded pool counters: the engine mirrors the pool's
         # monotone spill/revival counters into the closed telemetry
@@ -691,22 +372,12 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         }
         self._init_draft(draft, draft_k)
 
-    def _init_pool(self):
-        from elasticdl_tpu.serving.kv_pool import PagedKVPool
-
-        self.kv = PagedKVPool(
-            self._kv_shapes, self.seq_len, self.num_slots,
-            self.num_blocks, self.block_size,
-            share_prefix=self._share,
-            host_bytes=getattr(self, "host_bytes", 0),
-        )
-        self._kv_bytes_total = self.kv.bytes_total
-
     def _init_draft(self, draft, draft_k):
         """Seat the draft model for speculative decode: its own dense
         per-slot cache pool (the draft is small — that is the point)
         beside the paged target pool the reclaimed blocks feed."""
         self._draft = None
+        self.draft_k = 0  # speculative decode off unless a draft seats
         if draft is None or int(draft_k) < 1:
             return
         d_trainer, d_state = draft
@@ -726,15 +397,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 "draft seq_len %d must cover the target's %d"
                 % (d_model.seq_len, self.seq_len)
             )
-        from elasticdl_tpu.api.quantization import is_quantized
-
         if is_quantized(d_state.params):
             raise ValueError(
                 "speculative decode needs float draft params (the "
                 "draft is small; quantizing it buys nothing)"
             )
-        from elasticdl_tpu.api.generation import _decode_cache
-
         self.draft_k = int(draft_k)
         self._draft = d_trainer
         self._d_model = d_model
@@ -765,25 +432,73 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # forwards so those sites count into the same family; the
         # offline decode caches adopt it too (one process, one sentry)
         self._sentry = value
-        if hasattr(self, "kv"):
-            self.kv.sentry = value
+        self.kv.sentry = value
         from elasticdl_tpu.api import generation as _generation
 
         _generation.set_decode_sentry(value)
 
+    def _load_params(self, state, version):
+        """Bind `state`'s params as the serving weights. With int8
+        params (and the default non-fused path) this is the ONE place
+        the weights dequantize: the cached float tree in
+        `_exec_variables` serves every prefill/decode step until the
+        next reload replaces it here."""
+        self.variables = {"params": state.params, **state.model_state}
+        self.model_version = int(version)
+        if self._qz and not self._exec_qz:
+            if self._dequant_fn is None:
+                self._dequant_fn = self._tjit(
+                    "dequant",
+                    lambda v: dict(
+                        v, params=dequantize_params(v["params"])
+                    ),
+                )
+            with self.trainer.mesh:
+                self._exec_variables = self._dequant_fn(self.variables)
+        else:
+            self._exec_variables = self.variables
+
     def set_params(self, state, version):
-        """Hot reload, plus the sharing-specific obligation: cached
-        prefix rows were computed under the superseded params, so the
-        prefix index flushes — a NEW request must never seat on stale
-        rows (in-flight sequences keep their caches and continue on
-        the new weights, the same contract as the dense engine)."""
-        super().set_params(state, version)
-        if hasattr(self, "kv"):
-            self.kv.flush_prefix_cache()
+        """Swap the serving params (hot reload). Runs BETWEEN decode
+        steps (scheduler thread), so in-flight sequences simply continue
+        on the new weights — their KV rows, positions and pending
+        tokens are untouched. Shapes/dtypes must match the compiled
+        executables; a changed architecture needs a new server.
+        Cached prefix rows were computed under the superseded params,
+        so the prefix index flushes — a NEW request must never seat on
+        stale rows."""
+        if is_quantized(state.params) != self._qz:
+            raise ValueError(
+                "hot reload cannot change quantization (compiled "
+                "executables bake the dequantize path)"
+            )
+        with tracing.phase("reload_swap", version=int(version)):
+            self._load_params(state, version)
+        self.kv.flush_prefix_cache()
 
     # ------------------------------------------------------------- slots
 
+    def free_slots(self):
+        # a seated-but-still-prefilling slot is occupied: its blocks
+        # are reserved and its tiles are mid-flight
+        return [i for i, s in enumerate(self._slots)
+                if s is None and i not in self._prefilling]
+
+    def active_count(self):
+        return sum(1 for s in self._slots if s is not None)
+
+    def active_requests(self):
+        reqs = [s.request for s in self._slots if s is not None]
+        reqs.extend(j.request for j in self._prefilling.values())
+        return reqs
+
+    def prefilling_count(self):
+        return len(self._prefilling)
+
     def can_seat(self, request):
+        """Whether `request` can be seated RIGHT NOW beyond needing a
+        free slot (the scheduler checks slots separately): answered
+        from the block budget."""
         if (request.max_new_tokens <= 1
                 and not getattr(request, "prefill_only", False)):
             return True  # one-token answer; never touches the pool
@@ -792,10 +507,13 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                                 cached)
 
     def max_cached_tokens(self):
-        # a request must fit BOTH one slot's table and the whole pool
+        """Largest prompt+decode cache footprint a request may ever
+        need — the admission queue's never-fits bound: a request must
+        fit BOTH one slot's table and the whole pool."""
         return min(self.seq_len, self.num_blocks * self.block_size)
 
     def kv_stats(self):
+        """KV memory accounting for telemetry / ServerStatus."""
         return self.kv.stats()
 
     def _sync_host_telemetry(self):
@@ -815,14 +533,18 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 self._host_counters_seen[name] = stats[name]
 
     def insert(self, request):
-        """Dense-engine contract (prefill + first token), with the KV
-        landing in allocated blocks: the allocator reserves the FULL
-        cache budget (prompt + max_new_tokens - 1 rows) up front —
-        raising OutOfBlocks before any compute — so a seated request
-        can always extend to completion. A prompt whose prefix matches
+        """Seat `request` in a free slot: one prefill forward computes
+        the prompt's rows and the FIRST generated token (pushed by the
+        caller — this is the TTFT boundary), and the rows land in
+        allocated blocks. The allocator reserves the FULL cache budget
+        (prompt + max_new_tokens - 1 rows) up front — raising
+        OutOfBlocks before any compute — so a seated request can
+        always extend to completion. A prompt whose prefix matches
         the resident index seats the shared blocks by incref and runs
         ONLY the unshared suffix. A one-token request skips the pool
-        entirely (nothing will ever read its rows)."""
+        entirely (nothing will ever read its rows). Returns (slot_idx,
+        first_token, finished); raises RuntimeError when no slot is
+        free (callers check free_slots)."""
         free = self.free_slots()
         if not free:
             raise RuntimeError("no free slot")
@@ -954,20 +676,6 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         return first
 
     # --------------------------------------------------- chunked prefill
-
-    def free_slots(self):
-        # a seated-but-still-prefilling slot is occupied: its blocks
-        # are reserved and its tiles are mid-flight
-        return [i for i, s in enumerate(self._slots)
-                if s is None and i not in self._prefilling]
-
-    def active_requests(self):
-        reqs = [s.request for s in self._slots if s is not None]
-        reqs.extend(j.request for j in self._prefilling.values())
-        return reqs
-
-    def prefilling_count(self):
-        return len(self._prefilling)
 
     def begin_insert(self, request):
         """Chunked admission: seat `request` — the same full-budget
@@ -1135,13 +843,24 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         return min(self.seq_len, -(-int(t) // 8) * 8)
 
     def evict(self, slot):
-        """Free the slot AND drop its block references; private rows
-        are dead the moment the table forgets them, shared rows live
-        on under their other owners (copy-free churn — nothing is
-        zeroed or moved)."""
+        """Free the slot (completion or deadline eviction) AND drop
+        its block references; private rows are dead the moment the
+        table forgets them, shared rows live on under their other
+        owners (copy-free churn — nothing is zeroed or moved)."""
         self._slots[slot] = None
         self._positions[slot] = 0
         self.kv.release(slot)
+
+    def evict_expired(self, now):
+        """Evict every active request whose deadline has passed;
+        returns the evicted requests (the scheduler fails them with
+        DEADLINE_EXCEEDED — partial tokens already streamed stand)."""
+        out = []
+        for i, st in enumerate(self._slots):
+            if st is not None and st.request.expired(now):
+                self.evict(i)
+                out.append(st.request)
+        return out
 
     def _count_paged_stream(self):
         """Count what this tick's paged decode streams, a layer: the
@@ -1157,14 +876,16 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                       self.num_slots * self.kv.max_blocks_per_slot)
 
     def step(self):
-        """One vmapped decode step over the whole pool, paged: block
-        tables and positions enter as device arrays, each active slot
+        """One vmapped decode step over the WHOLE pool: block tables
+        and positions enter as device arrays, each active slot
         attends over its own table and its row scatters into its own
         block. Free lanes ride along masked (stale tokens, all-(-1)
-        tables, out-of-bounds scatter ids) — the dense engine's
-        static-shape contract, kept. With a draft seated the step is
-        the speculative draft-verify tick instead, committing 1..k+1
-        tokens per slot. Returns [(slot, request, tokens, finished)]."""
+        tables, out-of-bounds scatter ids): static shape, zero
+        recompiles. With a draft seated the step is the speculative
+        draft-verify tick instead. Returns [(slot, request, tokens,
+        finished)] for slots that were active — `tokens` is the LIST
+        of tokens the step committed for that slot (one here; the
+        speculative step commits 1..k+1). Finished slots are freed."""
         active = [
             (i, s) for i, s in enumerate(self._slots) if s is not None
         ]
@@ -1280,6 +1001,30 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
     # ------------------------------------------------------- compiled fns
 
+    def _tjit(self, name, fn, **jit_kwargs):
+        """jax.jit with recompile-sentry adoption: one fixed NAME per
+        call site (buckets included), so a second compile of any name
+        is, by construction, the churn-recompiles failure the sentry
+        exists to catch."""
+        return tracked_jit(fn, name, lambda: self.sentry, **jit_kwargs)
+
+    def _build_prefill(self, p_pad):
+        model, kv_shapes = self.model, self._kv_shapes
+        top_k, top_p, qz = self.top_k, self.top_p, self._exec_qz
+
+        def prefill(variables, buf, p_len, seed, temperature):
+            variables = _maybe_dequantize(variables, qz)
+            kv, last = _run_prefill(
+                model, variables, kv_shapes, buf, p_len, p_pad
+            )
+            first = serving_next_token(
+                last[0], seed, p_len, temperature, top_k, top_p
+            )
+            return kv, first
+
+        logger.info("serving: compiling prefill for bucket %d", p_pad)
+        return self._tjit("prefill[%d]" % p_pad, prefill)
+
     def _build_paged_step(self):
         from elasticdl_tpu.serving.kv_pool import scatter_rows
 
@@ -1292,9 +1037,10 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             variables = _maybe_dequantize(variables, qz)
 
             def one(table, pos, tok, seed, temp):
-                # pre-advance counter semantics match the dense step:
-                # this token's k/v rows belong at `pos`, the sampled
-                # token lands at pos + 1. The cache collection carries
+                # pre-advance counter: this token's k/v rows belong
+                # at `pos`, the sampled token lands at pos + 1 (the
+                # offline loop's `_next_token(..., i + 1)`). The cache
+                # collection carries
                 # ONLY the counter — the rows live in the shared
                 # arenas, read through this slot's table and written
                 # back via the sown "kv_out" rows. The scope is the

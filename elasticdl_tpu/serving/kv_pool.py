@@ -1,10 +1,9 @@
 """Block-paged KV storage for the serving engine.
 
-The dense decode pool (engine.py) gives every slot a contiguous
-`seq_len` stripe of cache per layer, so decode HBM scales as
-`num_slots x seq_len` even though most requests finish far short of
-`seq_len` — the padding is resident, bandwidth-neutral, and
-unsellable. This module converts that padding into admissible work:
+A pool that gave every slot a contiguous `seq_len` stripe of cache per
+layer would scale decode HBM as `num_slots x seq_len` even though most
+requests finish far short of `seq_len` — the padding resident and
+unsellable. This module spends those bytes on admissible work:
 
 * KV rows live in per-layer block ARENAS shaped
   `[num_blocks, block_size, kv_heads, head_dim]`, shared by every
@@ -122,8 +121,7 @@ obliges the callers to:
   is the share of pool updates that ran in place.
 
 Block ids enter the compiled decode step as DEVICE arrays (the tables),
-so slot churn and sequence growth never recompile anything — the same
-zero-recompile contract the dense pool holds, at block granularity.
+so slot churn and sequence growth never recompile anything.
 The device table upload is CACHED and refreshed only when some table
 actually changed (one device put per mutating step, not per slot —
 mid-decode steps where no block boundary is crossed reuse the resident

@@ -26,6 +26,15 @@ from elasticdl_tpu.common.log_utils import default_logger as logger
 from elasticdl_tpu.common.model_utils import get_model_spec
 
 
+def _kv_paged_flag(value):
+    if value != "1":
+        raise argparse.ArgumentTypeError(
+            "the dense KV pool was removed in PR 30: the block-paged "
+            "pool is the server's only layout (drop the flag, or pass 1)"
+        )
+    return 1
+
+
 def parse_serving_args(args=None):
     parser = argparse.ArgumentParser(
         description="elasticdl-tpu generation server"
@@ -51,24 +60,28 @@ def parse_serving_args(args=None):
                              "explicit reload_checkpoint RPC (the "
                              "rollout-managed fleet mode)")
     parser.add_argument("--tensorboard_log_dir", default="")
-    # KV pool layout: -1 resolves from EDL_KV_PAGED (the drill/CI
-    # toggle); 1 = block-paged pool (serving/kv_pool.py), 0 = dense
-    parser.add_argument("--kv_paged", type=int, default=-1,
-                        choices=(-1, 0, 1))
+    # selects nothing: the block-paged pool (serving/kv_pool.py) is the
+    # only KV layout. Still parsed because the benchmark's files pass
+    # `--kv_paged 1` (chipbench/configs/sc2-3b-serve.json and
+    # tests/chipbench/test_chipbench_dropin.py, through
+    # chipbench/drivers/open_loop.py) and only a `benchmark` PR may
+    # edit those (ROADMAP D1b); any other value is refused here
+    parser.add_argument("--kv_paged", type=_kv_paged_flag, default=1)
     parser.add_argument("--kv_block_size", type=int, default=16)
     parser.add_argument("--kv_num_blocks", type=int, default=0,
-                        help="block budget; 0 = dense-equivalent bytes")
-    # prefix sharing (paged only): -1 resolves from EDL_KV_SHARED
-    # (default on) — refcounted dedupe of matching prompt prefixes
+                        help="block budget; 0 = the rows of num_slots "
+                             "sequences of seq_len tokens")
+    # prefix sharing: -1 resolves from EDL_KV_SHARED (default on) —
+    # refcounted dedupe of matching prompt prefixes
     parser.add_argument("--kv_shared", type=int, default=-1,
                         choices=(-1, 0, 1))
-    # tiered host spill (paged only): byte budget for evicted prefix
+    # tiered host spill: byte budget for evicted prefix
     # chains demoted to host RAM and revived by upload instead of
     # re-prefill; -1 resolves from EDL_KV_HOST_BYTES, 0 = off
     parser.add_argument("--kv_host_bytes", type=int, default=-1)
     # speculative decode: a small DRAFT model proposes draft_k tokens
-    # per tick, verified in one target step (paged pool only; token-
-    # exact with plain decode)
+    # per tick, verified in one target step (token-exact with plain
+    # decode)
     parser.add_argument("--draft_k", type=int, default=0)
     parser.add_argument("--draft_model_def", default="",
                         help="zoo model_def for the draft; empty = "
@@ -109,8 +122,7 @@ def parse_serving_args(args=None):
     # (default "unified")
     parser.add_argument("--role", default="",
                         choices=("", "prefill", "decode", "unified"))
-    # chunked prefill: tile size in tokens (paged pool only; long
-    # prompts prefill in tiles interleaved with decode steps instead
+    # chunked prefill: tile size in tokens (long prompts prefill in tiles interleaved with decode steps instead
     # of monopolizing a tick); -1 resolves from
     # EDL_PREFILL_CHUNK_TOKENS, 0 = monolithic prefill
     parser.add_argument("--prefill_chunk_tokens", type=int, default=-1)
@@ -127,11 +139,9 @@ def _paged_decode_choice(engine):
     engine (the start-up log line)."""
     from elasticdl_tpu.ops.attention import paged_decode_impl
 
-    kv = getattr(engine, "kv", None)
-    if kv is None:
-        return "none (dense per-slot KV)"
     import jax
 
+    kv = engine.kv
     arena = max((leaf for leaf in jax.tree.leaves(kv.pools)
                  if leaf.ndim == 4), key=lambda leaf: leaf.shape[-1])
     return paged_decode_impl(kv.max_blocks_per_slot, arena,
@@ -199,7 +209,6 @@ def build_server(args):
             telemetry_dir=args.tensorboard_log_dir,
             port=args.port,
             max_workers=args.max_workers,
-            kv_paged=None if args.kv_paged < 0 else bool(args.kv_paged),
             kv_block_size=args.kv_block_size,
             kv_num_blocks=args.kv_num_blocks,
             kv_shared=(None if args.kv_shared < 0

@@ -9,7 +9,7 @@ Wiring (one process):
                                            │ watcher.poll  (hot reload)
                                            │ telemetry gauges
                                            v
-                              ContinuousBatchingEngine (jit decode pool)
+                              PagedContinuousBatchingEngine (jit decode pool)
 
 All jax work happens on the single scheduler thread; gRPC handler
 threads only touch the admission queue and their request's event queue,
@@ -53,10 +53,8 @@ from elasticdl_tpu.observability.metrics import (
     metrics_port_default,
 )
 from elasticdl_tpu.serving.engine import (
-    ContinuousBatchingEngine,
     PagedContinuousBatchingEngine,
     kv_host_bytes_default,
-    kv_paged_default,
     kv_shared_default,
     prefill_budget_default,
     prefill_chunk_default,
@@ -104,14 +102,13 @@ class ServingConfig(object):
     top_k/top_p are static server-level sampling filters (per-request
     temperature/seed select greedy vs sampling).
 
-    KV layout: kv_paged=None resolves from EDL_KV_PAGED (the drills'
-    env toggle). Paged mode stores KV rows in kv_num_blocks blocks of
-    kv_block_size tokens (0 blocks = the dense-equivalent budget for
-    num_slots); with a fixed block budget, num_slots can then be raised
-    beyond what the same bytes would buy dense slots — short requests
-    pack densely instead of pinning `seq_len` stripes.
+    KV layout: the block-paged pool (serving/kv_pool.py) stores KV
+    rows in kv_num_blocks blocks of kv_block_size tokens (0 blocks =
+    the rows of num_slots sequences of `seq_len` tokens); with a fixed
+    block budget, num_slots can be raised beyond that — short requests
+    pack densely instead of pinning `seq_len` rows each.
 
-    kv_shared (paged only; None resolves from EDL_KV_SHARED, default
+    kv_shared (None resolves from EDL_KV_SHARED, default
     on) refcounts blocks and dedupes matching prompt prefixes to one
     resident chain (copy-on-write on divergence) — N requests with the
     same system prompt pay for its cache once. draft_k > 0 (with a
@@ -119,7 +116,7 @@ class ServingConfig(object):
     into a speculative draft-verify step committing up to draft_k + 1
     tokens, token-exact with plain decode.
 
-    kv_host_bytes (paged only; None resolves from EDL_KV_HOST_BYTES,
+    kv_host_bytes (None resolves from EDL_KV_HOST_BYTES,
     default 0 = off) bounds the host-RAM spill tier: evicted prefix
     chains demote to host buffers and revive by device upload instead
     of re-paying prefill — a cell's system-prompt working set survives
@@ -136,8 +133,7 @@ class ServingConfig(object):
                  top_p=1.0, checkpoint_dir="", reload_poll_secs=2.0,
                  telemetry_dir="", telemetry_flush_every=50,
                  idle_wait_secs=0.05, handler_poll_secs=0.25,
-                 port=0, max_workers=64, kv_paged=None,
-                 kv_block_size=16, kv_num_blocks=0, kv_shared=None,
+                 port=0, max_workers=64, kv_block_size=16, kv_num_blocks=0, kv_shared=None,
                  draft_k=0, kv_host_bytes=None, metrics_port=None,
                  forensics=None, runtime_health=None,
                  stall_after_secs=None, health_reconcile_secs=2.0,
@@ -155,9 +151,6 @@ class ServingConfig(object):
         self.handler_poll_secs = float(handler_poll_secs)
         self.port = int(port)
         self.max_workers = int(max_workers)
-        self.kv_paged = (
-            kv_paged_default() if kv_paged is None else bool(kv_paged)
-        )
         self.kv_block_size = int(kv_block_size)
         self.kv_num_blocks = int(kv_num_blocks)
         self.kv_shared = (
@@ -210,7 +203,7 @@ class ServingConfig(object):
         # replica's advertised phase: a router keeps "prefill"
         # replicas out of normal rotation and targets them only for
         # cache-warming handoffs. prefill_chunk_tokens (None resolves
-        # from EDL_PREFILL_CHUNK_TOKENS, 0 = off; paged only) splits
+        # from EDL_PREFILL_CHUNK_TOKENS, 0 = off) splits
         # prompt prefill into fixed-token tiles the scheduler
         # interleaves with decode ticks; prefill_budget_ms (None
         # resolves from EDL_PREFILL_BUDGET_MS, default 8.0, <= 0 =
@@ -249,12 +242,11 @@ class _Scheduler(threading.Thread):
         self.telemetry = telemetry
         self.watcher = watcher
         self.idle_wait_secs = idle_wait_secs
-        # chunked prefill (paged engine only): seated-but-prefilling
-        # jobs advance one tile per visit, budgeted per tick while
-        # decode slots are waiting (engine.prefill_chunk_tokens = 0 or
-        # a dense engine keeps the monolithic insert path)
-        self._chunked = bool(getattr(engine, "prefill_chunk_tokens", 0)
-                             and hasattr(engine, "begin_insert"))
+        # chunked prefill: seated-but-prefilling jobs advance one tile
+        # per visit, budgeted per tick while decode slots are waiting
+        # (engine.prefill_chunk_tokens = 0 keeps the monolithic insert
+        # path)
+        self._chunked = bool(engine.prefill_chunk_tokens)
         self.prefill_budget_ms = float(prefill_budget_ms)
         self._pending_prefills = []
         self._tile_ms = 0.0  # EWMA tile cost; prices the budget check
@@ -739,11 +731,10 @@ class ServingServicer(object):
         coordinator's accounting obligation, not a resource release)."""
         from elasticdl_tpu.serving import disagg
 
-        kv = getattr(self._engine, "kv", None)
-        alloc = getattr(kv, "allocator", None)
-        if alloc is None or not alloc.share_prefix:
+        kv = self._engine.kv
+        if not kv.allocator.share_prefix:
             self._fail(context, "FAILED_PRECONDITION",
-                       "chain export needs the shared paged pool")
+                       "chain export needs prefix sharing (kv_shared)")
         prompt = list(request.prompt)
         with self._transfers_lock:
             self._transfers_inflight += 1
@@ -775,11 +766,10 @@ class ServingServicer(object):
         failure."""
         from elasticdl_tpu.serving import disagg
 
-        kv = getattr(self._engine, "kv", None)
-        alloc = getattr(kv, "allocator", None)
-        if alloc is None or not alloc.share_prefix:
+        kv = self._engine.kv
+        if not kv.allocator.share_prefix:
             self._fail(context, "FAILED_PRECONDITION",
-                       "chain import needs the shared paged pool")
+                       "chain import needs prefix sharing (kv_shared)")
         with self._transfers_lock:
             self._transfers_inflight += 1
         try:
@@ -881,12 +871,12 @@ class ServingServicer(object):
             cow_copies=kv["cow_copies"],
             # tiered host spill: occupancy gauges + the monotone
             # revival economy (tokens seated by upload instead of
-            # re-prefill) — .get so bare test engines stay valid
-            kv_host_blocks=kv.get("kv_host_blocks", 0),
-            kv_host_bytes=kv.get("kv_host_bytes", 0),
-            revive_uploads=kv.get("revive_uploads", 0),
-            prefill_tokens_revived=kv.get("prefill_tokens_revived", 0),
-            host_drops=kv.get("host_drops", 0),
+            # re-prefill)
+            kv_host_blocks=kv["kv_host_blocks"],
+            kv_host_bytes=kv["kv_host_bytes"],
+            revive_uploads=kv["revive_uploads"],
+            prefill_tokens_revived=kv["prefill_tokens_revived"],
+            host_drops=kv["host_drops"],
             draft_k=self._engine.draft_k,
             draft_proposed=self._engine.draft_proposed,
             draft_accepted=self._engine.draft_accepted,
@@ -912,11 +902,11 @@ class ServingServicer(object):
             # disaggregated serving: the advertised phase role plus
             # the handoff ledger (pool-side chain counters, the
             # transfer RPCs executing right now, and closed-out
-            # failures) — .get so bare/dense engines stay valid
+            # failures)
             role=self._role,
-            chain_exports=kv.get("chain_exports", 0),
-            chain_imports=kv.get("chain_imports", 0),
-            chain_import_tokens=kv.get("chain_import_tokens", 0),
+            chain_exports=kv["chain_exports"],
+            chain_imports=kv["chain_imports"],
+            chain_import_tokens=kv["chain_import_tokens"],
             transfer_aborts=transfer_aborts,
             transfers_inflight=transfers_inflight,
             # hot-reload failure latch: the watcher exhausted its retry
@@ -1050,28 +1040,16 @@ class GenerationServer(object):
                  draft=None):
         self.config = config or ServingConfig()
         cfg = self.config
-        if cfg.kv_paged:
-            self.engine = PagedContinuousBatchingEngine(
-                trainer, state, cfg.num_slots,
-                top_k=cfg.top_k, top_p=cfg.top_p,
-                block_size=cfg.kv_block_size,
-                num_blocks=cfg.kv_num_blocks,
-                share_prefix=cfg.kv_shared,
-                draft=draft, draft_k=cfg.draft_k,
-                host_bytes=cfg.kv_host_bytes,
-                prefill_chunk_tokens=cfg.prefill_chunk_tokens,
-            )
-        else:
-            if draft is not None and cfg.draft_k:
-                raise ValueError(
-                    "speculative decode needs the paged pool "
-                    "(kv_paged=True) — the reclaimed blocks are what "
-                    "seat the draft"
-                )
-            self.engine = ContinuousBatchingEngine(
-                trainer, state, cfg.num_slots,
-                top_k=cfg.top_k, top_p=cfg.top_p,
-            )
+        self.engine = PagedContinuousBatchingEngine(
+            trainer, state, cfg.num_slots,
+            top_k=cfg.top_k, top_p=cfg.top_p,
+            block_size=cfg.kv_block_size,
+            num_blocks=cfg.kv_num_blocks,
+            share_prefix=cfg.kv_shared,
+            draft=draft, draft_k=cfg.draft_k,
+            host_bytes=cfg.kv_host_bytes,
+            prefill_chunk_tokens=cfg.prefill_chunk_tokens,
+        )
         self.queue = RequestQueue(
             cfg.queue_capacity, self.engine.seq_len,
             max_cached_tokens=self.engine.max_cached_tokens(),
@@ -1094,7 +1072,7 @@ class GenerationServer(object):
         self._injector = injector or FaultInjector.from_env()
         # the runtime health plane (observability/runtime_health.py):
         # recompile sentry adopted by the engine (which forwards it to
-        # the paged pool and the offline decode caches), device-memory
+        # the KV pool and the offline decode caches), device-memory
         # ledger reconciliation, progress watchdog + flight recorder —
         # driven by its OWN daemon thread, because the scheduler being
         # wedged is the failure under observation
@@ -1108,11 +1086,6 @@ class GenerationServer(object):
                 injector=self._injector,
             )
             self.engine.sentry = self.health.sentry
-            # the dense engine carries a plain attribute (no property
-            # forwarding), so the offline decode caches adopt here
-            from elasticdl_tpu.api.generation import set_decode_sentry
-
-            set_decode_sentry(self.health.sentry)
         watcher = None
         if cfg.checkpoint_dir:
             watcher = CheckpointWatcher(

@@ -31,9 +31,9 @@ Latency distributions live in fixed-bucket log-linear histograms
 (observability/histogram.py) — TTFT, queue wait, step time and
 end-to-end latency — NOT in point-gauges: the status RPCs report
 p50/p90/p99 from them, the router merges the raw bucket counts across
-replicas, and bench_serving.py computes its percentiles with the same
-histogram code, so bench numbers and live numbers are definitionally
-identical.
+replicas, and the drills compute their client-side percentiles with
+the same histogram code, so drill numbers and live numbers are
+definitionally identical.
 
 The LIVE signal plane (observability/metrics.py): every telemetry
 object also feeds a windowed **TimeSeriesRing** — fixed-interval

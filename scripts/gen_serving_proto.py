@@ -85,8 +85,8 @@ SERVING_MESSAGES = {
         ("reloads", 10, T.TYPE_INT64, _OPT),
         ("uptime_secs", 11, T.TYPE_DOUBLE, _OPT),
         ("max_active_slots", 12, T.TYPE_INT32, _OPT),
-        # KV-pool memory accounting (block-paged pool lands these;
-        # the dense pool reports bytes with zero block fields)
+        # KV-pool memory accounting (serving/kv_pool.py; kv_paged is
+        # always true: the block-paged pool is the only layout)
         ("kv_bytes_in_use", 13, T.TYPE_INT64, _OPT),
         ("kv_bytes_total", 14, T.TYPE_INT64, _OPT),
         ("kv_blocks_free", 15, T.TYPE_INT32, _OPT),
@@ -105,8 +105,8 @@ SERVING_MESSAGES = {
         # EWMA) — part of the router's least-loaded signal
         ("queue_wait_ms", 22, T.TYPE_DOUBLE, _OPT),
         # latency percentiles from the shared log-linear histograms
-        # (observability/histogram.py) — the same code path
-        # bench_serving.py computes its percentiles with
+        # (observability/histogram.py) — the same code path the
+        # drills compute their client-side percentiles with
         ("ttft_p50_ms", 23, T.TYPE_DOUBLE, _OPT),
         ("ttft_p90_ms", 24, T.TYPE_DOUBLE, _OPT),
         ("ttft_p99_ms", 25, T.TYPE_DOUBLE, _OPT),
@@ -174,7 +174,8 @@ SERVING_MESSAGES = {
         ("health_state", 48, T.TYPE_STRING, _OPT),
         # recompile sentry: total tracked jit compilations, and the
         # post-warmup-boundary recompile anomalies ("churn never
-        # recompiles" — serve-smoke pins steady_recompiles at zero)
+        # recompiles" — tests/test_runtime_health.py pins
+        # steady_recompiles at zero on a live server)
         ("jit_compiles", 49, T.TYPE_INT64, _OPT),
         ("steady_recompiles", 50, T.TYPE_INT64, _OPT),
         # device-memory accountant: PEAK unaccounted device-byte

@@ -8,8 +8,8 @@ fleet is owned by the replica supervisor (serving/autoscaler.py),
 which spawns `elasticdl_tpu.serving.main` replica SUBPROCESSES,
 journals every lifecycle transition, and scales on the router's own
 load signals. The drill ramps an open-loop piecewise-Poisson unary
-load through the router (the SAME generator bench_serving --ramp
-uses) and forces every transition the autoscaler claims to survive:
+load through the router (parse_ramp / ramp_arrivals below) and forces
+every transition the autoscaler claims to survive:
 
   * RAMP UP   — the high phase is calibrated to ~1.3x one replica's
     measured capacity, so the queue-wait EWMA rises and the policy
@@ -76,8 +76,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-from bench_serving import parse_ramp, ramp_arrivals  # noqa: E402
-
 CLIENT_TIMEOUT = 120.0  # backstop; the drill asserts we stay far under
 # Per-window p99 TTFT bound. The backlog a calibrated 1.3x overload
 # builds scales with HIGH_SECS AND with whatever else the shared CI
@@ -95,6 +93,38 @@ TAIL_SECS = 30.0
 MAX_REPLICAS = 2  # 1 -> 2 -> (replace) -> 1 is the whole story; a
 # small ceiling also keeps the drill honest on single-core CI, where
 # each extra spawn's jit compile steals serving time
+
+
+
+def parse_ramp(spec):
+    """'r1:t1,r2:t2,...' -> [(rate_rps, duration_secs), ...]: the
+    drill's ramp grammar."""
+    phases = []
+    for part in spec.split(","):
+        rate_text, _, secs_text = part.strip().partition(":")
+        rate, secs = float(rate_text), float(secs_text)
+        if rate <= 0 or secs <= 0:
+            raise ValueError("bad ramp phase %r in %r" % (part, spec))
+        phases.append((rate, secs))
+    if not phases:
+        raise ValueError("empty ramp spec %r" % spec)
+    return phases
+
+
+def ramp_arrivals(phases, rs):
+    """Open-loop piecewise-Poisson arrival plan: [(offset_secs,
+    phase_index), ...] with exponential gaps at each phase's rate,
+    phase boundaries at the cumulative durations."""
+    out = []
+    t0 = 0.0
+    for idx, (rate, secs) in enumerate(phases):
+        t = t0 + float(rs.exponential(1.0 / rate))
+        while t < t0 + secs:
+            out.append((t, idx))
+            t += float(rs.exponential(1.0 / rate))
+        t0 += secs
+    return out
+
 
 # heavy enough that one single-slot replica saturates at a few req/s
 # on CPU — the ramp's high phase is calibrated to ~1.3x that, so the
@@ -370,7 +400,6 @@ def main():
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["EDL_KV_PAGED"] = "1"
     env["EDL_TRACE_DIR"] = trace_dir
     env.pop("PYTHONPATH", None)
 

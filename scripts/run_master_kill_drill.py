@@ -4,8 +4,13 @@ scripts/run_worker_kill_drill.py).
 
 Runs the REAL distributed stack with the master as a subprocess —
 `python -m elasticdl_tpu.master.main` with a --job_state_dir journal,
-LocalInstanceManager spawning a worker subprocess — then SIGKILLs the
-MASTER mid-job. The orphaned worker keeps retrying inside its bounded
+LocalInstanceManager spawning a worker subprocess — and has the MASTER
+SIGKILL itself mid-job: EDL_FAULT_SPEC=report_task_result:kill:1:skip=2
+(common/fault_injection.py) fires on the worker's THIRD task report,
+so two ranges are done and one is in flight whatever the machine's
+load (a kill sent by this script after polling the journal raced a
+job of a few short tasks, and lost whenever the poll ran late). The
+orphaned worker keeps retrying inside its bounded
 reconnect window (common/retry.py) instead of exiting; a second master
 process started over the same --job_state_dir restores the dispatcher
 from the journal (todo ∪ requeued-doing), the worker re-registers, and
@@ -72,19 +77,24 @@ def completed_ranges(events):
     return out
 
 
-def find_worker_pids():
-    """PIDs of elasticdl_tpu.worker.main processes (the orphan-worker
-    probe: /proc scan, no psutil dependency)."""
+def find_worker_pids(master_addr):
+    """PIDs of THIS job's elasticdl_tpu.worker.main processes: those
+    started with `--master_addr <master_addr>` (the orphan-worker
+    probe: /proc scan, no psutil dependency). Another job's workers on
+    the same machine — a test running beside this one — dial another
+    master and are not the drill's to assert on or to kill."""
     pids = []
     for pid in os.listdir("/proc"):
         if not pid.isdigit():
             continue
         try:
             with open("/proc/%s/cmdline" % pid, "rb") as f:
-                cmd = f.read().decode("utf-8", "replace")
+                argv = f.read().decode("utf-8", "replace").split("\0")
         except OSError:
             continue
-        if "elasticdl_tpu.worker.main" in cmd:
+        if ("elasticdl_tpu.worker.main" in argv
+                and "--master_addr" in argv[:-1]
+                and argv[argv.index("--master_addr") + 1] == master_addr):
             pids.append(int(pid))
     return pids
 
@@ -163,43 +173,39 @@ def run_drill(
     env["EDL_STATE_SNAPSHOT_EVERY"] = "100000"
 
     port = free_port()
+    # what master/main.py hands its local workers as --master_addr
+    master_addr = "localhost:%d" % port
     journal = os.path.join(state_dir, "journal.jsonl")
     args = (train_dir, state_dir, status_file, tb_dir)
     m2 = None
+    # master #1 dies on its third task report, before applying it: two
+    # ranges done, one in flight — the kill lands between ranges by
+    # construction, proving both replay paths (done stays done, doing
+    # gets requeued)
     m1 = subprocess.Popen(
         master_cmd(port, *args, num_workers=1,
                    records_per_task=records_per_task,
                    minibatch_size=minibatch_size, num_epochs=num_epochs),
-        env=env,
+        env=dict(env, EDL_FAULT_SPEC="report_task_result:kill:1:skip=2"),
     )
     log("[drill] master #1 (pid %d) on :%d, journaling to %s"
         % (m1.pid, port, state_dir))
 
     try:
-        # wait until the worker is mid-job: at least one task dispatched
-        # AND one completed (so the kill lands between ranges, proving
-        # both replay paths: done stays done, doing gets requeued)
-        deadline = time.time() + startup_timeout
-        while time.time() < deadline:
-            events = read_journal(journal)
-            kinds = [e.get("ev") for e in events]
-            if kinds.count("dispatch") >= 2 and "done" in kinds:
-                break
-            if m1.poll() is not None:
-                raise AssertionError(
-                    "master #1 exited rc=%s before the kill"
-                    % m1.returncode)
-            time.sleep(0.2)
-        else:
-            raise AssertionError("worker never got mid-job (journal: %s)"
-                                 % kinds)
-
-        worker_pids = find_worker_pids()
+        try:
+            m1.wait(timeout=startup_timeout)
+        except subprocess.TimeoutExpired:
+            raise AssertionError(
+                "master #1 never reached its third task report "
+                "(journal: %s)"
+                % [e.get("ev") for e in read_journal(journal)])
+        assert m1.returncode == -signal.SIGKILL, (
+            "master #1 exited rc=%s, not by the injected SIGKILL"
+            % m1.returncode)
+        worker_pids = find_worker_pids(master_addr)
         assert worker_pids, "no worker subprocess found"
-        log("[drill] worker(s) %s mid-job — SIGKILL master #1"
-            % worker_pids)
-        os.kill(m1.pid, signal.SIGKILL)
-        m1.wait()
+        log("[drill] master #1 SIGKILLed itself mid-job; worker(s) %s "
+            "orphaned" % worker_pids)
 
         # audit what master #1's lifetime completed, BEFORE the restart
         # compacts the journal
@@ -207,6 +213,9 @@ def run_drill(
         done1 = completed_ranges(events1)
         log("[drill] master #1 journal: %d events, %d ranges done"
             % (len(events1), len(done1)))
+        assert len(done1) == 2, (
+            "the kill did not land on the third report: %d ranges done"
+            % len(done1))
 
         time.sleep(1.0)
         alive = [p for p in worker_pids
@@ -285,7 +294,7 @@ def run_drill(
         for proc in (m1, m2):
             if proc is not None and proc.poll() is None:
                 proc.kill()
-        for pid in find_worker_pids():
+        for pid in find_worker_pids(master_addr):
             try:
                 os.kill(pid, signal.SIGKILL)
             except OSError:

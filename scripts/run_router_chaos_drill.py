@@ -37,10 +37,7 @@ parents under a router dispatch span (the cross-process merge
 actually merged). The merged Chrome-trace JSON is archived at
 ROUTER_CHAOS_TRACE.json (repo root) — open it at ui.perfetto.dev.
 
-Runs TWICE: dense KV pool and block-paged pool (EDL_KV_PAGED), like
-the single-replica kill drill.
-
-A third ROUTER-KILL phase then moves the chaos one tier up: three
+A second ROUTER-KILL phase then moves the chaos one tier up: three
 replicas behind TWO router cells sharing a registry journal
 (--cells / --cell_journal_dir), a CellFront dispatching shared-prefix
 load pinned by fingerprint to one owning cell, SIGKILL of that cell
@@ -49,7 +46,7 @@ cell with zero loss, and the killed cell must restart replica-flag-
 free and rebuild its whole fleet view from journal replay.
 
 Usage: python scripts/run_router_chaos_drill.py
-Exit 0 = the invariant holds in both modes."""
+Exit 0 = the invariant holds in both phases."""
 
 import os
 import signal
@@ -100,8 +97,7 @@ def start_router(replica_ports, extra_env=None):
                         ready_marker="ROUTER_READY")
 
 
-def start_router_cell(replica_ports, cell_id, cells, journal_dir,
-                      extra_env=None):
+def start_router_cell(replica_ports, cell_id, cells, journal_dir):
     """One router CELL: a full router process that shares its replica
     registry with its siblings through the write-ahead journal in
     `journal_dir`. Launched with an explicit --cell_id (no supervisor)
@@ -116,8 +112,7 @@ def start_router_cell(replica_ports, cell_id, cells, journal_dir,
     ]
     for p in replica_ports:
         cmd += ["--replica", "localhost:%d" % p]
-    return launch_ready(cmd, extra_env=extra_env,
-                        ready_marker="ROUTER_READY")
+    return launch_ready(cmd, ready_marker="ROUTER_READY")
 
 
 def build_checkpoint_state():
@@ -236,7 +231,7 @@ def verify_traces(mode, trace_dir, killed_addr, outcomes):
     return spans
 
 
-def run_mode(mode, mode_env, state, tmp_root):
+def run_replica_kill(state, tmp_root):
     import grpc
     import numpy as np
 
@@ -244,6 +239,7 @@ def run_mode(mode, mode_env, state, tmp_root):
     from elasticdl_tpu.proto import elasticdl_pb2 as pb
     from elasticdl_tpu.proto.service import RouterStub, build_channel
 
+    mode = "replicas"
     print("[chaos:%s] starting %d replicas + router"
           % (mode, NUM_REPLICAS))
     reload_dir = os.path.join(tmp_root, "ckpt_%s" % mode)
@@ -253,19 +249,19 @@ def run_mode(mode, mode_env, state, tmp_root):
     # causality lives in the router's dispatch spans
     trace_dir = os.path.join(tmp_root, "traces_%s" % mode)
     os.makedirs(trace_dir, exist_ok=True)
-    mode_env = dict(mode_env, EDL_TRACE_DIR=trace_dir)
+    trace_env = {"EDL_TRACE_DIR": trace_dir}
     replicas = []
     try:
         for i in range(NUM_REPLICAS):
             proc, port = start_replica(
                 ckpt_dir=reload_dir if i == 1 else None,
-                extra_env=mode_env,
+                extra_env=trace_env,
             )
             replicas.append([proc, port, None])
         for rep in replicas:
             rep[2] = warm(rep[1])
         router_proc, router_port = start_router(
-            [r[1] for r in replicas], extra_env=mode_env
+            [r[1] for r in replicas], extra_env=trace_env
         )
         replicas.append([router_proc, router_port, None])  # for cleanup
         stub = RouterStub(build_channel("localhost:%d" % router_port))
@@ -451,7 +447,6 @@ def run_cell_failover(tmp_root):
     from elasticdl_tpu.serving.router_cell import CellFront
 
     mode = "cells"
-    env = {"EDL_KV_PAGED": "1"}
     journal_dir = os.path.join(tmp_root, "cell_journal")
     os.makedirs(journal_dir, exist_ok=True)
     procs = []  # every subprocess, for the finally-kill backstop
@@ -461,7 +456,7 @@ def run_cell_failover(tmp_root):
               % (mode, NUM_REPLICAS))
         replica_ports = []
         for _ in range(NUM_REPLICAS):
-            proc, port = start_replica(extra_env=env)
+            proc, port = start_replica()
             procs.append(proc)
             replica_ports.append(port)
         for port in replica_ports:
@@ -469,12 +464,9 @@ def run_cell_failover(tmp_root):
         # cell 0 seeds the journal with the fleet; cell 1 starts BLIND
         # (no --replica flags) and must learn every replica from replay
         cell0, port0 = start_router_cell(
-            replica_ports, 0, 2, journal_dir, extra_env=env
-        )
+            replica_ports, 0, 2, journal_dir)
         procs.append(cell0)
-        cell1, port1 = start_router_cell(
-            [], 1, 2, journal_dir, extra_env=env
-        )
+        cell1, port1 = start_router_cell([], 1, 2, journal_dir)
         procs.append(cell1)
         stub1 = RouterStub(build_channel("localhost:%d" % port1))
         deadline = time.time() + 30
@@ -609,8 +601,7 @@ def run_cell_failover(tmp_root):
               % mode)
         cell_id = 0 if victim is cell0 else 1
         reborn, reborn_port = start_router_cell(
-            [], cell_id, 2, journal_dir, extra_env=env
-        )
+            [], cell_id, 2, journal_dir)
         procs.append(reborn)
         stub_r = RouterStub(build_channel("localhost:%d" % reborn_port))
         deadline = time.time() + 30
@@ -661,21 +652,17 @@ def main():
 
     state = build_checkpoint_state()
     with tempfile.TemporaryDirectory(prefix="edl_chaos_") as tmp_root:
-        for mode, env in (
-            ("dense", {"EDL_KV_PAGED": "0"}),
-            ("paged", {"EDL_KV_PAGED": "1"}),
-        ):
-            spans = run_mode(mode, env, state, tmp_root)
+        spans = run_replica_kill(state, tmp_root)
         # router-kill phase: same invariant one tier up — SIGKILL a
         # ROUTER CELL mid-load, zero accepted-request loss
         run_cell_failover(tmp_root)
-    # archive the last mode's merged trace as the CI artifact — one
+    # archive the replica phase's merged trace as the CI artifact — one
     # real chaos run, loadable at ui.perfetto.dev / chrome://tracing
     out = os.path.join(REPO, "ROUTER_CHAOS_TRACE.json")
     with open(out, "w") as f:
         json.dump(chrome_trace(spans), f)
     print("[chaos] merged trace archived -> %s" % out)
-    print("[chaos] router chaos drill PASSED (dense + paged + cells): "
+    print("[chaos] router chaos drill PASSED (replicas + cells): "
           "zero accepted-request loss under replica SIGKILL, hot "
           "reload, AND router-cell SIGKILL with journaled failover")
     return 0

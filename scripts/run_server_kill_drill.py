@@ -21,7 +21,7 @@ Phase 2 — hard kill (EDL_FAULT_SPEC=generate:kill:1:skip=N, the same
   and common/retry.py classifies exactly these codes as transient for
   the retry-elsewhere path.
 
-Phase 3 — shared-prefix ledger (paged mode: EDL_KV_SHARED=1): every
+Phase 3 — shared-prefix ledger (EDL_KV_SHARED=1): every
   request carries a COMMON prompt prefix so refcounted shared chains
   are resident (serving/kv_pool.py); a full wave completes and the
   block ledger must drain clean (every block free or cached — no
@@ -31,7 +31,7 @@ Phase 3 — shared-prefix ledger (paged mode: EDL_KV_SHARED=1): every
   again — a crash can never corrupt block accounting across restarts
   because the ledger is process-local and rebuilt from nothing.
 
-Phase 4 — tiered host spill (paged mode, --kv_host_bytes): three
+Phase 4 — tiered host spill (--kv_host_bytes): three
   distinct system prompts over a device pool too small for their
   chains plus an active seat, so reclaimable chains are forced to
   SPILL to the host tier and REVIVE by upload when their prefix comes
@@ -45,7 +45,7 @@ Phase 4 — tiered host spill (paged mode, --kv_host_bytes): three
   memory across restarts), serve the same load, revive again, and
   drain to a clean two-tier ledger.
 
-Phase 5 — disaggregated handoff (paged+shared, serving/disagg.py): a
+Phase 5 — disaggregated handoff (serving/disagg.py): a
   role-split fleet (one prefill replica, one decode replica, a router
   orchestrating the chain handoff between them) first proves the
   success path — handoffs counted, chains exported/imported, both
@@ -56,20 +56,14 @@ Phase 5 — disaggregated handoff (paged+shared, serving/disagg.py): a
   handoff may cost the warm-start, never the request) and the
   surviving decode pool must drain to a clean ledger.
 
-All phases run TWICE: against the dense KV pool and against the
-block-paged pool (EDL_KV_PAGED=1, serving/kv_pool.py) — drain and
-SIGKILL semantics must hold regardless of where the cache rows live
-(phase 3's ledger assertions are paged-only; dense mode still proves
-the no-hang/clean-status contract under the shared-prefix load; the
-phase 4 host tier exists only over the paged pool).
-A THIRD pass runs phases 1 + 3 + 4 with INT8 arenas
+A SECOND pass runs phases 1 + 3 + 4 with INT8 arenas
 (kv_cache_dtype='int8'): graceful drain, the shared-chain ledger,
 the spill/revive lifecycle, SIGKILL mid-load and the fresh-restart
 rebuild must all hold with scale leaves in the arenas (the hard-kill
 transport semantics of phase 2 are dtype-blind and already covered).
 
 Usage: python scripts/run_server_kill_drill.py
-Exit 0 = all phases hold in all modes."""
+Exit 0 = all phases hold with both arena dtypes."""
 
 import os
 import signal
@@ -209,11 +203,10 @@ def join_all(threads, outcomes, t0, n):
     return elapsed
 
 
-def phase_graceful(mode_env=None, mode="dense", model_params=None):
+def phase_graceful(mode="paged", model_params=None):
     print("[drill] phase 1 (%s): SIGTERM mid-load (graceful drain)"
           % mode)
-    proc, port = start_server(extra_env=mode_env,
-                              model_params=model_params)
+    proc, port = start_server(model_params=model_params)
     try:
         threads, outcomes, t0 = fire_requests(port, 8)
         time.sleep(0.4)  # let some seat, some queue
@@ -234,11 +227,10 @@ def phase_graceful(mode_env=None, mode="dense", model_params=None):
     print("[drill] phase 1 (%s) OK" % mode)
 
 
-def phase_hard_kill(mode_env=None, mode="dense"):
+def phase_hard_kill(mode="paged"):
     print("[drill] phase 2 (%s): EDL_FAULT_SPEC self-SIGKILL mid-load"
           % mode)
     env = {"EDL_FAULT_SPEC": "generate:kill:1:skip=3"}
-    env.update(mode_env or {})
     proc, port = start_server(extra_env=env)
     try:
         threads, outcomes, t0 = fire_requests(port, 8)
@@ -281,15 +273,12 @@ def _assert_clean_ledger(st, where):
     )
 
 
-def phase_shared_ledger(mode_env=None, mode="dense",
-                        model_params=None):
+def phase_shared_ledger(mode="paged", model_params=None):
     print("[drill] phase 3 (%s): shared prefixes resident through "
           "SIGKILL + restart" % mode)
-    env = dict(mode_env or {})
-    env["EDL_KV_SHARED"] = "1"
+    env = {"EDL_KV_SHARED": "1"}
     proc, port = start_server(extra_env=env, num_slots=3,
                               model_params=model_params)
-    paged = mode.startswith("paged")
     try:
         # wave 1: completes fully; the ledger must drain clean with
         # the prefix chains parked reclaimable (no leaked refcount)
@@ -299,12 +288,11 @@ def phase_shared_ledger(mode_env=None, mode="dense",
         join_all(threads, outcomes, t0, 6)
         assert set(outcomes.values()) == {"OK"}, outcomes
         st = _ledger(port)
-        if paged:
-            assert st.kv_paged and st.kv_shared
-            assert st.prefix_hit_tokens > 0, (
-                "shared load never matched a prefix"
-            )
-            _assert_clean_ledger(st, "post-wave-1")
+        assert st.kv_paged and st.kv_shared
+        assert st.prefix_hit_tokens > 0, (
+            "shared load never matched a prefix"
+        )
+        _assert_clean_ledger(st, "post-wave-1")
         # wave 2: SIGKILL lands mid-load with shared chains LIVE
         threads, outcomes, t0 = fire_requests(
             port, 6, max_new=16, shared_prefix=True
@@ -330,9 +318,8 @@ def phase_shared_ledger(mode_env=None, mode="dense",
         join_all(threads, outcomes, t0, 6)
         assert set(outcomes.values()) == {"OK"}, outcomes
         st = _ledger(port)
-        if paged:
-            assert st.prefix_hit_tokens > 0
-            _assert_clean_ledger(st, "post-restart")
+        assert st.prefix_hit_tokens > 0
+        _assert_clean_ledger(st, "post-restart")
     finally:
         if proc.poll() is None:
             proc.send_signal(signal.SIGTERM)
@@ -355,12 +342,11 @@ def _host_prompt(i):
     return HOST_PREFIXES[i % len(HOST_PREFIXES)] + [1 + i % 5]
 
 
-def phase_host_tier(mode_env=None, mode="paged", model_params=None):
+def phase_host_tier(mode="paged", model_params=None):
     print("[drill] phase 4 (%s): host tier — spill under pressure, "
           "revive through a wave, SIGKILL with spilled chains live, "
           "fresh restart rebuilds an empty tier" % mode)
-    env = dict(mode_env or {})
-    env["EDL_KV_SHARED"] = "1"
+    env = {"EDL_KV_SHARED": "1"}
     # 8 device blocks: one active seat commits 6 (9 prompt rows + 15
     # decode rows), so at most one 2-block chain survives beside it —
     # the other two spill; the host budget holds them all. The wave
@@ -538,7 +524,7 @@ def phase_disagg_handoff():
     clean ledger with nothing in flight."""
     print("[drill] phase 5 (disagg): prefill->decode handoff, then "
           "SIGKILL the prefill replica mid-transfer")
-    env = {"EDL_KV_PAGED": "1", "EDL_KV_SHARED": "1"}
+    env = {"EDL_KV_SHARED": "1"}
     decode, decode_port = start_server(
         extra_env=env, num_slots=3,
         extra_args=("--role", "decode", "--queue_capacity", "16"),
@@ -621,35 +607,27 @@ def phase_disagg_handoff():
 
 
 def main():
-    # dense pool, then the block-paged pool (kv_block_size 4 divides
-    # the drill model's seq_len=32; sharing needs full blocks)
-    for mode, env in (
-        ("dense", {"EDL_KV_PAGED": "0"}),
-        ("paged", {"EDL_KV_PAGED": "1"}),
-    ):
-        phase_graceful(mode_env=env, mode=mode)
-        phase_hard_kill(mode_env=env, mode=mode)
-        phase_shared_ledger(mode_env=env, mode=mode)
-    # the tiered host spill lifecycle exists only over the paged pool
-    phase_host_tier(mode_env={"EDL_KV_PAGED": "1"}, mode="paged")
+    # kv_block_size 4 divides the drill model's seq_len=32; sharing
+    # needs full blocks
+    phase_graceful()
+    phase_hard_kill()
+    phase_shared_ledger()
+    phase_host_tier()
     # int8 arenas: the same drain / SIGKILL-restart / shared-chain
     # ledger / spill-revive invariants must hold with scale leaves in
     # the arenas (kv_cache_dtype='int8'); the hard-kill transport
     # semantics are dtype-blind and already covered above
     int8_params = MODEL_PARAMS + "; kv_cache_dtype='int8'"
-    phase_graceful(mode_env={"EDL_KV_PAGED": "1"}, mode="paged_int8",
-                   model_params=int8_params)
-    phase_shared_ledger(mode_env={"EDL_KV_PAGED": "1"},
-                        mode="paged_int8", model_params=int8_params)
-    phase_host_tier(mode_env={"EDL_KV_PAGED": "1"},
-                    mode="paged_int8", model_params=int8_params)
+    phase_graceful(mode="paged_int8", model_params=int8_params)
+    phase_shared_ledger(mode="paged_int8", model_params=int8_params)
+    phase_host_tier(mode="paged_int8", model_params=int8_params)
     # disaggregated prefill/decode: clean handoff, then a SIGKILL'd
-    # prefill replica mid-transfer (paged+shared only — the handoff
-    # surface exists only over the prefix-shared paged pool)
+    # prefill replica mid-transfer (the handoff surface needs prefix
+    # sharing)
     phase_disagg_handoff()
-    print("[drill] serving kill drill PASSED (dense + paged + "
-          "paged-int8, shared-prefix ledger, host-tier spill/revive, "
-          "disagg handoff)")
+    print("[drill] serving kill drill PASSED (paged + paged-int8, "
+          "shared-prefix ledger, host-tier spill/revive, disagg "
+          "handoff)")
     return 0
 
 

@@ -134,7 +134,6 @@ def main():
 
     base_env = dict(os.environ)
     base_env["JAX_PLATFORMS"] = "cpu"
-    base_env["EDL_KV_PAGED"] = "1"
     base_env["EDL_HEALTH_DIR"] = health_dir
     base_env.pop("PYTHONPATH", None)
     base_env.pop("EDL_FAULT_SPEC", None)

@@ -31,8 +31,8 @@ def slow(telemetry):
 
 def health(telemetry):
     # typo'd runtime-health counter (steady_recompiles): the anomaly
-    # count would fork and serve-smoke's zero-recompile gate would
-    # watch a dead series -> EDL401
+    # count would fork and the zero-recompile test would watch a
+    # dead series -> EDL401
     telemetry.count("steady_recompile")
     # typo'd runtime-health gauge (last_progress_age_ms): the
     # autoscaler's self-report signal would scrape a dead series

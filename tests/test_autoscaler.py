@@ -731,3 +731,43 @@ def test_journal_is_wal_compacted(tmp_path):
 
 if __name__ == "__main__":
     raise SystemExit(pytest.main([__file__, "-q"]))
+
+
+# ------------------------------------------- the autoscale drill's load
+
+
+@pytest.mark.parametrize("spec", [
+    "", "8", "8:", ":0.5", "0:1", "8:0", "-2:1", "8:0.5,", "a:b",
+])
+def test_parse_ramp_refuses_what_is_not_rate_colon_seconds(spec):
+    from scripts.run_autoscale_drill import parse_ramp
+
+    with pytest.raises(ValueError):
+        parse_ramp(spec)
+
+
+def test_ramp_arrivals_respect_phase_boundaries_and_rates():
+    """scripts/run_autoscale_drill.py's load: 'r1:t1,r2:t2' parses to
+    (rate, seconds) phases, and the seeded piecewise-Poisson plan puts
+    every arrival inside its own phase's span, in time order, at about
+    the phase's rate — the same plan for the same seed."""
+    import numpy as np
+
+    from scripts.run_autoscale_drill import parse_ramp, ramp_arrivals
+
+    phases = parse_ramp("40:5, 4:10,80:2.5")
+    assert phases == [(40.0, 5.0), (4.0, 10.0), (80.0, 2.5)]
+    plan = ramp_arrivals(phases, np.random.RandomState(7))
+    assert plan == ramp_arrivals(phases, np.random.RandomState(7))
+    times = [t for t, _ in plan]
+    assert times == sorted(times)
+    starts = [0.0, 5.0, 15.0, 17.5]
+    counts = [0, 0, 0]
+    for t, idx in plan:
+        assert starts[idx] <= t < starts[idx + 1], (t, idx)
+        counts[idx] += 1
+    # Poisson counts: 200, 40, 200 expected; five sigma either way
+    for n, (rate, secs) in zip(counts, phases):
+        mean = rate * secs
+        assert abs(n - mean) < 5 * mean ** 0.5, (n, mean)
+

@@ -422,6 +422,7 @@ class _SlowSeatEngine(object):
     draft_k = 0
     draft_proposed = 0
     draft_accepted = 0
+    prefill_chunk_tokens = 0
 
     def __init__(self):
         self._slots = {}
@@ -468,7 +469,11 @@ class _SlowSeatEngine(object):
                 "kv_blocks_total": 0, "kv_blocks_free": 0,
                 "kv_blocks_cached": 0, "kv_blocks_shared": 0,
                 "kv_bytes_total": 0, "kv_bytes_in_use": 0,
-                "prefix_hit_tokens": 0, "cow_copies": 0}
+                "prefix_hit_tokens": 0, "cow_copies": 0,
+                "kv_host_blocks": 0, "kv_host_bytes": 0,
+                "revive_uploads": 0, "prefill_tokens_revived": 0,
+                "host_drops": 0, "chain_exports": 0,
+                "chain_imports": 0, "chain_import_tokens": 0}
 
 
 def test_scheduler_counts_slow_cause_for_expired_queued_request():

@@ -1114,12 +1114,12 @@ def test_disagg_handoff_matches_offline_int8_32way():
         specs.append({"prompt": prompt, "new": 3 + i % 5})
 
     cfg_p = ServingConfig(
-        num_slots=2, queue_capacity=16, kv_paged=True,
+        num_slots=2, queue_capacity=16,
         kv_block_size=4, kv_num_blocks=24, kv_shared=True,
         role="prefill",
     )
     cfg_d = ServingConfig(
-        num_slots=6, queue_capacity=64, kv_paged=True,
+        num_slots=6, queue_capacity=64,
         kv_block_size=4, kv_num_blocks=24, kv_shared=True,
         draft_k=2, role="decode",
     )

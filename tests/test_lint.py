@@ -519,7 +519,8 @@ def test_edl105_conviction_set_matches_runtime_sentry():
     """Cross-check of the static rule against the PR 14 runtime
     sentry: the serving decode paths (engine, kv_pool, offline
     generation) compile exclusively through tracked_jit-adopted sites,
-    and serve-smoke pins their steady_recompiles at ZERO. The static
+    and tests/test_runtime_health.py pins a live server's
+    steady_recompiles at ZERO. The static
     conviction set over those files must therefore be EMPTY — any
     EDL105 finding here would be a shape the runtime sentry could
     observe as a steady-state recompile (conviction set is a subset

@@ -191,6 +191,39 @@ def test_master_recovery_gauges_exported(tmp_path, train_dir):
     )
 
 
+def test_drill_worker_discovery_ignores_another_jobs_workers():
+    """The drill asserts on, and in its `finally` SIGKILLs, the
+    workers it finds: a scan for ANY elasticdl_tpu.worker.main process
+    adopted the workers of whichever test ran a job beside it (the
+    tier-1 run's flaky failure under `-n 6`). A decoy whose command
+    line names the worker module and ANOTHER master's address is found
+    for that address only — not for the drill's, and not for one that
+    merely starts with the same characters."""
+    import subprocess
+    import sys
+    import time
+
+    from scripts.run_master_kill_drill import find_worker_pids
+
+    decoy = subprocess.Popen([
+        sys.executable, "-c", "import time; time.sleep(120)",
+        "-m", "elasticdl_tpu.worker.main",
+        "--master_addr", "localhost:61", "--worker_id", "0",
+    ])
+    try:
+        deadline = time.time() + 30
+        while (decoy.pid not in find_worker_pids("localhost:61")
+               and time.time() < deadline):
+            time.sleep(0.05)  # exec has to replace the forked cmdline
+        assert decoy.pid in find_worker_pids("localhost:61")
+        assert decoy.pid not in find_worker_pids("localhost:62")
+        assert decoy.pid not in find_worker_pids("localhost:6")
+        assert decoy.pid not in find_worker_pids("localhost:611")
+    finally:
+        decoy.kill()
+        decoy.wait()
+
+
 def test_master_kill_drill_end_to_end(tmp_path):
     """The full SIGKILL drill: master dies mid-job, restarts from the
     journal, the orphan worker reconnects (never exits), the job

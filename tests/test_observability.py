@@ -6,8 +6,8 @@ recording the union, the span ring buffer's drop-OLDEST bound, one
 request = ONE span tree across router re-dispatch and hedging with the
 legs as SIBLING spans, the replica serve span parenting under the
 router's dispatch span (cross-process merge via the dump tool), the
-percentile fields on ServerStatus/router_status, bench_serving's
-percentiles being the SAME code path, the closed telemetry counter
+percentile fields on ServerStatus/router_status, the list-entry
+`percentiles` against the same oracle, the closed telemetry counter
 sets, telemetry tail-flush on close(), and the tb_events binary format
 round-tripped through an independent record/CRC parser."""
 
@@ -116,14 +116,11 @@ def test_histogram_edges():
         assert lo < hi
 
 
-def test_bench_serving_uses_the_shared_percentile_code():
-    """bench numbers and live numbers must be definitionally
-    identical: the bench's percentile entry IS the histogram module's
-    (same function object), and its answers match the sorted oracle
-    within bucket resolution."""
-    import scripts.bench_serving as bench
-
-    assert bench.percentiles is percentiles
+def test_percentiles_of_a_list_match_the_sorted_oracle():
+    """`percentiles` — the list entry point the drills report client
+    latencies through — answers from the same buckets as the live
+    histograms: within bucket resolution of the sorted oracle, and
+    None per quantile for an empty list."""
     rng = random.Random(3)
     values = [rng.uniform(1.0, 500.0) for _ in range(500)]
     out = percentiles(values, (50, 90, 99))
@@ -219,6 +216,7 @@ class FinishingEngine(object):
     draft_k = 0
     draft_proposed = 0
     draft_accepted = 0
+    prefill_chunk_tokens = 0
 
     def kv_stats(self):
         return {"kv_paged": False, "kv_shared": False,
@@ -227,7 +225,11 @@ class FinishingEngine(object):
                 "kv_blocks_total": 0, "kv_blocks_free": 0,
                 "kv_blocks_cached": 0, "kv_blocks_shared": 0,
                 "kv_bytes_total": 0, "kv_bytes_in_use": 0,
-                "prefix_hit_tokens": 0, "cow_copies": 0}
+                "prefix_hit_tokens": 0, "cow_copies": 0,
+                "kv_host_blocks": 0, "kv_host_bytes": 0,
+                "revive_uploads": 0, "prefill_tokens_revived": 0,
+                "host_drops": 0, "chain_exports": 0,
+                "chain_imports": 0, "chain_import_tokens": 0}
 
 
 def _replica_rig():

@@ -294,7 +294,7 @@ def paged_server():
     state = trainer.init_state(({"tokens": dummy}, dummy))
     server = GenerationServer(
         trainer, state,
-        ServingConfig(num_slots=3, kv_paged=True, kv_block_size=4,
+        ServingConfig(num_slots=3, kv_block_size=4,
                       kv_shared=False, idle_wait_secs=0.01,
                       handler_poll_secs=0.05),
     ).start(grpc_server=False)

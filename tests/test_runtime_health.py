@@ -457,7 +457,7 @@ def test_server_self_reports_health_end_to_end(tmp_path):
     server = GenerationServer(
         trainer, state,
         ServingConfig(
-            num_slots=2, kv_paged=True, kv_block_size=4,
+            num_slots=2, kv_block_size=4,
             runtime_health=True, stall_after_secs=0.5,
             health_dir=str(tmp_path), idle_wait_secs=0.01,
             handler_poll_secs=0.05,

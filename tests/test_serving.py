@@ -218,6 +218,7 @@ class FakeEngine(object):
     draft_k = 0
     draft_proposed = 0
     draft_accepted = 0
+    prefill_chunk_tokens = 0
 
     def kv_stats(self):
         return {"kv_paged": False, "kv_shared": False,
@@ -229,7 +230,8 @@ class FakeEngine(object):
                 "prefix_hit_tokens": 0, "cow_copies": 0,
                 "kv_host_blocks": 0, "kv_host_bytes": 0,
                 "revive_uploads": 0, "prefill_tokens_revived": 0,
-                "host_drops": 0}
+                "host_drops": 0, "chain_exports": 0,
+                "chain_imports": 0, "chain_import_tokens": 0}
 
 
 def _rig(clock):
