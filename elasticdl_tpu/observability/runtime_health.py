@@ -278,8 +278,8 @@ class DeviceMemoryAccountant(object):
     device's actual holdings.
 
     Ledger side (what the runtime can NAME): the KV pool's
-    `kv_bytes_total` + host-tier bytes + param bytes (the served
-    float tree AND the int8 source when they differ) + the draft
+    `kv_bytes_total` + host-tier bytes + param bytes (the tree the
+    engine serves: it keeps no other, and the draft's) + the draft
     pool. Device side: `jax.live_arrays()` byte sum. The difference
     can never be zero — executables pin constants, prefill buffers
     come and go — so the accountant BASELINES at `rebase()` (the
@@ -311,7 +311,7 @@ class DeviceMemoryAccountant(object):
 
         seen = set()
         total = 0
-        for attr in ("_exec_variables", "variables", "_d_variables"):
+        for attr in ("_exec_variables", "_d_variables"):
             tree = getattr(self._engine, attr, None)
             if tree is None:
                 continue
@@ -321,7 +321,7 @@ class DeviceMemoryAccountant(object):
                     continue
                 key = id(leaf)
                 if key in seen:
-                    continue  # non-quantized: exec IS variables
+                    continue  # a draft that shares the target's leaves
                 seen.add(key)
                 total += int(nbytes)
         return total

@@ -141,7 +141,15 @@ COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             # that consumed the pool they were handed (donation: the
             # update ran in place). The ratio is 1.0 or some caller
             # kept the pool from being donated
-            "pool.launches", "pool.inplace_launches")
+            "pool.launches", "pool.inplace_launches",
+            # serving/engine.py _load_params(), once a (re)load of a
+            # weight tree: the bytes of the tree handed in and of the
+            # tree the programs are served, and the leaves replaced by
+            # their cast to the compute dtype / kept as handed in
+            # (serving/exec_weights.py). bf16 compute over fp32
+            # weights: exec / source is about 0.5
+            "weights.source_bytes", "weights.exec_bytes",
+            "weights.leaves_cast", "weights.leaves_kept")
 
 Phase = collections.namedtuple(
     "Phase", "name start_ns end_ns seq parent trace_id attrs")

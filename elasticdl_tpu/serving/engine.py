@@ -44,14 +44,33 @@ from the call's result in the same statement (`kv.update`,
 `run_inplace`); a donating call that raises after consuming the pool
 is KVPoolLost, which ends the scheduler like any step that raises.
 
-Weight-only int8 params (api/quantization): by default the engine
-dequantizes ONCE per set_params (initial load and every hot reload)
-and serves the cached float weights — a single-token decode step that
-re-dequantized the full weight set every step dominated the step on
-the latency-bound path (the decode_kv_int8 bench regression).
+The weights are served in the dtype the programs COMPUTE in, made
+once a load. The state handed in stays what a checkpoint holds (fp32);
+`_load_params` (construction and every hot reload) runs one jitted
+program over it and keeps only the result, `_exec_variables`, the tree
+every prefill / decode program takes. In it a leaf that those programs
+consume only through a cast to one narrower dtype is that cast (bf16
+compute over fp32 parameters: every matmul kernel, the MLP biases, the
+head and the embedding table — half the bytes a tick streams, and no
+cast of the whole table to gather a row a slot); a leaf that any
+program reads as it is (LayerNorm scales and biases, an fp32 router)
+is kept as handed in, and with fp32 compute nothing is a cast and
+`_exec_variables` IS the tree handed in. Which is which comes from the
+jaxprs of the engine's own programs, traced over shapes at
+construction (serving/exec_weights.py): no option, leaf name or rank
+decides. The engine holds no reference to the tree it was handed, so
+once the caller lets go of its state the fp32 kernels leave the
+device. The draft's tree goes through the same rule.
+
+Weight-only int8 params (api/quantization): by default that same load
+program dequantizes first — ONCE per set_params — and the float
+weights (cast as above) serve every step: a single-token decode step
+that re-dequantized the full weight set every step dominated the step
+on the latency-bound path (the decode_kv_int8 bench regression).
 EDL_SERVING_FUSED_DEQUANT=1 restores in-jit dequantize (int8 weights
 stream HBM->VMEM per step — the right trade when weights dwarf VMEM
-and HBM bandwidth, not latency, bounds the step).
+and HBM bandwidth, not latency, bounds the step); the tree served is
+then the int8 tree as handed in.
 """
 
 import os
@@ -78,6 +97,11 @@ from elasticdl_tpu.common.log_utils import default_logger as logger
 from elasticdl_tpu.observability import tracing
 from elasticdl_tpu.observability.runtime_health import tracked_jit
 from elasticdl_tpu.ops.attention import paged_live_blocks
+from elasticdl_tpu.serving.exec_weights import (
+    cast_leaves,
+    remembered_casts,
+    tree_bytes,
+)
 
 
 def kv_shared_default():
@@ -347,11 +371,8 @@ class PagedContinuousBatchingEngine(object):
         self.sentry = None
         self._qz = is_quantized(state.params)
         # in-jit dequantize is opt-in (see the module docstring); the
-        # default path serves float weights cached by _load_params
+        # default path serves float weights made once by _load_params
         self._exec_qz = self._qz and _fused_dequant()
-        self._dequant_fn = None
-        self._load_params(state, getattr(state, "version", 0))
-
         self._slots = [None] * self.num_slots  # _Slot or None
         self._prefilling = {}  # slot -> _PrefillJob (chunked, pending)
         self._positions = np.zeros(self.num_slots, np.int32)
@@ -370,16 +391,33 @@ class PagedContinuousBatchingEngine(object):
             "revive_uploads": 0, "prefill_tokens_revived": 0,
             "host_drops": 0,
         }
-        self._init_draft(draft, draft_k)
+        d_variables = self._init_draft(draft, draft_k)
+        # the weights, last: which leaves are served as a cast is
+        # decided from every program that takes them, the draft's too
+        variables = {"params": state.params, **state.model_state}
+        self._loader = self._weight_loader(
+            "load_weights", 0, variables, d_variables,
+            dequantize=self._qz and not self._exec_qz,
+        )
+        self._load_params(state, getattr(state, "version", 0))
+        if d_variables is not None:
+            # the draft's are served like the target's: the same rule
+            # over the programs that take them, once (no hot reload)
+            self._d_variables = self._load_weights(
+                self._weight_loader("load_draft_weights", 1,
+                                    self._exec_variables, d_variables),
+                d_variables,
+            )
 
     def _init_draft(self, draft, draft_k):
         """Seat the draft model for speculative decode: its own dense
         per-slot cache pool (the draft is small — that is the point)
-        beside the paged target pool the reclaimed blocks feed."""
+        beside the paged target pool the reclaimed blocks feed.
+        Returns the draft's weights as handed in (None: no draft)."""
         self._draft = None
         self.draft_k = 0  # speculative decode off unless a draft seats
         if draft is None or int(draft_k) < 1:
-            return
+            return None
         d_trainer, d_state = draft
         d_model = d_trainer.model
         _require_kv_convention(d_model)
@@ -405,9 +443,6 @@ class PagedContinuousBatchingEngine(object):
         self.draft_k = int(draft_k)
         self._draft = d_trainer
         self._d_model = d_model
-        self._d_variables = {
-            "params": d_state.params, **d_state.model_state
-        }
         self._d_kv_shapes = _kv_shapes_for(
             _decode_cache(d_trainer), d_model, 1
         )
@@ -418,6 +453,7 @@ class PagedContinuousBatchingEngine(object):
         )
         self._d_prefill_fns = {}
         self._d_write_fn = None
+        return {"params": d_state.params, **d_state.model_state}
 
     # ------------------------------------------------------------ params
 
@@ -437,26 +473,90 @@ class PagedContinuousBatchingEngine(object):
 
         _generation.set_decode_sentry(value)
 
-    def _load_params(self, state, version):
-        """Bind `state`'s params as the serving weights. With int8
-        params (and the default non-fused path) this is the ONE place
-        the weights dequantize: the cached float tree in
-        `_exec_variables` serves every prefill/decode step until the
-        next reload replaces it here."""
-        self.variables = {"params": state.params, **state.model_state}
-        self.model_version = int(version)
-        if self._qz and not self._exec_qz:
-            if self._dequant_fn is None:
-                self._dequant_fn = self._tjit(
-                    "dequant",
-                    lambda v: dict(
-                        v, params=dequantize_params(v["params"])
-                    ),
-                )
+    def _weight_loader(self, name, which, variables, d_variables,
+                       dequantize=False):
+        """The program that turns a weight tree as handed in into the
+        tree the serving programs take, for the target's tree
+        (`which` 0, `variables`) or the draft's (1, `d_variables`):
+        int8 leaves dequantized (the default, non-fused path), then
+        every leaf that _weight_programs consume only through a cast
+        to one narrower dtype replaced by that cast
+        (exec_weights.narrowing_casts: decided from the traced
+        programs, here, once, over shapes, and remembered beside the
+        compiled programs for the next start). Jitted through _tjit
+        and first run at construction, so a hot reload compiles
+        nothing. Returns (program, leaves cast, leaves kept); the
+        program is None when the programs take the tree as handed in
+        (fp32 compute: nothing is a cast)."""
+        def prepare(v):
+            if dequantize:
+                v = dict(v, params=dequantize_params(v["params"]))
+            return v
+
+        # what the programs close over, for the remembered decision's
+        # key: the models and every setting a program builder reads
+        closed_over = (
+            name, self.model, getattr(self, "_d_model", None),
+            self.trainer.mesh, self.num_slots, self.seq_len,
+            self.block_size, self.num_blocks, self.top_k, self.top_p,
+            self.draft_k, self._qz, self._exec_qz, dequantize,
+        )
+        with self.trainer.mesh:
+            trees = [variables, d_variables]
+            trees[which] = prepared = jax.eval_shape(
+                prepare, trees[which])
+            plan = remembered_casts(closed_over, prepared, [
+                (fn, args, argnums[which])
+                for fn, args, argnums in self._weight_programs(*trees)
+                if argnums[which] is not None])
+        cast = sum(to is not None for to in plan)
+        if not dequantize and not cast:
+            return None, cast, len(plan)
+        fn = self._tjit(name, lambda v: cast_leaves(prepare(v), plan))
+        return fn, cast, len(plan) - cast
+
+    def _load_weights(self, loader, variables):
+        """`variables` as the programs take them, by `loader` (from
+        _weight_loader), and the four `weights.*` counters: the bytes
+        handed in and served, the leaves cast and kept."""
+        load_fn, cast, kept = loader
+        served = variables
+        if load_fn is not None:
             with self.trainer.mesh:
-                self._exec_variables = self._dequant_fn(self.variables)
-        else:
-            self._exec_variables = self.variables
+                served = load_fn(variables)
+        tracing.count("weights.source_bytes", tree_bytes(variables))
+        tracing.count("weights.exec_bytes", tree_bytes(served))
+        tracing.count("weights.leaves_cast", cast)
+        tracing.count("weights.leaves_kept", kept)
+        return served
+
+    def _load_params(self, state, version):
+        """Bind `state`'s params as the serving weights: ONE program
+        (_weight_loader) makes `_exec_variables`, the tree every
+        prefill / decode program takes until the next reload replaces
+        it here. It holds, for each leaf of the state: the cast to the
+        compute dtype where the programs consume the leaf only through
+        that cast (bf16 compute over fp32 weights: the matmul kernels,
+        the MLP biases, the head, the embedding table), the leaf as
+        handed in where any program reads it as it is (LayerNorm
+        scales and biases, an fp32 router; every leaf under fp32
+        compute, where `_exec_variables` IS the tree handed in), and
+        the dequantized float in place of an int8 leaf (non-fused
+        path). The engine keeps no reference to the tree it was
+        handed: once the caller lets go of `state`, a leaf that was
+        replaced is gone from the device."""
+        self._exec_variables = self._load_weights(
+            self._loader, {"params": state.params, **state.model_state}
+        )
+        self.model_version = int(version)
+
+    @property
+    def variables(self):
+        """The weights as served: there is one tree, and this is the
+        name it had before it was served as anything but what was
+        handed in. In the repo only tests/test_serving_one_engine.py
+        reads it; the engine itself reads _exec_variables."""
+        return self._exec_variables
 
     def set_params(self, state, version):
         """Swap the serving params (hot reload). Runs BETWEEN decode
@@ -1001,6 +1101,49 @@ class PagedContinuousBatchingEngine(object):
 
     # ------------------------------------------------------- compiled fns
 
+    def _weight_programs(self, variables, d_variables):
+        """EVERY program that takes a weight tree, as narrowing_casts
+        traces it: [(program, its arguments over shapes, (where the
+        target's tree is among them, where the draft's; None: not
+        taken))]. The model's prompt prefill and its decode tile (the
+        suffix / chunked-prefill program), each at its smallest
+        bucket — a bucket changes a program's widths, not what it
+        does with a weight — and the tick's own program: the paged
+        step or, with a draft seated (`d_variables`), the speculative
+        step and the draft's prefill. A model may do with a weight in
+        a one-token call what it does in no other, so no program
+        stands in for another; a start with the decision remembered
+        traces none of them."""
+        spec = jax.ShapeDtypeStruct
+        i32, f32 = spec((), jnp.int32), spec((), jnp.float32)
+        tile = self._suffix_bucket(1)
+        prompt = spec((1, self.seq_len), jnp.int32)
+        tables = spec(self.kv.tables.shape, jnp.int32)
+        lanes = tuple(spec((self.num_slots,), d) for d in (
+            jnp.int32, jnp.int32, jnp.int32, jnp.float32, jnp.int32))
+        programs = [
+            (self._prefill_program(_prefill_bucket(1, self.seq_len)),
+             (variables, prompt, i32, i32, f32), (0, None)),
+            (self._suffix_prefill_program(tile),
+             (self.kv.pools, variables,
+              spec(self.kv.tables.shape[1:], jnp.int32),
+              spec((1, tile), jnp.int32), i32, i32, i32, f32),
+             (1, None)),
+        ]
+        if d_variables is None:
+            return programs + [
+                (self._paged_step_program(),
+                 (self.kv.pools, variables, tables) + lanes[:4],
+                 (1, None))]
+        return programs + [
+            (self._spec_step_program(),
+             (self.kv.pools, self._d_pool, variables, d_variables,
+              tables) + lanes, (2, 3)),
+            (self._draft_prefill_program(
+                _prefill_bucket(1, self.seq_len)),
+             (d_variables, prompt, i32), (None, 0)),
+        ]
+
     def _tjit(self, name, fn, **jit_kwargs):
         """jax.jit with recompile-sentry adoption: one fixed NAME per
         call site (buckets included), so a second compile of any name
@@ -1008,7 +1151,7 @@ class PagedContinuousBatchingEngine(object):
         exists to catch."""
         return tracked_jit(fn, name, lambda: self.sentry, **jit_kwargs)
 
-    def _build_prefill(self, p_pad):
+    def _prefill_program(self, p_pad):
         model, kv_shapes = self.model, self._kv_shapes
         top_k, top_p, qz = self.top_k, self.top_p, self._exec_qz
 
@@ -1022,10 +1165,14 @@ class PagedContinuousBatchingEngine(object):
             )
             return kv, first
 
-        logger.info("serving: compiling prefill for bucket %d", p_pad)
-        return self._tjit("prefill[%d]" % p_pad, prefill)
+        return prefill
 
-    def _build_paged_step(self):
+    def _build_prefill(self, p_pad):
+        logger.info("serving: compiling prefill for bucket %d", p_pad)
+        return self._tjit("prefill[%d]" % p_pad,
+                          self._prefill_program(p_pad))
+
+    def _paged_step_program(self):
         from elasticdl_tpu.serving.kv_pool import scatter_rows
 
         model = self.model
@@ -1075,15 +1222,19 @@ class PagedContinuousBatchingEngine(object):
                                  positions % block_size)
             return pools, nxt
 
+        return step
+
+    def _build_paged_step(self):
         logger.info(
             "serving: compiling paged decode step for %d slots over "
             "%d x %d-token blocks", self.num_slots, self.num_blocks,
             self.block_size,
         )
-        return self._tjit("paged_step", step, donate_argnums=(0,))
+        return self._tjit("paged_step", self._paged_step_program(),
+                          donate_argnums=(0,))
 
-    def _build_suffix_prefill(self, t_pad):
-        """Compiled shared-prefix suffix prefill: decode a tile of up
+    def _suffix_prefill_program(self, t_pad):
+        """The shared-prefix suffix prefill: decode a tile of up
         to `t_pad` prompt tokens at positions [start, start + t) over
         the resident prefix blocks, scatter the tile's rows into the
         slot's blocks (pad rows dropped via out-of-bounds ids), and
@@ -1123,14 +1274,18 @@ class PagedContinuousBatchingEngine(object):
             )
             return pools, first
 
+        return fn
+
+    def _build_suffix_prefill(self, t_pad):
         logger.info(
             "serving: compiling shared-prefix suffix prefill for "
             "tile %d", t_pad,
         )
-        return self._tjit("suffix_prefill[%d]" % t_pad, fn,
+        return self._tjit("suffix_prefill[%d]" % t_pad,
+                          self._suffix_prefill_program(t_pad),
                           donate_argnums=(0,))
 
-    def _build_draft_prefill(self, p_pad):
+    def _draft_prefill_program(self, p_pad):
         d_model, d_kv_shapes = self._d_model, self._d_kv_shapes
 
         def prefill(d_variables, buf, p_len):
@@ -1139,10 +1294,14 @@ class PagedContinuousBatchingEngine(object):
             )
             return kv
 
+        return prefill
+
+    def _build_draft_prefill(self, p_pad):
         logger.info(
             "serving: compiling draft prefill for bucket %d", p_pad
         )
-        return self._tjit("draft_prefill[%d]" % p_pad, prefill)
+        return self._tjit("draft_prefill[%d]" % p_pad,
+                          self._draft_prefill_program(p_pad))
 
     def _write_draft_slot(self, kv, slot):
         from elasticdl_tpu.serving.kv_pool import run_inplace
@@ -1164,8 +1323,8 @@ class PagedContinuousBatchingEngine(object):
             jnp.asarray(slot, jnp.int32),
         )
 
-    def _build_spec_step(self):
-        """The speculative tick as ONE compiled program: k vmapped
+    def _spec_step_program(self):
+        """The speculative tick as ONE program: k vmapped
         draft steps (a lax.scan of single-token greedy proposals),
         then the target verifying the whole [last, d_1..d_k] tile in
         one vmapped (k+1)-wide paged decode. Acceptance is the longest
@@ -1263,8 +1422,12 @@ class PagedContinuousBatchingEngine(object):
             pools = scatter_rows(pools, rows, bids, wpos % block_size)
             return pools, (d_pool_out, out_toks, c)
 
+        return step
+
+    def _build_spec_step(self):
         logger.info(
             "serving: compiling speculative draft-verify step "
-            "(k=%d) for %d slots", k, self.num_slots,
+            "(k=%d) for %d slots", self.draft_k, self.num_slots,
         )
-        return self._tjit("spec_step", step, donate_argnums=(0, 1))
+        return self._tjit("spec_step", self._spec_step_program(),
+                          donate_argnums=(0, 1))

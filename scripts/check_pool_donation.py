@@ -11,6 +11,16 @@ placeholder takes a 512-byte tile on the chip) and the `copy`
 instructions of an arena's shape in its optimized HLO. In place is
 alias_bytes >= pool_bytes and no such copy; exits 1 otherwise.
 
+Are the weights served in the compute dtype? A program that takes the
+weights (`paged_step`, `suffix_prefill`) also prints the bytes of its
+weight arguments by dtype (`weight_bytes`), the float32 weight leaves
+of two or more dimensions among them (`f32_matrices`) and the
+`convert` instructions of the embedding table's shape in its optimized
+HLO (`table_converts`). Where the configuration computes in a narrower
+dtype than float32, `paged_step` must show none of either (the engine
+casts such leaves once a load, serving/exec_weights.py); exits 1
+otherwise.
+
     python scripts/check_pool_donation.py                    # described v5e
     python scripts/check_pool_donation.py --device attached  # on the chip
 
@@ -33,15 +43,15 @@ sys.path.insert(0, ROOT)
 
 def build_engine(cfg):
     """The paged engine of `cfg` (a chipbench configuration's contents)
-    over shapes: the Trainer's init and the pool's arenas are traced,
-    not run."""
+    over shapes, and the weights' shapes as it was handed them: the
+    Trainer's init and the pool's arenas are traced, not run."""
     import jax
     import numpy as np
 
     from elasticdl_tpu.common.model_utils import get_model_spec
     from elasticdl_tpu.parallel import mesh as mesh_lib
+    from elasticdl_tpu.serving import engine as engine_mod
     from elasticdl_tpu.serving import kv_pool
-    from elasticdl_tpu.serving.engine import PagedContinuousBatchingEngine
     from elasticdl_tpu.training import trainer as trainer_mod
 
     model, server = cfg["model"], cfg["server"]
@@ -53,20 +63,24 @@ def build_engine(cfg):
             "%s=%r" % kv for kv in sorted(model["params"].items())))
     tokens = np.zeros((1, model["params"]["seq_len"]), np.int32)
 
-    def shapes_only(fn, **_):
+    def shapes_only(fn, *_, **__):
         return lambda *args: jax.eval_shape(fn, *args)
 
     build_pools = kv_pool.build_pools
     with mock.patch.object(trainer_mod.jax, "jit", shapes_only):
         state = trainer.init_state(({"tokens": tokens}, tokens))
     state = state.replace(step=np.zeros((), np.int32))  # its version
+    # the engine's own load program (the weights' cast to the compute
+    # dtype) is the one it runs while it is built: shapes there too
     with mock.patch.object(
             kv_pool, "build_pools",
-            lambda *a: jax.eval_shape(lambda: build_pools(*a))):
-        return PagedContinuousBatchingEngine(
+            lambda *a: jax.eval_shape(lambda: build_pools(*a))), \
+            mock.patch.object(engine_mod, "tracked_jit", shapes_only):
+        eng = engine_mod.PagedContinuousBatchingEngine(
             trainer, state, num_slots=server["num_slots"],
             block_size=server["kv_block_size"],
             num_blocks=server["kv_num_blocks"])
+    return eng, {"params": state.params, **state.model_state}
 
 
 def programs(eng, tile, upload_blocks):
@@ -97,6 +111,26 @@ def programs(eng, tile, upload_blocks):
             kv._upload_program(upload_blocks),
             [rows, spec((upload_blocks,), jnp.int32), i32], {}),
     }
+
+
+def weight_report(variables, hlo, table_shape):
+    """What a program that takes `variables` reads of them: bytes by
+    dtype, the float32 leaves of two or more dimensions, and the
+    `convert`s of the embedding table's shape in its optimized HLO."""
+    import jax
+    import numpy as np
+
+    by_dtype, f32_matrices = {}, 0
+    for leaf in jax.tree.leaves(variables):
+        name = np.dtype(leaf.dtype).name
+        by_dtype[name] = by_dtype.get(name, 0) + (
+            int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize)
+        f32_matrices += name == "float32" and len(leaf.shape) >= 2
+    shaped = "[%s]" % ",".join(str(n) for n in table_shape)
+    converts = [line for line in hlo.splitlines()
+                if " convert(" in line and shaped in line]
+    return {"weight_bytes": by_dtype, "f32_matrices": int(f32_matrices),
+            "table_converts": len(converts)}
 
 
 def compile_program(eng, program, sharding=None):
@@ -155,7 +189,11 @@ def main(argv=None):
             sys.exit("--device attached needs a TPU, found %r" % device)
         on_tpu = contextlib.nullcontext()
 
-    eng = build_engine(cfg)
+    params = cfg["model"]["params"]
+    narrow_compute = str(params.get("dtype") or "float32") not in (
+        "float32", "fp32", "f32")
+    with on_tpu:
+        eng, _handed_in = build_engine(cfg)
     in_place = True
     for name, program in programs(eng, args.tile,
                                   args.upload_blocks).items():
@@ -163,13 +201,21 @@ def main(argv=None):
         with on_tpu:
             compiled, pools = compile_program(eng, program, sharding)
         line = dict(pool_aliasing(compiled, pools), program=name,
-                    layers=cfg["model"]["params"]["num_layers"],
+                    layers=params["num_layers"],
                     device=device.device_kind,
                     attached=sharding is None,
                     compile_s=round(time.time() - t0, 1))
         line["in_place"] = (line["alias_bytes"] >= line["pool_bytes"]
                             and not line["pool_shaped_copies"])
         in_place = in_place and line["in_place"]
+        if program[1] and program[1][0] is eng._exec_variables:
+            line.update(weight_report(
+                eng._exec_variables, compiled.as_text(),
+                (params["vocab_size"], params["embed_dim"])))
+            if name == "paged_step" and narrow_compute:
+                line["weights_cast"] = not (line["f32_matrices"]
+                                            or line["table_converts"])
+                in_place = in_place and line["weights_cast"]
         print(json.dumps(line, sort_keys=True), flush=True)
         if args.hlo_dir:
             os.makedirs(args.hlo_dir, exist_ok=True)

@@ -456,7 +456,8 @@ def cell_programs():
                            "sc2-3b-serve.json")) as f:
         cfg = json.load(f)
     cfg["model"]["params"]["num_layers"] = 2
-    eng = check.build_engine(cfg)
+    eng, handed_in = check.build_engine(cfg)
+    eng.handed_in = handed_in
     return eng, check.programs(eng, tile=16, upload_blocks=4)
 
 
@@ -479,3 +480,60 @@ def test_compiled_for_a_v5e_the_program_aliases_its_whole_pool(
     # copied on the way
     assert 0 <= got["alias_bytes"] - got["pool_bytes"] <= 512, got
     assert got["pool_shaped_copies"] == 0, got
+
+
+@pytest.mark.parametrize("program", ["paged_step", "suffix_prefill[16]"])
+def test_compiled_for_a_v5e_the_program_takes_its_weights_in_bf16(
+        program, one_chip, cell_programs):
+    """The cell computes in bf16: the engine hands each program that
+    takes the weights the cast of every kernel, made once a load
+    (serving/exec_weights.py). No float32 matrix is an argument, and
+    the optimized HLO casts no `[vocab, d]` table to gather 16 rows."""
+    from elasticdl_tpu.ops import dispatch
+    from scripts import check_pool_donation as check
+
+    eng, todo = cell_programs
+    assert todo[program][1][0] is eng._exec_variables
+    with mock.patch.object(dispatch, "is_tpu_backend", lambda: True):
+        compiled, _pools = check.compile_program(eng, todo[program],
+                                                 one_chip)
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           "sc2-3b-serve.json")) as f:
+        model = json.load(f)["model"]["params"]
+    table = (model["vocab_size"], model["embed_dim"])
+    got = check.weight_report(eng._exec_variables, compiled.as_text(),
+                              table)
+    assert got["f32_matrices"] == 0 and got["table_converts"] == 0, got
+    # two layers: kernels, MLP biases, head and table in bf16; the
+    # five LayerNorms' scales and biases as handed in
+    assert got["weight_bytes"] == {
+        "bfloat16": 987820032, "float32": 5 * 2 * 3072 * 4}
+    # and the report does see a cast table where there is one
+    hlo = ("%c = bf16[49152,3072]{1,0} convert(f32[49152,3072]{1,0} %p)"
+           "\n%d = bf16[3072,49152]{1,0} convert(f32[3072,49152] %q)")
+    fp32 = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32),
+        eng._exec_variables)
+    assert check.weight_report(fp32, hlo, table) == {
+        "weight_bytes": {"float32": 2 * 987820032 + 5 * 2 * 3072 * 4},
+        "f32_matrices": 2 * 4 + 2, "table_converts": 1}
+
+
+def test_at_the_cells_widths_the_decision_is_the_steps_too(
+        cell_programs):
+    """The engine decides which leaves to serve as a cast from every
+    program that takes them, the tick's own among them: at the cell's
+    widths, two layers deep, the four kernels and two MLP biases of a
+    layer, the head and the table; the five LayerNorms stay."""
+    eng, _todo = cell_programs
+    handed = jax.tree.leaves(eng.handed_in)
+    assert {x.dtype for x in handed} == {jnp.dtype(jnp.float32)}
+    assert [fn.__qualname__.split(".")[1] for fn, _args, _where
+            in eng._weight_programs(eng.handed_in, None)] == [
+        "_prefill_program", "_suffix_prefill_program",
+        "_paged_step_program"]
+    served = [x.dtype for x in jax.tree.leaves(eng._exec_variables)]
+    assert served.count(jnp.dtype(jnp.bfloat16)) == 2 * 6 + 2
+    assert served.count(jnp.dtype(jnp.float32)) == 5 * 2
+    assert [x.shape for x in handed] == [
+        x.shape for x in jax.tree.leaves(eng._exec_variables)]
