@@ -136,6 +136,17 @@ COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             # table slots in reach of the lanes' sequences (what the
             # paged kernel streams a layer) of lanes x table width
             "paged.blocks_streamed", "paged.table_slots",
+            # the same tick, over ALL layers: blocks a seated lane has
+            # written, and those of them wholly behind their layer's
+            # window (held by the pool, never read again)
+            "kv.blocks_held", "kv.window_dead_blocks",
+            # what the model's expert layers sow into "counters" in a
+            # decode step (model_zoo/transformer_lm ExpertFFN), handed
+            # back behind the tick's tokens and summed over layers:
+            # (row, choice) pairs routed / those whose expert is held
+            # here / held experts some lane chose / held experts
+            "moe.pairs_routed", "moe.pairs_held", "moe.experts_hit",
+            "moe.expert_slots",
             # serving/kv_pool.py run_inplace(): calls of a program that
             # takes a KV pool and hands one back, and those of them
             # that consumed the pool they were handed (donation: the

@@ -1,0 +1,172 @@
+"""Gated expert feed-forward products over row tiles: the kernel under
+parallel/moe.py's drop-free expert layer.
+
+The work is a list of TILES. Tile i is `tm` rows of activations
+(`x_tiles[x_of[i]]`), ONE expert (`expert_of[i]`) and a float32 weight
+a row (`gates[i]`); its result is
+
+    gates[i] * ((relu(x W_gate[e]) * (x W_up[e])) W_down[e])        ReGLU
+
+in float32, products in the operands' dtype with float32 accumulation.
+Only the first `n_live` tiles are computed; the rest are written as
+zeros and move no weight. That one shape serves both ends of serving
+(parallel/moe.py builds the lists):
+
+* decode, a handful of rows: every hit expert is a tile over the SAME
+  rows (`x_of` all 0), so a tick reads each hit expert's three matrices
+  once and no other expert's;
+* prefill, thousands of rows: the (token, choice) pairs are sorted by
+  expert and each expert's run is padded to whole tiles, so a token is
+  multiplied by its own experts only.
+
+On the TPU it is one Mosaic kernel: grid (tile, slice of the experts'
+hidden width), the expert of a tile read from scalar memory by the
+weights' index maps, so the pipeline fetches `W[expert_of[i]]` slice by
+slice while the previous slice is multiplied; dead tiles repeat the
+last live tile's block index and fetch nothing. Elsewhere (the CPU
+tests, kernels switched off) `expert_tiles_reference` computes the same
+thing with a `lax.map` over tiles.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from elasticdl_tpu.ops.dispatch import interpret_mode, use_pallas
+
+#: the kernel's name in a device trace (the scope around the call names
+#: its HLO instruction: `moe_expert_tiles.N`)
+KERNEL_SCOPE = "moe_expert_tiles"
+#: Mosaic may use this much VMEM for the kernel: a 256-row tile of a
+#: 2560-wide model double-buffers 18 MB, over the 16 MB default and far
+#: under a v5e core's 128 MiB
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def hidden_slice(hidden):
+    """Columns of the experts' hidden width a grid step multiplies:
+    the widest of 512, 256, 128 that divides it, else all of it."""
+    for th in (512, 256, 128):
+        if hidden % th == 0 and hidden > th:
+            return th
+    return hidden
+
+
+def kernel_supported(tm, d, hidden, dtype):
+    """Shape gate of the Mosaic kernel: whole (sublane, 128) tiles of
+    the operand dtype. Every other shape takes the reference."""
+    sublanes = 32 // jnp.dtype(dtype).itemsize  # 8 f32, 16 bf16
+    return (tm % sublanes == 0 and d % 128 == 0
+            and hidden_slice(hidden) % 128 == 0)
+
+
+def _tile_kernel(x_of_ref, expert_of_ref, n_live_ref, x_ref, g_ref,
+                 wg_ref, wu_ref, wd_ref, out_ref, acc_ref):
+    i, j = pl.program_id(0), pl.program_id(1)
+    live = i < n_live_ref[0]
+
+    @pl.when(j == 0)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _multiply():
+        x = x_ref[...]
+        a = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+        act = (jnp.maximum(a, 0.0) * u).astype(x.dtype)
+        acc_ref[...] += jnp.dot(act, wd_ref[...],
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _write():
+        out_ref[...] = acc_ref[...] * g_ref[...]
+
+
+def _expert_tiles_kernel(x_tiles, x_of, gates, expert_of, n_live, w_gate,
+                         w_up, w_down):
+    n_tiles, tm = gates.shape[:2]
+    d, hidden = w_gate.shape[1:]
+    th = hidden_slice(hidden)
+    n_h = hidden // th
+
+    def slice_of(i, j, n_live_ref):
+        # a dead tile asks for the block the last live step left behind
+        return jnp.where(i < n_live_ref[0], j, n_h - 1)
+
+    in_specs = [
+        pl.BlockSpec((None, tm, d),
+                     lambda i, j, xo, eo, nl: (xo[i], 0, 0)),
+        pl.BlockSpec((None, tm, 1), lambda i, j, xo, eo, nl: (i, 0, 0)),
+        pl.BlockSpec((None, d, th),
+                     lambda i, j, xo, eo, nl: (eo[i], 0,
+                                               slice_of(i, j, nl))),
+        pl.BlockSpec((None, d, th),
+                     lambda i, j, xo, eo, nl: (eo[i], 0,
+                                               slice_of(i, j, nl))),
+        pl.BlockSpec((None, th, d),
+                     lambda i, j, xo, eo, nl: (eo[i],
+                                               slice_of(i, j, nl), 0)),
+    ]
+    call = pl.pallas_call(
+        _tile_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_tiles, n_h),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (None, tm, d), lambda i, j, xo, eo, nl: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((tm, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, tm, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret_mode(),
+    )
+    with jax.named_scope(KERNEL_SCOPE):
+        return call(x_of, expert_of, n_live.reshape(1), x_tiles, gates,
+                    w_gate, w_up, w_down)
+
+
+def expert_tiles_reference(x_tiles, x_of, gates, expert_of, n_live, w_gate,
+                           w_up, w_down):
+    """The same tiles in plain jax.numpy, one at a time."""
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+
+    def tile(i):
+        def live():
+            x, e = x_tiles[x_of[i]], expert_of[i]
+            act = jnp.maximum(dot(x, w_gate[e]), 0.0) * dot(x, w_up[e])
+            return dot(act.astype(x.dtype), w_down[e]) * gates[i]
+
+        return jax.lax.cond(
+            i < n_live, live,
+            lambda: jnp.zeros(gates.shape[1:2] + w_gate.shape[1:2],
+                              jnp.float32))
+
+    return jax.lax.map(tile, jnp.arange(gates.shape[0]))
+
+
+def expert_tiles(x_tiles, x_of, gates, expert_of, n_live, w_gate, w_up,
+                 w_down, use_kernel=None):
+    """float32 [n_tiles, tm, d]: tile i's gated ReGLU product, zeros
+    from tile `n_live` on.
+
+    x_tiles [n_x, tm, d]; x_of, expert_of [n_tiles] int32; gates
+    [n_tiles, tm, 1] float32; n_live int32 scalar; w_gate, w_up
+    [E, d, hidden], w_down [E, hidden, d] in x_tiles' dtype.
+    `use_kernel=None` takes the Mosaic kernel where kernels are on and
+    the shapes are whole tiles (`kernel_supported`)."""
+    tm, d = x_tiles.shape[1:]
+    if use_kernel is None:
+        use_kernel = use_pallas() and kernel_supported(
+            tm, d, w_gate.shape[2], x_tiles.dtype)
+    fn = _expert_tiles_kernel if use_kernel else expert_tiles_reference
+    return fn(x_tiles, jnp.asarray(x_of, jnp.int32), gates,
+              jnp.asarray(expert_of, jnp.int32),
+              jnp.asarray(n_live, jnp.int32), w_gate, w_up, w_down)

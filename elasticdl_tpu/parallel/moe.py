@@ -37,11 +37,15 @@ Two dispatch implementations share those semantics:
   (global queue vs per-group queues).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common.constants import MeshAxis
+from elasticdl_tpu.ops.expert_ffn import expert_tiles
 from elasticdl_tpu.parallel.pipeline import shard_map
 
 
@@ -384,3 +388,165 @@ def moe_reference(params, x, capacity_factor=1.25,
         for ei, p in kept[ti]:
             y[ti] += (p / denom) * expert_out(ti, ei)
     return y
+
+
+# ---------------------------------------------- drop-free gated experts
+#
+# The serving-path expert layer: top-k of E by router logit, weights a
+# softmax over the k chosen logits, gated (three-matrix, ReGLU) experts,
+# NO capacity and NO drop, and told which experts it holds. Built on
+# ops/expert_ffn.expert_tiles; this half decides which tiles there are.
+
+#: up to this many rows the layer reads each HIT expert once for all
+#: rows (a decode tick: the experts' weights are the cost, so the rows
+#: ride along); above it each row is multiplied by its own experts only
+#: (a prefill: the products are the cost). Chosen from T, no knob.
+DECODE_ROWS = 16
+#: rows of a prefill tile: at 256 a tile's products (3 GFLOP at widths
+#: 2560 x 768) take about as long as its expert's weights (11.8 MB)
+PREFILL_TILE_ROWS = 256
+
+
+def route_top_k(logits, k):
+    """(gates [T, k] float32, experts [T, k] int32): the k largest of
+    the router's logits [T, E] and a softmax over those k (the same
+    numbers as a softmax over all E renormalised over the chosen)."""
+    top_v, top_i = jax.lax.top_k(logits.astype(jnp.float32), k)
+    return jax.nn.softmax(top_v, axis=-1), top_i.astype(jnp.int32)
+
+
+def _held_choices(experts, first, count):
+    """(local [T, k], held [T, k]): each choice's index among the
+    `count` experts held here from `first` on, and whether it is one."""
+    local = experts - first
+    return local, (local >= 0) & (local < count)
+
+
+def _hit_tiles(h, gates, experts, first, w_gate, w_up, w_down,
+               use_kernel):
+    """Few rows: one tile per HIT expert over all the rows."""
+    t = h.shape[0]
+    count = w_gate.shape[0]
+    local, held = _held_choices(experts, first, count)
+    onehot = (local[..., None] == jnp.arange(count)) & held[..., None]
+    gate_te = jnp.sum(jnp.where(onehot, gates[..., None], 0.0), axis=1)
+    hit = jnp.any(onehot, axis=(0, 1))  # [count]
+    n_live = jnp.sum(hit.astype(jnp.int32))
+    # hit experts first, in order; the dead tail repeats the last live
+    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)
+    order = jnp.where(jnp.arange(count) < n_live, order,
+                      order[jnp.maximum(n_live - 1, 0)])
+    tm = -(-t // DECODE_ROWS) * DECODE_ROWS
+    x = jnp.pad(h, ((0, tm - t), (0, 0)))[None]
+    tile_gates = jnp.pad(gate_te.T[order], ((0, 0), (0, tm - t)))
+    y = expert_tiles(x, jnp.zeros((count,), jnp.int32),
+                     tile_gates[..., None], order, n_live, w_gate, w_up,
+                     w_down, use_kernel=use_kernel)
+    return jnp.sum(y, axis=0)[:t], held, hit
+
+
+def _grouped_tiles(h, gates, experts, first, w_gate, w_up, w_down,
+                   use_kernel):
+    """Many rows: the (row, choice) pairs sorted by expert, each held
+    expert's run padded to whole tiles; gathers only, no scatter."""
+    t, d = h.shape
+    k = experts.shape[1]
+    count = w_gate.shape[0]
+    tm = PREFILL_TILE_ROWS
+    n_tiles = -(-t * k // tm) + count
+    local, held = _held_choices(experts, first, count)
+    # pairs of experts held elsewhere sort behind every held expert
+    key = jnp.where(held, local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    rank = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+    sizes = jnp.sum(key[:, None] == jnp.arange(count), axis=0)
+    start = jnp.cumsum(sizes) - sizes  # first sorted pair of each expert
+    tiles_of = -(-sizes // tm)
+    tile_end = jnp.cumsum(tiles_of)
+    tile_start = tile_end - tiles_of
+    n_live = tile_end[-1]
+    expert_of = jnp.searchsorted(tile_end, jnp.arange(n_tiles),
+                                 side="right")
+    expert_of = jnp.where(jnp.arange(n_tiles) < n_live, expert_of,
+                          expert_of[jnp.maximum(n_live - 1, 0)])
+    expert_of = jnp.minimum(expert_of, count - 1).astype(jnp.int32)
+    # row j of tile i is sorted pair start[e] + (row - first row of e)
+    row = jnp.arange(n_tiles * tm).reshape(n_tiles, tm)
+    e_row = expert_of[:, None]
+    offset = row - tile_start[e_row] * tm
+    live_row = ((jnp.arange(n_tiles) < n_live)[:, None]
+                & (offset < sizes[e_row]))
+    pair = order[jnp.clip(start[e_row] + offset, 0, t * k - 1)]
+    x_tiles = h[pair // k]  # [n_tiles, tm, d]
+    tile_gates = jnp.where(live_row, gates.reshape(-1)[pair], 0.0)
+    y = expert_tiles(x_tiles, jnp.arange(n_tiles), tile_gates[..., None],
+                     expert_of, n_live, w_gate, w_up, w_down,
+                     use_kernel=use_kernel)
+    # each pair's row of the tiles; a pair held elsewhere reads none
+    e_pair = jnp.minimum(key, count - 1)
+    dest = tile_start[e_pair] * tm + rank - start[e_pair]
+    rows = y.reshape(n_tiles * tm, d)[jnp.where(key < count, dest, 0)]
+    rows = jnp.where((key < count)[:, None], rows, 0.0)
+    hit = sizes > 0
+    return jnp.sum(rows.reshape(t, k, d), axis=1), held, hit
+
+
+def _held_experts(first, use_kernel, h, gates, experts, w_gate, w_up,
+                  w_down):
+    path = _hit_tiles if h.shape[0] <= DECODE_ROWS else _grouped_tiles
+    y, held, hit = path(h, gates, experts, first, w_gate, w_up, w_down,
+                        use_kernel)
+    return (y, jnp.sum(held, axis=1).astype(jnp.int32),
+            hit.astype(jnp.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def _lanes_as_one_call(first, use_kernel):
+    """_held_experts(first, use_kernel, ...) with a batching rule:
+    mapped over rows with the weights shared, the lanes are laid side
+    by side and computed as ONE call (itself mappable again)."""
+    plain = functools.partial(_held_experts, first, use_kernel)
+    call = custom_vmap(plain)
+
+    @call.def_vmap
+    def _lanes(axis_size, in_batched, h, gates, experts, *weights):
+        if any(in_batched[3:]) or not all(in_batched[:3]):
+            axes = [0 if b else None for b in in_batched]
+            out = jax.vmap(plain, in_axes=axes)(h, gates, experts,
+                                                *weights)
+            return out, (True, True, True)
+        t = h.shape[1]
+        flat = [a.reshape((axis_size * t,) + a.shape[2:])
+                for a in (h, gates, experts)]
+        y, held, hit = call(*flat, *weights)
+        return ((y.reshape((axis_size, t) + y.shape[1:]),
+                 held.reshape(axis_size, t), hit), (True, True, False))
+
+    return call
+
+
+def held_experts_reglu(h, gates, experts, w_gate, w_up, w_down, first=0,
+                       use_kernel=None):
+    """This chip's part of a drop-free gated expert layer.
+
+    h [T, D] (the compute dtype), gates [T, k] float32 and experts
+    [T, k] (route_top_k over ALL the layer's experts), and the weights
+    of the `count = w_gate.shape[0]` experts held here, experts
+    `first .. first + count` of the layer: w_gate, w_up [count, D, H],
+    w_down [count, H, D] in h's dtype. Returns
+
+        y [T, D] float32     sum over a row's HELD choices of
+                             gate * ((relu(h W_gate) * (h W_up)) W_down)
+        held [T] int32       how many of a row's k choices are held
+        hit [count] int32    1 for each held expert some row chose
+
+    No choice is dropped and no expert is computed that no row chose;
+    what the experts held elsewhere would add is left out (on one chip
+    there is no exchange, and nothing stands in for one). With `first`
+    0 and all the experts it is the whole layer. The path is chosen
+    from T (DECODE_ROWS). Under `jax.vmap` over rows with the weights
+    shared (the serving step maps one lane a sequence) the lanes are
+    laid side by side and computed as ONE call, so a tick reads a hit
+    expert once and not once a lane; `hit` is then the tick's."""
+    return _lanes_as_one_call(int(first), use_kernel)(
+        h, gates, experts, w_gate, w_up, w_down)

@@ -165,6 +165,22 @@ def role_default():
     return role
 
 
+def _tick_counts(sown):
+    """{name: int32 scalar} of what a model's layers sowed into
+    "counters" in a vmapped decode step: each leaf has the lanes as its
+    first axis. A scalar a lane is a count of that lane's rows and is
+    summed over the lanes; a vector a lane marks items touched (an
+    expert hit) and an item counts once however many lanes touched it.
+    Leaves of one name (one a layer) add up."""
+    out = {}
+    for path, value in jax.tree_util.tree_leaves_with_path(sown):
+        name = [k.key for k in path if hasattr(k, "key")][-1]
+        n = jnp.sum(value if value.ndim == 1
+                    else jnp.max(value, axis=0)).astype(jnp.int32)
+        out[name] = out.get(name, 0) + n
+    return out
+
+
 def _trace_id(request):
     """The request's trace id for a phase that serves it ("" for the
     bare requests of tests and benches)."""
@@ -311,6 +327,14 @@ class PagedContinuousBatchingEngine(object):
         self.model = model
         self.num_slots = int(num_slots)
         self.seq_len = int(model.seq_len)
+        # [(window, layers that have it)]: a model may give each layer
+        # its own (`layer_windows()`); one that does not has one kind
+        windows = (model.layer_windows()
+                   if hasattr(model, "layer_windows")
+                   else (getattr(model, "attn_window", 0),))
+        self._window_kinds = sorted(
+            (int(w), windows.count(w)) for w in set(windows))
+        self._window_layers = len(windows)
         self.top_k = int(top_k)
         self.top_p = float(top_p)
         self.block_size = int(block_size)
@@ -383,6 +407,11 @@ class PagedContinuousBatchingEngine(object):
         self._suffix_fns = {}  # suffix bucket -> compiled tile prefill
         self._step_fn = None
         self._spec_fn = None
+        # the counters the model's layers sow in a decode step, by
+        # name, in the order they follow the tokens in the step's
+        # result (filled when the step is traced; a model that sows
+        # none leaves it empty)
+        self._tick_counters = []
         # last-forwarded pool counters: the engine mirrors the pool's
         # monotone spill/revival counters into the closed telemetry
         # set by DELTA, so the event file stays in lockstep with the
@@ -965,15 +994,31 @@ class PagedContinuousBatchingEngine(object):
     def _count_paged_stream(self):
         """Count what this tick's paged decode streams, a layer: the
         table slots in reach of each lane's sequence (the kernel's own
-        live range — a free lane sits at position 0 and has none) of
-        all the table slots the lanes have."""
-        j_lo, j_hi = paged_live_blocks(
-            self._positions, getattr(self.model, "attn_window", 0) or None,
-            self.block_size, self.kv.max_blocks_per_slot, xp=np,
-        )
-        tracing.count("paged.blocks_streamed", int((j_hi - j_lo).sum()))
-        tracing.count("paged.table_slots",
-                      self.num_slots * self.kv.max_blocks_per_slot)
+        live range; a free lane sits at position 0 and has none) of
+        all the table slots the lanes have. Layers may differ in their
+        window (`model.layer_windows()`), so the reach is taken a kind
+        of layer and averaged over the layers (whole blocks); with one
+        window for every layer that is the one layer's count. And what
+        the pool holds in vain: every layer keeps every block of a
+        seated lane (kv_pool.plan charges the whole length, which a
+        layer that sees every key needs), so a block wholly behind a
+        layer's window is `kv.window_dead_blocks`, of the
+        `kv.blocks_held` written so far over all layers."""
+        m = self.kv.max_blocks_per_slot
+        held = streamed = dead = 0
+        for window, layers in self._window_kinds:
+            j_lo, j_hi = paged_live_blocks(
+                self._positions, window or None, self.block_size, m,
+                xp=np,
+            )
+            held += layers * int(j_hi.sum())
+            dead += layers * int(j_lo.sum())
+            streamed += layers * int((j_hi - j_lo).sum())
+        tracing.count("paged.blocks_streamed",
+                      streamed // self._window_layers)
+        tracing.count("paged.table_slots", self.num_slots * m)
+        tracing.count("kv.window_dead_blocks", dead)
+        tracing.count("kv.blocks_held", held)
 
     def step(self):
         """One vmapped decode step over the WHOLE pool: block tables
@@ -1020,6 +1065,9 @@ class PagedContinuousBatchingEngine(object):
                 nxt = np.asarray(nxt)  # the host waits for the device
         out = []
         with tracing.phase("tick.commit"):
+            for name, n in zip(self._tick_counters,
+                               nxt[self.num_slots:]):
+                tracing.count(name, int(n))
             for slot, st in active:
                 self._positions[slot] += 1
                 token = int(nxt[slot])
@@ -1178,6 +1226,7 @@ class PagedContinuousBatchingEngine(object):
         model = self.model
         top_k, top_p, qz = self.top_k, self.top_p, self._exec_qz
         block_size, num_blocks = self.block_size, self.num_blocks
+        tick_counters = self._tick_counters  # named when traced
 
         def step(pools, variables, tables, positions, last_tokens,
                  seeds, temps):
@@ -1197,7 +1246,7 @@ class PagedContinuousBatchingEngine(object):
                         dict(variables, cache={"pos": pos}),
                         {"tokens": tok[None, None]},
                         training=False, decode=True,
-                        mutable=["cache", "kv_out"],
+                        mutable=["cache", "kv_out", "counters"],
                         paged={"pools": pools, "table": table[None]},
                     )
                 nxt = serving_next_token(
@@ -1207,11 +1256,18 @@ class PagedContinuousBatchingEngine(object):
                     lambda t: t[0][0, :, 0, :], aux["kv_out"],
                     is_leaf=lambda x: isinstance(x, tuple),
                 )  # sown [1, hkv, 1, d] -> [hkv, d]
-                return nxt, rows
+                return nxt, rows, aux.get("counters", {})
 
-            nxt, rows = jax.vmap(one)(
+            nxt, rows, sown = jax.vmap(one)(
                 tables, positions, last_tokens, seeds, temps
             )
+            # what the model's layers counted this tick rides home
+            # behind the tokens, in the one array the host fetches
+            counted = _tick_counts(sown)
+            tick_counters[:] = sorted(counted)
+            nxt = jnp.concatenate(
+                [nxt] + [counted[name][None] for name in tick_counters]
+            ).astype(jnp.int32)
             bids = jnp.take_along_axis(
                 tables, (positions // block_size)[:, None], axis=1
             )[:, 0]

@@ -37,6 +37,7 @@ from elasticdl_tpu.parallel.context_parallel import (
     sharded_flash_attention,
     ulysses_attention,
 )
+from elasticdl_tpu.parallel.moe import held_experts_reglu, route_top_k
 
 
 def _tp_dense_init(split_axis):
@@ -78,6 +79,7 @@ class CausalSelfAttention(nn.Module):
     tp_shard: bool = True
     causal: bool = True
     use_rope: bool = False  # rotary q/k (global positions; sp-safe)
+    rope_theta: float = 10000.0
     window: int = 0  # sliding-window size; 0 = full attention
     cache_len: int = 0  # KV-cache capacity for decode mode
     # grouped-query attention: kv heads (0 = num_heads, i.e. standard
@@ -219,8 +221,8 @@ class CausalSelfAttention(nn.Module):
                                      paged=paged)
         if self.use_rope:
             pos = jnp.arange(l) if positions is None else positions
-            q = apply_rope(q, pos)
-            k = apply_rope(k, pos)
+            q = apply_rope(q, pos, self.rope_theta)
+            k = apply_rope(k, pos, self.rope_theta)
         if prefill:
             # Batched prompt prefill: one causal forward populates the
             # decode KV cache for positions [0, l) — O(prompt) single-
@@ -383,8 +385,8 @@ class CausalSelfAttention(nn.Module):
         idx = decode_pos
         if self.use_rope:
             pos = idx + jnp.arange(t)
-            q = apply_rope(q, pos)
-            k = apply_rope(k, pos)
+            q = apply_rope(q, pos, self.rope_theta)
+            k = apply_rope(k, pos, self.rope_theta)
         if paged is not None:
             # t = 1: the classic per-token step. t > 1: a query TILE —
             # the speculative verify-k step and the shared-prefix
@@ -470,7 +472,96 @@ class CausalSelfAttention(nn.Module):
         return self._proj(out, e)
 
 
+def _norm(kind, dtype, eps, name=None):
+    """The stack's normalisation: "layer" (LayerNorm, scale and bias)
+    or "rms" (RMSNorm, scale only). Unnamed it takes flax's own name
+    in its parent (`LayerNorm_0`, `RMSNorm_0`, ...)."""
+    if kind == "layer":
+        return nn.LayerNorm(epsilon=eps, dtype=dtype, name=name)
+    if kind == "rms":
+        return nn.RMSNorm(epsilon=eps, dtype=dtype, name=name)
+    raise ValueError("Unknown norm %r (valid: 'layer', 'rms')" % (kind,))
+
+
+class ExpertFFN(nn.Module):
+    """The block's MLP slot as a drop-free gated expert layer
+    (parallel/moe.held_experts_reglu): a router over ALL `num_experts`
+    (float32, its logits at full precision), top `top_k` by logit with a
+    softmax over the chosen, ReGLU experts of width `hidden`, and this
+    chip's share of them, `held = (first, count)`: the router keeps its
+    width, the weights are the held experts' only, and what the others
+    would add is left out. `()` holds them all.
+
+    `route_from` is what the router reads (the block's own input, so
+    that routing is known before attention runs); `h` what the experts
+    multiply. Where the caller collects "counters" (the serving step)
+    it is handed what the layer did: `moe.pairs_routed`,
+    `moe.pairs_held` (scalars, of this call's rows) and
+    `moe.experts_hit`, `moe.expert_slots` (a mark an expert held)."""
+
+    num_experts: int
+    top_k: int
+    hidden: int
+    held: tuple = ()
+    dtype: object = None
+
+    @nn.compact
+    def __call__(self, h, route_from, training=False):
+        b, l, d = h.shape
+        first, count = self.held or (0, self.num_experts)
+        if first < 0 or count < 1 or first + count > self.num_experts:
+            raise ValueError(
+                "experts_held %r is no range of %d experts"
+                % (self.held, self.num_experts))
+        dtype = self.dtype or h.dtype
+
+        def bank(fan_in):
+            return nn.with_partitioning(
+                nn.initializers.normal(fan_in ** -0.5),
+                (MeshAxis.EP, None, None))
+
+        router = self.param("router", nn.initializers.normal(d ** -0.5),
+                            (d, self.num_experts), jnp.float32)
+        weights = [
+            jnp.asarray(self.param(name, bank(shape[1]), shape,
+                                   jnp.float32), dtype)
+            for name, shape in (
+                ("w_gate", (count, d, self.hidden)),
+                ("w_up", (count, d, self.hidden)),
+                ("w_down", (count, self.hidden, d)))
+        ]
+        with jax.named_scope("moe_router"):
+            logits = jnp.matmul(
+                route_from.reshape(b * l, d).astype(jnp.float32), router,
+                precision=jax.lax.Precision.HIGHEST)
+            gates, experts = route_top_k(logits, self.top_k)
+        with jax.named_scope("moe_experts"):
+            # the Mosaic kernel has no backward: a training forward
+            # takes the plain products
+            y, held, hit = held_experts_reglu(
+                h.reshape(b * l, d).astype(dtype), gates, experts,
+                *weights, first=first,
+                use_kernel=False if training else None)
+        if (self.is_mutable_collection("counters")
+                and not self.is_initializing()):
+            counts = {
+                "moe.pairs_routed": jnp.asarray(b * l * self.top_k,
+                                                jnp.int32),
+                "moe.pairs_held": jnp.sum(held),
+                "moe.experts_hit": hit,
+                "moe.expert_slots": jnp.ones_like(hit),
+            }
+            for name, value in counts.items():
+                self.sow("counters", name, value)
+        return y.reshape(b, l, d)
+
+
 class Block(nn.Module):
+    """THE block of the stack, configured per layer: normalisation,
+    rotary on or off (and its theta), this layer's window, and what
+    sits in the MLP slot (the dense GELU MLP, or the expert layer fed
+    by the block's own input)."""
+
     num_heads: int
     head_dim: int
     mlp_ratio: int = 4
@@ -480,24 +571,34 @@ class Block(nn.Module):
     tp_shard: bool = True
     causal: bool = True
     use_rope: bool = False
+    rope_theta: float = 10000.0
     window: int = 0
     cache_len: int = 0
     num_kv_heads: int = 0  # grouped-query attention (0 = MHA)
     lora_rank: int = 0
     lora_alpha: float = 16.0
     kv_cache_dtype: str = ""  # "" | "int8" (see CausalSelfAttention)
+    norm: str = "layer"  # "layer" | "rms"
+    norm_eps: float = 1e-6
+    mlp: str = "gelu"  # "gelu" dense MLP | "moe_reglu" expert layer
+    moe_experts: int = 0
+    moe_top_k: int = 0
+    moe_hidden: int = 0
+    experts_held: tuple = ()
 
     @nn.compact
     def __call__(self, x, training=False, decode=False, decode_pos=None,
                  prefill=False, segments=None, positions=None,
                  paged=None):
         e = x.shape[-1]
-        y = nn.LayerNorm(dtype=self.dtype)(x)
+        block_in = x
+        y = _norm(self.norm, self.dtype, self.norm_eps)(x)
         x = x + CausalSelfAttention(
             self.num_heads, self.head_dim, dtype=self.dtype,
             attn_impl=self.attn_impl, sp_impl=self.sp_impl,
             tp_shard=self.tp_shard, causal=self.causal,
-            use_rope=self.use_rope, window=self.window,
+            use_rope=self.use_rope, rope_theta=self.rope_theta,
+            window=self.window,
             cache_len=self.cache_len,
             num_kv_heads=self.num_kv_heads,
             lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
@@ -506,7 +607,18 @@ class Block(nn.Module):
         )(y, training, decode=decode, decode_pos=decode_pos,
           prefill=prefill, segments=segments, positions=positions,
           paged=paged)
-        y = nn.LayerNorm(dtype=self.dtype)(x)
+        y = _norm(self.norm, self.dtype, self.norm_eps)(x)
+        if self.mlp == "moe_reglu":
+            y = ExpertFFN(
+                self.moe_experts, self.moe_top_k, self.moe_hidden,
+                held=tuple(self.experts_held), dtype=self.dtype,
+                name="moe",
+            )(y, block_in, training)
+            return x + y.astype(x.dtype)
+        if self.mlp != "gelu":
+            raise ValueError(
+                "Unknown mlp %r (valid: 'gelu', 'moe_reglu')"
+                % (self.mlp,))
         up_init = (
             _tp_dense_init(1) if self.tp_shard
             else nn.initializers.lecun_normal()
@@ -611,6 +723,27 @@ class TransformerLM(nn.Module):
     sp_impl: str = "ring"  # sequence-parallel scheme: "ring" | "ulysses"
     pos_emb: str = "learned"  # "learned" wpe table | "rope" rotary q/k
     attn_window: int = 0  # sliding-window attention; 0 = full
+    # The stack is ONE block driven by a per-layer pattern. Each layout
+    # is a 0/1 entry a layer (empty = every layer alike): which layers
+    # rotate q and k (pos_emb="rope"; a 0 is a layer with no positional
+    # encoding at all) and which keep keys to `attn_window` (a 0 sees
+    # every earlier key).
+    rope_layout: tuple = ()
+    window_layout: tuple = ()
+    rope_theta: float = 10000.0
+    head_dim: int = 0  # 0 = embed_dim // num_heads
+    norm: str = "layer"  # "layer" LayerNorm | "rms" RMSNorm
+    norm_eps: float = 1e-6
+    # the MLP slot: "gelu" = the dense 4x GELU MLP; "moe_reglu" = the
+    # drop-free gated expert layer (ExpertFFN): a router over
+    # `moe_experts`, `moe_top_k` a token, experts of width
+    # `moe_hidden`, of which this chip holds `experts_held = (first,
+    # count)` (() = all)
+    mlp: str = "gelu"
+    moe_experts: int = 0
+    moe_top_k: int = 0
+    moe_hidden: int = 0
+    experts_held: tuple = ()
     tp_shard: bool = True  # annotate kernels over the tp mesh axis
     fused_head: bool = False  # stream the LM head inside the loss
     num_kv_heads: int = 0  # grouped-query attention (0 = MHA)
@@ -628,6 +761,20 @@ class TransformerLM(nn.Module):
     # KV-cache storage: "" = compute dtype; "int8" halves (vs bf16) the
     # decode path's dominant HBM stream (see CausalSelfAttention)
     kv_cache_dtype: str = ""
+
+    def _layout(self, name):
+        layout = tuple(getattr(self, name))
+        if layout and len(layout) != self.num_layers:
+            raise ValueError(
+                "%s has %d entries for %d layers"
+                % (name, len(layout), self.num_layers))
+        return layout or (1,) * self.num_layers
+
+    def layer_windows(self):
+        """Each layer's window (0 = every earlier key), in order: what
+        the serving engine counts a tick's reach by."""
+        return tuple(self.attn_window if on else 0
+                     for on in self._layout("window_layout"))
 
     @nn.compact
     def __call__(self, features, training=False, decode=False,
@@ -679,7 +826,10 @@ class TransformerLM(nn.Module):
                 "Unknown pos_emb %r (valid: 'learned', 'rope')"
                 % (self.pos_emb,)
             )
-        head_dim = self.embed_dim // self.num_heads
+        head_dim = self.head_dim or self.embed_dim // self.num_heads
+        windows = self.layer_windows()
+        rotary = [self.pos_emb == "rope" and bool(on)
+                  for on in self._layout("rope_layout")]
         if self.remat not in ("", "full", "dots"):
             raise ValueError(
                 "Unknown remat %r (valid: '', 'full', 'dots')"
@@ -709,12 +859,16 @@ class TransformerLM(nn.Module):
                 self.num_heads, head_dim, dtype=self.dtype,
                 attn_impl=self.attn_impl, sp_impl=self.sp_impl,
                 tp_shard=self.tp_shard,
-                use_rope=self.pos_emb == "rope",
-                window=self.attn_window,
+                use_rope=rotary[i], rope_theta=self.rope_theta,
+                window=windows[i],
                 cache_len=self.seq_len,
                 num_kv_heads=self.num_kv_heads,
                 lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
                 kv_cache_dtype=self.kv_cache_dtype,
+                norm=self.norm, norm_eps=self.norm_eps,
+                mlp=self.mlp, moe_experts=self.moe_experts,
+                moe_top_k=self.moe_top_k, moe_hidden=self.moe_hidden,
+                experts_held=tuple(self.experts_held),
                 name="block_%d" % i,
             )
             blk_paged = None
@@ -734,7 +888,7 @@ class TransformerLM(nn.Module):
                         decode_pos=decode_pos, prefill=prefill,
                         segments=segments, positions=positions,
                         paged=blk_paged)
-        x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
+        x = _norm(self.norm, self.dtype, self.norm_eps, name="ln_f")(x)
         head = LMHead(
             self.vocab_size, dtype=self.dtype, name="head",
             kernel_init=(
@@ -770,6 +924,10 @@ def resolve_dtype(kwargs, family):
 
 
 def custom_model(**kwargs):
+    # a layout arrives as a list (--model_params); a module's fields
+    # are hashable
+    kwargs = {k: tuple(v) if isinstance(v, list) else v
+              for k, v in kwargs.items()}
     return TransformerLM(**resolve_dtype(kwargs, "transformer_lm"))
 
 

@@ -14,9 +14,11 @@ alias_bytes >= pool_bytes and no such copy; exits 1 otherwise.
 Are the weights served in the compute dtype? A program that takes the
 weights (`paged_step`, `suffix_prefill`) also prints the bytes of its
 weight arguments by dtype (`weight_bytes`), the float32 weight leaves
-of two or more dimensions among them (`f32_matrices`) and the
-`convert` instructions of the embedding table's shape in its optimized
-HLO (`table_converts`). Where the configuration computes in a narrower
+of two or more dimensions among them that its optimized HLO casts
+(`f32_matrices`: a `convert` of the leaf's shape from float32; a
+float32 matrix the program reads as it is, an expert layer's router,
+is no fault) and the `convert` instructions of the embedding table's
+shape (`table_converts`). Where the configuration computes in a narrower
 dtype than float32, `paged_step` must show none of either (the engine
 casts such leaves once a load, serving/exec_weights.py); exits 1
 otherwise.
@@ -120,17 +122,21 @@ def weight_report(variables, hlo, table_shape):
     import jax
     import numpy as np
 
+    converts = [line for line in hlo.splitlines() if " convert(" in line]
+
+    def converts_of(shape):
+        shaped = "[%s]" % ",".join(str(n) for n in shape)
+        return [line for line in converts if shaped in line]
+
     by_dtype, f32_matrices = {}, 0
     for leaf in jax.tree.leaves(variables):
         name = np.dtype(leaf.dtype).name
         by_dtype[name] = by_dtype.get(name, 0) + (
             int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize)
-        f32_matrices += name == "float32" and len(leaf.shape) >= 2
-    shaped = "[%s]" % ",".join(str(n) for n in table_shape)
-    converts = [line for line in hlo.splitlines()
-                if " convert(" in line and shaped in line]
+        f32_matrices += (name == "float32" and len(leaf.shape) >= 2
+                         and bool(converts_of(leaf.shape)))
     return {"weight_bytes": by_dtype, "f32_matrices": int(f32_matrices),
-            "table_converts": len(converts)}
+            "table_converts": len(converts_of(table_shape))}
 
 
 def compile_program(eng, program, sharding=None):

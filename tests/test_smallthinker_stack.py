@@ -1,0 +1,365 @@
+"""The `transformer_lm` stack configured as SmallThinker-21BA3B (RMSNorm,
+NoPE-global and RoPE-window layers, a drop-free ReGLU expert layer fed
+by the block's input, a share of the experts held) against the plain
+reference chipbench/refs/smallthinker.py, at small size on the CPU with
+seeded random weights, float32 compute:
+
+(a) the program's full forward, logits;
+(b) prefill, then decode through the paged pool, logits at every
+    decoded position, with a request longer than the (small) window,
+    and the same logits against references whose layers all have ONE
+    window: they must differ;
+(c) the shares add up: two halves of a layer's experts sum to the
+    uncut layer and to the reference;
+(d) no token is dropped under a routing skewed onto one expert, and
+    the decode path and the prefill path of the layer agree;
+and what the serving step hands back for the counters.
+
+Tolerances: both sides are float32 and sum in different orders (block
+attention against the program's blockwise scan, experts one by one
+against tiles), through 4 layers: 2e-4 on logits of unit scale is 100x
+the rounding seen and 100x under the smallest effect tested (one
+layer's window: 0.03 and more).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from flax.core import FrozenDict
+
+from chipbench.drivers.open_loop import _unflatten
+from chipbench.refs import smallthinker as ref
+from elasticdl_tpu.common.model_utils import load_model_spec_from_module
+from elasticdl_tpu.observability import tracing
+from elasticdl_tpu.parallel import mesh as mesh_lib
+from elasticdl_tpu.parallel import moe
+from elasticdl_tpu.serving.admission import ServingRequest
+from elasticdl_tpu.serving.engine import (
+    PagedContinuousBatchingEngine,
+    _tick_counts,
+)
+from elasticdl_tpu.training import trainer as trainer_mod
+from model_zoo.transformer_lm import transformer_lm as zoo
+
+TOL = 2e-4
+PARAMS = {
+    "vocab_size": 96, "seq_len": 64, "embed_dim": 48, "num_heads": 3,
+    "num_kv_heads": 1, "head_dim": 32, "num_layers": 4, "pos_emb": "rope",
+    "rope_theta": 1500000, "rope_layout": [0, 1, 1, 1], "attn_window": 8,
+    "window_layout": [0, 1, 1, 1], "norm": "rms", "norm_eps": 1e-6,
+    "mlp": "moe_reglu", "moe_experts": 8, "moe_top_k": 3, "moe_hidden": 24,
+    "experts_held": [0, 4],
+}
+WEIGHTS = {"qk_gain": 2.0, "router_gain": 1.0}
+
+
+def _cfg(**over):
+    return dict(PARAMS, **WEIGHTS, **over)
+
+
+def _engine(params, leaves):
+    """The paged engine over `leaves` (the reference's, by path), two
+    slots, blocks of four."""
+    trainer = trainer_mod.Trainer(
+        load_model_spec_from_module(zoo),
+        mesh=mesh_lib.build_mesh({"dp": 1}, devices=jax.devices()[:1]),
+        model_params="; ".join(
+            "%s=%r" % kv for kv in sorted(params.items())))
+    state = trainer_mod.TrainState(
+        step=jnp.zeros((), jnp.int32), params=_unflatten(leaves),
+        opt_state=(), model_state=FrozenDict({}),
+        rng=jax.random.PRNGKey(0))
+    return PagedContinuousBatchingEngine(trainer, state, 2, block_size=4)
+
+
+def _model(cfg):
+    return zoo.custom_model(**{k: v for k, v in cfg.items()
+                               if k not in WEIGHTS})
+
+
+def _tokens(seed, n, vocab=96):
+    return np.random.default_rng(seed).integers(0, vocab, (1, n))
+
+
+# ------------------------------------------------ (a) the full forward
+
+
+@pytest.mark.parametrize("seed,held", [(0, [0, 4]), (1, [4, 4]),
+                                       (2, [0, 8]), (3, [2, 3])])
+def test_full_forward_matches_the_reference(seed, held):
+    cfg = _cfg(experts_held=held)
+    w = ref.make_leaves(cfg, seed, ref.all_leaves(cfg))
+    tokens = _tokens(seed, 40)
+    got = _model(cfg).apply({"params": _unflatten(w)},
+                            {"tokens": jnp.asarray(tokens)})
+    want = ref.forward(cfg, w, jnp.asarray(tokens), rows=8)
+    assert got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - want))) < TOL
+
+
+def test_the_sc2_stack_keeps_its_parameter_names_and_its_one_window():
+    model = zoo.custom_model(vocab_size=32, seq_len=16, embed_dim=16,
+                             num_heads=2, num_layers=2, pos_emb="rope",
+                             attn_window=4)
+    tree = model.init(jax.random.PRNGKey(0),
+                      {"tokens": jnp.zeros((1, 8), jnp.int32)})["params"]
+    assert set(tree["block_1"]) == {"LayerNorm_0", "LayerNorm_1", "attn",
+                                    "mlp_up", "mlp_down"}
+    assert set(tree["ln_f"]) == {"scale", "bias"}
+    assert model.layer_windows() == (4, 4)
+    assert _model(_cfg()).layer_windows() == (0, 8, 8, 8)
+    with pytest.raises(ValueError, match="3 entries for 4 layers"):
+        _model(_cfg(window_layout=[0, 1, 1])).layer_windows()
+
+
+# ------------------------------- (b) prefill, then the paged pool
+
+
+@functools.lru_cache(maxsize=None)
+def _served(seed=0, prompt_len=20, new=12):
+    """A request longer than the window through the engine: the logits
+    of the model's own paged decode call at every decoded position
+    (read from the engine's pool before each step), the tokens the
+    engine streamed, and the counters its steps handed back."""
+    cfg = _cfg()
+    w = ref.make_leaves(cfg, seed, ref.all_leaves(cfg))
+    eng = _engine(PARAMS, w)
+    prompt = [int(t) for t in _tokens(seed + 7, prompt_len)[0]]
+    request = ServingRequest(prompt, new)
+    before = dict(tracing.recorder().counts())
+    slot, _, _ = eng.insert(request)
+    logits = []
+    while eng.active_count():
+        pos, tok = int(eng._positions[slot]), int(eng._last_tokens[slot])
+        out, _ = eng.model.apply(
+            dict(eng._exec_variables, cache={"pos": jnp.asarray(pos)}),
+            {"tokens": jnp.asarray([[tok]])}, training=False, decode=True,
+            mutable=["cache", "kv_out"],
+            paged={"pools": eng.kv.pools,
+                   "table": eng.kv.tables_device()[slot][None]})
+        logits.append(np.asarray(out[0, 0]))
+        eng.step()
+    after = tracing.recorder().counts()
+    counts = {k: after[k] - before.get(k, 0) for k in after}
+    return cfg, w, prompt, list(request.generated), logits, counts
+
+
+def _reference_logits(cfg, w, prompt, generated):
+    seq = prompt + generated
+    pad = -len(seq) % 8
+    out = ref.forward(cfg, w, jnp.asarray([seq + [0] * pad]), rows=8)[0]
+    return np.asarray(out[len(prompt) - 1:len(seq) - 1])
+
+
+def test_prefill_then_paged_decode_matches_the_reference_past_the_window():
+    cfg, w, prompt, generated, logits, _ = _served()
+    assert len(prompt) + len(generated) > cfg["attn_window"] * 3
+    want = _reference_logits(cfg, w, prompt, generated)
+    # want[0] is the prefill's own logit row (the first token); the
+    # paged steps produced tokens 1..n-1 from positions p..p+n-2
+    assert generated[0] == int(want[0].argmax())
+    got = np.stack(logits)
+    assert got.shape == want[1:].shape
+    assert np.abs(got - want[1:]).max() < TOL
+    assert generated[1:] == [int(r.argmax()) for r in got]
+
+
+@pytest.mark.parametrize("layout", [[1, 1, 1, 1], [0, 0, 0, 0]],
+                         ids=["every-layer-windowed", "no-layer-windowed"])
+def test_one_window_for_every_layer_is_another_model(layout):
+    """Were every layer given the same window (the stack before this
+    configuration had one `attn_window`), the served logits would be
+    those of a reference with that layout: they are not."""
+    cfg, w, prompt, generated, logits, _ = _served()
+    other = _reference_logits(dict(cfg, window_layout=layout), w, prompt,
+                              generated)
+    assert np.abs(np.stack(logits) - other[1:]).max() > 100 * TOL
+
+
+def test_a_rotary_global_layer_is_another_model_too():
+    cfg, w, prompt, generated, logits, _ = _served()
+    other = _reference_logits(dict(cfg, rope_layout=[1, 1, 1, 1]), w,
+                              prompt, generated)
+    assert np.abs(np.stack(logits) - other[1:]).max() > 100 * TOL
+
+
+def test_the_step_hands_back_what_the_expert_layers_did():
+    cfg, _, prompt, generated, _, counts = _served()
+    ticks = len(generated) - 1
+    layers, k = cfg["num_layers"], cfg["moe_top_k"]
+    # both lanes ride every tick, the free one included
+    assert counts["moe.pairs_routed"] == ticks * 2 * k * layers
+    assert 0 < counts["moe.pairs_held"] < counts["moe.pairs_routed"]
+    assert counts["moe.expert_slots"] == ticks * 4 * layers
+    assert 0 < counts["moe.experts_hit"] <= counts["moe.expert_slots"]
+    # 3 of 4 layers have a window of 8 = 2 blocks of 4: a lane at 20..31
+    # holds 5..8 blocks a layer, the oldest of them dead in those three
+    assert counts["kv.blocks_held"] > 0
+    dead = counts["kv.window_dead_blocks"] / counts["kv.blocks_held"]
+    assert 0.3 < dead < 0.75
+    assert counts["paged.blocks_streamed"] > 0
+
+
+def test_tick_counts_sums_scalars_and_counts_a_marked_item_once():
+    lanes = {"block_0": {"moe": {
+        "n": (jnp.asarray([3, 4, 5]),),
+        "hit": (jnp.asarray([[1, 0, 0, 1], [1, 0, 1, 0], [0, 0, 0, 0]]),),
+    }}, "block_1": {"moe": {"n": (jnp.asarray([1, 1, 1]),),
+                            "hit": (jnp.asarray([[0, 1, 0, 0]] * 3),)}}}
+    out = _tick_counts(lanes)
+    assert {k: int(v) for k, v in out.items()} == {"n": 15, "hit": 4}
+    assert _tick_counts({}) == {}
+
+
+# --------------------------------------- (c), (d) the expert layer
+
+
+def _layer(seed, t, experts=8, d=32, hidden=16, k=3, skew=None):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    w = {
+        "moe/router": jax.random.normal(ks[0], (d, experts)) * d ** -0.5,
+        "moe/w_gate": jax.random.normal(ks[1], (experts, d, hidden))
+        * d ** -0.5,
+        "moe/w_up": jax.random.normal(ks[2], (experts, d, hidden))
+        * d ** -0.5,
+        "moe/w_down": jax.random.normal(ks[3], (experts, hidden, d))
+        * hidden ** -0.5,
+    }
+    if skew is not None:  # every token's first choice is expert `skew`
+        w["moe/router"] = w["moe/router"].at[:, skew].set(0.0)
+    h = jax.random.normal(ks[4], (t, d))
+    x = jax.random.normal(ks[5], (t, d))
+    logits = ref.matmul(x, w["moe/router"])
+    if skew is not None:
+        logits = logits.at[:, skew].add(50.0)
+    return w, h, x, logits
+
+
+def _share(w, h, logits, first, count, k=3, **kwargs):
+    gates, experts = moe.route_top_k(logits, k)
+    return moe.held_experts_reglu(
+        h, gates, experts, *(w[n][first:first + count] for n in
+                             ("moe/w_gate", "moe/w_up", "moe/w_down")),
+        first=first, **kwargs)
+
+
+def _reference_layer(w, h, logits, first, count, k=3):
+    cfg = {"moe_experts": 8, "moe_top_k": k, "experts_held": [first, count]}
+    top_v, top_i = jax.lax.top_k(logits, k)
+    weights = jnp.sum(jnp.where(
+        top_i[..., None] == jnp.arange(8),
+        jax.nn.softmax(top_v, -1)[..., None], 0.0), axis=-2)
+    held = {n: v[first:first + count] for n, v in w.items()
+            if n != "moe/router"}
+    return ref.experts(cfg, held, h, weights)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("t", [1, 16, 17, 300],
+                         ids=["one-row", "decode-rows", "first-prefill",
+                              "two-tiles"])
+def test_the_shares_add_up_to_the_whole_layer_and_to_the_reference(seed, t):
+    w, h, _, logits = _layer(seed, t)
+    whole, held_all, hit_all = _share(w, h, logits, 0, 8)
+    low, held_low, hit_low = _share(w, h, logits, 0, 4)
+    high, held_high, hit_high = _share(w, h, logits, 4, 4)
+    # float32 rounding: the halves sum the same products in two parts
+    assert float(jnp.max(jnp.abs(low + high - whole))) < 2e-6
+    assert float(jnp.max(jnp.abs(
+        whole - _reference_layer(w, h, logits, 0, 8)))) < 2e-6
+    assert float(jnp.max(jnp.abs(
+        high - _reference_layer(w, h, logits, 4, 4)))) < 2e-6
+    assert (held_low + held_high == held_all).all()
+    assert (held_all == 3).all()
+    assert hit_low.tolist() + hit_high.tolist() == hit_all.tolist()
+
+
+@pytest.mark.parametrize("t", [8, 16, 40, 600])
+def test_no_token_is_dropped_when_every_token_chooses_one_expert(t):
+    """A capacity of 1.25 would drop most of these rows' first choice;
+    here every row reaches expert 2 with its full weight."""
+    w, h, _, logits = _layer(5, t, skew=2)
+    got, held, hit = _share(w, h, logits, 0, 8)
+    assert hit[2] == 1 and (held == 3).all()
+    want = _reference_layer(w, h, logits, 0, 8)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+    gates, experts = moe.route_top_k(logits, 3)
+    assert (experts[:, 0] == 2).all() and float(gates[:, 0].min()) > 0.99
+    # and the expert alone gives nearly all of every row
+    alone, _, _ = _share(w, h, logits, 2, 1)
+    assert float(jnp.max(jnp.abs(alone - want))) < 0.05 * float(
+        jnp.max(jnp.abs(want)))
+    assert float(jnp.min(jnp.max(jnp.abs(alone), axis=1))) > 0
+
+
+@pytest.mark.parametrize("first,count", [(0, 8), (0, 4), (4, 4), (3, 2)])
+def test_the_decode_path_and_the_prefill_path_agree(first, count):
+    w, h, _, logits = _layer(9, 12)
+    gates, experts = moe.route_top_k(logits, 3)
+    weights = [w[n][first:first + count]
+               for n in ("moe/w_gate", "moe/w_up", "moe/w_down")]
+    assert h.shape[0] <= moe.DECODE_ROWS
+    hit_path = moe._hit_tiles(h, gates, experts, first, *weights, False)
+    grouped = moe._grouped_tiles(h, gates, experts, first, *weights, False)
+    assert float(jnp.max(jnp.abs(hit_path[0] - grouped[0]))) < 2e-6
+    assert (hit_path[1] == grouped[1]).all()
+    assert (hit_path[2] == grouped[2]).all()
+
+
+def test_lanes_mapped_one_by_one_are_computed_as_one_call():
+    """The serving step maps a lane a sequence: the layer lays them side
+    by side (one read of a hit expert a tick) and marks the tick's hit
+    experts; a lane alone gives the same rows."""
+    w, h, _, logits = _layer(4, 6)
+
+    def lane(hh, ll):
+        return _share(w, hh, ll, 0, 4)
+
+    y, held, hit = jax.vmap(lane)(h[:, None], logits[:, None])
+    together, held_t, hit_t = _share(w, h, logits, 0, 4)
+    assert float(jnp.max(jnp.abs(y[:, 0] - together))) < 2e-6
+    assert (held[:, 0] == held_t).all()
+    assert (hit == hit_t[None]).all()  # the tick's mark, on every lane
+    jaxpr = str(jax.make_jaxpr(jax.vmap(lane))(h[:, None], logits[:, None]))
+    assert jaxpr.count("custom_vmap_call") == 1  # one call for all lanes
+
+
+def test_the_kernel_agrees_with_the_plain_tiles_when_interpreted(
+        monkeypatch):
+    monkeypatch.setenv("ELASTICDL_TPU_FORCE_INTERPRET", "1")
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    e, d, hidden = 4, 128, 256
+    w = [jax.random.normal(ks[0], (e, d, hidden)) * d ** -0.5,
+         jax.random.normal(ks[1], (e, d, hidden)) * d ** -0.5,
+         jax.random.normal(ks[2], (e, hidden, d)) * hidden ** -0.5]
+    for t in (5, 40):
+        h = jax.random.normal(ks[3], (t, d))
+        gates, experts = moe.route_top_k(
+            jax.random.normal(ks[4], (t, 8)), 3)
+        kernel = moe.held_experts_reglu(h, gates, experts, *w, first=2,
+                                        use_kernel=True)
+        plain = moe.held_experts_reglu(h, gates, experts, *w, first=2,
+                                       use_kernel=False)
+        assert float(jnp.max(jnp.abs(kernel[0] - plain[0]))) < 1e-5
+        assert (kernel[2] == plain[2]).all()
+
+
+def test_the_router_is_kept_float32_and_the_experts_are_served_as_bf16():
+    """serving/exec_weights.py, by the programs' jaxprs: the expert
+    banks are consumed through one narrowing cast, the router raw."""
+    w = ref.make_leaves(_cfg(), 0, ref.all_leaves(_cfg()))
+    before = dict(tracing.recorder().counts())
+    eng = _engine(dict(PARAMS, dtype="bf16"), w)
+    moe_leaves = eng._exec_variables["params"]["block_2"]["moe"]
+    assert moe_leaves["router"].dtype == jnp.float32
+    for name in ("w_gate", "w_up", "w_down"):
+        assert jax.tree.leaves(moe_leaves[name])[0].dtype == jnp.bfloat16
+    after = tracing.recorder().counts()
+    # a layer: qkv, proj and three banks cast; two norms and a router kept
+    assert after["weights.leaves_cast"] - before["weights.leaves_cast"] \
+        == 4 * 5 + 2
+    assert after["weights.leaves_kept"] - before["weights.leaves_kept"] \
+        == 4 * 3 + 1
