@@ -687,10 +687,7 @@ def child_kernels(sizes):
                 # traced and compiled, never run: the step donates
                 # the pool it is handed, and this one stays the engine's
                 engine.kv.pools, engine._exec_variables,
-                engine.kv.tables_device(),
-                jnp.asarray(engine._positions),
-                jnp.asarray(engine._last_tokens),
-                jnp.asarray(engine._seeds), jnp.asarray(engine._temps)))
+                engine._lanes_spec()))
 
     # -- each kernel against its jnp oracle, on a small input
     rng = np.random.default_rng(0)

@@ -153,6 +153,11 @@ COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             # update ran in place). The ratio is 1.0 or some caller
             # kept the pool from being donated
             "pool.launches", "pool.inplace_launches",
+            # serving/engine.py _tick_lanes(), once a decode tick
+            # inside `tick.upload`: host-to-device transfers the tick
+            # made for its lane state (0 when no lane changed since
+            # the last tick, else 1)
+            "tick.transfers",
             # serving/engine.py _load_params(), once a (re)load of a
             # weight tree: the bytes of the tree handed in and of the
             # tree the programs are served, and the leaves replaced by

@@ -44,6 +44,19 @@ from the call's result in the same statement (`kv.update`,
 `run_inplace`); a donating call that raises after consuming the pool
 is KVPoolLost, which ends the scheduler like any step that raises.
 
+The tick's LANE STATE lives on the device: each lane's block table,
+position, last token, seed and temperature are one small array
+(`lane_fields`) that the step takes and hands back advanced — a seated
+lane one position on, its last token the token just sampled — and
+that never comes to the host. The numpy mirror (`_positions`,
+`_last_tokens`, `_seeds`, `_temps`, `kv.tables`) stays the book the
+host works from; what the host changes in it outside the commit loop
+(a lane seated or freed, a table row written) marks the device's copy
+stale, and the next tick sends the mirror in one transfer
+(`_tick_lanes`); a tick whose lanes the host left alone sends nothing.
+After a step that raised the device's state is not trusted: the next
+tick rebuilds it from the mirror.
+
 The weights are served in the dtype the programs COMPUTE in, made
 once a load. The state handed in stays what a checkpoint holds (fp32);
 `_load_params` (construction and every hot reload) runs one jitted
@@ -73,6 +86,7 @@ and HBM bandwidth, not latency, bounds the step); the tree served is
 then the int8 tree as handed in.
 """
 
+import collections
 import os
 import time
 
@@ -179,6 +193,29 @@ def _tick_counts(sown):
                     else jnp.max(value, axis=0)).astype(jnp.int32)
         out[name] = out.get(name, 0) + n
     return out
+
+
+#: the columns of the device's lane state, an int32 [slots, 4 +
+#: max_blocks] array with a row a lane; the temperature is kept as its
+#: float32 bits
+_LANE_POS, _LANE_TOKEN, _LANE_SEED, _LANE_TEMP, _LANE_TABLE = range(5)
+
+
+Lanes = collections.namedtuple(
+    "Lanes", "tables positions last_tokens seeds temps")
+
+
+def lane_fields(lanes):
+    """The lane state array (numpy, or jax inside a program) as its
+    named parts: block tables [slots, max_blocks], positions, last
+    tokens, seeds (int32) and temperatures (float32)."""
+    temps = lanes[:, _LANE_TEMP]
+    return Lanes(
+        lanes[:, _LANE_TABLE:], lanes[:, _LANE_POS],
+        lanes[:, _LANE_TOKEN], lanes[:, _LANE_SEED],
+        temps.view(np.float32) if isinstance(temps, np.ndarray)
+        else jax.lax.bitcast_convert_type(temps, jnp.float32),
+    )
 
 
 def _trace_id(request):
@@ -403,6 +440,11 @@ class PagedContinuousBatchingEngine(object):
         self._last_tokens = np.zeros(self.num_slots, np.int32)
         self._seeds = np.zeros(self.num_slots, np.int32)
         self._temps = np.zeros(self.num_slots, np.float32)
+        # the lane state on the device as the last step handed it back
+        # (None: not to be trusted, the next tick sends the mirror),
+        # and whether the host has written a lane's scalars since
+        self._lanes = None
+        self._lanes_dirty = False
         self._prefill_fns = {}  # bucket -> compiled prefill
         self._suffix_fns = {}  # suffix bucket -> compiled tile prefill
         self._step_fn = None
@@ -750,12 +792,20 @@ class PagedContinuousBatchingEngine(object):
             return slot, first, True
         if not decoding:
             return slot, first, True
-        self._slots[slot] = _Slot(request, total)
+        self._activate(slot, request, first)
+        return slot, first, False
+
+    def _activate(self, slot, request, first):
+        """Start decoding `request` in `slot`, its prompt's rows
+        resident and `first` its first generated token: the lane's
+        scalars are written in the mirror and owed to the device."""
+        p = len(request.prompt)
+        self._slots[slot] = _Slot(request, p + request.max_new_tokens)
         self._positions[slot] = p
         self._last_tokens[slot] = first
         self._seeds[slot] = request.seed
         self._temps[slot] = request.temperature
-        return slot, first, False
+        self._lanes_dirty = True
 
     def _insert_shared(self, slot, request, shared):
         """Seat on a prefix match: the shared blocks are resident, so
@@ -929,13 +979,7 @@ class PagedContinuousBatchingEngine(object):
             self.kv.release(slot)
             job.finished = True
             return
-        self._slots[slot] = _Slot(
-            request, job.prompt_len + request.max_new_tokens
-        )
-        self._positions[slot] = job.prompt_len
-        self._last_tokens[slot] = first
-        self._seeds[slot] = request.seed
-        self._temps[slot] = request.temperature
+        self._activate(slot, request, first)
 
     def abort_prefill(self, job):
         """Abandon a pending chunked prefill (deadline expiry between
@@ -978,6 +1022,7 @@ class PagedContinuousBatchingEngine(object):
         owners (copy-free churn — nothing is zeroed or moved)."""
         self._slots[slot] = None
         self._positions[slot] = 0
+        self._lanes_dirty = True
         self.kv.release(slot)
 
     def evict_expired(self, now):
@@ -1020,6 +1065,30 @@ class PagedContinuousBatchingEngine(object):
         tracing.count("kv.window_dead_blocks", dead)
         tracing.count("kv.blocks_held", held)
 
+    def _tick_lanes(self, budgets=None):
+        """The lane state this tick's program takes, on the device,
+        inside `tick.upload`. When the host wrote no lane and no table
+        row since the last tick it is the array the last step handed
+        back and nothing is sent. Otherwise (a lane seated or freed, a
+        row grown or rewritten, no trusted state on the device: the
+        first tick, or a step that raised) the mirror goes whole, in
+        ONE transfer. The speculative tick hands no state back and
+        sends the mirror every tick, its `budgets` one more column.
+        Counts `tick.transfers` (0 or 1). The state is taken: the tick
+        puts back what its program returned once the mirror has caught
+        up with it, and a tick that raises leaves none."""
+        lanes, self._lanes = self._lanes, None
+        send = (lanes is None or budgets is not None
+                or self._lanes_dirty or self.kv.tables_dirty)
+        self._lanes_dirty = self.kv.tables_dirty = False
+        if send:
+            lanes = jax.device_put(np.column_stack(
+                [self._positions, self._last_tokens, self._seeds,
+                 self._temps.view(np.int32), self.kv.tables]
+                + ([] if budgets is None else [budgets])))
+        tracing.count("tick.transfers", int(send))
+        return lanes
+
     def step(self):
         """One vmapped decode step over the WHOLE pool: block tables
         and positions enter as device arrays, each active slot
@@ -1052,14 +1121,10 @@ class PagedContinuousBatchingEngine(object):
             self._step_fn = self._build_paged_step()
         with self.trainer.mesh:
             with tracing.phase("tick.upload"):
-                args = (self.kv.tables_device(),
-                        jnp.asarray(self._positions),
-                        jnp.asarray(self._last_tokens),
-                        jnp.asarray(self._seeds),
-                        jnp.asarray(self._temps))
+                lanes = self._tick_lanes()
             with tracing.phase("tick.dispatch"):
-                nxt = self.kv.update(
-                    self._step_fn, self._exec_variables, *args
+                lanes, nxt = self.kv.update(
+                    self._step_fn, self._exec_variables, lanes
                 )
             with tracing.phase("tick.fetch"):
                 nxt = np.asarray(nxt)  # the host waits for the device
@@ -1081,6 +1146,9 @@ class PagedContinuousBatchingEngine(object):
                 if finished:
                     self.evict(slot)
                 out.append((slot, st.request, [token], finished))
+            # the mirror has advanced as the device did: what the step
+            # handed back is the state again
+            self._lanes = lanes
         return out
 
     def _spec_step(self, active):
@@ -1107,16 +1175,14 @@ class PagedContinuousBatchingEngine(object):
             self._spec_fn = self._build_spec_step()
         with self.trainer.mesh:
             with tracing.phase("tick.upload"):
-                args = (self.kv.tables_device(),
-                        jnp.asarray(self._positions),
-                        jnp.asarray(self._last_tokens),
-                        jnp.asarray(self._seeds),
-                        jnp.asarray(self._temps),
-                        jnp.asarray(budgets))
+                # positions advance by what the tick accepts, which
+                # this program does not carry on the device (D14): it
+                # hands no state back, so every tick sends the mirror
+                lanes = self._tick_lanes(budgets)
             with tracing.phase("tick.dispatch"):
                 self._d_pool, toks, counts = self.kv.update(
                     self._spec_fn, self._d_pool,
-                    self._exec_variables, self._d_variables, *args
+                    self._exec_variables, self._d_variables, lanes
                 )
             with tracing.phase("tick.fetch"):
                 toks = np.asarray(toks)
@@ -1166,9 +1232,7 @@ class PagedContinuousBatchingEngine(object):
         i32, f32 = spec((), jnp.int32), spec((), jnp.float32)
         tile = self._suffix_bucket(1)
         prompt = spec((1, self.seq_len), jnp.int32)
-        tables = spec(self.kv.tables.shape, jnp.int32)
-        lanes = tuple(spec((self.num_slots,), d) for d in (
-            jnp.int32, jnp.int32, jnp.int32, jnp.float32, jnp.int32))
+        lanes = self._lanes_spec()
         programs = [
             (self._prefill_program(_prefill_bucket(1, self.seq_len)),
              (variables, prompt, i32, i32, f32), (0, None)),
@@ -1181,16 +1245,21 @@ class PagedContinuousBatchingEngine(object):
         if d_variables is None:
             return programs + [
                 (self._paged_step_program(),
-                 (self.kv.pools, variables, tables) + lanes[:4],
-                 (1, None))]
+                 (self.kv.pools, variables, lanes), (1, None))]
         return programs + [
             (self._spec_step_program(),
              (self.kv.pools, self._d_pool, variables, d_variables,
-              tables) + lanes, (2, 3)),
+              self._lanes_spec(budgets=True)), (2, 3)),
             (self._draft_prefill_program(
                 _prefill_bucket(1, self.seq_len)),
              (d_variables, prompt, i32), (None, 0)),
         ]
+
+    def _lanes_spec(self, budgets=False):
+        """The shape of what _tick_lanes hands a tick's program, for
+        who traces one without running it."""
+        width = _LANE_TABLE + self.kv.max_blocks_per_slot + bool(budgets)
+        return jax.ShapeDtypeStruct((self.num_slots, width), jnp.int32)
 
     def _tjit(self, name, fn, **jit_kwargs):
         """jax.jit with recompile-sentry adoption: one fixed NAME per
@@ -1228,9 +1297,10 @@ class PagedContinuousBatchingEngine(object):
         block_size, num_blocks = self.block_size, self.num_blocks
         tick_counters = self._tick_counters  # named when traced
 
-        def step(pools, variables, tables, positions, last_tokens,
-                 seeds, temps):
+        def step(pools, variables, lanes):
             variables = _maybe_dequantize(variables, qz)
+            tables, positions, last_tokens, seeds, temps = lane_fields(
+                lanes)
 
             def one(table, pos, tok, seed, temp):
                 # pre-advance counter: this token's k/v rows belong
@@ -1265,18 +1335,29 @@ class PagedContinuousBatchingEngine(object):
             # behind the tokens, in the one array the host fetches
             counted = _tick_counts(sown)
             tick_counters[:] = sorted(counted)
+            # the state the next tick starts from: a seated lane is
+            # one position on and its last token is the one just
+            # sampled; a lane at position 0 is free (or its prompt is
+            # still being written) and stays as it is
+            seated = positions > 0
+            lanes = lanes.at[:, _LANE_POS].add(
+                seated.astype(jnp.int32)
+            ).at[:, _LANE_TOKEN].set(
+                jnp.where(seated, nxt.astype(jnp.int32), last_tokens))
             nxt = jnp.concatenate(
                 [nxt] + [counted[name][None] for name in tick_counters]
             ).astype(jnp.int32)
             bids = jnp.take_along_axis(
                 tables, (positions // block_size)[:, None], axis=1
             )[:, 0]
-            # free lanes (table row -1): point past the arena so the
-            # scatter's mode="drop" discards them
-            bids = jnp.where(bids < 0, num_blocks, bids)
+            # free lanes (table row -1), and a lane whose prompt is
+            # still being written tile by tile (its row is there, its
+            # position 0): point past the arena so the scatter's
+            # mode="drop" discards them
+            bids = jnp.where(seated & (bids >= 0), bids, num_blocks)
             pools = scatter_rows(pools, rows, bids,
                                  positions % block_size)
-            return pools, nxt
+            return pools, (lanes, nxt)
 
         return step
 
@@ -1398,9 +1479,12 @@ class PagedContinuousBatchingEngine(object):
         max_blocks = self.kv.max_blocks_per_slot
         k = self.draft_k
 
-        def step(pools, d_pool, variables, d_variables, tables,
-                 positions, last_tokens, seeds, temps, budgets):
+        def step(pools, d_pool, variables, d_variables, lanes):
             variables = _maybe_dequantize(variables, qz)
+            # the mirror as sent this tick, the budgets its last column
+            budgets = lanes[:, -1]
+            tables, positions, last_tokens, seeds, temps = lane_fields(
+                lanes[:, :-1])
             # force the draft counters to the committed truth — the
             # rollback contract: rows past the counter are masked junk
             d_pool_f = dict(d_pool, pos=positions)
@@ -1473,7 +1557,10 @@ class PagedContinuousBatchingEngine(object):
                 tables, jnp.minimum(wpos // block_size, max_blocks - 1),
                 axis=1,
             )
-            keep = (jnp.arange(k + 1)[None, :] < c[:, None]) & (bids >= 0)
+            # (nor a lane at position 0, whose prompt is still being
+            # written tile by tile: its row is there, it decodes nothing)
+            keep = ((jnp.arange(k + 1)[None, :] < c[:, None])
+                    & (bids >= 0) & (positions[:, None] > 0))
             bids = jnp.where(keep, bids, num_blocks)
             pools = scatter_rows(pools, rows, bids, wpos % block_size)
             return pools, (d_pool_out, out_toks, c)

@@ -121,11 +121,12 @@ obliges the callers to:
   is the share of pool updates that ran in place.
 
 Block ids enter the compiled decode step as DEVICE arrays (the tables),
-so slot churn and sequence growth never recompile anything.
-The device table upload is CACHED and refreshed only when some table
-actually changed (one device put per mutating step, not per slot —
-mid-decode steps where no block boundary is crossed reuse the resident
-array). The attention that consumes this layout is
+so slot churn and sequence growth never recompile anything. The tables
+the step reads STAY on the device, carried from tick to tick in the
+engine's lane state; `tables` here is the book they are rebuilt from,
+and `tables_dirty` says that the host wrote a row of it (growth,
+seating, copy-on-write, release) since the engine last sent it. The
+attention that consumes this layout is
 `ops.attention.paged_decode_attention`.
 """
 
@@ -907,11 +908,10 @@ class PagedKVPool(object):
     and the jitted block write/copy. `cache_len % block_size == 0` is
     required so prompt blocks slice cleanly out of the prefill cache.
 
-    The device copy of the table mirror is cached: `tables_device()`
-    re-uploads only after a mutation (alloc/extend/CoW/release), so a
-    decode step that crosses no block boundary costs zero host->device
-    table traffic — the per-step assembly is one cached handle, not
-    per-slot work.
+    The mirror is not what the step reads: the engine keeps the
+    tables on the device and sends the mirror when `tables_dirty`
+    says a row was written, so a decode step that crosses no block
+    boundary costs zero host->device table traffic.
 
     `pools` is the ONE reference to the arenas: every program that
     updates them runs through `update`, which donates the tree and
@@ -938,7 +938,9 @@ class PagedKVPool(object):
         self.tables = np.full(
             (int(num_slots), self.max_blocks_per_slot), -1, np.int32
         )
-        self._tables_dev = None  # cached device upload of `tables`
+        # a row of `tables` was written since the engine last sent
+        # them to the device (it clears this when it does)
+        self.tables_dirty = False
         # TRUE arena bytes: summed per leaf at its OWN dtype, so int8
         # arenas count their int8 rows AND f32 scale leaves exactly —
         # never a homogeneous row-dtype assumption. This is what
@@ -1350,7 +1352,7 @@ class PagedKVPool(object):
         freed = self.allocator.free(slot)
         if freed:
             self.tables[slot, :] = -1
-            self._tables_dev = None
+            self.tables_dirty = True
         return freed
 
     def flush_prefix_cache(self):
@@ -1365,15 +1367,7 @@ class PagedKVPool(object):
         row = np.full(self.max_blocks_per_slot, -1, np.int32)
         row[: len(table)] = table
         self.tables[slot] = row
-        self._tables_dev = None  # mutation: next step re-uploads once
-
-    def tables_device(self):
-        """The block tables as ONE cached device array — re-uploaded
-        only after a mutation, so steady-state decode steps pay no
-        host->device table transfer."""
-        if self._tables_dev is None:
-            self._tables_dev = jnp.asarray(self.tables)
-        return self._tables_dev
+        self.tables_dirty = True
 
     # ------------------------------------------------------------- stats
 

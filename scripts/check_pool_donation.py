@@ -93,15 +93,12 @@ def programs(eng, tile, upload_blocks):
     kv = eng.kv
     spec = jax.ShapeDtypeStruct
     i32, f32 = spec((), jnp.int32), spec((), jnp.float32)
-    lanes = [spec((eng.num_slots,), d) for d in
-             (jnp.int32, jnp.int32, jnp.int32, jnp.float32)]
     rows = [spec((upload_blocks,) + shape, jnp.dtype(dtype))
             for shape, dtype in zip(kv.row_shapes, kv.leaf_dtypes())]
     return {
         "paged_step": (
             eng._build_paged_step(),
-            [eng._exec_variables, spec(kv.tables.shape, jnp.int32)]
-            + lanes, {}),
+            [eng._exec_variables, eng._lanes_spec()], {}),
         "prompt_write": (kv._write_program(), [eng._kv_shapes, i32, i32],
                          {"block_size": kv.block_size}),
         "cow_copy": (kv._copy_program(), [i32, i32], {}),

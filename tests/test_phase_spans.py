@@ -367,6 +367,45 @@ def test_tokens_equal_the_offline_oracle_with_the_spans_recording(
     assert not hasattr(server.engine, "profiler")
 
 
+def test_a_decode_tick_keeps_its_six_phases_and_counts_what_it_sends(
+        paged_server):
+    """The tick's lane state lives on the device: `tick.upload` stays
+    a phase of every decode tick, around whatever the tick sends, and
+    counts that inside itself: `tick.transfers`, 0 on a tick whose
+    lanes did not change, else 1."""
+    _trainer, _state, server = paged_server
+    _serve(server, [([1, 2, 3], 14)])
+    first = max(p.seq for p in tracing.recorder().phases()
+                if p.name == "tick")
+    _serve(server, [([1, 2, 3, 4, 5], 14), ([6, 7], 3)])
+    ticks = {}
+    for p in tracing.recorder().phases():
+        if p.seq is not None and p.seq > first and p.parent in (
+                "tick", "tick.upload"):
+            ticks.setdefault(p.seq, []).append(p)
+    decode = [t for t in ticks.values()
+              if any(p.name == "tick.dispatch" for p in t)]
+    assert len(decode) >= 13
+    six = ["tick.ensure", "tick.upload", "tick.dispatch", "tick.fetch",
+           "tick.commit", "tick.stream"]
+    sent = []
+    for tick in decode:
+        by_name = {p.name: p for p in tick}
+        spans = sorted((by_name[name] for name in six),
+                       key=lambda p: p.start_ns)
+        assert [p.name for p in spans] == six
+        assert all(p.parent == "tick" for p in spans)
+        counts = [p for p in tick if p.parent == "tick.upload"]
+        assert [p.name for p in counts] == ["tick.transfers"]
+        upload = by_name["tick.upload"]
+        assert all(upload.start_ns <= p.start_ns <= upload.end_ns
+                   for p in counts)
+        sent.append(by_name["tick.transfers"].attrs["n"])
+    # seatings, grown blocks and completions send once; the other
+    # ticks, most of them, send nothing
+    assert set(sent) == {0, 1} and sent.count(0) > sent.count(1)
+
+
 @pytest.mark.parametrize("window,want", [(0, 4), (6, 3)],
                          ids=("full", "window6"))
 def test_a_decode_tick_counts_the_blocks_its_lanes_have_in_reach(

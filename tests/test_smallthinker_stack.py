@@ -139,7 +139,7 @@ def _served(seed=0, prompt_len=20, new=12):
             {"tokens": jnp.asarray([[tok]])}, training=False, decode=True,
             mutable=["cache", "kv_out"],
             paged={"pools": eng.kv.pools,
-                   "table": eng.kv.tables_device()[slot][None]})
+                   "table": jnp.asarray(eng.kv.tables[slot])[None]})
         logits.append(np.asarray(out[0, 0]))
         eng.step()
     after = tracing.recorder().counts()
