@@ -305,6 +305,46 @@ def kv_row_leaf(leaf, cache_len):
             and shape[2] == cache_len)
 
 
+#: the kinds of a decode-cache leaf (cache_leaf_kinds)
+ROWS, STATE, SCALAR = "rows", "state", "scalar"
+
+
+def cache_leaf_kinds(model, kv_shapes, cache_len):
+    """The KIND of every leaf of a batch-1 decode-cache template
+    (`_kv_shapes_for(cache, model, 1)`), as a tree of the same
+    structure: ROWS, a cached token a row of a `[1, kv_heads,
+    cache_len, ...]` buffer, which the serving pool pages into block
+    arenas by block table; STATE, a fixed-size per-sequence state
+    `[1, ...]` (a state-space layer's) that the pool keeps a slot of
+    for each of its lanes; SCALAR, the position counter. The model
+    DECLARES them, by path (`model.cache_leaf_kind(path)`); one that
+    declares nothing has rows where the `kv_row_leaf` convention finds
+    them and scalars elsewhere. A leaf's rank decides nothing: a state
+    may well be 4-d."""
+    declare = getattr(model, "cache_leaf_kind", None)
+
+    def kind(path, leaf):
+        if declare is None:
+            return ROWS if kv_row_leaf(leaf, cache_len) else SCALAR
+        names = tuple(getattr(k, "key", getattr(k, "name", None))
+                      for k in path)
+        out = declare(names)
+        if out == ROWS and not kv_row_leaf(leaf, cache_len):
+            raise ValueError(
+                "cache leaf %r is declared rows and is shaped %r, not "
+                "[1, kv_heads, %d, ...]" % (names, leaf.shape, cache_len))
+        if out == STATE and (not leaf.shape or leaf.shape[0] != 1):
+            raise ValueError(
+                "cache leaf %r is declared a per-sequence state and is "
+                "shaped %r, not [1, ...]" % (names, leaf.shape))
+        if out not in (ROWS, STATE, SCALAR):
+            raise ValueError("cache leaf %r is declared %r"
+                             % (names, out))
+        return out
+
+    return jax.tree_util.tree_map_with_path(kind, kv_shapes)
+
+
 def _run_prefill(model, variables, kv_shapes, tokens2d, p_len, p_pad):
     """Shared batched-prefill contract for the greedy-KV and beam-KV
     paths: zero caches, ONE prefill=True forward over the static

@@ -121,7 +121,7 @@ PHASES = (
     "tick.commit",
     # serving/engine.py insert() and friends, serving/kv_pool.py
     "prefill", "prefill_tile", "suffix_tile", "draft", "reload_swap",
-    "prompt_write", "revive_upload",
+    "prompt_write", "state_write", "revive_upload",
     # api/local_executor.py train()
     "train.task_get", "train.next_batch", "train.pad", "train.step",
     "train.loss_fetch", "train.checkpoint", "train.eval",
@@ -158,6 +158,15 @@ COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             # made for its lane state (0 when no lane changed since
             # the last tick, else 1)
             "tick.transfers",
+            # serving/kv_pool.py write_prompt(): launches that seated a
+            # prompt's per-sequence state (one for all the state layers
+            # of a model that has any)
+            "state_write.launches",
+            # serving/engine.py step(), a decode tick inside
+            # `tick.ensure`: (lane, state layer) updates the step makes
+            # (every lane rides every tick, so lanes x state layers)
+            # and those of them of seated lanes
+            "ssm.lanes", "ssm.lanes_live",
             # serving/engine.py _load_params(), once a (re)load of a
             # weight tree: the bytes of the tree handed in and of the
             # tree the programs are served, and the leaves replaced by

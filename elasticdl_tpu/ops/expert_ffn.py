@@ -1,11 +1,19 @@
-"""Gated expert feed-forward products over row tiles: the kernel under
+"""Expert feed-forward products over row tiles: the kernel under
 parallel/moe.py's drop-free expert layer.
 
 The work is a list of TILES. Tile i is `tm` rows of activations
 (`x_tiles[x_of[i]]`), ONE expert (`expert_of[i]`) and a float32 weight
-a row (`gates[i]`); its result is
+a row (`gates[i]`); its result is, by how many matrices an expert has,
 
     gates[i] * ((relu(x W_gate[e]) * (x W_up[e])) W_down[e])        ReGLU
+    gates[i] * (relu(x W_up[e]^T)^2 W_down[e])                      relu^2
+
+(the two-matrix form keeps BOTH matrices a hidden unit a row, [hidden,
+d], as a checkpoint stores an up projection: where no multiple of 128
+divides the hidden width (1856 = 29 x 64) the device lays a [d, hidden]
+matrix out with d minor, and a kernel that wants it row-major makes the
+compiler copy the whole bank every launch: 1.0 ms a layer a tick at 32
+experts of 2688 x 1856, my chip run, PR 36)
 
 in float32, products in the operands' dtype with float32 accumulation.
 Only the first `n_live` tiles are computed; the rest are written as
@@ -13,8 +21,8 @@ zeros and move no weight. That one shape serves both ends of serving
 (parallel/moe.py builds the lists):
 
 * decode, a handful of rows: every hit expert is a tile over the SAME
-  rows (`x_of` all 0), so a tick reads each hit expert's three matrices
-  once and no other expert's;
+  rows (`x_of` all 0), so a tick reads each hit expert's matrices once
+  and no other expert's;
 * prefill, thousands of rows: the (token, choice) pairs are sorted by
   expert and each expert's run is padded to whole tiles, so a token is
   multiplied by its own experts only.
@@ -57,10 +65,14 @@ def hidden_slice(hidden):
 
 def kernel_supported(tm, d, hidden, dtype):
     """Shape gate of the Mosaic kernel: whole (sublane, 128) tiles of
-    the operand dtype. Every other shape takes the reference."""
+    the operand dtype; a hidden width that no 128-multiple divides
+    (1856 = 29 x 64) goes through whole, as the full extent of its
+    axis, and has to be whole sublanes of the down projection's rows.
+    Every other shape takes the reference."""
     sublanes = 32 // jnp.dtype(dtype).itemsize  # 8 f32, 16 bf16
+    th = hidden_slice(hidden)
     return (tm % sublanes == 0 and d % 128 == 0
-            and hidden_slice(hidden) % 128 == 0)
+            and (th % 128 == 0 or (th == hidden and th % sublanes == 0)))
 
 
 def _tile_kernel(x_of_ref, expert_of_ref, n_live_ref, x_ref, g_ref,
@@ -86,10 +98,33 @@ def _tile_kernel(x_of_ref, expert_of_ref, n_live_ref, x_ref, g_ref,
         out_ref[...] = acc_ref[...] * g_ref[...]
 
 
-def _expert_tiles_kernel(x_tiles, x_of, gates, expert_of, n_live, w_gate,
-                         w_up, w_down):
+def _tile_kernel_relu2(x_of_ref, expert_of_ref, n_live_ref, x_ref, g_ref,
+                       wu_ref, wd_ref, out_ref, acc_ref):
+    """The two-matrix form: relu(x W_up^T)^2 W_down, both [hidden, d]."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    live = i < n_live_ref[0]
+
+    @pl.when(j == 0)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _multiply():
+        x = x_ref[...]
+        u = jnp.maximum(jax.lax.dot_general(
+            x, wu_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32), 0.0)
+        acc_ref[...] += jnp.dot((u * u).astype(x.dtype), wd_ref[...],
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _write():
+        out_ref[...] = acc_ref[...] * g_ref[...]
+
+
+def _expert_tiles_kernel(x_tiles, x_of, gates, expert_of, n_live, *weights):
     n_tiles, tm = gates.shape[:2]
-    d, hidden = w_gate.shape[1:]
+    hidden, d = weights[-1].shape[1:]
     th = hidden_slice(hidden)
     n_h = hidden // th
 
@@ -97,22 +132,20 @@ def _expert_tiles_kernel(x_tiles, x_of, gates, expert_of, n_live, w_gate,
         # a dead tile asks for the block the last live step left behind
         return jnp.where(i < n_live_ref[0], j, n_h - 1)
 
+    into_hidden = pl.BlockSpec(  # [d, hidden]: ReGLU's gate and up
+        (None, d, th),
+        lambda i, j, xo, eo, nl: (eo[i], 0, slice_of(i, j, nl)))
+    from_hidden = pl.BlockSpec(  # [hidden, d]: a down, and relu^2's up
+        (None, th, d),
+        lambda i, j, xo, eo, nl: (eo[i], slice_of(i, j, nl), 0))
     in_specs = [
         pl.BlockSpec((None, tm, d),
                      lambda i, j, xo, eo, nl: (xo[i], 0, 0)),
         pl.BlockSpec((None, tm, 1), lambda i, j, xo, eo, nl: (i, 0, 0)),
-        pl.BlockSpec((None, d, th),
-                     lambda i, j, xo, eo, nl: (eo[i], 0,
-                                               slice_of(i, j, nl))),
-        pl.BlockSpec((None, d, th),
-                     lambda i, j, xo, eo, nl: (eo[i], 0,
-                                               slice_of(i, j, nl))),
-        pl.BlockSpec((None, th, d),
-                     lambda i, j, xo, eo, nl: (eo[i],
-                                               slice_of(i, j, nl), 0)),
-    ]
+    ] + ([into_hidden] * 2 if len(weights) == 3 else [from_hidden]) + [
+        from_hidden]
     call = pl.pallas_call(
-        _tile_kernel,
+        _tile_kernel if len(weights) == 3 else _tile_kernel_relu2,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n_tiles, n_h),
@@ -130,43 +163,56 @@ def _expert_tiles_kernel(x_tiles, x_of, gates, expert_of, n_live, w_gate,
     )
     with jax.named_scope(KERNEL_SCOPE):
         return call(x_of, expert_of, n_live.reshape(1), x_tiles, gates,
-                    w_gate, w_up, w_down)
+                    *weights)
 
 
-def expert_tiles_reference(x_tiles, x_of, gates, expert_of, n_live, w_gate,
-                           w_up, w_down):
+def _activation(x, weights, dot):
+    """An expert's hidden activation, float32: ReGLU of (W_gate, W_up)
+    [d, hidden], or relu^2 of (W_up,) [hidden, d]."""
+    if len(weights) == 2:
+        return jnp.maximum(dot(x, weights[0]), 0.0) * dot(x, weights[1])
+    return jnp.square(jnp.maximum(dot(x, weights[0].T), 0.0))
+
+
+def expert_tiles_reference(x_tiles, x_of, gates, expert_of, n_live,
+                           *weights):
     """The same tiles in plain jax.numpy, one at a time."""
     dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
 
     def tile(i):
         def live():
             x, e = x_tiles[x_of[i]], expert_of[i]
-            act = jnp.maximum(dot(x, w_gate[e]), 0.0) * dot(x, w_up[e])
-            return dot(act.astype(x.dtype), w_down[e]) * gates[i]
+            act = _activation(x, [w[e] for w in weights[:-1]], dot)
+            return dot(act.astype(x.dtype), weights[-1][e]) * gates[i]
 
         return jax.lax.cond(
             i < n_live, live,
-            lambda: jnp.zeros(gates.shape[1:2] + w_gate.shape[1:2],
+            lambda: jnp.zeros(gates.shape[1:2] + weights[-1].shape[2:],
                               jnp.float32))
 
     return jax.lax.map(tile, jnp.arange(gates.shape[0]))
 
 
-def expert_tiles(x_tiles, x_of, gates, expert_of, n_live, w_gate, w_up,
-                 w_down, use_kernel=None):
-    """float32 [n_tiles, tm, d]: tile i's gated ReGLU product, zeros
-    from tile `n_live` on.
+def expert_tiles(x_tiles, x_of, gates, expert_of, n_live, *weights,
+                 use_kernel=None):
+    """float32 [n_tiles, tm, d]: tile i's weighted expert product,
+    zeros from tile `n_live` on.
 
     x_tiles [n_x, tm, d]; x_of, expert_of [n_tiles] int32; gates
-    [n_tiles, tm, 1] float32; n_live int32 scalar; w_gate, w_up
-    [E, d, hidden], w_down [E, hidden, d] in x_tiles' dtype.
-    `use_kernel=None` takes the Mosaic kernel where kernels are on and
-    the shapes are whole tiles (`kernel_supported`)."""
+    [n_tiles, tm, 1] float32; n_live int32 scalar; `weights` in
+    x_tiles' dtype, three for ReGLU experts (w_gate, w_up [E, d,
+    hidden], w_down [E, hidden, d]) or two for relu^2 experts (w_up,
+    w_down, BOTH [E, hidden, d]). `use_kernel=None` takes the Mosaic
+    kernel where kernels are on and the shapes are whole tiles
+    (`kernel_supported`)."""
     tm, d = x_tiles.shape[1:]
+    if len(weights) not in (2, 3):
+        raise ValueError("an expert has two matrices (relu^2) or three "
+                         "(ReGLU), not %d" % len(weights))
     if use_kernel is None:
         use_kernel = use_pallas() and kernel_supported(
-            tm, d, w_gate.shape[2], x_tiles.dtype)
+            tm, d, weights[-1].shape[1], x_tiles.dtype)
     fn = _expert_tiles_kernel if use_kernel else expert_tiles_reference
     return fn(x_tiles, jnp.asarray(x_of, jnp.int32), gates,
               jnp.asarray(expert_of, jnp.int32),
-              jnp.asarray(n_live, jnp.int32), w_gate, w_up, w_down)
+              jnp.asarray(n_live, jnp.int32), *weights)

@@ -392,10 +392,12 @@ def moe_reference(params, x, capacity_factor=1.25,
 
 # ---------------------------------------------- drop-free gated experts
 #
-# The serving-path expert layer: top-k of E by router logit, weights a
-# softmax over the k chosen logits, gated (three-matrix, ReGLU) experts,
-# NO capacity and NO drop, and told which experts it holds. Built on
-# ops/expert_ffn.expert_tiles; this half decides which tiles there are.
+# The serving-path expert layer: top-k of E by router score (a softmax
+# over the k chosen logits, or sigmoid scores chosen with a selection
+# bias and renormalised), experts of three matrices (gated, ReGLU) or
+# two (relu^2), NO capacity and NO drop, and told which experts it
+# holds. Built on ops/expert_ffn.expert_tiles; this half decides which
+# tiles there are.
 
 #: up to this many rows the layer reads each HIT expert once for all
 #: rows (a decode tick: the experts' weights are the cost, so the rows
@@ -415,6 +417,19 @@ def route_top_k(logits, k):
     return jax.nn.softmax(top_v, axis=-1), top_i.astype(jnp.int32)
 
 
+def route_sigmoid_top_k(logits, k, bias, scale=1.0):
+    """(gates [T, k] float32, experts [T, k] int32) of a sigmoid
+    router: scores s = sigmoid(logits [T, E]); the k experts with the
+    largest s + bias (`bias` [E] only selects); weights
+    scale * s / (the chosen scores' sum + 1e-20)."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, top_i, axis=-1)
+    gates = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
+                              + 1e-20)
+    return gates, top_i.astype(jnp.int32)
+
+
 def _held_choices(experts, first, count):
     """(local [T, k], held [T, k]): each choice's index among the
     `count` experts held here from `first` on, and whether it is one."""
@@ -422,11 +437,11 @@ def _held_choices(experts, first, count):
     return local, (local >= 0) & (local < count)
 
 
-def _hit_tiles(h, gates, experts, first, w_gate, w_up, w_down,
-               use_kernel):
+def _hit_tiles(h, gates, experts, first, *weights_and_use_kernel):
     """Few rows: one tile per HIT expert over all the rows."""
+    *weights, use_kernel = weights_and_use_kernel
     t = h.shape[0]
-    count = w_gate.shape[0]
+    count = weights[0].shape[0]
     local, held = _held_choices(experts, first, count)
     onehot = (local[..., None] == jnp.arange(count)) & held[..., None]
     gate_te = jnp.sum(jnp.where(onehot, gates[..., None], 0.0), axis=1)
@@ -440,18 +455,18 @@ def _hit_tiles(h, gates, experts, first, w_gate, w_up, w_down,
     x = jnp.pad(h, ((0, tm - t), (0, 0)))[None]
     tile_gates = jnp.pad(gate_te.T[order], ((0, 0), (0, tm - t)))
     y = expert_tiles(x, jnp.zeros((count,), jnp.int32),
-                     tile_gates[..., None], order, n_live, w_gate, w_up,
-                     w_down, use_kernel=use_kernel)
+                     tile_gates[..., None], order, n_live, *weights,
+                     use_kernel=use_kernel)
     return jnp.sum(y, axis=0)[:t], held, hit
 
 
-def _grouped_tiles(h, gates, experts, first, w_gate, w_up, w_down,
-                   use_kernel):
+def _grouped_tiles(h, gates, experts, first, *weights_and_use_kernel):
     """Many rows: the (row, choice) pairs sorted by expert, each held
     expert's run padded to whole tiles; gathers only, no scatter."""
+    *weights, use_kernel = weights_and_use_kernel
     t, d = h.shape
     k = experts.shape[1]
-    count = w_gate.shape[0]
+    count = weights[0].shape[0]
     tm = PREFILL_TILE_ROWS
     n_tiles = -(-t * k // tm) + count
     local, held = _held_choices(experts, first, count)
@@ -480,8 +495,7 @@ def _grouped_tiles(h, gates, experts, first, w_gate, w_up, w_down,
     x_tiles = h[pair // k]  # [n_tiles, tm, d]
     tile_gates = jnp.where(live_row, gates.reshape(-1)[pair], 0.0)
     y = expert_tiles(x_tiles, jnp.arange(n_tiles), tile_gates[..., None],
-                     expert_of, n_live, w_gate, w_up, w_down,
-                     use_kernel=use_kernel)
+                     expert_of, n_live, *weights, use_kernel=use_kernel)
     # each pair's row of the tiles; a pair held elsewhere reads none
     e_pair = jnp.minimum(key, count - 1)
     dest = tile_start[e_pair] * tm + rank - start[e_pair]
@@ -491,11 +505,9 @@ def _grouped_tiles(h, gates, experts, first, w_gate, w_up, w_down,
     return jnp.sum(rows.reshape(t, k, d), axis=1), held, hit
 
 
-def _held_experts(first, use_kernel, h, gates, experts, w_gate, w_up,
-                  w_down):
+def _held_experts(first, use_kernel, h, gates, experts, *weights):
     path = _hit_tiles if h.shape[0] <= DECODE_ROWS else _grouped_tiles
-    y, held, hit = path(h, gates, experts, first, w_gate, w_up, w_down,
-                        use_kernel)
+    y, held, hit = path(h, gates, experts, first, *weights, use_kernel)
     return (y, jnp.sum(held, axis=1).astype(jnp.int32),
             hit.astype(jnp.int32))
 
@@ -525,18 +537,21 @@ def _lanes_as_one_call(first, use_kernel):
     return call
 
 
-def held_experts_reglu(h, gates, experts, w_gate, w_up, w_down, first=0,
-                       use_kernel=None):
-    """This chip's part of a drop-free gated expert layer.
+def held_experts(h, gates, experts, weights, first=0, use_kernel=None):
+    """This chip's part of a drop-free expert layer.
 
     h [T, D] (the compute dtype), gates [T, k] float32 and experts
-    [T, k] (route_top_k over ALL the layer's experts), and the weights
-    of the `count = w_gate.shape[0]` experts held here, experts
-    `first .. first + count` of the layer: w_gate, w_up [count, D, H],
-    w_down [count, H, D] in h's dtype. Returns
+    [T, k] (a router's choice over ALL the layer's experts), and the
+    `weights` of the `count = weights[0].shape[0]` experts held here,
+    experts `first .. first + count` of the layer, in h's dtype: three
+    matrices an expert for gated (ReGLU) experts, (w_gate, w_up
+    [count, D, H], w_down [count, H, D]), or two for relu^2 experts,
+    (w_up, w_down), both [count, H, D], a hidden unit a row
+    (ops/expert_ffn.py says why). Returns
 
         y [T, D] float32     sum over a row's HELD choices of
                              gate * ((relu(h W_gate) * (h W_up)) W_down)
+                             or gate * (relu(h W_up^T)^2 W_down)
         held [T] int32       how many of a row's k choices are held
         hit [count] int32    1 for each held expert some row chose
 
@@ -549,4 +564,11 @@ def held_experts_reglu(h, gates, experts, w_gate, w_up, w_down, first=0,
     laid side by side and computed as ONE call, so a tick reads a hit
     expert once and not once a lane; `hit` is then the tick's."""
     return _lanes_as_one_call(int(first), use_kernel)(
-        h, gates, experts, w_gate, w_up, w_down)
+        h, gates, experts, *weights)
+
+
+def held_experts_reglu(h, gates, experts, w_gate, w_up, w_down, first=0,
+                       use_kernel=None):
+    """`held_experts` of gated (ReGLU) experts."""
+    return held_experts(h, gates, experts, (w_gate, w_up, w_down),
+                        first=first, use_kernel=use_kernel)

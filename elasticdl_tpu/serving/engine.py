@@ -95,12 +95,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from elasticdl_tpu.api.generation import (
+    STATE,
     _decode_cache,
     _kv_shapes_for,
     _maybe_dequantize,
     _prefill_bucket,
     _require_kv_convention,
     _run_prefill,
+    cache_leaf_kinds,
     serving_next_token,
 )
 from elasticdl_tpu.api.quantization import (
@@ -218,6 +220,21 @@ def lane_fields(lanes):
     )
 
 
+def _at_path(tree, path):
+    """The leaf of a tree of nested dicts at a key path."""
+    for key in path:
+        tree = tree[key.key]
+    return tree
+
+
+def _set_path(tree, path, leaf):
+    """Set the leaf at a key path of a tree of nested dicts, in place,
+    making the dicts on the way."""
+    for key in path[:-1]:
+        tree = tree.setdefault(key.key, {})
+    tree[path[-1].key] = leaf
+
+
 def _trace_id(request):
     """The request's trace id for a phase that serves it ("" for the
     bare requests of tests and benches)."""
@@ -329,6 +346,23 @@ class PagedContinuousBatchingEngine(object):
     or buys proportionally more blocks at equal bytes — sharing, CoW
     and speculative decode compose unchanged (the trie is keyed on
     token ids, dtype-blind).
+
+    PER-SLOT STATE (a model with state-space layers, declared by
+    `model.cache_leaf_kind`): beside the row arenas of its attention
+    layers the pool keeps a `[num_slots, ...]` arena of each state
+    leaf, slot i for lane i (kv_pool.py, LEAVES BY KIND). One prefill
+    computes the prompt's rows and the state AT the prompt's true
+    length; seating writes the blocks and, in one more launch, the
+    state of every state layer; the step maps the state arenas over
+    the lanes beside the lane state, so lane i reads and writes slot i
+    in place (donated like the row arenas); a free lane is updated
+    like a seated one and nothing reads what it holds; eviction costs
+    no device work, the next seating overwrites the slot. Blocks are
+    charged for the attention layers' rows alone. What would need a
+    SNAPSHOT of the state at some earlier position refuses to start
+    with such a model, by name: prefix sharing, the host tier, a draft
+    (speculative rollback), chunked prefill tiles, and the disagg
+    chain export / a prefill-only role.
     """
 
     def __init__(self, trainer, state, num_slots, top_k=0, top_p=1.0,
@@ -371,7 +405,7 @@ class PagedContinuousBatchingEngine(object):
                    else (getattr(model, "attn_window", 0),))
         self._window_kinds = sorted(
             (int(w), windows.count(w)) for w in set(windows))
-        self._window_layers = len(windows)
+        self._window_layers = max(1, len(windows))
         self.top_k = int(top_k)
         self.top_p = float(top_p)
         self.block_size = int(block_size)
@@ -417,11 +451,23 @@ class PagedContinuousBatchingEngine(object):
         # batch-1 cache template (shares the trainer's compile cache
         # so offline callers reuse the shapes) -> the block arenas
         self._kv_shapes = _kv_shapes_for(_decode_cache(trainer), model, 1)
+        kinds = cache_leaf_kinds(model, self._kv_shapes, self.seq_len)
+        # the layers that keep a per-sequence state (a model may name
+        # them by several leaves each: the recurrence's and the
+        # convolution's)
+        self._state_paths = [
+            path for (path, _), kind in zip(
+                jax.tree_util.tree_flatten_with_path(self._kv_shapes)[0],
+                jax.tree.leaves(kinds)) if kind == STATE]
+        self._state_layers = len({path[0].key
+                                  for path in self._state_paths})
+        if self._state_layers:
+            self._refuse_what_needs_a_state_snapshot(draft, draft_k)
         self.kv = PagedKVPool(
             self._kv_shapes, self.seq_len, self.num_slots,
             self.num_blocks, self.block_size,
             share_prefix=bool(share_prefix),
-            host_bytes=self.host_bytes,
+            host_bytes=self.host_bytes, kinds=kinds,
         )
         # optional recompile sentry (runtime_health.RecompileSentry;
         # the server attaches it under ServingConfig.runtime_health).
@@ -479,6 +525,26 @@ class PagedContinuousBatchingEngine(object):
                                     self._exec_variables, d_variables),
                 d_variables,
             )
+
+    def _refuse_what_needs_a_state_snapshot(self, draft, draft_k):
+        """A model with state layers (the pool refuses prefix sharing
+        and the host tier itself): no draft, whose rejected proposals
+        roll the target back to a position whose state nobody kept,
+        and no chunked prefill, whose tiles are decode tiles of
+        several tokens."""
+        why = ("this model keeps a per-sequence state (a state-space "
+               "layer) beside its KV rows, and %s would need the state "
+               "of an earlier position kept, which nothing keeps yet. "
+               "Start the server without it (%s)")
+        if draft is not None and int(draft_k) >= 1:
+            raise ValueError(why % (
+                "speculative decode (draft, draft_k)",
+                "--draft_k 0, no --draft_model_def"))
+        if self.prefill_chunk_tokens:
+            raise ValueError(why % (
+                "chunked prefill (prefill_chunk_tokens)",
+                "--prefill_chunk_tokens 0 / EDL_PREFILL_CHUNK_TOKENS "
+                "unset"))
 
     def _init_draft(self, draft, draft_k):
         """Seat the draft model for speculative decode: its own dense
@@ -728,6 +794,12 @@ class PagedContinuousBatchingEngine(object):
                 % (total, self.seq_len)
             )
         prefill_only = getattr(request, "prefill_only", False)
+        if prefill_only and self._state_layers:
+            raise ValueError(
+                "a prefill-only seat (the disagg handoff) parks a "
+                "prompt's KV blocks for a sibling to import, and this "
+                "model keeps a per-sequence state beside them that no "
+                "chain carries yet")
         decoding = request.max_new_tokens > 1 or prefill_only
         shared = 0
         if decoding:
@@ -1117,6 +1189,12 @@ class PagedContinuousBatchingEngine(object):
             # telemetry mirror current even on decode-only ticks
             self._sync_host_telemetry()
             self._count_paged_stream()
+            if self._state_layers:
+                # every lane's state is updated, a free lane's too
+                tracing.count("ssm.lanes",
+                              self.num_slots * self._state_layers)
+                tracing.count("ssm.lanes_live",
+                              len(active) * self._state_layers)
         if self._step_fn is None:
             self._step_fn = self._build_paged_step()
         with self.trainer.mesh:
@@ -1236,12 +1314,14 @@ class PagedContinuousBatchingEngine(object):
         programs = [
             (self._prefill_program(_prefill_bucket(1, self.seq_len)),
              (variables, prompt, i32, i32, f32), (0, None)),
-            (self._suffix_prefill_program(tile),
-             (self.kv.pools, variables,
-              spec(self.kv.tables.shape[1:], jnp.int32),
-              spec((1, tile), jnp.int32), i32, i32, i32, f32),
-             (1, None)),
         ]
+        if not self._state_layers:  # else no decode tile ever runs
+            programs.append(
+                (self._suffix_prefill_program(tile),
+                 (self.kv.pools, variables,
+                  spec(self.kv.tables.shape[1:], jnp.int32),
+                  spec((1, tile), jnp.int32), i32, i32, i32, f32),
+                 (1, None)))
         if d_variables is None:
             return programs + [
                 (self._paged_step_program(),
@@ -1296,13 +1376,21 @@ class PagedContinuousBatchingEngine(object):
         top_k, top_p, qz = self.top_k, self.top_p, self._exec_qz
         block_size, num_blocks = self.block_size, self.num_blocks
         tick_counters = self._tick_counters  # named when traced
+        state_paths = self._state_paths
+        state_at = [i for i, kind in enumerate(self.kv.kinds)
+                    if kind == STATE]
 
         def step(pools, variables, lanes):
             variables = _maybe_dequantize(variables, qz)
             tables, positions, last_tokens, seeds, temps = lane_fields(
                 lanes)
+            # the per-slot state arenas ride the lanes' axis: lane i
+            # reads and writes slot i (none for a model without state
+            # layers)
+            flat, treedef = jax.tree.flatten(pools)
+            states = [flat[i] for i in state_at]
 
-            def one(table, pos, tok, seed, temp):
+            def one(table, pos, tok, seed, temp, state):
                 # pre-advance counter: this token's k/v rows belong
                 # at `pos`, the sampled token lands at pos + 1 (the
                 # offline loop's `_next_token(..., i + 1)`). The cache
@@ -1311,9 +1399,12 @@ class PagedContinuousBatchingEngine(object):
                 # arenas, read through this slot's table and written
                 # back via the sown "kv_out" rows. The scope is the
                 # per-slot body's op_name in a device trace.
+                cache = {"pos": pos}
+                for path, leaf in zip(state_paths, state):
+                    _set_path(cache, path, leaf[None])  # a batch of one
                 with jax.named_scope("paged_slot"):
                     logits, aux = model.apply(
-                        dict(variables, cache={"pos": pos}),
+                        dict(variables, cache=cache),
                         {"tokens": tok[None, None]},
                         training=False, decode=True,
                         mutable=["cache", "kv_out", "counters"],
@@ -1323,14 +1414,19 @@ class PagedContinuousBatchingEngine(object):
                     logits[0, 0], seed, pos + 1, temp, top_k, top_p
                 )
                 rows = jax.tree.map(
-                    lambda t: t[0][0, :, 0, :], aux["kv_out"],
+                    lambda t: t[0][0, :, 0, :], aux.get("kv_out", {}),
                     is_leaf=lambda x: isinstance(x, tuple),
                 )  # sown [1, hkv, 1, d] -> [hkv, d]
-                return nxt, rows, aux.get("counters", {})
+                state = [_at_path(aux["cache"], path)[0]
+                         for path in state_paths]
+                return nxt, rows, aux.get("counters", {}), state
 
-            nxt, rows, sown = jax.vmap(one)(
-                tables, positions, last_tokens, seeds, temps
+            nxt, rows, sown, states = jax.vmap(one)(
+                tables, positions, last_tokens, seeds, temps, states
             )
+            for i, leaf in zip(state_at, states):
+                flat[i] = leaf
+            pools = jax.tree.unflatten(treedef, flat)
             # what the model's layers counted this tick rides home
             # behind the tokens, in the one array the host fetches
             counted = _tick_counts(sown)

@@ -120,6 +120,31 @@ obliges the callers to:
   consumed (one leaf's `is_deleted()`, a host-side flag): their ratio
   is the share of pool updates that ran in place.
 
+LEAVES BY KIND: the pool's tree mirrors the model's batch-1 decode
+cache, and each leaf is of a DECLARED kind
+(api/generation.cache_leaf_kinds; `PagedKVPool.kinds`), never told by
+its rank:
+
+* ROWS, `[1, hkv, cache_len, d]` in the template: a cached token a row,
+  paged into a `[num_blocks, block_size, hkv, d]` arena by block table.
+  Everything above (blocks, refcounts, the trie, the host tier, copy on
+  write) is about these leaves alone, and `bytes_total`, `block_bytes`
+  and admission count them alone: a sequence is charged blocks for its
+  attention layers' rows and for nothing else;
+* STATE, `[1, ...]`: what a state-space layer carries from token to
+  token, the same size whatever the length. The pool keeps a
+  `[num_slots, ...]` arena of it, slot i for lane i: seating writes the
+  prefilled state of EVERY state leaf into the slot in ONE launch
+  (`write_state`, donated like every other update), the decode step
+  reads and writes lane i's state at slot i in place, and releasing a
+  slot costs no device work, because the next seating overwrites the
+  whole of it. A state cannot be shared by prefix, spilled or shipped
+  as a chain (the state after a prompt's first blocks is not kept), so
+  a pool with state leaves refuses prefix sharing, the host tier and
+  chain export;
+* SCALAR, the position counter: a zero-d placeholder that keeps the
+  tree's structure.
+
 Block ids enter the compiled decode step as DEVICE arrays (the tables),
 so slot churn and sequence growth never recompile anything. The tables
 the step reads STAY on the device, carried from tick to tick in the
@@ -136,7 +161,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from elasticdl_tpu.api.generation import kv_row_leaf
+from elasticdl_tpu.api.generation import (
+    ROWS,
+    STATE,
+    cache_leaf_kinds,
+)
 from elasticdl_tpu.observability import tracing
 
 
@@ -164,21 +193,26 @@ def run_inplace(program, pools, *args, **kwargs):
     tree was consumed. A program that raises with the tree consumed
     raises KVPoolLost (from its error); one that raises with the tree
     intact re-raises as it is."""
-    # one leaf stands for the tree, the largest: jit hands a leaf the
-    # program returns untouched (the zero-d position placeholder)
-    # straight back, never donated
-    probe = max(jax.tree.leaves(pools), key=lambda leaf: leaf.size)
+    # jit hands a leaf the program returns untouched (the zero-d
+    # position placeholder; the state arenas in a program that writes
+    # rows, and the other way round) straight back, never donated: the
+    # tree was consumed if ANY of its leaves was
+    leaves = jax.tree.leaves(pools)
+
+    def consumed():  # flags on the host: no sync
+        return any(leaf.is_deleted() for leaf in leaves)
+
     try:
         out = program(pools, *args, **kwargs)
     except Exception as e:
-        if probe.is_deleted():
+        if consumed():
             raise KVPoolLost(
                 "a pool-updating program raised after consuming the "
                 "KV pool: %r" % (e,)
             ) from e
         raise
     tracing.count("pool.launches")
-    if probe.is_deleted():  # a flag on the host: no sync
+    if consumed():
         tracing.count("pool.inplace_launches")
     return out
 
@@ -210,13 +244,23 @@ def pool_aliasing(compiled, *pool_trees):
     arenas = {
         "%s[%s]" % (_HLO_DTYPE[np.dtype(leaf.dtype).name],
                     ",".join(str(n) for n in leaf.shape))
-        for leaf in leaves if len(leaf.shape) == 4
+        for leaf in leaves if len(leaf.shape)
     }
     copies = 0
     for line in compiled.as_text().splitlines():
-        m = re.match(r"\s*(?:ROOT )?\S+ = (.*?) copy(?:-start)?\(", line)
-        if m and any(a in m.group(1) for a in arenas):
-            copies += 1
+        m = re.match(r"\s*(?:ROOT )?\S+ = (.*?) copy(-start)?\(", line)
+        if not m or not any(a in m.group(1) for a in arenas):
+            continue
+        if m.group(2):
+            # an asynchronous copy names (destination, source): one
+            # that crosses memory spaces (`S(n)` in one layout only) is
+            # the compiler moving a small arena through on-chip memory
+            # around the loop that reads it, not a second arena
+            spaces = ["S(" in layout for layout in
+                      re.findall(r"\]\{([^}]*)\}", m.group(1))[:2]]
+            if len(spaces) == 2 and spaces[0] != spaces[1]:
+                continue
+        copies += 1
     return {
         "pool_bytes": sum(_leaf_bytes(leaf) for leaf in leaves),
         "alias_bytes": int(
@@ -797,34 +841,55 @@ class BlockAllocator(object):
         return len(table)
 
 
-def build_pools(kv_shapes, cache_len, num_blocks, block_size):
+def build_pools(kv_shapes, cache_len, num_blocks, block_size, kinds=None,
+                num_slots=0):
     """Device arenas from the model's batch-1 decode-cache template
-    (api/generation._kv_shapes_for): every KV row leaf
+    (api/generation._kv_shapes_for) and its leaves' declared `kinds` (a
+    tree alongside; None = the `kv_row_leaf` convention): a ROWS leaf
     `[1, hkv, cache_len, d]` becomes `[num_blocks, block_size, hkv, d]`
-    zeros; non-row leaves (the position counter) stay as zero-d
-    placeholders so the pool tree keeps the cache tree's structure —
-    the model slices its own layer's arenas out of it by name."""
-    def arena(leaf):
-        if kv_row_leaf(leaf, cache_len):
+    zeros, a STATE leaf `[1, ...]` becomes `[num_slots, ...]` zeros, and
+    the position counter stays a zero-d placeholder, so the pool tree
+    keeps the cache tree's structure: the model slices its own layer's
+    arenas out of it by name."""
+    if kinds is None:
+        kinds = cache_leaf_kinds(None, kv_shapes, cache_len)
+
+    def arena(leaf, kind):
+        if kind == ROWS:
             _, hkv, _, d = leaf.shape
             return jnp.zeros((num_blocks, block_size, hkv, d),
                              leaf.dtype)
+        if kind == STATE:
+            return jnp.zeros((num_slots,) + leaf.shape[1:], leaf.dtype)
         return jnp.zeros(leaf.shape, leaf.dtype)
 
-    return jax.tree.map(arena, kv_shapes)
+    return jax.tree.map(arena, kv_shapes, kinds)
 
 
-def write_prompt_block(pools, kv, j, bid, block_size):
+def _map_kind(fn, which, kinds, pools, *trees):
+    """`pools` with `fn(pool leaf, *the other trees' leaves)` in place
+    of each leaf of kind `which`; `kinds` along jax.tree.leaves(pools)."""
+    flat, treedef = jax.tree.flatten(pools)
+    others = [treedef.flatten_up_to(tree) for tree in trees]
+    if len(kinds) != len(flat):
+        raise ValueError("%d kinds for a pool of %d leaves"
+                         % (len(kinds), len(flat)))
+    return jax.tree.unflatten(treedef, [
+        fn(pool, *rest) if kind == which else pool
+        for pool, kind, *rest in zip(flat, kinds, *others)])
+
+
+def write_prompt_block(pools, kv, j, bid, block_size, kinds):
     """Insert block `j` of a freshly prefilled batch-1 cache tree into
-    the arenas at block id `bid` — ONE `dynamic_update_slice` per row
-    leaf at a TRACED (j, bid), so one compiled write serves every
-    (prompt bucket, block, slot) combination. Rows past the true
-    prompt length inside the last block are prefill junk; the paged
-    attention masks `k_pos < length` so they are never read before the
-    decode scatter overwrites them."""
+    the arenas at block id `bid` — ONE `dynamic_update_slice` per ROWS
+    leaf (`kinds`, static, along the pool's leaves) at a TRACED (j,
+    bid), so one compiled write serves every (prompt bucket, block,
+    slot) combination. Rows past the true prompt length inside the
+    last block are prefill junk; the paged attention masks `k_pos <
+    length` so they are never read before the decode scatter
+    overwrites them. A leaf of another kind is handed back as it is,
+    whatever its rank."""
     def upd(pool, leaf):
-        if leaf.ndim != 4:  # the position counter placeholder
-            return pool
         rows = jax.lax.dynamic_slice_in_dim(
             leaf[0], j * block_size, block_size, axis=1
         )  # [hkv, block_size, d]
@@ -833,23 +898,34 @@ def write_prompt_block(pools, kv, j, bid, block_size):
             pool, rows[None], (bid, 0, 0, 0)
         )
 
-    return jax.tree.map(upd, pools, kv)
+    return _map_kind(upd, ROWS, kinds, pools, kv)
 
 
-def copy_block(pools, src, dst):
+def write_state(pools, kv, slot, kinds):
+    """Seat a freshly prefilled batch-1 cache tree's STATE leaves in
+    slot `slot` of their arenas: every state leaf of every layer in
+    this ONE program, a `dynamic_update_slice` each at a traced slot.
+    The whole of the slot's state is overwritten, so nothing of the
+    sequence that sat there before is left to reset."""
+    def upd(pool, leaf):
+        return jax.lax.dynamic_update_slice(
+            pool, leaf.astype(pool.dtype), (slot,) + (0,) * (pool.ndim - 1))
+
+    return _map_kind(upd, STATE, kinds, pools, kv)
+
+
+def copy_block(pools, src, dst, kinds):
     """Device-side CoW: duplicate arena block `src` into `dst` on
-    every row leaf (one gather + dynamic_update_slice per leaf, traced
+    every ROWS leaf (one gather + dynamic_update_slice per leaf, traced
     indices — one compiled copy serves every fault)."""
     def upd(pool):
-        if pool.ndim != 4:
-            return pool
         return jax.lax.dynamic_update_slice(
             pool,
             jax.lax.dynamic_slice_in_dim(pool, src, 1, axis=0),
             (dst, 0, 0, 0),
         )
 
-    return jax.tree.map(upd, pools)
+    return _map_kind(upd, ROWS, kinds, pools)
 
 
 def scatter_rows(pools, rows, bids, offs):
@@ -919,7 +995,8 @@ class PagedKVPool(object):
     `pool.pools` in a variable across such a call."""
 
     def __init__(self, kv_shapes, cache_len, num_slots, num_blocks,
-                 block_size, share_prefix=False, host_bytes=0):
+                 block_size, share_prefix=False, host_bytes=0,
+                 kinds=None):
         cache_len = int(cache_len)
         block_size = int(block_size)
         if cache_len % block_size:
@@ -933,8 +1010,25 @@ class PagedKVPool(object):
         self.max_blocks_per_slot = cache_len // block_size
         self.allocator = BlockAllocator(num_blocks, block_size,
                                         share_prefix=share_prefix)
+        # each leaf's declared kind (module docstring, LEAVES BY KIND),
+        # as a tree alongside the template's and, for the programs that
+        # take the pool, static, along jax.tree.leaves(self.pools)
+        if kinds is None:
+            kinds = cache_leaf_kinds(None, kv_shapes, cache_len)
+        self.kinds = tuple(jax.tree.leaves(kinds))
+        self.has_state = STATE in self.kinds
+        if self.has_state and (share_prefix or int(host_bytes) > 0):
+            raise ValueError(
+                "this model keeps a per-sequence state (a state-space "
+                "layer) beside its KV rows, and %s cannot carry one "
+                "yet: the state after a prompt's first blocks is not "
+                "kept. Start the server without it (%s)" % (
+                    "prefix sharing (share_prefix)" if share_prefix
+                    else "the host spill tier (host_bytes)",
+                    "--kv_shared 0 / EDL_KV_SHARED=0" if share_prefix
+                    else "--kv_host_bytes 0 / EDL_KV_HOST_BYTES unset"))
         self.pools = build_pools(kv_shapes, cache_len, num_blocks,
-                                 block_size)
+                                 block_size, kinds, int(num_slots))
         self.tables = np.full(
             (int(num_slots), self.max_blocks_per_slot), -1, np.int32
         )
@@ -945,12 +1039,13 @@ class PagedKVPool(object):
         # arenas count their int8 rows AND f32 scale leaves exactly —
         # never a homogeneous row-dtype assumption. This is what
         # kv_bytes_in_use / bytes-per-generated-token report.
-        row_leaves = [
-            leaf for leaf in jax.tree.leaves(self.pools)
-            if leaf.ndim == 4
-        ]
+        row_leaves = self._of_kind(ROWS)
         self.bytes_total = sum(_leaf_bytes(leaf) for leaf in row_leaves)
         self.block_bytes = self.bytes_total // max(1, self.num_blocks)
+        # the per-slot state arenas beside them: fixed, whatever is
+        # seated, and no part of a block
+        self.state_bytes = sum(_leaf_bytes(leaf)
+                               for leaf in self._of_kind(STATE))
         # one block's row shape and the dtype of every row leaf, in
         # jax.tree.leaves order, recorded here so that no reader needs
         # a buffer for them (a handler thread may ask mid-update)
@@ -964,6 +1059,7 @@ class PagedKVPool(object):
             else ""
         )
         self._write_fn = None
+        self._state_fn = None
         self._copy_fn = None
         # ---- tiered host spill (serving the ROADMAP "Tiered KV
         # cache" item): the budget is BYTES, the allocator accounts in
@@ -976,7 +1072,7 @@ class PagedKVPool(object):
         self.allocator.host_blocks = int(host_blocks)
         self.allocator._spill_sink = self._spill_block
         self.allocator._drop_sink = self._drop_host_block
-        self._host_rows = {}   # vid -> [np rows per 4-d leaf, in order]
+        self._host_rows = {}   # vid -> [np rows per ROWS leaf, in order]
         self.host_blocks_peak = 0
         self.revive_uploads = 0  # monotone: batched revival scatters
         # disaggregated handoff economy (serving/disagg.py): chains
@@ -992,6 +1088,16 @@ class PagedKVPool(object):
         # upload buckets, prompt write, CoW copy) count into the same
         # edl_serving_recompiles_total{fn=} family. None = plain jit.
         self.sentry = None
+
+    def _of_kind(self, kind, pools=None):
+        """The pool's leaves of one kind, in jax.tree.leaves order."""
+        leaves = jax.tree.leaves(self.pools if pools is None else pools)
+        return [leaf for leaf, k in zip(leaves, self.kinds) if k == kind]
+
+    def row_arenas(self):
+        """The ROWS arenas, in jax.tree.leaves order. Scheduler thread,
+        between updates (module docstring)."""
+        return self._of_kind(ROWS, self._live_pools())
 
     # ----------------------------------------------------- in-place update
 
@@ -1045,7 +1151,7 @@ class PagedKVPool(object):
     # ------------------------------------------------- host spill tier
 
     def _gather_rows(self, bid):
-        """One block's rows as host numpy arrays — every 4-d arena
+        """One block's rows as host numpy arrays — every ROWS arena
         leaf (int8 rows and f32 scale leaves alike) through ONE
         compiled gather with a traced bid. The spill sink and the
         chain export both read through here, so an exported chain is
@@ -1056,8 +1162,7 @@ class PagedKVPool(object):
         them."""
         if self._gather_fn is None:
             def gather(pools, b):
-                return [leaf[b] for leaf in jax.tree.leaves(pools)
-                        if leaf.ndim == 4]
+                return [leaf[b] for leaf in self._of_kind(ROWS, pools)]
 
             self._gather_fn = _pool_tjit(
                 self, "kv_spill_gather", gather
@@ -1119,7 +1224,8 @@ class PagedKVPool(object):
                 # scatter over the block axis makes the chip's
                 # compiler re-lay the whole arena out, there and back
                 flat, treedef = jax.tree_util.tree_flatten(pools)
-                at = [i for i, leaf in enumerate(flat) if leaf.ndim == 4]
+                at = [i for i, kind in enumerate(self.kinds)
+                      if kind == ROWS]
 
                 def one(i, flat):
                     flat = list(flat)
@@ -1144,14 +1250,24 @@ class PagedKVPool(object):
         if self._write_fn is None:
             self._write_fn = _pool_tjit(
                 self, "kv_prompt_write", write_prompt_block,
-                static_argnames=("block_size",), donate_argnums=(0,),
+                static_argnames=("block_size", "kinds"),
+                donate_argnums=(0,),
             )
         return self._write_fn
+
+    def _state_program(self):
+        if self._state_fn is None:
+            self._state_fn = _pool_tjit(
+                self, "kv_state_write", write_state,
+                static_argnames=("kinds",), donate_argnums=(0,),
+            )
+        return self._state_fn
 
     def _copy_program(self):
         if self._copy_fn is None:
             self._copy_fn = _pool_tjit(
-                self, "kv_cow_copy", copy_block, donate_argnums=(0,),
+                self, "kv_cow_copy", copy_block,
+                static_argnames=("kinds",), donate_argnums=(0,),
             )
         return self._copy_fn
 
@@ -1182,6 +1298,11 @@ class PagedKVPool(object):
         store (copied, not consumed). Runs on the scheduler thread, so
         nothing can evict a chain entry mid-gather. Empty list = no
         full prompt block is indexed (nothing to hand off)."""
+        if self.has_state:
+            raise ValueError(
+                "chain export (the disagg handoff) ships a prompt's KV "
+                "blocks, and this model keeps a per-sequence state "
+                "beside them that no chain carries yet")
         alloc = self.allocator
         chain = alloc.match_prefix(prompt)
         tuples = alloc._full_block_tuples(prompt)[:len(chain)]
@@ -1302,7 +1423,9 @@ class PagedKVPool(object):
         """Scatter the prefilled cache's blocks [start_block, ...)
         into the slot's allocated blocks — block-granular, no
         whole-slot copy (shared blocks below start_block are already
-        resident and must not be re-written)."""
+        resident and must not be re-written) — and, where the model
+        keeps a per-sequence state, seat that in the slot: one more
+        launch for every state leaf together (`write_state`)."""
         write = self._write_program()
         table = self.allocator.table(slot)
         blocks = range(start_block,
@@ -1313,8 +1436,14 @@ class PagedKVPool(object):
                 self.update(
                     write, kv, jnp.asarray(j, jnp.int32),
                     jnp.asarray(table[j], jnp.int32),
-                    block_size=self.block_size,
+                    block_size=self.block_size, kinds=self.kinds,
                 )
+        if self.has_state:
+            with tracing.phase("state_write", slot=int(slot)):
+                self.update(self._state_program(), kv,
+                            jnp.asarray(slot, jnp.int32),
+                            kinds=self.kinds)
+            tracing.count("state_write.launches")
         # work done, counted where it happens: one launch per block,
         # and the prompt tokens those blocks now hold
         tracing.count("prompt_write.launches", len(blocks))
@@ -1341,14 +1470,17 @@ class PagedKVPool(object):
             return None
         old, new = moved
         self.update(self._copy_program(), jnp.asarray(old, jnp.int32),
-                    jnp.asarray(new, jnp.int32))
+                    jnp.asarray(new, jnp.int32), kinds=self.kinds)
         self._sync_row(slot)
         return moved
 
     def release(self, slot):
         """Reclaim a finished/evicted slot's references (O(1) per
         block); private rows are dead the moment the table forgets
-        them, shared rows live on under their other owners."""
+        them, shared rows live on under their other owners. A slot's
+        per-sequence state needs no device work either: the next
+        seating overwrites the whole of it (`write_state`), and until
+        then the lane is free and nothing reads it."""
         freed = self.allocator.free(slot)
         if freed:
             self.tables[slot, :] = -1
@@ -1389,6 +1521,9 @@ class PagedKVPool(object):
             "kv_blocks_shared": self.allocator.shared_blocks(),
             "kv_bytes_total": self.bytes_total,
             "kv_bytes_in_use": self.bytes_in_use(),
+            # the per-slot state arenas beside the row arenas (0 for a
+            # model without state layers): fixed, seated or not
+            "kv_state_bytes": self.state_bytes,
             "prefix_hit_tokens": self.allocator.prefix_hit_tokens,
             "cow_copies": self.allocator.cow_copies,
             # tiered host spill: current host-tier occupancy (gauges)

@@ -139,11 +139,11 @@ def _paged_decode_choice(engine):
     engine (the start-up log line)."""
     from elasticdl_tpu.ops.attention import paged_decode_impl
 
-    import jax
-
     kv = engine.kv
-    arena = max((leaf for leaf in jax.tree.leaves(kv.pools)
-                 if leaf.ndim == 4), key=lambda leaf: leaf.shape[-1])
+    arenas = kv.row_arenas()
+    if not arenas:  # a model without attention layers pages nothing
+        return "none"
+    arena = max(arenas, key=lambda leaf: leaf.shape[-1])
     return paged_decode_impl(kv.max_blocks_per_slot, arena,
                              kv.kv_cache_dtype == "int8")
 

@@ -1050,6 +1050,13 @@ class GenerationServer(object):
             host_bytes=cfg.kv_host_bytes,
             prefill_chunk_tokens=cfg.prefill_chunk_tokens,
         )
+        if self.engine.kv.has_state and cfg.role != "unified":
+            raise ValueError(
+                "this model keeps a per-sequence state (a state-space "
+                "layer) beside its KV rows, and a %r replica (role) "
+                "hands a prompt's KV chain to a sibling, which carries "
+                "no state yet. Start it unified (--role unified / "
+                "EDL_SERVING_ROLE unset)" % (cfg.role,))
         self.queue = RequestQueue(
             cfg.queue_capacity, self.engine.seq_len,
             max_cached_tokens=self.engine.max_cached_tokens(),

@@ -37,7 +37,12 @@ from elasticdl_tpu.parallel.context_parallel import (
     sharded_flash_attention,
     ulysses_attention,
 )
-from elasticdl_tpu.parallel.moe import held_experts_reglu, route_top_k
+from elasticdl_tpu.parallel.moe import (
+    held_experts,
+    route_sigmoid_top_k,
+    route_top_k,
+)
+from model_zoo.transformer_lm.mamba2 import Mamba2Mixer
 
 
 def _tp_dense_init(split_axis):
@@ -484,26 +489,47 @@ def _norm(kind, dtype, eps, name=None):
 
 
 class ExpertFFN(nn.Module):
-    """The block's MLP slot as a drop-free gated expert layer
-    (parallel/moe.held_experts_reglu): a router over ALL `num_experts`
-    (float32, its logits at full precision), top `top_k` by logit with a
-    softmax over the chosen, ReGLU experts of width `hidden`, and this
-    chip's share of them, `held = (first, count)`: the router keeps its
-    width, the weights are the held experts' only, and what the others
-    would add is left out. `()` holds them all.
+    """The block's MLP slot as a drop-free expert layer
+    (parallel/moe.held_experts): a router over ALL `num_experts`
+    (float32, its logits at full precision), top `top_k` a token,
+    experts of width `hidden`, and this chip's share of them, `held =
+    (first, count)`: the router keeps its width, the weights are the
+    held experts' only, and what the others would add is left out. `()`
+    holds them all. What kind of layer it is comes from parameters:
 
-    `route_from` is what the router reads (the block's own input, so
-    that routing is known before attention runs); `h` what the experts
-    multiply. Where the caller collects "counters" (the serving step)
-    it is handed what the layer did: `moe.pairs_routed`,
-    `moe.pairs_held` (scalars, of this call's rows) and
-    `moe.experts_hit`, `moe.expert_slots` (a mark an expert held)."""
+    * `activation`: "reglu", gated experts of three matrices,
+      (relu(h W_gate) * (h W_up)) W_down; "relu2", experts of two,
+      relu(h W_up^T)^2 W_down: there is no `w_gate`, and `w_up` is
+      [count, hidden, d] like `w_down`, a hidden unit a row, as a
+      checkpoint stores an up projection (ops/expert_ffn.py says what
+      the other orientation costs on the chip);
+    * `scoring`: "softmax", the k largest logits and a softmax over
+      those; "sigmoid", scores sigmoid(logits), the k largest of score
+      + `router_bias` (a parameter that only selects), weights
+      `route_scale` * score / the chosen scores' sum;
+    * `shared_hidden` > 0: one more expert of that width and the
+      experts' activation that every token passes through, unweighted
+      (`shared_up`, `shared_down`): a plain dense product that every
+      chip of a deployment computes alike, counted once.
+
+    `route_from` is what the router reads (a block with attention
+    hands its own input, so that routing is known before attention
+    runs; a layer that is the expert layer alone, the normed input the
+    experts multiply); `h` what the experts multiply. Where the caller
+    collects "counters" (the serving step) it is handed what the layer
+    did: `moe.pairs_routed`, `moe.pairs_held` (scalars, of this call's
+    rows) and `moe.experts_hit`, `moe.expert_slots` (a mark an expert
+    held)."""
 
     num_experts: int
     top_k: int
     hidden: int
     held: tuple = ()
     dtype: object = None
+    activation: str = "reglu"
+    scoring: str = "softmax"
+    route_scale: float = 1.0
+    shared_hidden: int = 0
 
     @nn.compact
     def __call__(self, h, route_from, training=False):
@@ -513,6 +539,15 @@ class ExpertFFN(nn.Module):
             raise ValueError(
                 "experts_held %r is no range of %d experts"
                 % (self.held, self.num_experts))
+        if self.activation not in ("reglu", "relu2"):
+            raise ValueError("Unknown moe_activation %r (valid: 'reglu', "
+                             "'relu2')" % (self.activation,))
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError("Unknown moe_scoring %r (valid: 'softmax', "
+                             "'sigmoid')" % (self.scoring,))
+        if self.shared_hidden and self.activation != "relu2":
+            raise ValueError(
+                "a shared expert is built for relu2 experts only")
         dtype = self.dtype or h.dtype
 
         def bank(fan_in):
@@ -522,26 +557,47 @@ class ExpertFFN(nn.Module):
 
         router = self.param("router", nn.initializers.normal(d ** -0.5),
                             (d, self.num_experts), jnp.float32)
+        into, back = (count, d, self.hidden), (count, self.hidden, d)
+        banks = ((("w_gate", into, d), ("w_up", into, d))
+                 if self.activation == "reglu" else (("w_up", back, d),))
         weights = [
-            jnp.asarray(self.param(name, bank(shape[1]), shape,
+            jnp.asarray(self.param(name, bank(fan_in), shape,
                                    jnp.float32), dtype)
-            for name, shape in (
-                ("w_gate", (count, d, self.hidden)),
-                ("w_up", (count, d, self.hidden)),
-                ("w_down", (count, self.hidden, d)))
+            for name, shape, fan_in in banks + (
+                ("w_down", back, self.hidden),)
         ]
         with jax.named_scope("moe_router"):
             logits = jnp.matmul(
                 route_from.reshape(b * l, d).astype(jnp.float32), router,
                 precision=jax.lax.Precision.HIGHEST)
-            gates, experts = route_top_k(logits, self.top_k)
+            if self.scoring == "sigmoid":
+                gates, experts = route_sigmoid_top_k(
+                    logits, self.top_k,
+                    self.param("router_bias", nn.initializers.zeros,
+                               (self.num_experts,), jnp.float32),
+                    self.route_scale)
+            else:
+                gates, experts = route_top_k(logits, self.top_k)
+        rows = h.reshape(b * l, d).astype(dtype)
         with jax.named_scope("moe_experts"):
             # the Mosaic kernel has no backward: a training forward
             # takes the plain products
-            y, held, hit = held_experts_reglu(
-                h.reshape(b * l, d).astype(dtype), gates, experts,
-                *weights, first=first,
+            y, held, hit = held_experts(
+                rows, gates, experts, weights, first=first,
                 use_kernel=False if training else None)
+        if self.shared_hidden:
+            with jax.named_scope("moe_shared"):
+                up, down = (
+                    jnp.asarray(self.param(
+                        name, nn.initializers.normal(shape[0] ** -0.5),
+                        shape, jnp.float32), dtype)
+                    for name, shape in (
+                        ("shared_up", (d, self.shared_hidden)),
+                        ("shared_down", (self.shared_hidden, d))))
+                act = jnp.square(jnp.maximum(jnp.dot(
+                    rows, up, preferred_element_type=jnp.float32), 0.0))
+                y = y + jnp.dot(act.astype(dtype), down,
+                                preferred_element_type=jnp.float32)
         if (self.is_mutable_collection("counters")
                 and not self.is_initializing()):
             counts = {
@@ -560,7 +616,11 @@ class Block(nn.Module):
     """THE block of the stack, configured per layer: normalisation,
     rotary on or off (and its theta), this layer's window, and what
     sits in the MLP slot (the dense GELU MLP, or the expert layer fed
-    by the block's own input)."""
+    by the block's own input). `kind` "" is that block, attention then
+    MLP. A stack that names its layers' kinds (`layer_kinds`) makes a
+    layer ONE mixer behind one norm with one residual, x + f(norm(x)):
+    "*" attention alone, "M" the Mamba-2 mixer alone (`ssm`), "E" the
+    expert layer alone, its router reading the same normed input."""
 
     num_heads: int
     head_dim: int
@@ -585,15 +645,15 @@ class Block(nn.Module):
     moe_top_k: int = 0
     moe_hidden: int = 0
     experts_held: tuple = ()
+    moe_activation: str = "reglu"
+    moe_scoring: str = "softmax"
+    moe_route_scale: float = 1.0
+    moe_shared_hidden: int = 0
+    kind: str = ""  # "" attention then MLP | "*" | "M" | "E"
+    ssm: tuple = ()  # Mamba2Mixer's fields, as sorted (name, value)
 
-    @nn.compact
-    def __call__(self, x, training=False, decode=False, decode_pos=None,
-                 prefill=False, segments=None, positions=None,
-                 paged=None):
-        e = x.shape[-1]
-        block_in = x
-        y = _norm(self.norm, self.dtype, self.norm_eps)(x)
-        x = x + CausalSelfAttention(
+    def _attention(self):
+        return CausalSelfAttention(
             self.num_heads, self.head_dim, dtype=self.dtype,
             attn_impl=self.attn_impl, sp_impl=self.sp_impl,
             tp_shard=self.tp_shard, causal=self.causal,
@@ -604,16 +664,49 @@ class Block(nn.Module):
             lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
             kv_cache_dtype=self.kv_cache_dtype,
             name="attn",
-        )(y, training, decode=decode, decode_pos=decode_pos,
-          prefill=prefill, segments=segments, positions=positions,
-          paged=paged)
+        )
+
+    def _experts(self):
+        return ExpertFFN(
+            self.moe_experts, self.moe_top_k, self.moe_hidden,
+            held=tuple(self.experts_held), dtype=self.dtype,
+            activation=self.moe_activation, scoring=self.moe_scoring,
+            route_scale=self.moe_route_scale,
+            shared_hidden=self.moe_shared_hidden,
+            name="moe",
+        )
+
+    @nn.compact
+    def __call__(self, x, training=False, decode=False, decode_pos=None,
+                 prefill=False, segments=None, positions=None,
+                 paged=None, prompt_len=None):
+        e = x.shape[-1]
+        block_in = x
+        y = _norm(self.norm, self.dtype, self.norm_eps)(x)
+        if self.kind == "M":
+            if segments is not None:
+                raise ValueError(
+                    "a state-space layer does not restart its state at "
+                    "a packed document's boundary yet: no segment_ids")
+            y = Mamba2Mixer(dtype=self.dtype, name="ssm",
+                            **dict(self.ssm))(
+                y, decode=decode, prefill=prefill, prompt_len=prompt_len)
+            return x + y.astype(x.dtype)
+        if self.kind == "E":
+            return x + self._experts()(y, y, training).astype(x.dtype)
+        x = x + self._attention()(
+            y, training, decode=decode, decode_pos=decode_pos,
+            prefill=prefill, segments=segments, positions=positions,
+            paged=paged)
+        if self.kind == "*":
+            return x
+        if self.kind:
+            raise ValueError(
+                "Unknown layer kind %r (valid: 'M', 'E', '*')"
+                % (self.kind,))
         y = _norm(self.norm, self.dtype, self.norm_eps)(x)
         if self.mlp == "moe_reglu":
-            y = ExpertFFN(
-                self.moe_experts, self.moe_top_k, self.moe_hidden,
-                held=tuple(self.experts_held), dtype=self.dtype,
-                name="moe",
-            )(y, block_in, training)
+            y = self._experts()(y, block_in, training)
             return x + y.astype(x.dtype)
         if self.mlp != "gelu":
             raise ValueError(
@@ -744,6 +837,28 @@ class TransformerLM(nn.Module):
     moe_top_k: int = 0
     moe_hidden: int = 0
     experts_held: tuple = ()
+    # what the expert layer is (ExpertFFN): experts "reglu" (gated,
+    # three matrices) or "relu2" (two); the router's scoring "softmax"
+    # (over the chosen logits) or "sigmoid" (selection bias,
+    # renormalised, times `moe_route_scale`); a shared expert of width
+    # `moe_shared_hidden` every token passes through (0 = none)
+    moe_activation: str = "reglu"
+    moe_scoring: str = "softmax"
+    moe_route_scale: float = 1.0
+    moe_shared_hidden: int = 0
+    # a character a layer, "" = every layer attention then MLP as
+    # above: "*" a layer that is attention alone, "M" the Mamba-2
+    # mixer alone, "E" the expert layer alone, each x + f(norm(x)).
+    # The rotary and window layouts keep an entry a LAYER (read at the
+    # attention layers only)
+    layer_kinds: str = ""
+    # the Mamba-2 mixer's sizes (model_zoo/transformer_lm/mamba2.py)
+    ssm_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_groups: int = 8
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
     tp_shard: bool = True  # annotate kernels over the tp mesh axis
     fused_head: bool = False  # stream the LM head inside the loss
     num_kv_heads: int = 0  # grouped-query attention (0 = MHA)
@@ -770,11 +885,32 @@ class TransformerLM(nn.Module):
                 % (name, len(layout), self.num_layers))
         return layout or (1,) * self.num_layers
 
+    def _kinds(self):
+        kinds = str(self.layer_kinds)
+        if kinds and (len(kinds) != self.num_layers
+                      or set(kinds) - set("ME*")):
+            raise ValueError(
+                "layer_kinds %r is not %d of 'M', 'E', '*'"
+                % (kinds, self.num_layers))
+        return kinds or ("",) * self.num_layers
+
     def layer_windows(self):
-        """Each layer's window (0 = every earlier key), in order: what
-        the serving engine counts a tick's reach by."""
-        return tuple(self.attn_window if on else 0
-                     for on in self._layout("window_layout"))
+        """The window (0 = every earlier key) of each layer that has
+        attention, in order: what the serving engine counts a tick's
+        reach by."""
+        return tuple(
+            self.attn_window if on else 0
+            for on, kind in zip(self._layout("window_layout"),
+                                self._kinds()) if kind in ("", "*"))
+
+    def cache_leaf_kind(self, path):
+        """What a leaf of this model's decode cache is, by its path
+        (api/generation.cache_leaf_kinds): the "rows" of an attention
+        layer, a cached token each; the per-sequence "state" of a
+        state-space layer; the "scalar" position counter."""
+        if "ssm" in path:
+            return "state"
+        return "rows" if "attn" in path else "scalar"
 
     @nn.compact
     def __call__(self, features, training=False, decode=False,
@@ -827,9 +963,16 @@ class TransformerLM(nn.Module):
                 % (self.pos_emb,)
             )
         head_dim = self.head_dim or self.embed_dim // self.num_heads
-        windows = self.layer_windows()
+        kinds = self._kinds()
+        windows = tuple(self.attn_window if on else 0
+                        for on in self._layout("window_layout"))
         rotary = [self.pos_emb == "rope" and bool(on)
                   for on in self._layout("rope_layout")]
+        ssm = tuple(sorted({
+            "num_heads": self.ssm_heads, "head_dim": self.ssm_head_dim,
+            "groups": self.ssm_groups, "state_dim": self.ssm_state,
+            "conv": self.ssm_conv, "chunk": self.ssm_chunk,
+            "norm_eps": self.norm_eps}.items()))
         if self.remat not in ("", "full", "dots"):
             raise ValueError(
                 "Unknown remat %r (valid: '', 'full', 'dots')"
@@ -869,10 +1012,15 @@ class TransformerLM(nn.Module):
                 mlp=self.mlp, moe_experts=self.moe_experts,
                 moe_top_k=self.moe_top_k, moe_hidden=self.moe_hidden,
                 experts_held=tuple(self.experts_held),
+                moe_activation=self.moe_activation,
+                moe_scoring=self.moe_scoring,
+                moe_route_scale=self.moe_route_scale,
+                moe_shared_hidden=self.moe_shared_hidden,
+                kind=kinds[i], ssm=ssm if kinds[i] == "M" else (),
                 name="block_%d" % i,
             )
             blk_paged = None
-            if paged is not None:
+            if paged is not None and kinds[i] in ("", "*"):
                 arena = paged["pools"]["block_%d" % i]["attn"]
                 blk_paged = {
                     "k": arena["k"], "v": arena["v"],
@@ -887,7 +1035,7 @@ class TransformerLM(nn.Module):
                 x = blk(x, training, decode=decode,
                         decode_pos=decode_pos, prefill=prefill,
                         segments=segments, positions=positions,
-                        paged=blk_paged)
+                        paged=blk_paged, prompt_len=prompt_len)
         x = _norm(self.norm, self.dtype, self.norm_eps, name="ln_f")(x)
         head = LMHead(
             self.vocab_size, dtype=self.dtype, name="head",

@@ -81,12 +81,17 @@ def build_engine(cfg):
         eng = engine_mod.PagedContinuousBatchingEngine(
             trainer, state, num_slots=server["num_slots"],
             block_size=server["kv_block_size"],
-            num_blocks=server["kv_num_blocks"])
+            num_blocks=server["kv_num_blocks"],
+            share_prefix=bool(server.get("kv_shared", 1)))
     return eng, {"params": state.params, **state.model_state}
 
 
 def programs(eng, tile, upload_blocks):
-    """name -> (program, its arguments after the pool, static keywords)."""
+    """name -> (program, its arguments after the pool, static keywords).
+    A pool with per-slot state leaves (a model with state-space layers)
+    has the state write beside the step and the prompt write, and none
+    of the programs such a model refuses to start with (copy on write,
+    the decode tile, the host tier's upload)."""
     import jax
     import jax.numpy as jnp
 
@@ -95,13 +100,20 @@ def programs(eng, tile, upload_blocks):
     i32, f32 = spec((), jnp.int32), spec((), jnp.float32)
     rows = [spec((upload_blocks,) + shape, jnp.dtype(dtype))
             for shape, dtype in zip(kv.row_shapes, kv.leaf_dtypes())]
-    return {
+    out = {
         "paged_step": (
             eng._build_paged_step(),
             [eng._exec_variables, eng._lanes_spec()], {}),
         "prompt_write": (kv._write_program(), [eng._kv_shapes, i32, i32],
-                         {"block_size": kv.block_size}),
-        "cow_copy": (kv._copy_program(), [i32, i32], {}),
+                         {"block_size": kv.block_size,
+                          "kinds": kv.kinds}),
+    }
+    if kv.has_state:
+        out["state_write"] = (kv._state_program(), [eng._kv_shapes, i32],
+                              {"kinds": kv.kinds})
+        return out
+    out.update({
+        "cow_copy": (kv._copy_program(), [i32, i32], {"kinds": kv.kinds}),
         "suffix_prefill[%d]" % tile: (
             eng._build_suffix_prefill(tile),
             [eng._exec_variables, spec(kv.tables.shape[1:], jnp.int32),
@@ -109,7 +121,8 @@ def programs(eng, tile, upload_blocks):
         "revive_upload[%d]" % upload_blocks: (
             kv._upload_program(upload_blocks),
             [rows, spec((upload_blocks,), jnp.int32), i32], {}),
-    }
+    })
+    return out
 
 
 def weight_report(variables, hlo, table_shape):

@@ -307,9 +307,11 @@ def test_int8_prompt_block_write_round_trips_quantizer():
     assert pools["k_scale"].dtype == jnp.float32
     assert pools["k_scale"].shape == (nb, bs, hkv, 1)
     assert pools["pos"].shape == ()  # non-row leaf stays a placeholder
+    # the leaves' kinds, as the pool hands them to its programs: by
+    # declaration, here the kv_row_leaf convention's (k, k_scale, pos)
     pools = write_prompt_block(
         pools, kv, jnp.asarray(1, jnp.int32), jnp.asarray(4, jnp.int32),
-        block_size=bs,
+        block_size=bs, kinds=("rows", "rows", "scalar"),
     )
     np.testing.assert_array_equal(
         np.asarray(pools["k"][4]),
@@ -377,7 +379,7 @@ def test_copy_block_carries_scale_leaves():
         ),
         "pos": jnp.zeros((), jnp.int32),
     }
-    out = copy_block(pools, 1, 4)
+    out = copy_block(pools, 1, 4, kinds=("rows", "rows", "scalar"))
     np.testing.assert_array_equal(
         np.asarray(out["k"][4]), np.asarray(pools["k"][1])
     )

@@ -24,6 +24,7 @@ the program to, shown here at the benchmark's rehearsal widths
 import functools
 import json
 import os
+import re
 from unittest import mock
 
 import jax
@@ -539,3 +540,58 @@ def test_at_the_cells_widths_the_decision_is_the_steps_too(
     assert served.count(jnp.dtype(jnp.float32)) == 5 * 2
     assert [x.shape for x in handed] == [
         x.shape for x in jax.tree.leaves(eng._exec_variables)]
+
+
+# -------- (f) a pool with per-slot state leaves, at the nm3n cell's widths
+
+
+@pytest.fixture(scope="module")
+def state_programs():
+    """The pool-updating programs of a model with state-space layers
+    (chipbench/configs/nm3n-30b-serve.json: its widths, 32 slots, its
+    pool), the first six layers `MEMEM*` deep, over shapes: the decode
+    step, the prompt's block write and the seating's state write."""
+    from scripts import check_pool_donation as check
+
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           "nm3n-30b-serve.json")) as f:
+        cfg = json.load(f)
+    params = cfg["model"]["params"]
+    params.update(num_layers=6, layer_kinds=params["layer_kinds"][:6],
+                  rope_layout=params["rope_layout"][:6])
+    eng, _handed_in = check.build_engine(cfg)
+    return eng, check.programs(eng, tile=16, upload_blocks=4)
+
+
+@pytest.mark.parametrize("program", ["paged_step", "prompt_write",
+                                     "state_write"])
+def test_a_pool_with_state_leaves_is_aliased_whole_on_a_v5e_too(
+        program, one_chip, state_programs):
+    from elasticdl_tpu.ops import dispatch
+    from scripts import check_pool_donation as check
+
+    eng, todo = state_programs
+    assert sorted(todo) == ["paged_step", "prompt_write", "state_write"]
+    with mock.patch.object(dispatch, "is_tpu_backend", lambda: True):
+        compiled, pools = check.compile_program(eng, todo[program],
+                                                one_chip)
+    got = kv_pool.pool_aliasing(compiled, pools)
+    # three Mamba-2 layers' float32 state and tails for 32 slots beside
+    # one attention layer's arenas: the state is most of the pool
+    assert eng.kv.state_bytes == 32 * 3 * (64 * 64 * 128 * 4
+                                           + 3 * 6144 * 2)
+    assert eng.kv.bytes_total == 2 * 2688 * 16 * 2 * 128 * 2
+    assert got["pool_bytes"] >= eng.kv.state_bytes + eng.kv.bytes_total
+    assert 0 <= got["alias_bytes"] - got["pool_bytes"] <= 512, got
+    assert got["pool_shaped_copies"] == 0, got
+    if program == "paged_step":
+        hlo = compiled.as_text()
+        # both new kernels are in the step, under their names, one a
+        # layer, and the state update writes over its input
+        assert hlo.count("ssm_state_update/pallas_call") >= 3
+        assert hlo.count("moe_expert_tiles/pallas_call") >= 2
+        assert "output_to_operand_aliasing={{1}: (0, {})}" in hlo
+        # no expert bank is copied to suit the kernel (a [d, 1856] bank
+        # would be: ops/expert_ffn.py)
+        assert not re.search(
+            r"= bf16\[32,(2688,1856|1856,2688)\]\S* copy\(", hlo)
