@@ -153,11 +153,20 @@ COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             # update ran in place). The ratio is 1.0 or some caller
             # kept the pool from being donated
             "pool.launches", "pool.inplace_launches",
-            # serving/engine.py _tick_lanes(), once a decode tick
-            # inside `tick.upload`: host-to-device transfers the tick
-            # made for its lane state (0 when no lane changed since
-            # the last tick, else 1)
+            # serving/engine.py _tick_lanes(), once a launch of the
+            # decode step (one a tick; two in the tick that starts
+            # with none in flight) inside `tick.upload`:
+            # host-to-device transfers the launch made for its lane
+            # state (0 when no lane changed since the last launch,
+            # else 1)
             "tick.transfers",
+            # serving/engine.py _launch(), once a launch inside
+            # `tick.dispatch`: 1 when the step was launched while the
+            # previous step's tokens were still unfetched (the device
+            # goes from one step to the next without the host), else 0.
+            # Over ticks: the share that ran ahead; beside
+            # tick.transfers, the share of those that sent the mirror
+            "tick.ahead",
             # serving/kv_pool.py write_prompt(): launches that seated a
             # prompt's per-sequence state (one for all the state layers
             # of a model that has any)

@@ -50,12 +50,40 @@ position, last token, seed and temperature are one small array
 lane one position on, its last token the token just sampled — and
 that never comes to the host. The numpy mirror (`_positions`,
 `_last_tokens`, `_seeds`, `_temps`, `kv.tables`) stays the book the
-host works from; what the host changes in it outside the commit loop
-(a lane seated or freed, a table row written) marks the device's copy
-stale, and the next tick sends the mirror in one transfer
-(`_tick_lanes`); a tick whose lanes the host left alone sends nothing.
-After a step that raised the device's state is not trusted: the next
-tick rebuilds it from the mirror.
+host works from; what the host changes in it (a lane seated or freed,
+a table row written) marks the device's copy stale, and the next
+launch sends the mirror in one transfer (`_tick_lanes`); a launch
+whose lanes the host left alone sends nothing. After a launch that
+raised the device's state is not trusted: the next one rebuilds it
+from the mirror.
+
+The decode loop keeps ONE STEP IN FLIGHT: `step` launches step n+1
+before it fetches step n's tokens, so the device goes from one step to
+the next while the host commits, streams, ensures, uploads and
+dispatches. That rests on three things. (1) A request ends by length
+alone, so the host knows without a token which lanes the next step
+has, where each writes and which block it grows into: the mirror's
+positions advance when a step is LAUNCHED, and the launch that
+produces a lane's last token frees the lane and releases its blocks at
+once (the completion stays with that step's commit). (2) The one
+thing the host lacks before the fetch is the VALUE of the last tokens,
+and only the device needs it: the mirror's token column holds a value
+only where the host is the one who knows it (a lane seated since the
+last launch: its prefill's first token) and `_KEEP` elsewhere, and a
+launch that sends the mirror takes the carried lanes' token wherever
+the sent one says `_KEEP` (`_merge_lanes`, run on the launches
+that send and on no other). (3) Every program that touches the pool
+takes the pool the one before it handed back (`kv.update`, donated), so
+the device runs them in the order the host launched them: blocks
+released while the step that last writes them is in flight, a prompt
+written into them, a tile, a copy, a spill or an export all come after
+that step. A launched step updated the pool and the state arenas in
+place, so it is never dropped: `_flights` keeps what it needs to be
+committed later (the lanes it ran, the weights' version it ran under,
+which lanes it finishes), and a lane evicted before its step is
+committed (a deadline) is skipped there. The speculative tick stays in
+line: its positions advance by what the verify accepts, which only the
+fetch tells.
 
 The weights are served in the dtype the programs COMPUTE in, made
 once a load. The state handed in stays what a checkpoint holds (fp32);
@@ -202,6 +230,11 @@ def _tick_counts(sown):
 #: float32 bits
 _LANE_POS, _LANE_TOKEN, _LANE_SEED, _LANE_TEMP, _LANE_TABLE = range(5)
 
+#: in the MIRROR's token column: the host has not seen this lane's last
+#: token (its step is in flight, or was when the next was launched);
+#: the device holds it. Never a token, which is an index >= 0
+_KEEP = -1
+
 
 Lanes = collections.namedtuple(
     "Lanes", "tables positions last_tokens seeds temps")
@@ -218,6 +251,15 @@ def lane_fields(lanes):
         temps.view(np.float32) if isinstance(temps, np.ndarray)
         else jax.lax.bitcast_convert_type(temps, jnp.float32),
     )
+
+
+def _merge_lanes(sent, carried):
+    """The mirror as sent, with the token the device carries wherever
+    the host's says `_KEEP`: a lane whose last token the host has not
+    seen (its step is in flight) keeps it."""
+    token = sent[:, _LANE_TOKEN]
+    return sent.at[:, _LANE_TOKEN].set(
+        jnp.where(token == _KEEP, carried[:, _LANE_TOKEN], token))
 
 
 def _at_path(tree, path):
@@ -242,11 +284,21 @@ def _trace_id(request):
 
 
 class _Slot(object):
-    __slots__ = ("request", "max_total")
+    __slots__ = ("request", "max_total", "evicted")
 
     def __init__(self, request, max_total):
         self.request = request
         self.max_total = max_total
+        # evicted before its end (a deadline): what its steps in
+        # flight produce is no longer its request's
+        self.evicted = False
+
+
+#: a decode step launched and not yet committed: the array its tokens
+#: (and the tick's counters behind them) come home in, the lanes it ran
+#: as [(slot, _Slot, whether this was the lane's last step)], and the
+#: weights' version it ran under
+_Flight = collections.namedtuple("_Flight", "tokens ran version")
 
 
 class _PrefillJob(object):
@@ -486,14 +538,22 @@ class PagedContinuousBatchingEngine(object):
         self._last_tokens = np.zeros(self.num_slots, np.int32)
         self._seeds = np.zeros(self.num_slots, np.int32)
         self._temps = np.zeros(self.num_slots, np.float32)
-        # the lane state on the device as the last step handed it back
-        # (None: not to be trusted, the next tick sends the mirror),
-        # and whether the host has written a lane's scalars since
+        # the lane state on the device as the last step launched hands
+        # it back (None: not to be trusted, the next launch sends the
+        # mirror), and whether the host has written a lane's scalars
+        # since
         self._lanes = None
         self._lanes_dirty = False
+        # the steps launched and not yet committed, oldest first (one
+        # between two calls of step(), two inside one), and how many
+        # of their lanes were freed at the launch (their last) and
+        # still owe their request its last token
+        self._flights = collections.deque()
+        self._landing = 0
         self._prefill_fns = {}  # bucket -> compiled prefill
         self._suffix_fns = {}  # suffix bucket -> compiled tile prefill
         self._step_fn = None
+        self._merge_fn = None
         self._spec_fn = None
         # the counters the model's layers sow in a decode step, by
         # name, in the order they follow the tokens in the step's
@@ -722,11 +782,32 @@ class PagedContinuousBatchingEngine(object):
                 if s is None and i not in self._prefilling]
 
     def active_count(self):
-        return sum(1 for s in self._slots if s is not None)
+        """The lanes that still owe a token: those seated, and those
+        freed at the launch of their last step whose tokens are in
+        flight (the scheduler calls step() while this is not 0, or they
+        would never be streamed). Read from other threads too (status,
+        the watchdog): two plain reads, and never more than the slots
+        there are, though a slot freed at a launch may be seated again
+        before that launch is committed."""
+        return min(self.num_slots, len(self._seated()) + self._landing)
+
+    def seated_count(self):
+        """The lanes seated now, those the next launch carries: what a
+        tick's root span says was left seated (a lane whose last token
+        is in flight holds no slot any more)."""
+        return len(self._seated())
+
+    def _seated(self):
+        """[(slot, its _Slot)] of the lanes that decode."""
+        return [(i, s) for i, s in enumerate(self._slots) if s is not None]
 
     def active_requests(self):
-        reqs = [s.request for s in self._slots if s is not None]
+        reqs = [s.request for _slot, s in self._seated()]
         reqs.extend(j.request for j in self._prefilling.values())
+        # freed at their last launch, not yet committed: still owed
+        # a `done` or an error
+        reqs.extend(st.request for flight in self._flights
+                    for _slot, st, last in flight.ran if last)
         return reqs
 
     def prefilling_count(self):
@@ -1088,10 +1169,23 @@ class PagedContinuousBatchingEngine(object):
         return min(self.seq_len, -(-int(t) // 8) * 8)
 
     def evict(self, slot):
-        """Free the slot (completion or deadline eviction) AND drop
-        its block references; private rows are dead the moment the
-        table forgets them, shared rows live on under their other
-        owners (copy-free churn — nothing is zeroed or moved)."""
+        """Evict the slot's request before its end (a deadline): free
+        the lane, and skip what its steps in flight produce when they
+        are committed."""
+        if self._slots[slot] is not None:
+            self._slots[slot].evicted = True
+        self._free(slot)
+
+    def _free(self, slot):
+        """Free the lane (the launch of its last step, or an eviction)
+        AND drop its block references; private rows are dead the
+        moment the table forgets them, shared rows live on under their
+        other owners (copy-free churn — nothing is zeroed or moved).
+        Host work only, and safe while a step that writes the slot's
+        blocks is in flight: the mirror is owed to the device, so no
+        later launch carries the lane, and whatever next touches those
+        blocks takes the pool that step hands back (kv_pool.py,
+        `release`)."""
         self._slots[slot] = None
         self._positions[slot] = 0
         self._lanes_dirty = True
@@ -1138,47 +1232,93 @@ class PagedContinuousBatchingEngine(object):
         tracing.count("kv.blocks_held", held)
 
     def _tick_lanes(self, budgets=None):
-        """The lane state this tick's program takes, on the device,
+        """The lane state this launch's program takes, on the device,
         inside `tick.upload`. When the host wrote no lane and no table
-        row since the last tick it is the array the last step handed
+        row since the last launch it is the array the last step handed
         back and nothing is sent. Otherwise (a lane seated or freed, a
         row grown or rewritten, no trusted state on the device: the
-        first tick, or a step that raised) the mirror goes whole, in
-        ONE transfer. The speculative tick hands no state back and
-        sends the mirror every tick, its `budgets` one more column.
-        Counts `tick.transfers` (0 or 1). The state is taken: the tick
-        puts back what its program returned once the mirror has caught
-        up with it, and a tick that raises leaves none."""
+        first launch, or one that raised) the mirror goes whole, in
+        ONE transfer, without waiting for any token: where its token
+        column says `_KEEP` the lane keeps the token the device
+        carries (one small program, run on the launches that send).
+        With no trusted state there is nothing to keep: whatever was
+        launched has been committed by then (`step`), and the token
+        column is rebuilt from what each seated request was last
+        given. The speculative tick hands no state back and sends the
+        mirror every tick, its `budgets` one more column. Counts
+        `tick.transfers` (0 or 1). The state is taken: the launch puts
+        back what its program returned, and one that raises leaves
+        none."""
         lanes, self._lanes = self._lanes, None
         send = (lanes is None or budgets is not None
                 or self._lanes_dirty or self.kv.tables_dirty)
         self._lanes_dirty = self.kv.tables_dirty = False
         if send:
-            lanes = jax.device_put(np.column_stack(
+            if lanes is None:
+                self._last_tokens[:] = 0
+                for slot, st in self._seated():
+                    self._last_tokens[slot] = st.request.generated[-1]
+            sent = jax.device_put(np.column_stack(
                 [self._positions, self._last_tokens, self._seeds,
                  self._temps.view(np.int32), self.kv.tables]
                 + ([] if budgets is None else [budgets])))
+            if budgets is None:
+                sent = self._merge_fn(sent, sent if lanes is None else lanes)
+            lanes = sent
         tracing.count("tick.transfers", int(send))
         return lanes
 
     def step(self):
-        """One vmapped decode step over the WHOLE pool: block tables
-        and positions enter as device arrays, each active slot
-        attends over its own table and its row scatters into its own
-        block. Free lanes ride along masked (stale tokens, all-(-1)
-        tables, out-of-bounds scatter ids): static shape, zero
-        recompiles. With a draft seated the step is the speculative
-        draft-verify tick instead. Returns [(slot, request, tokens,
-        finished)] for slots that were active — `tokens` is the LIST
-        of tokens the step committed for that slot (one here; the
-        speculative step commits 1..k+1). Finished slots are freed."""
-        active = [
-            (i, s) for i, s in enumerate(self._slots) if s is not None
-        ]
-        if not active:
-            return []
+        """One call a scheduler tick: keep one decode step in flight,
+        and hand back the tokens of the oldest. With a step in flight
+        (the steady state) it LAUNCHES THE NEXT and then fetches and
+        commits the older, so the device starts step n+1 the moment
+        step n ends, and the host's share of a tick runs while a step
+        computes; with none in flight it launches, launches the next
+        ahead, and collects the first. Returns [(slot, request,
+        tokens, finished)] for the lanes of the step it committed —
+        `tokens` is the LIST of tokens the step gave that request, the
+        tail of `request.generated` (one here; the speculative step
+        commits 1..k+1); `finished` lanes were freed when that step was
+        launched. A lane seated since the last launch joins the next
+        one, so its second token comes one call after its seating;
+        sampling is keyed by seed and position, so the tokens are
+        those of any other order. After a launch that raised nothing
+        runs ahead of tokens the host has not seen: the step in
+        flight, if any, is committed first and the next launch sends
+        every lane's real token. With a draft seated the call is the
+        speculative draft-verify tick instead, in line."""
         if self.draft_k:
-            return self._spec_step(active)
+            active = self._seated()
+            return self._spec_step(active) if active else []
+        if self._flights and self._lanes is None:
+            out = self._collect()
+            self._launch()
+            return out
+        if not self._flights and not self._launch():
+            return []
+        self._launch()
+        return self._collect()
+
+    def _launch(self):
+        """Launch one vmapped decode step over the WHOLE pool for the
+        lanes seated now (False: none is): block tables and positions
+        enter as device arrays, each seated lane attends over its own
+        table and its row scatters into its own block. Free lanes ride
+        along masked (stale tokens, all-(-1) tables, out-of-bounds
+        scatter ids): static shape, zero recompiles. Nothing here
+        waits for the device. Once the step is dispatched the book
+        moves on with it: each lane is one position on, its token the
+        device's to know (`_KEEP`), and a lane whose LAST token this
+        step produces (prompt + generated + in flight reaches its
+        total) is freed and its blocks released now, so the next
+        launch does not carry it and the next admission can seat into
+        it; its request completes when this step is committed.
+        `tick.ahead` counts whether an older step's tokens were still
+        unfetched."""
+        active = self._seated()
+        if not active:
+            return False
         with tracing.phase("tick.ensure"):
             for i, _st in active:
                 # the block this step writes (position = the slot's
@@ -1201,32 +1341,52 @@ class PagedContinuousBatchingEngine(object):
             with tracing.phase("tick.upload"):
                 lanes = self._tick_lanes()
             with tracing.phase("tick.dispatch"):
-                lanes, nxt = self.kv.update(
+                tracing.count("tick.ahead", int(bool(self._flights)))
+                self._lanes, tokens = self.kv.update(
                     self._step_fn, self._exec_variables, lanes
                 )
-            with tracing.phase("tick.fetch"):
-                nxt = np.asarray(nxt)  # the host waits for the device
+                ran = []
+                for slot, st in active:
+                    self._positions[slot] += 1
+                    self._last_tokens[slot] = _KEEP
+                    # the first token came with the seating, one more
+                    # with every step launched since
+                    last = bool(
+                        self._positions[slot] + 1 >= st.max_total)
+                    if last:
+                        self._free(slot)
+                        self._landing += 1
+                    ran.append((slot, st, last))
+                self._flights.append(
+                    _Flight(tokens, ran, self.model_version))
+        return True
+
+    def _collect(self):
+        """Fetch and commit the oldest step in flight: the one wait
+        for the device a tick has. A lane evicted since the launch (a
+        deadline) is skipped; the tokens carry the version of the
+        weights that made them, whatever has been loaded since. A
+        fetch that raises leaves the step in flight, and raises again
+        at the next call: its pool was updated in place and cannot be
+        run again."""
+        flight = self._flights[0]
+        with tracing.phase("tick.fetch"):
+            nxt = np.asarray(flight.tokens)  # the host waits here
+        self._flights.popleft()
         out = []
         with tracing.phase("tick.commit"):
             for name, n in zip(self._tick_counters,
                                nxt[self.num_slots:]):
                 tracing.count(name, int(n))
-            for slot, st in active:
-                self._positions[slot] += 1
+            for slot, st, last in flight.ran:
+                if last:
+                    self._landing -= 1
+                if st.evicted:
+                    continue
                 token = int(nxt[slot])
                 st.request.generated.append(token)
-                st.request.model_version = self.model_version
-                self._last_tokens[slot] = token
-                finished = (
-                    len(st.request.prompt) + len(st.request.generated)
-                    >= st.max_total
-                )
-                if finished:
-                    self.evict(slot)
-                out.append((slot, st.request, [token], finished))
-            # the mirror has advanced as the device did: what the step
-            # handed back is the state again
-            self._lanes = lanes
+                st.request.model_version = flight.version
+                out.append((slot, st.request, [token], last))
         return out
 
     def _spec_step(self, active):
@@ -1281,7 +1441,7 @@ class PagedContinuousBatchingEngine(object):
                     >= st.max_total
                 )
                 if finished:
-                    self.evict(slot)
+                    self._free(slot)
                 out.append((slot, st.request, committed, finished))
             self.draft_proposed += k * len(active)
             self.draft_accepted += accepted
@@ -1463,6 +1623,10 @@ class PagedContinuousBatchingEngine(object):
             "%d x %d-token blocks", self.num_slots, self.num_blocks,
             self.block_size,
         )
+        # with it the small program of the launches that send the
+        # mirror: every such launch runs it, the first one too, so it
+        # is compiled with the step and never later
+        self._merge_fn = self._tjit("merge_lanes", _merge_lanes)
         return self._tjit("paged_step", self._paged_step_program(),
                           donate_argnums=(0,))
 

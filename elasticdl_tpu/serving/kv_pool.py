@@ -1480,7 +1480,18 @@ class PagedKVPool(object):
         them, shared rows live on under their other owners. A slot's
         per-sequence state needs no device work either: the next
         seating overwrites the whole of it (`write_state`), and until
-        then the lane is free and nothing reads it."""
+        then the lane is free and nothing reads it. Host work only,
+        and safe while a decode step that still writes these blocks is
+        in flight (the engine releases a lane at the LAUNCH of its
+        last step): whatever touches the blocks next, for whomever
+        (a prompt's block writes, a tile, a copy, a spill's gather, an
+        export), is a program that takes the pool that step hands
+        back (`update`), so the device runs it after the step; a row
+        the step writes into a block that is by then another
+        sequence's lies past what that sequence has written and is
+        overwritten before it is read. The table row is marked
+        written, so the next launch sends the mirror and no later step
+        carries the lane."""
         freed = self.allocator.free(slot)
         if freed:
             self.tables[slot, :] = -1

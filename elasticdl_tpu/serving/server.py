@@ -228,9 +228,17 @@ class ServingConfig(object):
 class _Scheduler(threading.Thread):
     """The continuous-batching loop. Each iteration: reload params if a
     newer checkpoint landed, evict expired sequences, seat queued
-    prompts into free slots (prefill), run ONE pooled decode step, push
-    the produced tokens. Idle (no active slots) it parks on the queue's
-    condition with a short timeout so reload polling stays live."""
+    prompts into free slots (prefill), call engine.step() ONCE and
+    push the tokens it hands back. The engine keeps one decode step in
+    flight: the call launches the next step and then commits the
+    older one, so what a tick streams is what the tick before
+    launched, and the device computes while this thread streams,
+    admits and launches. `active_count()` stays above 0 while a lane's
+    last token is still in flight, so an emptying server and a
+    draining shutdown keep ticking until every lane has been given
+    its last token. Idle (nothing seated, nothing in flight) it parks
+    on the queue's condition with a short timeout so reload polling
+    stays live."""
 
     def __init__(self, engine, queue, telemetry, watcher=None,
                  idle_wait_secs=0.05, clock=time.monotonic,
@@ -356,13 +364,18 @@ class _Scheduler(threading.Thread):
 
     def _iterate(self):
         """One tick, under its root phase span (`tick`: seq = the tick
-        number; active = slots still seated when it ends)."""
+        number; active = slots still seated when it ends: a lane freed
+        at the launch of its last step is not, though active_count()
+        counts it until its last token is committed)."""
         tick = tracing.begin("tick", seq=self._tick_seq)
         self._tick_seq += 1
         try:
             self._tick()
         finally:
-            tracing.end(tick, active=self.engine.active_count(),
+            # getattr keeps bare test engines valid
+            seated = getattr(self.engine, "seated_count",
+                             self.engine.active_count)
+            tracing.end(tick, active=seated(),
                         queue_depth=len(self.queue))
 
     def _tick(self):

@@ -110,9 +110,18 @@ def _slot_state(eng, slot):
             if kind == STATE}
 
 
+def _step(eng):
+    """One decode step in line: launched and committed with nothing
+    ahead of it (engine.step() keeps one in flight), so that between
+    two of them the pool, the state arenas and the book are those of
+    the tokens committed, which is what _lane_logits reads."""
+    assert not eng._flights
+    return eng._collect() if eng._launch() else []
+
+
 def _lane_logits(eng, slot):
     """The logits of the model's own paged decode call for a seated
-    lane, read from the engine's pool before its next step."""
+    lane, read from the engine's pool before its next step (_step)."""
     read = eng.__dict__.get("_test_logits")
     if read is None:
         def logits(variables, pools, slot, pos, token, table):
@@ -131,7 +140,7 @@ def _lane_logits(eng, slot):
     return np.asarray(read(
         eng._exec_variables, eng.kv.pools, slot,
         jnp.asarray(eng._positions[slot]),
-        jnp.asarray(eng._last_tokens[slot]),
+        jnp.asarray(eng._slots[slot].request.generated[-1]),
         jnp.asarray(eng.kv.tables[slot])))
 
 
@@ -198,7 +207,7 @@ def _served(prompt_len=21, new=45):
     logits = []
     while eng.active_count():
         logits.append(_lane_logits(eng, slot))
-        eng.step()
+        _step(eng)
     after = tracing.recorder().counts()
     counts = {k: after[k] - before.get(k, 0) for k in after}
     return prompt, list(request.generated), logits, counts, eng
@@ -247,7 +256,10 @@ def test_the_tolerance_catches_what_it_is_there_for(fault, monkeypatch):
     got = []
     for token in generated[1:]:  # the sound run's tokens, forced
         got.append(_lane_logits(eng, slot))
-        eng.step()
+        _step(eng)
+        # the host says which token the lane holds: the mirror's value
+        # goes over the one the device carries
+        request.generated[-1] = token
         eng._last_tokens[slot] = token
         eng._lanes_dirty = True
     ssm._lanes_as_one_call.cache_clear()
@@ -312,8 +324,8 @@ def test_a_second_request_in_a_used_slot_is_served_as_by_a_fresh_server():
     while eng.active_count():
         np.testing.assert_array_equal(_lane_logits(eng, 0),
                                       _lane_logits(fresh, 0))
-        eng.step()
-        fresh.step()
+        _step(eng)
+        _step(fresh)
     assert second.generated == twin.generated == _alone(_prompt(12, 13), 20)
 
 
@@ -371,7 +383,7 @@ def test_whatever_a_free_lanes_state_holds_no_seated_lane_changes(junk):
         for leaf, kind in zip(flat, eng.kv.kinds)])
     for want in logits[:12]:
         np.testing.assert_array_equal(_lane_logits(eng, slot), want)
-        eng.step()
+        _step(eng)
     while eng.active_count():
         eng.step()
     assert list(request.generated) == generated

@@ -369,10 +369,15 @@ def test_tokens_equal_the_offline_oracle_with_the_spans_recording(
 
 def test_a_decode_tick_keeps_its_six_phases_and_counts_what_it_sends(
         paged_server):
-    """The tick's lane state lives on the device: `tick.upload` stays
-    a phase of every decode tick, around whatever the tick sends, and
-    counts that inside itself: `tick.transfers`, 0 on a tick whose
-    lanes did not change, else 1."""
+    """The tick's lane state lives on the device and one step stays in
+    flight: every decode tick keeps its six phases under the root, in
+    the order launch (`tick.ensure`, `tick.upload`, `tick.dispatch`)
+    then collect (`tick.fetch`, `tick.commit`) then `tick.stream`, so
+    the dispatch ends before the fetch of the OLDER step starts.
+    `tick.upload` counts inside itself what the launch sends
+    (`tick.transfers`, 0 or 1) and `tick.dispatch` whether it ran
+    ahead of unfetched tokens (`tick.ahead`). Only the tick that
+    starts with nothing in flight launches twice: 0, then 1."""
     _trainer, _state, server = paged_server
     _serve(server, [([1, 2, 3], 14)])
     first = max(p.seq for p in tracing.recorder().phases()
@@ -380,30 +385,52 @@ def test_a_decode_tick_keeps_its_six_phases_and_counts_what_it_sends(
     _serve(server, [([1, 2, 3, 4, 5], 14), ([6, 7], 3)])
     ticks = {}
     for p in tracing.recorder().phases():
-        if p.seq is not None and p.seq > first and p.parent in (
-                "tick", "tick.upload"):
+        if p.seq is not None and p.seq > first and (
+                p.parent in ("tick", "tick.upload")
+                or p.name == "tick.ahead"):
             ticks.setdefault(p.seq, []).append(p)
-    decode = [t for t in ticks.values()
+    decode = [sorted(t, key=lambda p: p.start_ns) for t in ticks.values()
               if any(p.name == "tick.dispatch" for p in t)]
-    assert len(decode) >= 13
-    six = ["tick.ensure", "tick.upload", "tick.dispatch", "tick.fetch",
-           "tick.commit", "tick.stream"]
-    sent = []
+    assert len(decode) >= 12
+    launch = ["tick.ensure", "tick.upload", "tick.dispatch"]
+    collect = ["tick.fetch", "tick.commit", "tick.stream"]
+    sent, twice = [], 0
     for tick in decode:
-        by_name = {p.name: p for p in tick}
-        spans = sorted((by_name[name] for name in six),
-                       key=lambda p: p.start_ns)
-        assert [p.name for p in spans] == six
-        assert all(p.parent == "tick" for p in spans)
+        spans = [p for p in tick if p.parent == "tick"
+                 and p.name != "tick.admit"]
+        ahead = [p.attrs["n"] for p in tick if p.name == "tick.ahead"]
+        if len(spans) == 9:  # nothing was in flight
+            assert [p.name for p in spans] == launch * 2 + collect
+            assert ahead == [0, 1]
+            twice += 1
+        else:
+            assert [p.name for p in spans] == launch + collect
+            assert ahead == [1]
+        assert {p.parent for p in tick if p.name == "tick.ahead"} == {
+            "tick.dispatch"}
+        by_name = {p.name: p for p in spans}  # a tick's last launch
+        assert (by_name["tick.dispatch"].end_ns
+                <= by_name["tick.fetch"].start_ns)
         counts = [p for p in tick if p.parent == "tick.upload"]
-        assert [p.name for p in counts] == ["tick.transfers"]
-        upload = by_name["tick.upload"]
-        assert all(upload.start_ns <= p.start_ns <= upload.end_ns
-                   for p in counts)
-        sent.append(by_name["tick.transfers"].attrs["n"])
-    # seatings, grown blocks and completions send once; the other
-    # ticks, most of them, send nothing
+        assert [p.name for p in counts] == ["tick.transfers"] * len(ahead)
+        uploads = [p for p in spans if p.name == "tick.upload"]
+        assert all(u.start_ns <= p.start_ns <= u.end_ns
+                   for u, p in zip(uploads, counts))
+        sent += [p.attrs["n"] for p in counts]
+    # seatings, grown blocks and releases send once; the other
+    # launches, most of them, send nothing; a run starts with nothing
+    # in flight, and so may the tick after a lone lane's last launch
     assert set(sent) == {0, 1} and sent.count(0) > sent.count(1)
+    assert 1 <= twice <= 3
+    # the last tokens of a run come out of ticks that launch nothing:
+    # they fetch, commit and stream all the same
+    tails = [t for t in ticks.values()
+             if not any(p.name == "tick.dispatch" for p in t)
+             and any(p.name == "tick.fetch" for p in t)]
+    assert tails and all(
+        [p.name for p in sorted(t, key=lambda p: p.start_ns)
+         if p.parent == "tick" and p.name != "tick.admit"] == collect
+        for t in tails)
 
 
 @pytest.mark.parametrize("window,want", [(0, 4), (6, 3)],

@@ -133,7 +133,7 @@ def _served(seed=0, prompt_len=20, new=12):
     slot, _, _ = eng.insert(request)
     logits = []
     while eng.active_count():
-        pos, tok = int(eng._positions[slot]), int(eng._last_tokens[slot])
+        pos, tok = int(eng._positions[slot]), request.generated[-1]
         out, _ = eng.model.apply(
             dict(eng._exec_variables, cache={"pos": jnp.asarray(pos)}),
             {"tokens": jnp.asarray([[tok]])}, training=False, decode=True,
@@ -141,7 +141,9 @@ def _served(seed=0, prompt_len=20, new=12):
             paged={"pools": eng.kv.pools,
                    "table": jnp.asarray(eng.kv.tables[slot])[None]})
         logits.append(np.asarray(out[0, 0]))
-        eng.step()
+        # in line (launched and committed, nothing ahead of it): the
+        # book between two steps is that of the tokens committed
+        assert eng._launch() and eng._collect()
     after = tracing.recorder().counts()
     counts = {k: after[k] - before.get(k, 0) for k in after}
     return cfg, w, prompt, list(request.generated), logits, counts
