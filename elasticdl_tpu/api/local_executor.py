@@ -7,6 +7,8 @@ local and distributed runs share semantics) and the same jit-compiled Trainer
 (so "local" already means "all local TPU chips via the mesh").
 """
 
+import json
+
 import numpy as np
 
 from elasticdl_tpu.common.constants import Mode
@@ -16,6 +18,7 @@ from elasticdl_tpu.common.model_utils import resolve_dataset_fn
 from elasticdl_tpu.data.reader.data_reader_factory import create_data_reader
 from elasticdl_tpu.master.task_dispatcher import TaskDispatcher, TaskType
 from elasticdl_tpu.observability import tracing
+from elasticdl_tpu.observability.phase_watch import PhaseWatcher
 from elasticdl_tpu.training.metrics import MetricsAggregator
 from elasticdl_tpu.training.trainer import Trainer
 
@@ -162,6 +165,20 @@ class LocalExecutor(object):
         raise ValueError("No data configured")
 
     def train(self):
+        """The train loop, with a watcher of its open phases beside it
+        (observability/phase_watch.py: this process has no health
+        plane) and, at its end, what its phases cost in one line."""
+        watcher = PhaseWatcher().start()
+        try:
+            return self._train()
+        finally:
+            watcher.stop()
+            rec = tracing.recorder()
+            logger.info("train phases: %s; gc %s",
+                        json.dumps(rec.phase_snapshot(), sort_keys=True),
+                        json.dumps(rec.gc_pauses()))
+
+    def _train(self):
         dispatcher = self._make_dispatcher()
         reader = self._reader(self.training_data)
         eval_reader = (

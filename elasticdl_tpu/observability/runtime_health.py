@@ -80,6 +80,8 @@ import traceback
 from collections import deque
 
 from elasticdl_tpu.common.log_utils import default_logger as logger
+from elasticdl_tpu.observability import tracing
+from elasticdl_tpu.observability.phase_watch import PhaseWatcher
 
 HEALTH_DIR_ENV = "EDL_HEALTH_DIR"
 HEALTH_ENV = "EDL_RUNTIME_HEALTH"
@@ -635,6 +637,7 @@ class RuntimeHealth(object):
             clock=clock,
         )
         self.recorder = FlightRecorder(capacity=ring_capacity)
+        self.phase_watch = PhaseWatcher(period_secs=check_secs, clock=clock)
         self.bundles = []  # paths written (drill/status introspection)
         self._bundle_seq = 0
         self._leak_checked = False
@@ -662,6 +665,7 @@ class RuntimeHealth(object):
     def _run(self):
         while not self._stop.is_set():
             try:
+                self.phase_watch.wake()
                 now = self._clock()
                 self.check(now)
                 if now - self._last_reconcile >= self.reconcile_secs:
@@ -669,7 +673,7 @@ class RuntimeHealth(object):
                     self._last_reconcile = now
             except Exception:  # noqa: BLE001 - the loop must survive
                 logger.exception("runtime health tick failed")
-            self._stop.wait(self.check_secs)
+            self.phase_watch.sleep(self._stop, self.check_secs)
 
     # ------------------------------------------------------- feeding
 
@@ -684,14 +688,16 @@ class RuntimeHealth(object):
             logger.exception("runtime health: rebase failed")
 
     def record_tick(self, queue_depth, active_slots, step_secs,
-                    tokens_committed):
+                    tokens_committed, kv=None):
         """One scheduler tick into the flight ring (scheduler thread).
-        KV occupancy is read engine-side so the ring shows the pool
-        the way the stalled step last saw it."""
-        try:
-            kv = self._engine.kv_stats()
-        except Exception:  # noqa: BLE001 - mid-teardown
-            kv = {}
+        `kv` is the `engine.kv_stats()` the tick has just read (the
+        ring shows the pool the way the stalled step last saw it);
+        a caller without one has it read here."""
+        if kv is None:
+            try:
+                kv = self._engine.kv_stats()
+            except Exception:  # noqa: BLE001 - mid-teardown
+                kv = {}
         self.recorder.record({
             "t": self._clock(),
             "queue_depth": int(queue_depth),
@@ -840,6 +846,10 @@ class RuntimeHealth(object):
             "memory": self.accountant.snapshot(),
             "recompiles": self.sentry.snapshot(),
             "stacks": _all_thread_stacks(),
+            # the phases open right now on every thread, and the slow
+            # phases kept with what lay beneath them (tracing.py)
+            "open_phases": tracing.open_phases(),
+            "slow_phases": tracing.recorder().slow_json(),
         }
         try:
             path = write_bundle(self.health_dir, bundle)

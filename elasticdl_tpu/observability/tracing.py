@@ -74,12 +74,62 @@ phase is recorded twice, on two clocks that agree:
   event of the calling thread in the xplane, beside the device's
   lines; with none it costs a flag check.
 
+THE RING IS COLUMNS (`SpanRecorder`, "phase ring"): an entry is six
+64-bit integers at one place of one private anonymous mapping (name,
+parent and two attribute keys packed into the first as indexes into
+`PHASES + COUNTERS` and a table of keys, then start_ns, end_ns, seq
+and the two attributes' values), written by `struct.pack_into` at one
+write position under the ring's lock. The mapping is made whole
+(``48 * phase_capacity`` bytes) and a page of it is the process's only
+once an entry is written there; nothing of it is an object, so the
+garbage collector walks none of it. At the default bound of 2^20
+entries (over 250 s of the fastest serving cell's 4,160 entries a
+second: a benchmark window, the end of its warm-up and its drain) a
+full ring is 48 MB, where 2^20 tuples with a dict each were 327 MB and
+117 ms of every full collection. Only a trace id, an attribute that is
+no 64-bit integer and a phase's third attribute go to a side table
+keyed by the entry's number. Entries are sealed as they end, so the end
+column is in order to within a lock's wait (`_disorder_ns` is the most
+it was ever out by) and `phases(since_ns, until_ns)` searches it;
+`phases()` without bounds hands back the list it last built while
+nothing has been sealed since.
+
 Every phase end also feeds that name's cumulative log-linear histogram
 (`phase_snapshot()`, the ``edl_serving_phase_ms{phase=}`` family): the
 ring drops its oldest, a cumulative family cannot. `count(name, n)`
 records work done where it happens (`COUNTERS`, closed too) as a
 zero-length entry of the same ring, so a reader can cut counts to a
 window like phases.
+
+SLOW PHASES AND WHAT LAY BENEATH THEM. One rule, in `_seal`: a phase
+that ends having lasted over 0.25 s of its own (less the slow phases
+already kept from inside it) and over three times its name's median so
+far (no median yet counts as 0) is SLOW; `idle`, `train.checkpoint`,
+`train.eval` and the names below are exempt. A slow phase, every ring
+entry inside its interval on any thread, and the watcher's samples of
+it go to a RETAINED tier of the phase store (`slow_phases()`; 256
+records, drop-oldest with ``slow_dropped``) that the ring's wrap
+cannot evict; it is logged once, in one line at warning level, and
+counted by name (`slow_counts()`, ``edl_serving_slow_phases_total``).
+What may lie beneath is recorded always, as phases of its own:
+
+* ``gc`` (`generation`, `collected`): `gc.callbacks`, from "start" to
+  "stop" on the thread that collected. A collection under 1 ms, or
+  one while no thread has a phase open (it lies beneath nothing),
+  writes no entry and feeds `gc_pauses()` (collections, total and
+  longest) only; a full collection is an ``edl/gc`` annotation too;
+* ``compile`` (`backend` 0 = lowering, 1 = backend compile or the
+  cache's read) and the counter ``compile.programs`` (lowerings): one
+  `jax.monitoring` duration listener, registered when this module
+  first sees jax; the phase is stamped from the duration it is handed,
+  when the compile ends, so it carries no annotation;
+* ``watch.sample`` and ``watch.late``: `observability/phase_watch.py`.
+  Every thread's stack of open phases is readable from any other
+  (`open_phases()`), so a watcher thread sees a phase that has not
+  ended: it samples the frames of a thread whose innermost open phase
+  is already slow (kept in the tier, not the ring) and stamps how late
+  it woke itself, with the CPU time the process used meanwhile (the
+  whole process stood still, not the device).
 
 Timestamps are ``time.time()`` (wall clock): spans from different
 processes must land on one timeline, which monotonic clocks cannot
@@ -90,14 +140,18 @@ process's spans.
 
 import atexit
 import collections
+import gc
 import json
+import mmap
 import os
 import random
+import struct
 import sys
 import threading
 import time
 from collections import deque
 
+from elasticdl_tpu.common.log_utils import default_logger as logger
 from elasticdl_tpu.observability.histogram import LogLinearHistogram
 
 TRACE_DIR_ENV = "EDL_TRACE_DIR"
@@ -107,9 +161,21 @@ _DEFAULT_CAPACITY = 4096
 #: smaller than the ring — retention is for the tail, not a second
 #: copy of everything
 _DEFAULT_RETAINED_CAPACITY = 2048
-#: the phase ring's bound: a 51 s window of 10 ticks/s x ~10 phases,
-#: its warm-up and its drain fit several times over
-_DEFAULT_PHASE_CAPACITY = 65536
+#: the phase ring's bound: over 250 s at the fastest serving cell's
+#: 4,160 entries a second (a 4.5 ms tick of 16 to 22 entries), so a
+#: 51 s window, the end of its warm-up and its drain fit with room for
+#: ticks twice as fast; 48 bytes an entry (module docstring, THE RING
+#: IS COLUMNS)
+_DEFAULT_PHASE_CAPACITY = 1 << 20
+#: the retained tier's bound, in slow phases, and the most ring
+#: entries one of them keeps from beneath it (the rest are counted)
+_SLOW_CAPACITY = 256
+_SLOW_BENEATH = 1024
+#: the slow rule: over this long, and over this many medians of its name
+_SLOW_NS = 250 * 10**6
+_SLOW_MEDIANS = 3
+#: a collection shorter than this writes no ring entry
+_GC_ENTRY_NS = 10**6
 
 #: the closed set of phase names, declared once: `begin` raises on
 #: anything else. One line per loop, outermost first.
@@ -128,7 +194,15 @@ PHASES = (
     "train.task_report",
     # training/trainer.py train_step()
     "trainer.host_prepare", "trainer.dispatch", "trainer.post_tiers",
+    # what may lie beneath any of them (module docstring, SLOW PHASES):
+    # this module's gc callback and jax.monitoring listener, and
+    # observability/phase_watch.py
+    "gc", "compile", "watch.sample", "watch.late",
 )
+#: never slow themselves: waits and work that is long by design, and
+#: the causes
+_SLOW_EXEMPT = ("idle", "train.checkpoint", "train.eval",
+                "gc", "compile", "watch.sample", "watch.late")
 #: the closed set of `count` names
 COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             "prompts_prefilled",
@@ -183,10 +257,76 @@ COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             # (serving/exec_weights.py). bf16 compute over fp32
             # weights: exec / source is about 0.5
             "weights.source_bytes", "weights.exec_bytes",
-            "weights.leaves_cast", "weights.leaves_kept")
+            "weights.leaves_cast", "weights.leaves_kept",
+            # this module's jax.monitoring listener: programs lowered
+            # (every jit miss lowers, whether or not the persistent
+            # cache then has the executable), each inside the `compile`
+            # phase of its lowering
+            "compile.programs")
 
 Phase = collections.namedtuple(
     "Phase", "name start_ns end_ns seq parent trace_id attrs")
+
+# how the ring's columns hold an entry (SpanRecorder._seal): a name or a
+# parent is its index here, an integer attribute the index of its key
+_NAMES = PHASES + COUNTERS
+_NAME_INDEX = {name: i for i, name in enumerate(_NAMES)}
+_NO_KEY = 255  # no parent; no attribute in this slot
+_NO_SEQ = -(1 << 63)  # seq is None
+_INT_MAX = 1 << 62
+_KEYS = ["n", "active", "queue_depth", "prompt_tokens", "bucket", "blocks",
+         "slot", "version", "generation", "collected", "backend", "cpu_ms"]
+_KEY_INDEX = {key: i for i, key in enumerate(_KEYS)}
+_keys_lock = threading.Lock()
+_NO_KEYS = _NO_KEY << 16 | _NO_KEY << 24  # packed: neither slot used
+_COUNT_KEYS = _KEY_INDEX["n"] << 16 | _NO_KEY << 24  # packed: {"n": v0}
+_PACK = struct.Struct("<6q").pack_into
+
+
+def _intern_key(key):
+    """The index of an attribute key seen for the first time, or
+    `_NO_KEY` when the column's 255 are taken."""
+    with _keys_lock:
+        if key not in _KEY_INDEX and len(_KEYS) < _NO_KEY:
+            _KEYS.append(key)
+            _KEY_INDEX[key] = len(_KEYS) - 1
+        return _KEY_INDEX.get(key, _NO_KEY)
+
+
+def _build(columns, number, side, since_ns, until_ns):
+    """`Phase` tuples from the copied columns (`SpanRecorder._columns`),
+    those that overlap [since_ns, until_ns]; `number` is the first
+    entry's, for the side tables. The collector is held off meanwhile:
+    a million new tuples would start it a thousand times."""
+    names, keys, new = _NAMES, _KEYS, tuple.__new__
+    out = []
+    collecting = gc.isenabled()
+    gc.disable()
+    try:
+        for meta, start, end, seq, v0, v1 in zip(*columns):
+            if ((since_ns is None or end >= since_ns)
+                    and (until_ns is None or start <= until_ns)):
+                pi, k0, k1 = meta >> 8 & 255, meta >> 16 & 255, meta >> 24
+                if k0 == _NO_KEY:
+                    attrs = {}
+                elif k1 == _NO_KEY:
+                    attrs = {keys[k0]: v0}
+                else:
+                    attrs = {keys[k0]: v0, keys[k1]: v1}
+                trace_id = ""
+                if side is not None and number in side:
+                    trace_id, extra = side[number]
+                    if extra:
+                        attrs.update(extra)
+                out.append(new(Phase, (
+                    names[meta & 255], start, end,
+                    None if seq == _NO_SEQ else seq,
+                    "" if pi == _NO_KEY else names[pi], trace_id, attrs)))
+            number += 1
+    finally:
+        if collecting:
+            gc.enable()
+    return out
 
 
 def new_trace_id():
@@ -296,40 +436,223 @@ class SpanRecorder(object):
         self._retained_traces = set()
         self._classifiers = []
         self._rand = random.Random(seed)
-        # the phase ring (module docstring, PHASE SPANS): its own
-        # bound, lock and drop count, so neither ring evicts the other
-        self.phase_capacity = int(phase_capacity)
-        self.phases_dropped = 0
-        self._phase_lock = threading.Lock()
-        self._phases = deque(maxlen=self.phase_capacity)
-        self._phase_hists = {p: LogLinearHistogram() for p in PHASES}
-        self._counts = dict.fromkeys(COUNTERS, 0)
+        # the phase ring (module docstring, THE RING IS COLUMNS): its
+        # own bound, lock and drop count, so neither ring evicts the
+        # other. The lock is re-entrant only so that the gc callback
+        # can tell when its own thread holds it (`_gc_pause`)
+        self.phase_capacity = max(1, int(phase_capacity))
+        self._phase_lock = threading.RLock()
+        self._reset_phases()
 
     # ------------------------------------------------------- phase ring
 
-    def _record_phase(self, record):
-        """Seal one phase or count: one short critical section."""
+    def _reset_phases(self):
+        # the columns, interleaved: entry i is six 64-bit ints at byte
+        # 48 * i = packed name / parent / two attribute keys, start_ns,
+        # end_ns, seq, two attribute values. Mapped whole and private;
+        # a page is the process's only once an entry is written to it
+        self._ring = mmap.mmap(
+            -1, 48 * self.phase_capacity,
+            flags=mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS)
+        self._appended = 0  # entries ever sealed: the next one's number
+        # by entry number: (trace id, what the columns cannot hold)
+        self._side = {}
+        # how far out of order the end column ever was: what a search
+        # by end has to allow for (`phases`)
+        self._max_end = self._disorder_ns = 0
+        self._built = (-1, None)  # (entries sealed, the list built then)
+        self._phase_hists = {p: LogLinearHistogram() for p in PHASES}
+        self._counts = dict.fromkeys(COUNTERS, 0)
+        # the retained tier of slow phases, and the watcher's samples of
+        # phases that have not ended yet
+        self.slow_dropped = 0
+        self._slow = deque(maxlen=_SLOW_CAPACITY)
+        self._slow_counts = {}
+        self._samples = deque(maxlen=_SLOW_CAPACITY)
+        self._gc = [0, 0, 0]  # collections, their ns, the longest's
+        self._gc_deferred = deque()
+
+    @property
+    def phases_dropped(self):
+        """Entries the ring's wrap has overwritten."""
+        return max(0, self._appended - self.phase_capacity)
+
+    def _held(self):
+        """Entries the ring holds."""
+        return min(self._appended, self.phase_capacity)
+
+    def _beside(self, trace_id, extra):
+        """Keep, for the entry about to be written, what the columns
+        cannot hold; what the wrap has left behind goes first."""
+        side, gone = self._side, self._appended - self.phase_capacity
+        while side:  # numbers only grow, so the oldest is the first
+            oldest = next(iter(side))
+            if oldest >= gone:
+                break
+            del side[oldest]
+        side[self._appended] = (trace_id, extra)
+
+    def _seal_count(self, name, ni, now_ns, seq, pi, n):
+        """Seal one count: the short critical section of `count`."""
+        if seq is None:
+            seq = _NO_SEQ
         with self._phase_lock:
-            if len(self._phases) == self.phase_capacity:
-                self.phases_dropped += 1
-            self._phases.append(record)
-            hist = self._phase_hists.get(record[0])
-            if hist is not None:
-                hist.record((record[2] - record[1]) * 1e-6)
+            at = 48 * (self._appended % self.phase_capacity)
+            try:
+                _PACK(self._ring, at, ni | pi << 8 | _COUNT_KEYS, now_ns,
+                      now_ns, seq, n, 0)
+            except struct.error:  # `n` is no 64-bit integer
+                self._beside("", {"n": n})
+                _PACK(self._ring, at, ni | pi << 8 | _NO_KEYS, now_ns,
+                      now_ns, seq, 0, 0)
+            self._appended += 1
+            if now_ns < self._max_end:
+                self._disorder_ns = max(self._disorder_ns,
+                                        self._max_end - now_ns)
             else:
-                self._counts[record[0]] += record[6]["n"]
+                self._max_end = now_ns
+            self._counts[name] += n
+
+    def _seal(self, name, ni, start_ns, end_ns, seq, parent, pi, trace_id,
+              attrs, own_ns=0):
+        """Seal one phase (`ni`, `pi`: the indexes of `name` and
+        `parent`): one short critical section. With `own_ns` (the
+        phase's length less its slow children's, when that is over the
+        slow rule's floor) the rule is applied, and True is returned
+        if the phase was kept as slow."""
+        if self._gc_deferred:
+            self._flush_gc()
+        meta = ni | pi << 8 | _NO_KEYS
+        v0 = v1 = 0
+        extra = None
+        if attrs:
+            k0 = k1 = _NO_KEY
+            for key, value in attrs.items():
+                ki = _KEY_INDEX.get(key)
+                if ki is None:
+                    ki = _intern_key(key)
+                if (type(value) is not int or ki == _NO_KEY
+                        or not -_INT_MAX < value < _INT_MAX):
+                    if extra is None:
+                        extra = {}
+                    extra[key] = value
+                elif k0 == _NO_KEY:
+                    k0, v0 = ki, value
+                elif k1 == _NO_KEY:
+                    k1, v1 = ki, value
+                else:
+                    if extra is None:
+                        extra = {}
+                    extra[key] = value
+            meta = ni | pi << 8 | k0 << 16 | k1 << 24
+        if seq is None:
+            seq = _NO_SEQ
+        median = None
+        with self._phase_lock:
+            if trace_id or extra is not None:
+                self._beside(trace_id, extra)
+            _PACK(self._ring, 48 * (self._appended % self.phase_capacity),
+                  meta, start_ns, end_ns, seq, v0, v1)
+            self._appended += 1
+            if end_ns < self._max_end:
+                self._disorder_ns = max(self._disorder_ns,
+                                        self._max_end - end_ns)
+            else:
+                self._max_end = end_ns
+            hist = self._phase_hists[name]
+            if own_ns:
+                median = hist.percentile(50)
+            hist.record((end_ns - start_ns) * 1e-6)
+        if median is None:
+            return False
+        if name in _SLOW_EXEMPT or own_ns <= _SLOW_MEDIANS * median * 1e6:
+            return False
+        self._keep_slow(
+            Phase(name, start_ns, end_ns, None if seq == _NO_SEQ else seq,
+                  parent, trace_id, attrs or {}), own_ns, median)
+        return True
+
+    def is_slow(self, name, lasted_ns):
+        """The slow rule for a phase `name` that has lasted `lasted_ns`
+        so far (the watcher asks it of phases still open)."""
+        if lasted_ns <= _SLOW_NS or name in _SLOW_EXEMPT:
+            return False
+        with self._phase_lock:
+            median = self._phase_hists[name].percentile(50)
+        return lasted_ns > _SLOW_MEDIANS * median * 1e6
+
+    def _first(self):
+        """Position of the oldest entry."""
+        return (self._appended % self.phase_capacity
+                if self._appended >= self.phase_capacity else 0)
+
+    def _search_end(self, x, after):
+        """Where a binary search puts `x` in the end column, as a
+        count of entries from the oldest (`after`: behind the ends
+        equal to it). The column is in order only to within
+        `_disorder_ns`, so what is sure is: every entry before that
+        place ends under x + `_disorder_ns`, and every one from it on
+        at or over x - `_disorder_ns`. `phases` widens its bounds by
+        as much and filters what it copies."""
+        first, cap = self._first(), self.phase_capacity
+        ends = memoryview(self._ring).cast("q")[2::6]
+        lo, hi = 0, self._held()
+        while lo < hi:
+            mid = (lo + hi) // 2
+            e = ends[(first + mid) % cap]
+            if e < x or (after and e == x):
+                lo = mid + 1
+            else:
+                hi = mid
+        return lo
+
+    def _columns(self, lo, hi):
+        """The entries `lo` to `hi` (counted from the oldest) as six
+        lists, copied: the caller builds from them outside the lock."""
+        ring, cap = self._ring, self.phase_capacity
+        a, b = self._first() + lo, self._first() + hi
+        if b <= cap:
+            part = ring[48 * a:48 * b]
+        elif a >= cap:
+            part = ring[48 * (a - cap):48 * (b - cap)]
+        else:
+            part = ring[48 * a:48 * cap] + ring[:48 * (b - cap)]
+        part = memoryview(part).cast("q")
+        return [part[field::6].tolist() for field in range(6)]
 
     def phases(self, since_ns=None, until_ns=None):
         """The raw ring, oldest first, as `Phase` tuples (counts are
         zero-length entries whose attrs hold ``n``); with bounds, the
-        entries that overlap [since_ns, until_ns]."""
+        entries that overlap [since_ns, until_ns], found by a search of
+        the end column and not by building the ring. Without bounds,
+        and with nothing sealed since the last such call, the list
+        built then: do not change it."""
+        bounded = since_ns is not None or until_ns is not None
         with self._phase_lock:
-            raw = list(self._phases)
-        return [
-            Phase(*r) for r in raw
-            if (since_ns is None or r[2] >= since_ns)
-            and (until_ns is None or r[1] <= until_ns)
-        ]
+            sealed, built = self._built
+            if not bounded and sealed == self._appended:
+                return built
+            lo, hi = 0, self._held()
+            if since_ns is not None:
+                lo = self._search_end(since_ns - self._disorder_ns, False)
+            if until_ns is not None:
+                # an entry that starts by `until_ns` ends no later than
+                # that plus the longest phase there ever was (the
+                # histograms keep each name's, in ms)
+                longest = 1000 + int(1e6 * max(
+                    h.max for h in self._phase_hists.values()))
+                hi = max(lo, self._search_end(
+                    until_ns + longest + self._disorder_ns, True))
+            columns = self._columns(lo, hi)
+            sealed = self._appended
+            number = sealed - self._held() + lo  # of the first copied
+            side = dict(self._side) if self._side else None
+        out = _build(columns, number, side, since_ns, until_ns)
+        if not bounded:
+            with self._phase_lock:
+                if sealed >= self._built[0]:
+                    self._built = (sealed, out)
+        return out
 
     def counts(self):
         """{counter: cumulative total} since start (or clear)."""
@@ -362,10 +685,144 @@ class SpanRecorder(object):
 
     def clear_phases(self):
         with self._phase_lock:
-            self._phases.clear()
-            self.phases_dropped = 0
-            self._phase_hists = {p: LogLinearHistogram() for p in PHASES}
-            self._counts = dict.fromkeys(COUNTERS, 0)
+            self._reset_phases()
+
+    # ------------------------------------- slow phases, and beneath them
+
+    def _keep_slow(self, ph, own_ns, median_ms):
+        """Move the slow phase `ph`, every ring entry inside its
+        interval (they were sealed before it, so they are the ring's
+        newest) and the watcher's samples of it into the retained
+        tier, and say so in one line."""
+        beneath, cut = [], 0
+        sums = {"gc": 0, "compile": 0, "watch.late": 0}
+        programs = late_cpu = 0
+        with self._phase_lock:
+            samples = [s for s in self._samples
+                       if s.parent == ph.name
+                       and s.attrs["phase_start_ns"] == ph.start_ns]
+            for s in samples:
+                self._samples.remove(s)
+        for p in reversed(self.phases(ph.start_ns, ph.end_ns)):
+            if (p.start_ns < ph.start_ns or p.end_ns > ph.end_ns
+                    or p[:3] == ph[:3]):
+                continue  # reaches out of the interval, or is `ph`
+            if p.name in sums:
+                sums[p.name] += p.end_ns - p.start_ns
+                late_cpu += p.attrs.get("cpu_ms", 0)
+            elif p.name == "compile.programs":
+                programs += p.attrs.get("n", 0)
+            if len(beneath) < _SLOW_BENEATH:
+                beneath.append(p)
+            else:
+                cut += 1
+        beneath.reverse()
+        lasted = ph.end_ns - ph.start_ns
+        line = (
+            "slow phase %s seq %s: %.0f ms (%smedian %.3g); gc %.0f ms; "
+            "compile %.0f ms, %d programs; watcher late %.0f ms (cpu %d); "
+            "at %s" % (
+                ph.name, ph.seq, lasted * 1e-6,
+                "" if own_ns == lasted else "%.0f its own, " % (own_ns * 1e-6),
+                median_ms, sums["gc"] * 1e-6, sums["compile"] * 1e-6,
+                programs, sums["watch.late"] * 1e-6, late_cpu,
+                " <- ".join(samples[-1].attrs["frames"][:4])
+                if samples else "(no sample)"))
+        record = {
+            "phase": ph, "own_ms": own_ns * 1e-6, "median_ms": median_ms,
+            "gc_ms": sums["gc"] * 1e-6, "compile_ms": sums["compile"] * 1e-6,
+            "compile_programs": programs,
+            "late_ms": sums["watch.late"] * 1e-6, "late_cpu_ms": late_cpu,
+            "beneath": beneath, "beneath_cut": cut, "samples": samples,
+            "line": line,
+        }
+        with self._phase_lock:
+            if len(self._slow) == _SLOW_CAPACITY:
+                self.slow_dropped += 1
+            self._slow.append(record)
+            self._slow_counts[ph.name] = (
+                self._slow_counts.get(ph.name, 0) + 1)
+        logger.warning(line)
+
+    def slow_phases(self):
+        """The retained tier, oldest first: one dict a slow phase
+        (`phase`, `own_ms`, `median_ms`, `gc_ms`, `compile_ms`,
+        `compile_programs`, `late_ms`, `beneath` = the ring's entries
+        inside it, `beneath_cut` = how many more there were,
+        `samples` = the watcher's, `line` = what was logged)."""
+        with self._phase_lock:
+            return list(self._slow)
+
+    def slow_json(self):
+        """The retained tier and the samples of phases still open with
+        their tuples as dicts: what `export()` and the health bundle
+        carry."""
+        def plain(record):
+            return dict(
+                record, phase=record["phase"]._asdict(),
+                beneath=[p._asdict() for p in record["beneath"]],
+                samples=[p._asdict() for p in record["samples"]])
+
+        with self._phase_lock:
+            return {
+                "dropped": self.slow_dropped,
+                "slow": [plain(r) for r in self._slow],
+                "open_samples": [p._asdict() for p in self._samples],
+            }
+
+    def slow_counts(self):
+        """{phase name: slow phases so far}, cumulative."""
+        with self._phase_lock:
+            return dict(self._slow_counts)
+
+    def watch_samples(self):
+        """The watcher's samples of phases that have not ended (those
+        of an ended slow phase are in its record)."""
+        with self._phase_lock:
+            return list(self._samples)
+
+    def _note_sample(self, sample):
+        with self._phase_lock:
+            self._samples.append(sample)
+
+    def gc_pauses(self):
+        """{collections, total_ms, longest_ms} of every garbage
+        collection since start (or clear), short ones included."""
+        n, ns, longest = self._gc
+        return {"collections": n, "total_ms": ns * 1e-6,
+                "longest_ms": longest * 1e-6}
+
+    def _gc_pause(self, start_ns, end_ns, seq, parent, info, beneath):
+        """One collection (the gc callback, on the thread that
+        collected; collections do not overlap). Only one of 1 ms or
+        more that lies `beneath` something (some thread has a phase
+        open) is a ring entry, and where this thread already holds
+        the ring's lock (the collection began inside `_seal`) the
+        entry waits for the next seal."""
+        dur = end_ns - start_ns
+        tally = self._gc
+        tally[0] += 1
+        tally[1] += dur
+        if dur > tally[2]:
+            tally[2] = dur
+        if dur < _GC_ENTRY_NS or not beneath:
+            return
+        entry = ("gc", _NAME_INDEX["gc"], start_ns, end_ns, seq, parent,
+                 _NAME_INDEX[parent] if parent else _NO_KEY, "",
+                 {"generation": info.get("generation", -1),
+                  "collected": info.get("collected", 0)})
+        if self._phase_lock._is_owned():
+            self._gc_deferred.append(entry)
+        else:
+            self._seal(*entry)
+
+    def _flush_gc(self):
+        while True:
+            try:
+                entry = self._gc_deferred.popleft()
+            except IndexError:
+                return
+            self._seal(*entry)
 
     # ---------------------------------------------------- request spans
 
@@ -504,6 +961,7 @@ class SpanRecorder(object):
             "phases_dropped": phases_dropped,
             "phases": [dict(p._asdict(), service=self.service)
                        for p in self.phases()],
+            "slow_phases": self.slow_json(),
         }
 
     def write(self, path):
@@ -565,21 +1023,32 @@ def configure(service=None, capacity=None):
 
 _LABELS = {p: "edl/" + p for p in PHASES}  # the xplane's event names
 _COUNTERS = frozenset(COUNTERS)
+_PHASE_INDEX = {name: _NAME_INDEX[name] for name in PHASES}
+_COUNTER_INDEX = {name: _NAME_INDEX[name] for name in COUNTERS}
 _tls = threading.local()
 _annotation = None  # jax.profiler.TraceAnnotation, once jax is imported
+# every thread's stack of open phases, where another thread can read
+# it: {thread ident: (thread name, the stack `_tls.stack` also names)}
+_stacks = {}
+_stacks_lock = threading.Lock()
 
 
 class _OpenPhase(object):
     """A phase between `begin` and `end`; also the context manager
-    `phase()` returns."""
+    `phase()` returns. `slow_ns` is the time of the slow phases already
+    kept from inside it, `sample_at_ns` how long it has to have been
+    open for the watcher's next sample of it."""
 
-    __slots__ = ("name", "seq", "parent", "trace_id", "attrs",
-                 "start_ns", "_ann", "_stack")
+    __slots__ = ("name", "ni", "seq", "parent", "pi", "trace_id", "attrs",
+                 "start_ns", "slow_ns", "sample_at_ns", "_ann", "_stack")
 
-    def __init__(self, name, seq, parent, trace_id, attrs, stack):
-        self.name, self.seq, self.parent = name, seq, parent
+    def __init__(self, name, ni, seq, parent, pi, trace_id, attrs, stack):
+        self.name, self.ni, self.seq = name, ni, seq
+        self.parent, self.pi = parent, pi
         self.trace_id, self.attrs, self._stack = trace_id, attrs, stack
         self._ann = None
+        # start_ns comes with the start; slow_ns and sample_at_ns only
+        # once the phase is slow (`getattr` with a default reads them)
 
     def __enter__(self):
         return self
@@ -589,32 +1058,132 @@ class _OpenPhase(object):
         return False
 
 
+def _my_stack():
+    stack = _tls.stack = []
+    me = threading.current_thread()
+    with _stacks_lock:
+        _stacks[me.ident] = (me.name, stack)
+    return stack
+
+
+def open_threads():
+    """[(thread ident, thread name, [open phases, outermost first])] of
+    the live threads that have a phase open: what `open_phases` and the
+    watcher read. The phases are the live objects: read, do not write."""
+    alive = {t.ident for t in threading.enumerate()}
+    with _stacks_lock:
+        for ident in [i for i in _stacks if i not in alive]:
+            del _stacks[ident]
+        threads = list(_stacks.items())
+    out = []
+    for ident, (name, stack) in threads:
+        opened = [ph for ph in list(stack) if getattr(ph, "start_ns", 0)]
+        if opened:
+            out.append((ident, name, opened))
+    return out
+
+
+def open_phases():
+    """{thread name: [(name, seq, start_ns), ...]} of the phases open
+    right now, outermost first, on every thread that has one: a phase
+    that never ends is in here and nowhere else."""
+    return {
+        name: [(ph.name, ph.seq, ph.start_ns) for ph in opened]
+        for _ident, name, opened in open_threads()
+    }
+
+
+def _see_jax():
+    """Once jax is imported: its annotation for `begin`, and the one
+    listener that stamps `compile` and counts `compile.programs`."""
+    global _annotation
+    jax = sys.modules["jax"]
+    if not hasattr(jax, "profiler") or not hasattr(jax, "monitoring"):
+        return  # jax is still being imported
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _annotation = jax.profiler.TraceAnnotation
+
+
+#: the two stages of a jit miss (jax._src.dispatch); the first is the
+#: event `chipbench.probes.CompileCounter` counts
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": 0,
+    "/jax/core/compile/backend_compile_duration": 1,
+}
+
+
+def _on_jax_duration(event, duration_secs, **_):
+    backend = _COMPILE_EVENTS.get(event)
+    if backend is None:
+        return
+    now = time.time_ns()
+    stamp("compile", now - int(duration_secs * 1e9), now, backend=backend)
+    if not backend:
+        count("compile.programs")
+
+
+_gc_open = None  # (start_ns, annotation or None) of the running collection
+
+
+def _on_gc(when, info):
+    """`gc.callbacks`: a collection as the phase `gc` on the thread
+    that collected. Only a full collection is annotated: the length is
+    not known at its start, and a young one is over in microseconds."""
+    global _gc_open
+    if when == "start":
+        ann = None
+        if (info.get("generation") == 2 and _annotation is not None
+                and _annotation.is_enabled()):
+            ann = _annotation(_LABELS["gc"])
+            ann.__enter__()
+        _gc_open = (time.time_ns(), ann)
+    elif _gc_open is not None:
+        end_ns = time.time_ns()
+        (start_ns, ann), _gc_open = _gc_open, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        stack = getattr(_tls, "stack", None)
+        top = stack[-1] if stack else None
+        # (a test may have put another object in the recorder's place)
+        pause = getattr(_RECORDER, "_gc_pause", None)
+        if pause is not None:
+            pause(start_ns, end_ns, top.seq if top else None,
+                  top.name if top else "", info,
+                  any(stack for _name, stack in list(_stacks.values())))
+
+
+gc.callbacks.append(_on_gc)
+atexit.register(gc.callbacks.remove, _on_gc)
+if "jax" in sys.modules:
+    _see_jax()
+
+
 def begin(name, seq=None, trace_id="", **attrs):
     """Open the phase `name` on this thread; close it with `end`.
     `seq` (the tick or step number) and `trace_id` (the request's,
     where the phase serves one request) are inherited from the
     enclosing phase when not given."""
-    global _annotation
-    label = _LABELS.get(name)
-    if label is None:
+    ni = _PHASE_INDEX.get(name)
+    if ni is None:
         raise ValueError(
             "unknown phase %r (declared: %s)" % (name, ", ".join(PHASES))
         )
     stack = getattr(_tls, "stack", None)
     if stack is None:
-        stack = _tls.stack = []
+        stack = _my_stack()
     if stack:
         top = stack[-1]
-        ph = _OpenPhase(name, top.seq if seq is None else seq, top.name,
-                        trace_id or top.trace_id, attrs, stack)
+        ph = _OpenPhase(name, ni, top.seq if seq is None else seq,
+                        top.name, top.ni, trace_id or top.trace_id, attrs,
+                        stack)
     else:
-        ph = _OpenPhase(name, seq, "", trace_id, attrs, stack)
+        ph = _OpenPhase(name, ni, seq, "", _NO_KEY, trace_id, attrs, stack)
     stack.append(ph)
     if _annotation is None and "jax" in sys.modules:
-        _annotation = sys.modules["jax"].profiler.TraceAnnotation
+        _see_jax()
     # with no profiler session the annotation is this flag check
     if _annotation is not None and _annotation.is_enabled():
-        ph._ann = _annotation(label)
+        ph._ann = _annotation(_LABELS[name])
         ph._ann.__enter__()
     ph.start_ns = time.time_ns()
     return ph
@@ -637,29 +1206,65 @@ def end(ph, **attrs):
             pass
     if attrs:
         ph.attrs.update(attrs)
-    _RECORDER._record_phase((ph.name, ph.start_ns, end_ns, ph.seq,
-                             ph.parent, ph.trace_id, ph.attrs))
+    lasted = end_ns - ph.start_ns
+    if lasted <= _SLOW_NS:
+        _RECORDER._seal(ph.name, ph.ni, ph.start_ns, end_ns, ph.seq,
+                        ph.parent, ph.pi, ph.trace_id, ph.attrs)
+        return
+    # the slow rule (module docstring): on the phase's own time, and
+    # what is kept, or was kept beneath it, is not its parent's own
+    beneath = getattr(ph, "slow_ns", 0)
+    own = lasted - beneath
+    kept = _RECORDER._seal(ph.name, ph.ni, ph.start_ns, end_ns, ph.seq,
+                           ph.parent, ph.pi, ph.trace_id, ph.attrs,
+                           own if own > _SLOW_NS else 0)
+    if stack:
+        # (a wait that is long by design is not its parent's own either)
+        if kept or ph.name in _SLOW_EXEMPT:
+            beneath = lasted
+        if beneath:
+            stack[-1].slow_ns = getattr(stack[-1], "slow_ns", 0) + beneath
 
 
 #: `with phase("tick.upload"): ...` — `begin` and `end` around a block
 phase = begin
 
 
+def stamp(name, start_ns, end_ns, **attrs):
+    """Seal a phase that has already happened (a length handed over by
+    whoever timed it), under this thread's innermost open phase."""
+    if name not in _PHASE_INDEX:
+        raise ValueError(
+            "unknown phase %r (declared: %s)" % (name, ", ".join(PHASES))
+        )
+    stack = getattr(_tls, "stack", None)
+    if stack:
+        top = stack[-1]
+        _RECORDER._seal(name, _PHASE_INDEX[name], start_ns, end_ns, top.seq,
+                        top.name, top.ni, "", attrs)
+    else:
+        _RECORDER._seal(name, _PHASE_INDEX[name], start_ns, end_ns, None,
+                        "", _NO_KEY, "", attrs)
+
+
 def count(name, n=1):
     """Count `n` units of work under the closed counter `name`, where
     the work happens."""
-    if name not in _COUNTERS:
+    ni = _COUNTER_INDEX.get(name)
+    if ni is None:
         raise ValueError(
             "unknown counter %r (declared: %s)"
             % (name, ", ".join(COUNTERS))
         )
+    if _annotation is None and "jax" in sys.modules:
+        _see_jax()
     stack = getattr(_tls, "stack", None)
-    top = stack[-1] if stack else None
     now = time.time_ns()
-    _RECORDER._record_phase((
-        name, now, now, top.seq if top else None,
-        top.name if top else "", "", {"n": n},
-    ))
+    if stack:
+        top = stack[-1]
+        _RECORDER._seal_count(name, ni, now, top.seq, top.ni, n)
+    else:
+        _RECORDER._seal_count(name, ni, now, None, _NO_KEY, n)
 
 
 # ------------------------------------------------------ chrome conversion

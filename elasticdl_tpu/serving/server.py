@@ -25,6 +25,7 @@ serving RPC names — overload and kill drills are spec-driven, e.g.
 """
 
 import collections
+import json
 import os
 import threading
 import time
@@ -438,7 +439,8 @@ class _Scheduler(threading.Thread):
                 )
                 if self.health is not None:
                     self.health.record_tick(
-                        len(self.queue), len(results), dt, committed
+                        len(self.queue), len(results), dt, committed,
+                        kv=kv,
                     )
         elif not self._pending_prefills:
             with tracing.phase("idle"):
@@ -1170,6 +1172,13 @@ class GenerationServer(object):
             "phase spans evicted from the bounded phase ring",
             [({}, recorder().phases_dropped)],
         ))
+        fams.append(labeled_counter_family(
+            "edl_serving_slow_phases_total",
+            "phases that lasted over 0.25 s and over three times their "
+            "name's median (tracing.py: kept with what lay beneath them)",
+            [({"phase": name}, n)
+             for name, n in sorted(recorder().slow_counts().items())],
+        ))
         if self.health is not None:
             # the per-fn recompile family (the scalar health gauges/
             # counters already ride the closed telemetry sets)
@@ -1233,6 +1242,10 @@ class GenerationServer(object):
             self.metrics.close()
             self.metrics = None
         self.telemetry.close()
+        # what the loop's phases cost, for a run nothing scraped
+        logger.info("serving phases: %s; gc %s",
+                    json.dumps(recorder().phase_snapshot(), sort_keys=True),
+                    json.dumps(recorder().gc_pauses()))
         # export this process's span ring when EDL_TRACE_DIR is set
         # (no-op otherwise) — the dump tool merges per-process files
         recorder().flush()

@@ -267,6 +267,399 @@ def test_a_tick_that_raises_still_seals_its_root():
     assert all(r.parent == "" for r in roots)
 
 
+# ------------------------------- the ring as columns, at its default bound
+
+
+def _seal(rec, name, start_ns, end_ns, seq, parent, trace_id, attrs):
+    """One entry with every field given, as `end` and `count` seal it."""
+    index = tracing._NAME_INDEX
+    pi = index[parent] if parent else tracing._NO_KEY
+    if name in tracing.COUNTERS:
+        rec._seal_count(name, index[name], start_ns, seq, pi, attrs["n"])
+    else:
+        rec._seal(name, index[name], start_ns, end_ns, seq, parent, pi,
+                  trace_id, attrs)
+
+
+def _fill(rec, entries, t0=10**18):
+    """`entries` of a decode loop's mix, a microsecond apart: one root
+    with its two attributes and fifteen counts a tick."""
+    for i in range(entries):
+        t = t0 + i * 1000
+        if i % 16 == 15:
+            _seal(rec, "tick", t - 15000, t, i // 16, "", "",
+                  {"active": 3, "queue_depth": 1})
+        else:
+            _seal(rec, "tick.ahead", t, t, i // 16, "tick.dispatch", "",
+                  {"n": 1})
+
+
+def test_the_ring_at_its_default_bound_holds_a_window_in_order(monkeypatch):
+    rec = SpanRecorder()
+    assert rec.phase_capacity == 2**20
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    for i in range(300000):
+        tracing.count("tick.transfers", i & 1)
+    got = rec.phases()
+    assert len(got) == 300000 and rec.phases_dropped == 0
+    assert [p.attrs["n"] for p in got[:4]] == [0, 1, 0, 1]
+    assert all(a.end_ns <= b.end_ns for a, b in zip(got, got[1:]))
+    assert rec.counts()["tick.transfers"] == 150000
+    # nothing was sealed since: the same list, not one built again
+    assert rec.phases() is got
+    tracing.count("tick.transfers")
+    again = rec.phases()
+    assert again is not got and len(again) == 300001
+
+
+def test_a_full_ring_is_small_and_nothing_the_collector_walks():
+    import gc
+    import tracemalloc
+
+    gc.collect()
+    before = len(gc.get_objects())
+    tracemalloc.start()
+    try:
+        rec = SpanRecorder()
+        _fill(rec, 2**20 + 1024)
+        held, _peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    gc.collect()
+    assert rec.phases_dropped == 1024
+    assert len(gc.get_objects()) - before < 10000
+    # the columns are a mapping of their own, which tracemalloc does
+    # not see: 48 bytes an entry
+    assert len(rec._ring) == 48 * 2**20
+    assert held + len(rec._ring) < 64 * 2**20
+    # and what it holds is still the mix that went in
+    newest = rec.phases(since_ns=10**18 + (2**20 + 1008) * 1000)
+    assert [p.name for p in newest] == ["tick.ahead"] * 15 + ["tick"]
+    assert newest[-1].attrs == {"active": 3, "queue_depth": 1}
+    assert newest[-1].seq == (2**20 + 1023) // 16
+    assert [p.attrs for p in newest[:2]] == [{"n": 1}] * 2
+
+
+def test_bounds_search_the_ring_and_equal_the_filtered_whole(monkeypatch):
+    """A small ring that has wrapped, entries of many lengths, some
+    sealed out of order (a stamped phase ends in the past): with
+    bounds, exactly what a filter of the whole list keeps."""
+    import random
+
+    rng = random.Random(7)
+    rec = SpanRecorder(phase_capacity=500)
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    t = 10**15
+    for i in range(1300):
+        t += rng.randrange(1, 2000)
+        lasted = rng.choice((0, 10, 500, 40000))
+        late = rng.choice((0, 0, 0, 3000))  # sealed after a younger one
+        _seal(rec, "tick.fetch", t - late - lasted, t - late, i, "tick", "",
+              {})
+    whole = rec.phases()
+    assert len(whole) == 500 and rec.phases_dropped == 800
+    assert rec._disorder_ns > 0
+    lo, hi = whole[0].end_ns, whole[-1].end_ns
+    for _ in range(200):
+        since = rng.randrange(lo - 5000, hi + 5000)
+        until = since + rng.choice((0, 1, 700, 90000))
+        for a, b in ((since, until), (since, None), (None, until)):
+            want = [p for p in whole
+                    if (a is None or p.end_ns >= a)
+                    and (b is None or p.start_ns <= b)]
+            assert rec.phases(since_ns=a, until_ns=b) == want
+    # a search, not a scan: a bound near the end copies a few entries
+    copied = []
+    columns = rec._columns
+    monkeypatch.setattr(
+        rec, "_columns",
+        lambda a, b: copied.append(b - a) or columns(a, b))
+    assert len(rec.phases(since_ns=whole[-3].end_ns)) >= 3
+    assert copied[0] < 60
+
+
+def test_attributes_the_columns_cannot_hold_go_beside_them():
+    rec = SpanRecorder(phase_capacity=4)
+    _seal(rec, "prefill", 1, 2, 0, "tick.admit", "t1",
+          {"prompt_tokens": 5, "bucket": 8, "shared": 3, "why": "x"})
+    _seal(rec, "tick.transfers", 3, 3, 0, "", "", {"n": 2.5})
+    _seal(rec, "reload_swap", 4, 5, None, "", "", {"version": 2**70})
+    got = rec.phases()
+    assert got[0].attrs == {"prompt_tokens": 5, "bucket": 8, "shared": 3,
+                            "why": "x"}
+    assert got[0].trace_id == "t1" and got[1].trace_id == ""
+    assert got[1].attrs == {"n": 2.5} and rec.counts()[
+        "tick.transfers"] == 2.5
+    assert got[2].attrs == {"version": 2**70} and got[2].seq is None
+    for i in range(4):  # what the wrap leaves behind is not handed out
+        _seal(rec, "idle", 10 + i, 11 + i, i, "", "", {})
+    assert [(p.attrs, p.trace_id) for p in rec.phases()] == [({}, "")] * 4
+
+
+# ------------------------------------- slow phases and what lay beneath them
+
+
+@pytest.fixture
+def warnings_logged(monkeypatch):
+    lines = []
+    monkeypatch.setattr(tracing.logger, "warning",
+                        lambda msg, *args: lines.append(msg % args))
+    return lines
+
+
+def test_a_slow_phase_is_kept_with_the_collection_and_the_compile_inside_it(
+        monkeypatch, warnings_logged):
+    import gc
+
+    import jax
+
+    rec = SpanRecorder()
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    for seq in range(20):
+        with tracing.phase("tick.fetch", seq=seq):
+            time.sleep(0.001)
+    assert rec.slow_phases() == [] and warnings_logged == []
+    with tracing.phase("tick", seq=20):
+        with tracing.phase("tick.fetch"):
+            time.sleep(0.3)
+            gc.collect()
+            jax.jit(lambda x: x * 3 + 1)(np.arange(7.0))
+    [kept] = rec.slow_phases()
+    assert kept["phase"].name == "tick.fetch" and kept["phase"].seq == 20
+    assert 300 <= kept["own_ms"] and 0.9 <= kept["median_ms"] <= 3.0
+    beneath = {}
+    for p in kept["beneath"]:
+        beneath.setdefault(p.name, []).append(p)
+        assert kept["phase"].start_ns <= p.start_ns
+        assert p.end_ns <= kept["phase"].end_ns
+    assert beneath["gc"][-1].parent == "tick.fetch"
+    assert beneath["gc"][-1].attrs["generation"] == 2
+    assert kept["gc_ms"] >= 1.0
+    # one program: a lowering and a backend compile, and the count
+    assert [p.attrs["backend"] for p in beneath["compile"]] == [0, 1]
+    assert [p.attrs["n"] for p in beneath["compile.programs"]] == [1]
+    assert kept["compile_programs"] == 1 and kept["compile_ms"] > 0
+    # the root took as long, but none of it was its own: one record,
+    # one line, one count
+    assert rec.slow_counts() == {"tick.fetch": 1}
+    assert len(warnings_logged) == 1
+    assert warnings_logged[0].startswith("slow phase tick.fetch seq 20: ")
+    assert "; gc " in warnings_logged[0] and "; compile " in warnings_logged[0]
+    assert warnings_logged[0] == kept["line"]
+    # the ring's wrap cannot evict it
+    _fill(rec, 2**20)
+    assert rec.phases_dropped > 0
+    assert not [p for p in rec.phases() if p.name == "tick.fetch"]
+    assert rec.slow_phases() == [kept] and rec.slow_dropped == 0
+    doc = rec.export()["slow_phases"]
+    assert doc == rec.slow_json() and doc["dropped"] == 0
+    assert doc["slow"][0]["phase"]["name"] == "tick.fetch"
+    assert doc["slow"][0]["line"] == kept["line"]
+    json.dumps(doc)
+
+
+def test_waits_and_the_causes_themselves_are_never_slow(
+        monkeypatch, warnings_logged):
+    rec = SpanRecorder()
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    now = time.time_ns()
+    for name in ("idle", "train.checkpoint", "train.eval"):
+        ph = tracing.begin(name)
+        ph.start_ns -= 10**9
+        tracing.end(ph)
+    tracing.stamp("compile", now - 2 * 10**9, now, backend=1)
+    tracing.stamp("watch.late", now - 10**9, now)
+    assert rec.slow_phases() == [] and warnings_logged == []
+    # nor is a wait its parent's own time
+    tick = tracing.begin("tick", seq=3)
+    idle = tracing.begin("idle")
+    idle.start_ns -= 10**9
+    tracing.end(idle)
+    tick.start_ns -= 10**9
+    tracing.end(tick)
+    assert rec.slow_phases() == []
+    assert not rec.is_slow("idle", 10**10)
+    # a name with no median yet has nothing to be three times of
+    assert rec.is_slow("train.task_get", 3 * 10**8)
+    assert not rec.is_slow("train.task_get", 2 * 10**8)
+    ph = tracing.begin("train.task_get")
+    ph.start_ns -= 4 * 10**8
+    tracing.end(ph)
+    assert [r["phase"].name for r in rec.slow_phases()] == ["train.task_get"]
+    assert "(no sample)" in warnings_logged[0]
+    # and one that is always this long is not slow either
+    for _ in range(5):
+        ph = tracing.begin("train.task_get")
+        ph.start_ns -= 4 * 10**8
+        tracing.end(ph)
+    assert len(rec.slow_phases()) == 1
+
+
+def test_the_retained_tier_is_bounded_and_counts_what_it_drops(
+        monkeypatch, warnings_logged):
+    rec = SpanRecorder()
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    with tracing.phase("tick.commit"):
+        pass
+    for seq in range(260):
+        ph = tracing.begin("tick.commit", seq=seq)
+        ph.start_ns -= 3 * 10**8
+        tracing.end(ph)
+        rec._phase_hists["tick.commit"] = type(
+            rec._phase_hists["tick.commit"])()
+        rec._phase_hists["tick.commit"].record(0.001)
+    assert len(rec.slow_phases()) == 256 and rec.slow_dropped == 4
+    assert rec.slow_phases()[0]["phase"].seq == 4
+    assert rec.slow_counts() == {"tick.commit": 260}
+    rec.clear_phases()
+    assert rec.slow_phases() == [] and rec.slow_counts() == {}
+
+
+def test_a_collection_that_starts_inside_the_rings_lock_waits_its_turn(
+        monkeypatch):
+    """The gc callback runs wherever a collection starts, and that may
+    be inside `_seal` with the ring's lock held by this very thread:
+    the entry is kept aside and sealed by the next seal."""
+    rec = SpanRecorder()
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    with tracing.phase("tick", seq=1):
+        with rec._phase_lock:
+            rec._gc_pause(10, 10 + 5 * 10**6, 1, "tick",
+                          {"generation": 2, "collected": 9}, True)
+            assert len(rec._gc_deferred) == 1
+        # short ones, and one beneath nothing, are tallied only
+        rec._gc_pause(20, 20 + 10**5, 1, "tick", {"generation": 0}, True)
+        rec._gc_pause(30, 30 + 10**7, None, "", {"generation": 2}, False)
+    got = rec.phases()
+    assert [p.name for p in got] == ["gc", "tick"]
+    assert got[0].attrs == {"generation": 2, "collected": 9}
+    assert got[0].parent == "tick" and got[0].seq == 1
+    pauses = rec.gc_pauses()
+    assert pauses["collections"] == 3
+    assert pauses["longest_ms"] == 10.0
+    assert pauses["total_ms"] == pytest.approx(15.1)
+    assert rec.phase_snapshot()["gc"]["count"] == 1
+
+
+def _sleeps_in_a_phase(opened, release):
+    with tracing.phase("tick", seq=41):
+        with tracing.phase("tick.fetch"):
+            opened.set()
+            release.wait(20)
+
+
+def test_an_open_phase_is_seen_and_sampled_from_another_thread(
+        monkeypatch, warnings_logged):
+    from elasticdl_tpu.observability.phase_watch import PhaseWatcher
+
+    rec = SpanRecorder()
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    with tracing.phase("tick.fetch"):
+        pass  # a median to be slow against
+    opened, release = threading.Event(), threading.Event()
+    worker = threading.Thread(target=_sleeps_in_a_phase, name="scheduler-x",
+                              args=(opened, release))
+    worker.start()
+    try:
+        assert opened.wait(10)
+        seen = tracing.open_phases()["scheduler-x"]
+        assert [(name, seq) for name, seq, _start in seen] == [
+            ("tick", 41), ("tick.fetch", 41)]
+        assert abs(seen[1][2] - time.time_ns()) < 5 * 10**9
+        assert "scheduler-x" not in {
+            p.parent for p in rec.phases()}  # nothing has ended
+        watcher = PhaseWatcher()
+        assert watcher.wake() is None  # not yet slow
+        time.sleep(0.3)
+        sample = watcher.wake()
+        assert sample.name == "watch.sample" and sample.parent == "tick.fetch"
+        assert sample.seq == 41 and sample.attrs["thread"] == "scheduler-x"
+        assert sample.attrs["open_ms"] >= 300
+        assert len(sample.attrs["frames"]) <= 10
+        assert any("_sleeps_in_a_phase" in f for f in sample.attrs["frames"])
+        assert sample.attrs["frames"][0].startswith("threading.py:")
+        assert rec.watch_samples() == [sample]
+        # at most one of a phase until it has been open twice as long
+        assert watcher.wake() is None
+        assert not [p for p in rec.phases() if p.name == "watch.sample"]
+    finally:
+        release.set()
+        worker.join(20)
+    assert not worker.is_alive()
+    assert "scheduler-x" not in tracing.open_phases()
+    [kept] = rec.slow_phases()
+    assert kept["phase"].name == "tick.fetch" and kept["samples"] == [sample]
+    assert rec.watch_samples() == []
+    assert "_sleeps_in_a_phase" in warnings_logged[0].split("; at ")[1]
+
+
+def test_watch_late_is_stamped_when_the_watcher_itself_wakes_late(
+        monkeypatch):
+    from elasticdl_tpu.observability.phase_watch import PhaseWatcher
+
+    rec = SpanRecorder()
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    now = [100.0]
+    watcher = PhaseWatcher(clock=lambda: now[0])
+    waited = threading.Event()
+    waited.set()  # the wait returns at once; the clock is stepped by hand
+    watcher.sleep(waited, 0.25)
+    now[0] += 0.30  # 50 ms over: a busy machine, not a standstill
+    watcher.wake()
+    assert rec.phases() == []
+    watcher.sleep(waited, 0.25)
+    now[0] += 0.25 + 1.5
+    watcher.wake()
+    [late] = rec.phases()
+    assert late.name == "watch.late"
+    assert late.end_ns - late.start_ns == pytest.approx(1.5e9, rel=1e-6)
+    assert 0 <= late.attrs["cpu_ms"] < 1000  # the process's, meanwhile
+    assert abs(late.end_ns - time.time_ns()) < 5 * 10**9
+    watcher.wake()  # no wait before it: nothing to be late for
+    assert len(rec.phases()) == 1
+
+
+def test_local_executor_train_runs_a_watcher_for_as_long_as_it_trains(
+        tmp_path, monkeypatch):
+    from elasticdl_tpu.api.local_executor import LocalExecutor
+    from elasticdl_tpu.common.model_utils import get_model_spec
+    from elasticdl_tpu.data import recordio_gen
+    from elasticdl_tpu.observability import phase_watch
+
+    train_dir = str(tmp_path / "train")
+    recordio_gen.gen_mnist_like(train_dir, num_files=1, records_per_file=32)
+    executor = LocalExecutor(
+        get_model_spec(
+            "model_zoo",
+            "mnist_functional_api.mnist_functional_api.custom_model"),
+        training_data=train_dir, minibatch_size=16, num_epochs=1,
+        records_per_task=32,
+    )
+    watching = []
+    step = executor.trainer.train_step
+
+    def stepped(*args, **kwargs):
+        watching.append([t.name for t in threading.enumerate()
+                         if t.name == "phase-watch" and t.is_alive()])
+        return step(*args, **kwargs)
+
+    executor.trainer.train_step = stepped
+    said = []
+    monkeypatch.setattr("elasticdl_tpu.api.local_executor.logger.info",
+                        lambda msg, *args: said.append(msg % args))
+    assert "phase-watch" not in [t.name for t in threading.enumerate()]
+    executor.run()
+    assert watching == [["phase-watch"]] * 2
+    assert "phase-watch" not in [t.name for t in threading.enumerate()]
+    [line] = [s for s in said if s.startswith("train phases: ")]
+    snap = json.loads(line[len("train phases: "):].split("; gc ")[0])
+    assert snap["train.step"]["count"] == 2
+    assert {"p50_ms", "p99_ms"} <= set(snap["train.step"])
+    # the first step compiled inside its phases: the trainer's counter
+    assert tracing.recorder().counts()["compile.programs"] >= 1
+    assert phase_watch.LATE_SECS == 0.1
+
+
 # ------------------------------------------- a tiny paged server, for real
 
 
@@ -577,3 +970,29 @@ def test_local_executor_train_yields_one_train_step_per_step(tmp_path):
                   key=lambda p: p.start_ns)
     for a, b in zip(loop, loop[1:]):
         assert a.end_ns <= b.start_ns
+
+
+def test_a_stopped_server_says_what_its_phases_cost(paged_server,
+                                                   monkeypatch):
+    """`GenerationServer.stop()` logs the cumulative snapshot in one
+    line (stop is safe to call twice; the fixture calls it again),
+    and /metrics counts slow phases by name."""
+    _trainer, _state, server = paged_server
+    _serve(server, SPECS[:1])
+    ph = tracing.begin("tick.commit", seq=10**6)
+    ph.start_ns -= 10**9
+    tracing.end(ph)
+    fams = parse_prometheus_text(
+        render_prometheus(server._metrics_families()))
+    slow = {lab["phase"]: v for _n, lab, v in
+            fams["edl_serving_slow_phases_total"]["samples"]}
+    assert slow["tick.commit"] == 1
+    said = []
+    monkeypatch.setattr("elasticdl_tpu.serving.server.logger.info",
+                        lambda msg, *args: said.append(msg % args))
+    server.stop()
+    [line] = [s for s in said if s.startswith("serving phases: ")]
+    snap = json.loads(line[len("serving phases: "):].split("; gc ")[0])
+    assert snap["tick.dispatch"]["count"] >= 4
+    assert snap["tick"]["p99_ms"] >= snap["tick"]["p50_ms"]
+    assert json.loads(line.split("; gc ")[1])["collections"] >= 0

@@ -356,6 +356,79 @@ def test_health_stall_transition_counts_and_dumps(tmp_path):
     assert len(glob.glob(str(tmp_path / "health-bundle-*.json"))) == 1
 
 
+def test_the_bundle_carries_the_open_phases_and_the_slow_ones(tmp_path):
+    """What the watchdog cannot say: which phase the stuck thread is
+    in (a phase is sealed only when it ends), what the watcher saw
+    there, and the slow phases kept before it came to this."""
+    from elasticdl_tpu.observability import tracing
+
+    health, engine, _queue, _telemetry, clock = build_health(tmp_path)
+    tracing.recorder().clear_phases()
+    slow = tracing.begin("tick.commit", seq=8)
+    slow.start_ns -= 10**9
+    tracing.end(slow)
+    tick = tracing.begin("tick", seq=9)
+    fetch = tracing.begin("tick.fetch")
+    fetch.start_ns -= 2 * 10**9  # two seconds into a fetch
+    try:
+        sample = health.phase_watch.wake()
+        assert sample.parent == "tick.fetch" and sample.seq == 9
+        engine.active = 1
+        health.check()
+        clock.advance(2.5)
+        assert health.check() is True
+    finally:
+        tracing.end(fetch)
+        tracing.end(tick)
+        tracing.recorder().clear_phases()
+    [path] = glob.glob(str(tmp_path / "health-bundle-*.json"))
+    with open(path) as f:
+        bundle = json.load(f)
+    assert validate_bundle(bundle) == []
+    [mine] = [v for v in bundle["open_phases"].values()
+              if [p[0] for p in v] == ["tick", "tick.fetch"]]
+    assert mine[1][1] == 9
+    kept = bundle["slow_phases"]
+    assert [r["phase"]["name"] for r in kept["slow"]] == ["tick.commit"]
+    assert kept["slow"][0]["line"].startswith("slow phase tick.commit seq 8")
+    [open_sample] = kept["open_samples"]
+    assert open_sample["name"] == "watch.sample"
+    assert any("test_the_bundle_carries" in f
+               for f in open_sample["attrs"]["frames"])
+
+
+def test_record_tick_takes_the_kv_stats_the_tick_has_read():
+    health, engine, _queue, _telemetry, _clock = build_health()
+    reads = []
+    stats = engine.kv_stats
+    engine.kv_stats = lambda: reads.append(1) or stats()
+    health.record_tick(2, 1, 0.01, 3, kv={"kv_blocks_free": 7,
+                                          "kv_bytes_in_use": 96})
+    assert reads == []
+    last = health.recorder.snapshot()[-1]
+    assert last["kv_blocks_free"] == 7 and last["kv_bytes_in_use"] == 96
+    assert last["kv_host_blocks"] == 0 and last["queue_depth"] == 2
+    health.record_tick(0, 1, 0.01, 3)  # a caller without one
+    assert reads == [1]
+
+
+def test_the_health_thread_wakes_the_watcher_every_check():
+    health, _engine, _queue, _telemetry, _clock = build_health()
+    health.check_secs = 0.01
+    woken = []
+    wake = health.phase_watch.wake
+    health.phase_watch.wake = lambda: woken.append(1) or wake()
+    health.start()
+    try:
+        deadline = time.time() + 10
+        while len(woken) < 3 and time.time() < deadline:
+            time.sleep(0.01)
+    finally:
+        health.stop()
+    assert len(woken) >= 3
+    assert health._thread is None
+
+
 def test_health_tokens_recover_the_state(tmp_path):
     health, engine, _queue, telemetry, clock = build_health(tmp_path)
     engine.active = 1
