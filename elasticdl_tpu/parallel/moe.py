@@ -432,7 +432,8 @@ def route_sigmoid_top_k(logits, k, bias, scale=1.0):
 
 def _held_choices(experts, first, count):
     """(local [T, k], held [T, k]): each choice's index among the
-    `count` experts held here from `first` on, and whether it is one."""
+    `count` experts held here from `first` on, and whether it is one.
+    A choice below 0 is NO choice (held_experts) and is held nowhere."""
     local = experts - first
     return local, (local >= 0) & (local < count)
 
@@ -470,7 +471,8 @@ def _grouped_tiles(h, gates, experts, first, *weights_and_use_kernel):
     tm = PREFILL_TILE_ROWS
     n_tiles = -(-t * k // tm) + count
     local, held = _held_choices(experts, first, count)
-    # pairs of experts held elsewhere sort behind every held expert
+    # pairs of experts held elsewhere, and pairs that are no choice
+    # (below 0), sort behind every held expert and are in no tile
     key = jnp.where(held, local, count).reshape(-1)
     order = jnp.argsort(key, stable=True)
     rank = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
@@ -558,7 +560,18 @@ def held_experts(h, gates, experts, weights, first=0, use_kernel=None):
     No choice is dropped and no expert is computed that no row chose;
     what the experts held elsewhere would add is left out (on one chip
     there is no exchange, and nothing stands in for one). With `first`
-    0 and all the experts it is the whole layer. The path is chosen
+    0 and all the experts it is the whole layer.
+
+    A CHOICE BELOW 0 IS NO CHOICE: the caller writes -1 over the
+    choices of a row that carries no sequence (a free lane of the
+    decode step), and such a pair is held nowhere: it makes no tile,
+    marks no expert in `hit`, is not in `held`, and adds exactly 0.0
+    to its row, whatever its gate. The tiles that are left keep their
+    order (hit experts by index), so every other row's `y` is bit for
+    bit what it would be had the row chosen like the others. With no
+    choice at all the kernel is handed `n_live` 0 and `y` is zeros.
+
+    The path is chosen
     from T (DECODE_ROWS). Under `jax.vmap` over rows with the weights
     shared (the serving step maps one lane a sequence) the lanes are
     laid side by side and computed as ONE call, so a tick reads a hit

@@ -345,7 +345,11 @@ class PagedContinuousBatchingEngine(object):
       contents never recompile. Attention streams the table
       (ops.paged_decode_attention); the new token's k/v rows come back
       sown through "kv_out" and scatter into the arenas — free lanes
-      carry an out-of-bounds block id and drop;
+      carry an out-of-bounds block id and drop. A lane at position 0
+      (free, or its prompt still being written) is handed to the model
+      as carrying no sequence (`paged["live"]`): an expert layer reads
+      no expert for its sake and counts it nowhere but in `moe.lanes`
+      (model_zoo/transformer_lm ExpertFFN);
     * evict returns the slot's blocks to the free list, O(1) per
       block — copy-free slot churn.
 
@@ -407,8 +411,9 @@ class PagedContinuousBatchingEngine(object):
     length; seating writes the blocks and, in one more launch, the
     state of every state layer; the step maps the state arenas over
     the lanes beside the lane state, so lane i reads and writes slot i
-    in place (donated like the row arenas); a free lane is updated
-    like a seated one and nothing reads what it holds; eviction costs
+    in place (donated like the row arenas); a free lane's STATE is
+    updated like a seated one's and nothing reads what it holds (its
+    expert layers, above, read nothing); eviction costs
     no device work, the next seating overwrites the slot. Blocks are
     charged for the attention layers' rows alone. What would need a
     SNAPSHOT of the state at some earlier position refuses to start
@@ -1306,7 +1311,8 @@ class PagedContinuousBatchingEngine(object):
         enter as device arrays, each seated lane attends over its own
         table and its row scatters into its own block. Free lanes ride
         along masked (stale tokens, all-(-1) tables, out-of-bounds
-        scatter ids): static shape, zero recompiles. Nothing here
+        scatter ids, no expert chosen): static shape, zero recompiles.
+        Nothing here
         waits for the device. Once the step is dispatched the book
         moves on with it: each lane is one position on, its token the
         device's to know (`_KEEP`), and a lane whose LAST token this
@@ -1568,7 +1574,10 @@ class PagedContinuousBatchingEngine(object):
                         {"tokens": tok[None, None]},
                         training=False, decode=True,
                         mutable=["cache", "kv_out", "counters"],
-                        paged={"pools": pools, "table": table[None]},
+                        # a lane at position 0 (free, or its prompt
+                        # still being written) carries no sequence
+                        paged={"pools": pools, "table": table[None],
+                               "live": (pos > 0)[None]},
                     )
                 nxt = serving_next_token(
                     logits[0, 0], seed, pos + 1, temp, top_k, top_p
@@ -1778,7 +1787,8 @@ class PagedContinuousBatchingEngine(object):
                         {"tokens": toks[None]},
                         training=False, decode=True,
                         mutable=["cache", "kv_out"],
-                        paged={"pools": pools, "table": table[None]},
+                        paged={"pools": pools, "table": table[None],
+                               "live": (pos > 0)[None]},
                     )  # logits [1, k+1, V]: row j predicts pos + j + 1
                 g = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
                 rows = jax.tree.map(

@@ -515,11 +515,25 @@ class ExpertFFN(nn.Module):
     `route_from` is what the router reads (a block with attention
     hands its own input, so that routing is known before attention
     runs; a layer that is the expert layer alone, the normed input the
-    experts multiply); `h` what the experts multiply. Where the caller
-    collects "counters" (the serving step) it is handed what the layer
-    did: `moe.pairs_routed`, `moe.pairs_held` (scalars, of this call's
-    rows) and `moe.experts_hit`, `moe.expert_slots` (a mark an expert
-    held)."""
+    experts multiply); `h` what the experts multiply.
+
+    `live` (bool [b] or [b * l]; None = every row, and then the layer
+    is built of the same operations as before the argument existed)
+    says which rows carry a sequence. A row that carries none MAKES NO
+    CHOICE: the router still runs over it, its choices are then
+    written over with -1 (parallel/moe.held_experts: no choice), so no
+    expert is read for its sake and its routed part is exactly 0; the
+    shared expert, a dense product of all rows, runs as ever. The
+    decode step hands in which lanes are seated; nobody reads what a
+    free lane computes.
+
+    Where the caller collects "counters" (the serving step) it is
+    handed what the layer did, of LIVE rows only: `moe.pairs_routed`,
+    `moe.pairs_held` (scalars: live rows x `top_k`, and those of them
+    held here), `moe.experts_hit`, `moe.expert_slots` (a mark an
+    expert held: chosen by a live row; held at all), and `moe.lanes`,
+    `moe.lanes_live` (scalars: this call's rows, and those that
+    chose)."""
 
     num_experts: int
     top_k: int
@@ -532,7 +546,7 @@ class ExpertFFN(nn.Module):
     shared_hidden: int = 0
 
     @nn.compact
-    def __call__(self, h, route_from, training=False):
+    def __call__(self, h, route_from, training=False, live=None):
         b, l, d = h.shape
         first, count = self.held or (0, self.num_experts)
         if first < 0 or count < 1 or first + count > self.num_experts:
@@ -578,6 +592,11 @@ class ExpertFFN(nn.Module):
                     self.route_scale)
             else:
                 gates, experts = route_top_k(logits, self.top_k)
+            if live is not None:
+                live = jnp.broadcast_to(
+                    jnp.asarray(live, bool).reshape(b, -1),
+                    (b, l)).reshape(b * l)
+                experts = jnp.where(live[:, None], experts, -1)
         rows = h.reshape(b * l, d).astype(dtype)
         with jax.named_scope("moe_experts"):
             # the Mosaic kernel has no backward: a training forward
@@ -600,15 +619,17 @@ class ExpertFFN(nn.Module):
                                 preferred_element_type=jnp.float32)
         if (self.is_mutable_collection("counters")
                 and not self.is_initializing()):
+            lanes_live = b * l if live is None else jnp.sum(live)
             counts = {
-                "moe.pairs_routed": jnp.asarray(b * l * self.top_k,
-                                                jnp.int32),
+                "moe.pairs_routed": lanes_live * self.top_k,
                 "moe.pairs_held": jnp.sum(held),
                 "moe.experts_hit": hit,
                 "moe.expert_slots": jnp.ones_like(hit),
+                "moe.lanes": b * l,
+                "moe.lanes_live": lanes_live,
             }
             for name, value in counts.items():
-                self.sow("counters", name, value)
+                self.sow("counters", name, jnp.asarray(value, jnp.int32))
         return y.reshape(b, l, d)
 
 
@@ -679,7 +700,8 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, training=False, decode=False, decode_pos=None,
                  prefill=False, segments=None, positions=None,
-                 paged=None, prompt_len=None):
+                 paged=None, prompt_len=None, live=None):
+        # `live`: which rows carry a sequence (ExpertFFN), None = all
         e = x.shape[-1]
         block_in = x
         y = _norm(self.norm, self.dtype, self.norm_eps)(x)
@@ -693,7 +715,8 @@ class Block(nn.Module):
                 y, decode=decode, prefill=prefill, prompt_len=prompt_len)
             return x + y.astype(x.dtype)
         if self.kind == "E":
-            return x + self._experts()(y, y, training).astype(x.dtype)
+            return x + self._experts()(
+                y, y, training, live=live).astype(x.dtype)
         x = x + self._attention()(
             y, training, decode=decode, decode_pos=decode_pos,
             prefill=prefill, segments=segments, positions=positions,
@@ -706,7 +729,7 @@ class Block(nn.Module):
                 % (self.kind,))
         y = _norm(self.norm, self.dtype, self.norm_eps)(x)
         if self.mlp == "moe_reglu":
-            y = self._experts()(y, block_in, training)
+            y = self._experts()(y, block_in, training, live=live)
             return x + y.astype(x.dtype)
         if self.mlp != "gelu":
             raise ValueError(
@@ -919,7 +942,10 @@ class TransformerLM(nn.Module):
         # pool — {"pools": tree mirroring this model's cache collection
         # with per-layer [num_blocks, block_size, hkv, d] arenas,
         # "table": [b, m] int32 block table}. Each block slices out its
-        # own layer's arenas below; see serving/kv_pool.py.
+        # own layer's arenas below; see serving/kv_pool.py. The decode
+        # step adds "live": [b] bool, which rows carry a sequence (a
+        # free lane does not): the expert layers read no expert for a
+        # row that carries none (ExpertFFN).
         tokens = features["tokens"]  # [b, seq_len]; [b, 1] when decode
         if decode and prefill:
             raise ValueError("decode and prefill are mutually exclusive")
@@ -1035,7 +1061,8 @@ class TransformerLM(nn.Module):
                 x = blk(x, training, decode=decode,
                         decode_pos=decode_pos, prefill=prefill,
                         segments=segments, positions=positions,
-                        paged=blk_paged, prompt_len=prompt_len)
+                        paged=blk_paged, prompt_len=prompt_len,
+                        live=paged.get("live") if paged else None)
         x = _norm(self.norm, self.dtype, self.norm_eps, name="ln_f")(x)
         head = LMHead(
             self.vocab_size, dtype=self.dtype, name="head",

@@ -278,8 +278,12 @@ def test_the_step_counts_state_updates_and_a_seating_writes_once():
     # and that is all a sequence is charged: ceil(21 / 4) blocks
     assert counts["prompt_write.launches"] == 6
     assert counts["pool.inplace_launches"] == counts["pool.launches"]
-    assert counts["moe.pairs_routed"] == ticks * 3 * 3 * 2
+    # two expert layers; of the three lanes the seated one chooses
+    assert counts["moe.pairs_routed"] == ticks * 1 * 3 * 2
+    assert counts["moe.lanes"] == ticks * 3 * 2
+    assert counts["moe.lanes_live"] == ticks * 1 * 2
     assert 0 < counts["moe.pairs_held"] < counts["moe.pairs_routed"]
+    assert 0 < counts["moe.experts_hit"] <= ticks * 3 * 2
     stats = eng.kv.stats()
     assert stats["kv_state_bytes"] == 3 * 2 * (4 * 8 * 16 * 4 + 3 * 96 * 4)
     assert stats["kv_bytes_total"] == eng.kv.num_blocks * 4 * 2 * 2 * 16 * 4
@@ -391,6 +395,35 @@ def test_whatever_a_free_lanes_state_holds_no_seated_lane_changes(junk):
     assert not np.isfinite(
         _slot_state(eng, free[0])["['block_0']['ssm']['state']"]).all() \
         or junk == -1e30
+
+
+@pytest.mark.parametrize("slots", [3, 18], ids=["hit-tiles",
+                                                "grouped-tiles"])
+def test_whatever_token_a_free_lane_holds_it_hits_no_expert(slots):
+    """Every free lane is given another token every tick, on both
+    paths of the expert layer (18 lanes are over DECODE_ROWS): the
+    seated lane streams what it streams beside quiet lanes, and the
+    experts hit and the pairs held are one lane's."""
+    prompt, generated, _, counts, _ = _served()
+    assert (slots > moe.DECODE_ROWS) == (slots == 18)
+    eng = _engine(slots=slots)
+    request = ServingRequest(prompt, len(generated))
+    before = dict(tracing.recorder().counts())
+    slot, _, _ = eng.insert(request)
+    free = [s for s in range(slots) if s != slot]
+    tick = 0
+    while eng.active_count():
+        eng._last_tokens[free] = (np.arange(len(free)) * 5 + tick) % 96
+        eng._lanes_dirty = True
+        _step(eng)
+        tick += 1
+    after = tracing.recorder().counts()
+    assert list(request.generated) == generated
+    for name in ("moe.pairs_routed", "moe.pairs_held", "moe.experts_hit",
+                 "moe.lanes_live"):
+        assert after[name] - before.get(name, 0) == counts[name], name
+    assert after["moe.lanes"] - before.get("moe.lanes", 0) \
+        == slots * counts["moe.lanes_live"]
 
 
 # ------------------------------ (d) the scans, the kernels, the shares

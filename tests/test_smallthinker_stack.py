@@ -60,9 +60,9 @@ def _cfg(**over):
     return dict(PARAMS, **WEIGHTS, **over)
 
 
-def _engine(params, leaves):
-    """The paged engine over `leaves` (the reference's, by path), two
-    slots, blocks of four."""
+def _engine(params, leaves, slots=2):
+    """The paged engine over `leaves` (the reference's, by path),
+    `slots` lanes, blocks of four."""
     trainer = trainer_mod.Trainer(
         load_model_spec_from_module(zoo),
         mesh=mesh_lib.build_mesh({"dp": 1}, devices=jax.devices()[:1]),
@@ -72,7 +72,8 @@ def _engine(params, leaves):
         step=jnp.zeros((), jnp.int32), params=_unflatten(leaves),
         opt_state=(), model_state=FrozenDict({}),
         rng=jax.random.PRNGKey(0))
-    return PagedContinuousBatchingEngine(trainer, state, 2, block_size=4)
+    return PagedContinuousBatchingEngine(trainer, state, slots,
+                                         block_size=4)
 
 
 def _model(cfg):
@@ -192,11 +193,14 @@ def test_the_step_hands_back_what_the_expert_layers_did():
     cfg, _, prompt, generated, _, counts = _served()
     ticks = len(generated) - 1
     layers, k = cfg["num_layers"], cfg["moe_top_k"]
-    # both lanes ride every tick, the free one included
-    assert counts["moe.pairs_routed"] == ticks * 2 * k * layers
+    # both lanes ride every tick; the free one makes no choice
+    assert counts["moe.pairs_routed"] == ticks * 1 * k * layers
+    assert counts["moe.lanes"] == ticks * 2 * layers
+    assert counts["moe.lanes_live"] == ticks * 1 * layers
     assert 0 < counts["moe.pairs_held"] < counts["moe.pairs_routed"]
     assert counts["moe.expert_slots"] == ticks * 4 * layers
-    assert 0 < counts["moe.experts_hit"] <= counts["moe.expert_slots"]
+    # one lane's three choices hit three experts a layer at the most
+    assert 0 < counts["moe.experts_hit"] <= ticks * k * layers
     # 3 of 4 layers have a window of 8 = 2 blocks of 4: a lane at 20..31
     # holds 5..8 blocks a layer, the oldest of them dead in those three
     assert counts["kv.blocks_held"] > 0
@@ -309,6 +313,239 @@ def test_the_decode_path_and_the_prefill_path_agree(first, count):
     assert float(jnp.max(jnp.abs(hit_path[0] - grouped[0]))) < 2e-6
     assert (hit_path[1] == grouped[1]).all()
     assert (hit_path[2] == grouped[2]).all()
+
+
+def _dead_rows_case(seed, t, dead, form, d=32, hidden=16):
+    """A layer of 8 experts (the `form`'s banks), `t` rows routed top-3
+    so that expert 7 is the first choice of every row in `dead` and of
+    no other: (h, gates, experts, weights, live)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    into, back = (8, d, hidden), (8, hidden, d)
+    shapes = [into, into, back] if form == "reglu" else [back, back]
+    weights = [jax.random.normal(k, shape) * shape[1] ** -0.5
+               for k, shape in zip(ks, shapes)]
+    h = jax.random.normal(ks[3], (t, d))
+    live = np.ones(t, bool)
+    live[list(dead)] = False
+    logits = jax.random.normal(ks[4], (t, 8)).at[:, 7].set(
+        jnp.where(jnp.asarray(live), -50.0, 50.0))
+    gates, experts = moe.route_top_k(logits, 3)
+    return h, gates, experts, weights, live
+
+
+def _some(seed, t):
+    rng = np.random.default_rng(seed)
+    return sorted(rng.choice(t, size=rng.integers(1, t), replace=False))
+
+
+@pytest.mark.parametrize("form", ["reglu", "relu2"])
+@pytest.mark.parametrize("first,count", [(0, 8), (4, 4)])
+@pytest.mark.parametrize("t,dead", [
+    (4, [2]), (4, [0, 1, 3]), (4, range(4)), (16, _some(1, 16)),
+    (40, _some(2, 40)), (40, _some(3, 40)), (40, range(40)),
+    (300, _some(4, 300))], ids=[
+    "hit-one-dead", "hit-one-live", "hit-none-live", "hit-16-rows",
+    "grouped-a", "grouped-b", "grouped-none-live", "grouped-two-tiles"])
+def test_a_row_that_makes_no_choice_reads_no_expert(
+        t, dead, first, count, form, monkeypatch):
+    """A choice below 0 is no choice, on both paths: the rows that
+    chose keep their numbers bit for bit, the others get exactly 0,
+    an expert only they chose is not hit, and the kernel is handed
+    fewer live tiles: none at all when no row chose (a launch whose
+    lanes were all freed at the one before)."""
+    h, gates, experts, weights, live = _dead_rows_case(t, t, dead, form)
+    weights = [w[first:first + count] for w in weights]
+    masked = jnp.where(jnp.asarray(live)[:, None], experts, -1)
+    path = moe._hit_tiles if t <= moe.DECODE_ROWS else moe._grouped_tiles
+    n_live = []
+    real = moe.expert_tiles
+
+    def spy(x_tiles, x_of, tile_gates, expert_of, n, *w, **kw):
+        n_live.append(int(n))
+        return real(x_tiles, x_of, tile_gates, expert_of, n, *w, **kw)
+
+    with monkeypatch.context() as patch:
+        patch.setattr(moe, "expert_tiles", spy)
+        y_all, held_all, hit_all = path(h, gates, experts, first,
+                                        *weights, False)
+        y, held, hit = path(h, gates, masked, first, *weights, False)
+    y, y_all = np.asarray(y), np.asarray(y_all)
+    np.testing.assert_array_equal(y[live], y_all[live])
+    assert (y[~live] == 0.0).all() and np.isfinite(y).all()
+    held = np.asarray(held).sum(-1)
+    assert (held[~live] == 0).all()
+    assert (held[live] == np.asarray(held_all).sum(-1)[live]).all()
+    chosen = np.zeros(8, bool)
+    chosen[np.asarray(experts)[live].reshape(-1)] = True
+    assert np.asarray(hit).astype(bool).tolist() == chosen[
+        first:first + count].tolist()
+    # expert 7 is every dead row's first choice and no live row's
+    if first + count == 8:
+        assert hit_all[-1] and not hit[-1]
+    sizes = np.bincount(np.asarray(experts)[live].reshape(-1),
+                        minlength=8)[first:first + count]
+    want_live = (int((sizes > 0).sum()) if t <= moe.DECODE_ROWS
+                 else int((-(-sizes // moe.PREFILL_TILE_ROWS)).sum()))
+    assert n_live[1] == want_live <= n_live[0] - (first + count == 8)
+    if not live.any():
+        assert n_live[1] == 0 and not y.any() and not np.asarray(hit).any()
+    # the layer itself (its batching rule takes no new operand), lane
+    # by lane as the serving step maps it: the same rows, the tick's
+    # marks
+    if t <= moe.DECODE_ROWS:
+        by_lane = jax.vmap(lambda hh, gg, ee: moe.held_experts(
+            hh, gg, ee, weights, first=first, use_kernel=False))(
+            h[:, None], gates[:, None], masked[:, None])
+        np.testing.assert_array_equal(np.asarray(by_lane[0][:, 0]), y)
+        assert (np.asarray(by_lane[1][:, 0]) == held).all()
+        assert (np.asarray(by_lane[2]) == np.asarray(hit)[None]).all()
+
+
+@pytest.mark.parametrize("t", [4, 40])
+def test_the_kernel_hands_a_dead_row_zeros_when_interpreted(t, monkeypatch):
+    monkeypatch.setenv("ELASTICDL_TPU_FORCE_INTERPRET", "1")
+    for dead in ([1], range(t)):
+        h, gates, experts, weights, live = _dead_rows_case(
+            t, t, dead, "reglu", d=128, hidden=256)
+        masked = jnp.where(jnp.asarray(live)[:, None], experts, -1)
+        kernel = moe.held_experts(h, gates, masked, weights,
+                                  use_kernel=True)
+        plain = moe.held_experts(h, gates, masked, weights,
+                                 use_kernel=False)
+        whole = moe.held_experts(h, gates, experts, weights,
+                                 use_kernel=True)
+        assert float(jnp.max(jnp.abs(kernel[0] - plain[0]))) < 1e-5
+        np.testing.assert_array_equal(np.asarray(kernel[0])[live],
+                                      np.asarray(whole[0])[live])
+        assert not np.asarray(kernel[0])[~live].any()
+        assert (kernel[1] == plain[1]).all()
+        assert (kernel[2] == plain[2]).all() and not kernel[2][7]
+
+
+def _layer_as_it_was(params, h, route_from, form):
+    """ExpertFFN(8, 3, 24, held=(2, 4)) written out as it was before it
+    could be told which rows are live: the router at full precision,
+    the top 3, the held experts' tiles, the shared expert."""
+    b, l, d = h.shape
+    weights = [params[n] for n in (
+        ("w_gate", "w_up", "w_down") if form == "reglu"
+        else ("w_up", "w_down"))]
+    logits = jnp.matmul(route_from.reshape(b * l, d), params["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    if form == "reglu":
+        gates, experts = moe.route_top_k(logits, 3)
+    else:
+        gates, experts = moe.route_sigmoid_top_k(
+            logits, 3, params["router_bias"], 2.5)
+    rows = h.reshape(b * l, d)
+    y, _, _ = moe.held_experts(rows, gates, experts, weights, first=2)
+    if form == "relu2":
+        act = jnp.square(jnp.maximum(jnp.dot(
+            rows, params["shared_up"],
+            preferred_element_type=jnp.float32), 0.0))
+        y = y + jnp.dot(act, params["shared_down"],
+                        preferred_element_type=jnp.float32)
+    return y.reshape(b, l, d)
+
+
+@pytest.mark.parametrize("form", ["reglu", "relu2"])
+def test_told_nothing_the_layer_is_the_operations_it_always_was(form):
+    """`live=None` adds no operation: prefill, the tiles and the
+    training forward lower to what they did. Told which rows are live,
+    the layer is those operations and the few that write -1."""
+    layer = zoo.ExpertFFN(8, 3, 24, held=(2, 4), **(
+        {} if form == "reglu" else dict(
+            activation="relu2", scoring="sigmoid", route_scale=2.5,
+            shared_hidden=40)))
+    h = jax.random.normal(jax.random.PRNGKey(0), (2, 20, 32))
+    params = jax.tree.map(
+        lambda x: getattr(x, "value", x),
+        layer.init(jax.random.PRNGKey(1), h, h)["params"],
+        is_leaf=lambda x: hasattr(x, "value"))
+
+    def program(**kwargs):
+        return str(jax.make_jaxpr(lambda p, hh, rr: layer.apply(
+            {"params": p}, hh, rr, **kwargs))(params, h, 0.5 * h))
+
+    was = str(jax.make_jaxpr(
+        lambda p, hh, rr: _layer_as_it_was(p, hh, rr, form))(
+            params, h, 0.5 * h))
+    assert program() == program(live=None) == was
+    assert program(live=jnp.ones((2,), bool)) != was
+    # every row live: the same numbers, whoever says so
+    every = [layer.apply({"params": params}, h, 0.5 * h, live=live)
+             for live in (None, jnp.ones((2,), bool),
+                          jnp.ones((40,), bool))]
+    np.testing.assert_array_equal(every[0], every[1])
+    np.testing.assert_array_equal(every[0], every[2])
+    # a dead sequence's routed part is exactly 0 (relu2: the shared
+    # expert alone is left), a live one's bit for bit what it was
+    half = layer.apply({"params": params}, h, 0.5 * h,
+                       live=jnp.asarray([True, False]))
+    np.testing.assert_array_equal(half[0], every[0][0])
+    if form == "reglu":
+        assert not np.asarray(half[1]).any()
+    else:
+        assert np.asarray(half[1]).any()
+        assert not np.array_equal(half[1], every[0][1])
+
+
+def test_only_the_tick_says_which_rows_are_live(monkeypatch):
+    """Of the engine's programs the decode step alone hands `live`
+    down; prefill and the suffix tile leave it out, and are traced to
+    the programs they were."""
+    cfg = _cfg()
+    eng = _engine(PARAMS, ref.make_leaves(cfg, 0, ref.all_leaves(cfg)))
+    told = []
+    real = zoo.ExpertFFN.__call__
+
+    def spy(self, h, route_from, training=False, live=None):
+        told.append(None if live is None else live.shape)
+        return real(self, h, route_from, training, live)
+
+    monkeypatch.setattr(zoo.ExpertFFN, "__call__", spy)
+    by_program = []
+    for program, args, _ in eng._weight_programs(
+            eng._exec_variables, None):
+        del told[:]
+        jax.eval_shape(program, *args)
+        by_program.append(list(told))
+    layers = cfg["num_layers"]
+    assert by_program == [[None] * layers, [None] * layers,
+                          [(1,)] * layers]
+
+
+def test_whatever_token_a_free_lane_holds_it_hits_no_expert():
+    """The free lane is given another token every tick: the seated
+    lane streams the tokens it streams alone, and the experts hit and
+    the pairs held are those of a server with no free lane at all."""
+    cfg, _, prompt, generated, _, counts = _served()
+    w = ref.make_leaves(cfg, 0, ref.all_leaves(cfg))
+
+    def run(eng, poison):
+        request = ServingRequest(prompt, len(generated))
+        before = dict(tracing.recorder().counts())
+        slot, _, _ = eng.insert(request)
+        tick = 0
+        while eng.active_count():
+            if poison:
+                eng._last_tokens[1 - slot] = (7 * tick + 3) % 96
+                eng._lanes_dirty = True
+            assert eng._launch() and eng._collect()
+            tick += 1
+        after = tracing.recorder().counts()
+        return list(request.generated), {
+            k: after[k] - before.get(k, 0) for k in after
+            if k.startswith("moe.")}
+
+    poisoned, counted = run(_engine(PARAMS, w), True)
+    alone, counted_alone = run(_engine(PARAMS, w, slots=1), False)
+    assert poisoned == alone == generated
+    for name in ("moe.pairs_routed", "moe.pairs_held", "moe.experts_hit",
+                 "moe.lanes_live"):
+        assert counted[name] == counted_alone[name] == counts[name], name
+    assert counted["moe.lanes"] == 2 * counted_alone["moe.lanes"]
+    assert counted_alone["moe.lanes"] == counted_alone["moe.lanes_live"]
 
 
 def test_lanes_mapped_one_by_one_are_computed_as_one_call():
