@@ -220,6 +220,13 @@ def _say_window(run, w, tag):
                ms(w["late"], 50), 1e3 * max(w["late"])))
 
 
+def _ticks_within(steps, t0, t1):
+    """(lanes seated, step seconds) of the tapped decode steps that
+    ended in [t0, t1]."""
+    inside = [v for (t, v) in steps if t0 <= t <= t1]
+    return [v[0] for v in inside], [v[1] for v in inside]
+
+
 def token_reaches(clients, t0, t1):
     """The cached tokens behind every token that arrived in [t0, t1]
     (prompt + what the request had generated before it), raw: a
@@ -325,6 +332,13 @@ def run_cell(run):
                         vocab, probes.WindowTrace(False, 0, run.workdir),
                         run.seed + 1000 * (i + 1))
             _say_window(run, w, "sweep rate %.3g/s: " % rate)
+            lanes, step_s = _ticks_within(steps, w["t_open"], w["t_close"])
+            run.say("sweep rate %.3g/s: lanes seated a tick median %s p95 "
+                    "%s, step ms median %.3f over %d ticks" % (
+                        rate, stats.percentile(lanes, 50),
+                        stats.percentile(lanes, 95),
+                        1e3 * (stats.percentile(step_s, 50) or math.nan),
+                        len(lanes)))
         compiles_at_open = compiles.count
         run.mark_window_open()
         w = _window(run, mix, pool, stub, pb, vocab, tracer, run.seed)
@@ -341,7 +355,7 @@ def run_cell(run):
         if c.error:
             run.say("request failed: %s" % c.error)
     finished, failed = w["finished"], w["failed"]
-    in_window = [v for (t, v) in steps if t_open <= t <= t_close]
+    active_slots, decode_step_s = _ticks_within(steps, t_open, t_close)
     traced_reach = ([] if tracer.t1 is None
                     else token_reaches(clients, tracer.t0, tracer.t1))
 
@@ -363,8 +377,8 @@ def run_cell(run):
             "state_init_s": spans["state_init_s"],
             "queue_wait_s": [v for (t, v) in queue_wait
                              if t_open <= t <= t_close],
-            "active_slots": [v[0] for v in in_window],
-            "decode_step_s": [v[1] for v in in_window],
+            "active_slots": active_slots,
+            "decode_step_s": decode_step_s,
             "traced_token_reach": traced_reach,
         },
         "counters": {

@@ -1,6 +1,7 @@
 """Arithmetic on samples: exact percentiles (no histogram), the
-quartile spread the bounds are set from, and weighted multisets that
-every seed draws in another order."""
+quartile spread the bounds are set from, the spread of a set of runs as
+the driver takes it, and weighted multisets that every seed draws in
+another order."""
 
 import math
 import random
@@ -25,6 +26,25 @@ def spread(values):
     median (statistics.quantiles, n=4)."""
     q1, _, q3 = statistics.quantiles(values, n=4)
     return (q3 - q1) / statistics.median(values)
+
+
+def run_spread(values):
+    """The driver's spread of a set of runs of one cell on one tree:
+    the largest less the smallest, leaving out the run farthest from
+    the set's median where that narrows it (a set of three or more),
+    as (absolute, share of the set's median); None for no run."""
+    xs = sorted(values)
+    if not xs:
+        return None
+    median = statistics.median(xs)
+    if len(xs) >= 3:
+        below, above = median - xs[0], xs[-1] - median
+        if below != above:
+            xs = xs[1:] if below > above else xs[:-1]
+        else:  # both ends as far: the one whose going narrows it more
+            xs = min(xs[1:], xs[:-1], key=lambda k: k[-1] - k[0])
+    width = xs[-1] - xs[0]
+    return width, width / median
 
 
 def weighted_counts(pairs, n):

@@ -2,7 +2,12 @@
 (`traffic/<name>.json`); this module turns `open_loop` parameters and a
 seed into a request schedule. Every seed gets the same multiset of
 prompt lengths, answer lengths and inter-arrival gaps, in another
-order, so that seeds change the order of the work and not its amount."""
+order, so that seeds change the order of the work and not its amount.
+Where the order IS the amount (a server that batches what is resident
+together: which requests meet in the lanes sets every tick's cost), a
+mix states `deal_seed`: the lengths and gaps are then dealt in the one
+order that seed gives, for every `--seed`, and `--seed` draws only the
+tokens of each prompt (and, in the driver, the weights)."""
 
 import math
 
@@ -28,10 +33,13 @@ def open_loop_schedule(mix, seed, seconds, vocab_size):
     request is due at 0; all are due before `seconds`."""
     n = max(1, int(round(mix["rate_per_s"] * seconds)))
     rng = stats.rng_for(seed, "open_loop")
-    prompts = stats.shuffled_multiset(mix["prompt_lens"], n, rng)
-    news = stats.shuffled_multiset(mix["max_new_tokens"], n, rng)
+    deal = rng  # without `deal_seed`: one stream, the order then the tokens
+    if mix.get("deal_seed") is not None:
+        deal = stats.rng_for(mix["deal_seed"], "open_loop")
+    prompts = stats.shuffled_multiset(mix["prompt_lens"], n, deal)
+    news = stats.shuffled_multiset(mix["max_new_tokens"], n, deal)
     gaps = arrival_gaps(n, mix["rate_per_s"], mix.get("arrivals", "poisson"))
-    rng.shuffle(gaps)
+    deal.shuffle(gaps)
     out, due = [], 0.0
     for p, m, g in zip(prompts, news, gaps):
         out.append({
