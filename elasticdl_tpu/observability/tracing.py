@@ -252,6 +252,15 @@ COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             # (every lane rides every tick, so lanes x state layers)
             # and those of them of seated lanes
             "ssm.lanes", "ssm.lanes_live",
+            # serving/engine.py, a block-diffusion model's decode step
+            # (BLOCK TICK), handed back behind the tick's tokens like
+            # the moe.* ones and counted under `tick.commit`: passes of
+            # seated lanes (a lane a tick), those of them commit passes
+            # (which reveal nothing and write the block's rows), and
+            # positions revealed; and, on the host, blocks handed to
+            # their requests
+            "diffusion.lane_passes", "diffusion.commit_passes",
+            "diffusion.tokens_revealed", "diffusion.blocks_committed",
             # serving/engine.py _load_params(), once a (re)load of a
             # weight tree: the bytes of the tree handed in and of the
             # tree the programs are served, and the leaves replaced by

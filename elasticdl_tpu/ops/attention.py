@@ -254,15 +254,27 @@ def segments_float0(segments):
     return np.zeros(segments.shape, jax.dtypes.float0)
 
 
+def causal_limit(q_pos, block_causal):
+    """The last key position a causal row at `q_pos` sees: itself, or
+    with `block_causal` B > 1 the end of its block of B positions
+    (blocks aligned at position 0): causal across blocks, every
+    position of a row's own block in both directions."""
+    if block_causal and block_causal > 1:
+        return q_pos // block_causal * block_causal + (block_causal - 1)
+    return q_pos
+
+
 def naive_attention(q, k, v, causal=False, scale=None, window=None,
-                    segments=None):
+                    segments=None, block_causal=0):
     """Reference softmax(q k^T) v; O(L^2) memory. The test oracle (the
     flash backward is the Pallas two-pass _flash_backward below).
     `window` (sliding-window/local attention): query at position p sees
     keys in (p - window, p] under causal, |p - k| < window otherwise —
     None means unbounded. k/v may carry fewer heads than q (GQA).
     `segments` [b, l] int: sequence-packing mask — attention stays
-    within same-id runs (cross-segment scores are masked out)."""
+    within same-id runs (cross-segment scores are masked out).
+    `block_causal` B > 1 (with causal): a row's limit is the end of
+    its block of B positions (causal_limit)."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     _check_window(window, q.shape[2], k.shape[2])
     segments = _check_segments(segments, q.shape[0], q.shape[2],
@@ -275,7 +287,7 @@ def naive_attention(q, k, v, causal=False, scale=None, window=None,
     k_pos = jnp.arange(lk)[None, :]
     mask = jnp.ones((lq, lk), bool)
     if causal:
-        mask &= q_pos >= k_pos
+        mask &= causal_limit(q_pos, block_causal) >= k_pos
     if window is not None:
         mask &= q_pos - k_pos < window
         if not causal:
@@ -292,13 +304,15 @@ def naive_attention(q, k, v, causal=False, scale=None, window=None,
 
 def blockwise_attention(q, k, v, causal=False, scale=None, block_size=512,
                         window=None, with_lse=False, segments=None,
-                        pos_offset=0):
+                        pos_offset=0, block_causal=0):
     """Online-softmax attention via lax.scan over key blocks: O(L) memory,
     differentiable, pure jnp (the fallback when the flash kernel can't
     run). Matches naive_attention to float tolerance. With
     `with_lse=True` also returns the float32 logsumexp [b, h, lq] (the
     ring-attention partial form; see attention_forward_lse).
-    `segments` [b, l] int: sequence-packing mask (see naive_attention)."""
+    `segments` [b, l] int: sequence-packing mask (see naive_attention).
+    `block_causal`: a causal row's limit is its block's end
+    (causal_limit)."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     b, h, lq, d = q.shape
     lk = k.shape[2]
@@ -331,7 +345,8 @@ def blockwise_attention(q, k, v, causal=False, scale=None, block_size=512,
         k_pos = kb_idx * block + jnp.arange(block)
         valid = jnp.broadcast_to((k_pos < lk)[None, :], (lq, block))
         if causal:
-            valid = valid & (q_pos[:, None] >= k_pos[None, :])
+            valid = valid & (causal_limit(q_pos, block_causal)[:, None]
+                             >= k_pos[None, :])
         if window is not None:
             valid = valid & (q_pos[:, None] - k_pos[None, :] < window)
             if not causal:
@@ -410,26 +425,41 @@ def paged_live_blocks(length, window, block_size, m, xp=jnp):
     return xp.minimum(j_lo, j_hi), j_hi
 
 
-def _tile_causal_mask(group, t, window):
+def _tile_causal_mask(group, t, window, row_pos=None, block_causal=0):
     """[group*t, t] visibility of the query tile's OWN keys, shared by
     the scan and fused paths (both merge the tile outside the pool
     stream): tile key j' (absolute position length + j') is visible to
     tile row j iff j' <= j — causal within the tile — and any window >= 1
-    keeps the diagonal (_check_window)."""
+    keeps the diagonal (_check_window). With `block_causal` B > 1 the
+    row's limit is the end of its block of B ABSOLUTE positions
+    (causal_limit over `row_pos` [b, t]): a tile that is one aligned
+    block sees all of itself, and the mask is a sequence's own.
+    Returns it as the scores [b, hkv, group*t, t] take it: [1, 1, ...]
+    or [b, 1, ...]."""
+    if block_causal and block_causal > 1:
+        tri = (causal_limit(row_pos, block_causal)[:, :, None]
+               >= row_pos[:, None, :])  # [b, t_q, t_k]
+        if window is not None:
+            tri = tri & (row_pos[:, :, None] - row_pos[:, None, :]
+                         < window)
+        b = row_pos.shape[0]
+        return jnp.broadcast_to(
+            tri[:, None, :, :], (b, group, t, t)
+        ).reshape(b, 1, group * t, t)
     tile = jnp.arange(t)
     tri = tile[:, None] >= tile[None, :]  # [t_q, t_k] causal
     if window is not None:
         tri = tri & (tile[:, None] - tile[None, :] < window)
     return jnp.broadcast_to(
         tri[None, :, :], (group, t, t)
-    ).reshape(group * t, t)
+    ).reshape(group * t, t)[None, None]
 
 
 def paged_decode_attention(q, k_cur, v_cur, k_pool, v_pool, block_table,
                            length, scale=None, window=None,
                            k_scale_pool=None, v_scale_pool=None,
                            k_cur_scale=None, v_cur_scale=None,
-                           use_kernel=None):
+                           use_kernel=None, block_causal=0):
     """Decode attention over a BLOCK-PAGED KV pool for a tile of
     1 <= t new query tokens per sequence.
 
@@ -450,7 +480,11 @@ def paged_decode_attention(q, k_cur, v_cur, k_pool, v_pool, block_table,
     unshared tail of a prompt decodes as one tile over the resident
     prefix blocks) — tile row j sits at absolute position
     `length + j`, sees every pool row `k_pos < length`, and sees tile
-    keys `j' <= j` (causal within the tile).
+    keys `j' <= j` (causal within the tile). With `block_causal` B > 1
+    (a block-diffusion model's denoising tile) a tile row sees the
+    tile keys up to the end of its own block of B absolute positions:
+    a tile that is one aligned block sees every one of its keys
+    (_tile_causal_mask); the pool rows, all of them earlier, as ever.
 
     q:      [b, h, t, d]   the tile ([b, h, d] accepted for the t = 1
                            legacy shape; the result then drops t too)
@@ -593,8 +627,8 @@ def paged_decode_attention(q, k_cur, v_cur, k_pool, v_pool, block_table,
         # scores see exactly the rows every LATER step will read back
         s_cur = s_cur * k_cur_scale[..., 0][:, :, None, :]
         cur_w_scale = v_cur_scale[..., 0]  # [b, hkv, t]
-    trif = _tile_causal_mask(group, t, window)
-    s_cur = jnp.where(trif[None, None], s_cur, _NEG_INF)
+    trif = _tile_causal_mask(group, t, window, row_pos, block_causal)
+    s_cur = jnp.where(trif, s_cur, _NEG_INF)
     o, l, mx = softmax_merge(
         o, l, mx, s_cur, v_cur.astype(f32),  # already [b, hkv, t, d]
         w_scale=cur_w_scale,
@@ -1109,7 +1143,11 @@ def _block_mask_apply(s, qi, ki, block_q, block_k, causal, window,
     )
     keep = True
     if causal:
-        keep = q_pos >= k_pos
+        # `causal` may be a block length B > 1 (flash_attention's
+        # block_causal): the row's limit is then its block's end. The
+        # block-skip predicates above hold as they are, because the
+        # kernel blocks are whole multiples of B
+        keep = causal_limit(q_pos, causal) >= k_pos
     if window is not None:
         in_w = q_pos - k_pos < window
         keep = jnp.logical_and(keep, in_w) if causal else in_w
@@ -1698,7 +1736,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None, window=None,
-                    segments=None):
+                    segments=None, block_causal=0):
     """Tiled online-softmax attention (Pallas). head_dim is zero-padded
     to the 128-lane width (zeros don't change q·k or add output columns
     that survive the final slice); falls back to blockwise_attention when
@@ -1715,8 +1753,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     mask a row, and such rows return exactly 0 with zero gradient — the
     Pallas and blockwise backends are post-masked identically, so the
     two paths agree (ring itself merges unnormalized partials via
-    attention_forward_lse instead)."""
+    attention_forward_lse instead). `block_causal` B > 1 (with causal):
+    block-causal attention, row i sees key j iff j // B <= i // B
+    (causal_limit): the kernels take B in place of `causal` where
+    their blocks are whole multiples of B, the blockwise path else."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
+    block_causal = int(block_causal or 0)
+    if block_causal > 1 and not causal:
+        raise ValueError("block_causal needs causal=True")
     pair_form = isinstance(segments, (tuple, list))
     lq, lk, d = q.shape[2], k.shape[2], q.shape[3]
     group_size(q, k)  # validate GQA divisibility before kernel dispatch
@@ -1725,6 +1769,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     _check_window(window, lq, lk)
     segments = _check_segments(segments, q.shape[0], lq, lk)
     tiles = _flash_tiles(lq, lk, block_q, block_k)
+    if block_causal > 1:
+        tiles = tiles and not (block_q % block_causal
+                               or block_k % block_causal)
     if not (use_pallas() and tiles):
         if use_pallas():
             # trace-time, so once per compiled program, not per step
@@ -1734,11 +1781,13 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                 lq, lk, block_q, block_k,
             )
         out = blockwise_attention(q, k, v, causal=causal, scale=scale,
-                                  window=window, segments=segments)
+                                  window=window, segments=segments,
+                                  block_causal=block_causal)
     else:
         qp, kp, vp = _pad_lanes([q, k, v], d)
-        out = _flash(qp, kp, vp, segments, causal, scale, block_q,
-                     block_k, interpret, window)[..., :d]
+        out = _flash(qp, kp, vp, segments,
+                     block_causal if block_causal > 1 else causal, scale,
+                     block_q, block_k, interpret, window)[..., :d]
     if pair_form:
         # the fully-masked-row contract: both backends leave a
         # degenerate value there (blockwise: mean(v); kernel: depends

@@ -6,6 +6,7 @@ The work is a list of TILES. Tile i is `tm` rows of activations
 a row (`gates[i]`); its result is, by how many matrices an expert has,
 
     gates[i] * ((relu(x W_gate[e]) * (x W_up[e])) W_down[e])        ReGLU
+    gates[i] * ((silu(x W_gate[e]) * (x W_up[e])) W_down[e])        SwiGLU
     gates[i] * (relu(x W_up[e]^T)^2 W_down[e])                      relu^2
 
 (the two-matrix form keeps BOTH matrices a hidden unit a row, [hidden,
@@ -75,8 +76,17 @@ def kernel_supported(tm, d, hidden, dtype):
             and (th % 128 == 0 or (th == hidden and th % sublanes == 0)))
 
 
+#: what a gated expert does to its gate product, by `activation`
+_GATE_ACTS = {
+    "reglu": lambda a: jnp.maximum(a, 0.0),
+    "swiglu": jax.nn.silu,
+}
+
+
 def _tile_kernel(x_of_ref, expert_of_ref, n_live_ref, x_ref, g_ref,
-                 wg_ref, wu_ref, wd_ref, out_ref, acc_ref):
+                 wg_ref, wu_ref, wd_ref, out_ref, acc_ref, *, gate_act):
+    """The gated three-matrix form: (act(x W_gate) * (x W_up)) W_down,
+    `gate_act` relu (ReGLU) or silu (SwiGLU)."""
     i, j = pl.program_id(0), pl.program_id(1)
     live = i < n_live_ref[0]
 
@@ -89,7 +99,7 @@ def _tile_kernel(x_of_ref, expert_of_ref, n_live_ref, x_ref, g_ref,
         x = x_ref[...]
         a = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
         u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        act = (jnp.maximum(a, 0.0) * u).astype(x.dtype)
+        act = (gate_act(a) * u).astype(x.dtype)
         acc_ref[...] += jnp.dot(act, wd_ref[...],
                                 preferred_element_type=jnp.float32)
 
@@ -122,7 +132,8 @@ def _tile_kernel_relu2(x_of_ref, expert_of_ref, n_live_ref, x_ref, g_ref,
         out_ref[...] = acc_ref[...] * g_ref[...]
 
 
-def _expert_tiles_kernel(x_tiles, x_of, gates, expert_of, n_live, *weights):
+def _expert_tiles_kernel(x_tiles, x_of, gates, expert_of, n_live, *weights,
+                         activation):
     n_tiles, tm = gates.shape[:2]
     hidden, d = weights[-1].shape[1:]
     th = hidden_slice(hidden)
@@ -145,7 +156,8 @@ def _expert_tiles_kernel(x_tiles, x_of, gates, expert_of, n_live, *weights):
     ] + ([into_hidden] * 2 if len(weights) == 3 else [from_hidden]) + [
         from_hidden]
     call = pl.pallas_call(
-        _tile_kernel if len(weights) == 3 else _tile_kernel_relu2,
+        functools.partial(_tile_kernel, gate_act=_GATE_ACTS[activation])
+        if len(weights) == 3 else _tile_kernel_relu2,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n_tiles, n_h),
@@ -166,23 +178,25 @@ def _expert_tiles_kernel(x_tiles, x_of, gates, expert_of, n_live, *weights):
                     *weights)
 
 
-def _activation(x, weights, dot):
-    """An expert's hidden activation, float32: ReGLU of (W_gate, W_up)
-    [d, hidden], or relu^2 of (W_up,) [hidden, d]."""
+def _activation(x, weights, dot, activation):
+    """An expert's hidden activation, float32: ReGLU or SwiGLU of
+    (W_gate, W_up) [d, hidden], or relu^2 of (W_up,) [hidden, d]."""
     if len(weights) == 2:
-        return jnp.maximum(dot(x, weights[0]), 0.0) * dot(x, weights[1])
+        return (_GATE_ACTS[activation](dot(x, weights[0]))
+                * dot(x, weights[1]))
     return jnp.square(jnp.maximum(dot(x, weights[0].T), 0.0))
 
 
 def expert_tiles_reference(x_tiles, x_of, gates, expert_of, n_live,
-                           *weights):
+                           *weights, activation):
     """The same tiles in plain jax.numpy, one at a time."""
     dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
 
     def tile(i):
         def live():
             x, e = x_tiles[x_of[i]], expert_of[i]
-            act = _activation(x, [w[e] for w in weights[:-1]], dot)
+            act = _activation(x, [w[e] for w in weights[:-1]], dot,
+                              activation)
             return dot(act.astype(x.dtype), weights[-1][e]) * gates[i]
 
         return jax.lax.cond(
@@ -194,25 +208,34 @@ def expert_tiles_reference(x_tiles, x_of, gates, expert_of, n_live,
 
 
 def expert_tiles(x_tiles, x_of, gates, expert_of, n_live, *weights,
-                 use_kernel=None):
+                 use_kernel=None, activation=None):
     """float32 [n_tiles, tm, d]: tile i's weighted expert product,
     zeros from tile `n_live` on.
 
     x_tiles [n_x, tm, d]; x_of, expert_of [n_tiles] int32; gates
     [n_tiles, tm, 1] float32; n_live int32 scalar; `weights` in
-    x_tiles' dtype, three for ReGLU experts (w_gate, w_up [E, d,
+    x_tiles' dtype, three for gated experts (w_gate, w_up [E, d,
     hidden], w_down [E, hidden, d]) or two for relu^2 experts (w_up,
-    w_down, BOTH [E, hidden, d]). `use_kernel=None` takes the Mosaic
+    w_down, BOTH [E, hidden, d]). `activation` names the form: None
+    takes it from the count (three: "reglu", two: "relu2"); "swiglu"
+    is the gated form with silu. `use_kernel=None` takes the Mosaic
     kernel where kernels are on and the shapes are whole tiles
     (`kernel_supported`)."""
     tm, d = x_tiles.shape[1:]
     if len(weights) not in (2, 3):
         raise ValueError("an expert has two matrices (relu^2) or three "
-                         "(ReGLU), not %d" % len(weights))
+                         "(ReGLU, SwiGLU), not %d" % len(weights))
+    if activation is None:
+        activation = "reglu" if len(weights) == 3 else "relu2"
+    if (activation not in ("reglu", "swiglu", "relu2")
+            or (activation == "relu2") != (len(weights) == 2)):
+        raise ValueError("activation %r with %d matrices an expert"
+                         % (activation, len(weights)))
     if use_kernel is None:
         use_kernel = use_pallas() and kernel_supported(
             tm, d, weights[-1].shape[1], x_tiles.dtype)
     fn = _expert_tiles_kernel if use_kernel else expert_tiles_reference
     return fn(x_tiles, jnp.asarray(x_of, jnp.int32), gates,
               jnp.asarray(expert_of, jnp.int32),
-              jnp.asarray(n_live, jnp.int32), *weights)
+              jnp.asarray(n_live, jnp.int32), *weights,
+              activation=activation)

@@ -438,7 +438,8 @@ def _held_choices(experts, first, count):
     return local, (local >= 0) & (local < count)
 
 
-def _hit_tiles(h, gates, experts, first, *weights_and_use_kernel):
+def _hit_tiles(h, gates, experts, first, *weights_and_use_kernel,
+               activation=None):
     """Few rows: one tile per HIT expert over all the rows."""
     *weights, use_kernel = weights_and_use_kernel
     t = h.shape[0]
@@ -457,11 +458,12 @@ def _hit_tiles(h, gates, experts, first, *weights_and_use_kernel):
     tile_gates = jnp.pad(gate_te.T[order], ((0, 0), (0, tm - t)))
     y = expert_tiles(x, jnp.zeros((count,), jnp.int32),
                      tile_gates[..., None], order, n_live, *weights,
-                     use_kernel=use_kernel)
+                     use_kernel=use_kernel, activation=activation)
     return jnp.sum(y, axis=0)[:t], held, hit
 
 
-def _grouped_tiles(h, gates, experts, first, *weights_and_use_kernel):
+def _grouped_tiles(h, gates, experts, first, *weights_and_use_kernel,
+                   activation=None):
     """Many rows: the (row, choice) pairs sorted by expert, each held
     expert's run padded to whole tiles; gathers only, no scatter."""
     *weights, use_kernel = weights_and_use_kernel
@@ -497,7 +499,8 @@ def _grouped_tiles(h, gates, experts, first, *weights_and_use_kernel):
     x_tiles = h[pair // k]  # [n_tiles, tm, d]
     tile_gates = jnp.where(live_row, gates.reshape(-1)[pair], 0.0)
     y = expert_tiles(x_tiles, jnp.arange(n_tiles), tile_gates[..., None],
-                     expert_of, n_live, *weights, use_kernel=use_kernel)
+                     expert_of, n_live, *weights, use_kernel=use_kernel,
+                     activation=activation)
     # each pair's row of the tiles; a pair held elsewhere reads none
     e_pair = jnp.minimum(key, count - 1)
     dest = tile_start[e_pair] * tm + rank - start[e_pair]
@@ -507,19 +510,23 @@ def _grouped_tiles(h, gates, experts, first, *weights_and_use_kernel):
     return jnp.sum(rows.reshape(t, k, d), axis=1), held, hit
 
 
-def _held_experts(first, use_kernel, h, gates, experts, *weights):
+def _held_experts(first, use_kernel, activation, h, gates, experts,
+                  *weights):
     path = _hit_tiles if h.shape[0] <= DECODE_ROWS else _grouped_tiles
-    y, held, hit = path(h, gates, experts, first, *weights, use_kernel)
+    y, held, hit = path(h, gates, experts, first, *weights, use_kernel,
+                        activation=activation)
     return (y, jnp.sum(held, axis=1).astype(jnp.int32),
             hit.astype(jnp.int32))
 
 
 @functools.lru_cache(maxsize=None)
-def _lanes_as_one_call(first, use_kernel):
-    """_held_experts(first, use_kernel, ...) with a batching rule:
+def _lanes_as_one_call(first, use_kernel, activation=None):
+    """_held_experts(first, use_kernel, activation, ...) with a
+    batching rule:
     mapped over rows with the weights shared, the lanes are laid side
     by side and computed as ONE call (itself mappable again)."""
-    plain = functools.partial(_held_experts, first, use_kernel)
+    plain = functools.partial(_held_experts, first, use_kernel,
+                              activation)
     call = custom_vmap(plain)
 
     @call.def_vmap
@@ -539,7 +546,8 @@ def _lanes_as_one_call(first, use_kernel):
     return call
 
 
-def held_experts(h, gates, experts, weights, first=0, use_kernel=None):
+def held_experts(h, gates, experts, weights, first=0, use_kernel=None,
+                 activation=None):
     """This chip's part of a drop-free expert layer.
 
     h [T, D] (the compute dtype), gates [T, k] float32 and experts
@@ -549,7 +557,9 @@ def held_experts(h, gates, experts, weights, first=0, use_kernel=None):
     matrices an expert for gated (ReGLU) experts, (w_gate, w_up
     [count, D, H], w_down [count, H, D]), or two for relu^2 experts,
     (w_up, w_down), both [count, H, D], a hidden unit a row
-    (ops/expert_ffn.py says why). Returns
+    (ops/expert_ffn.py says why). `activation` names the form where
+    the count does not ("swiglu": gated, with silu in relu's place;
+    None: "reglu" for three matrices, "relu2" for two). Returns
 
         y [T, D] float32     sum over a row's HELD choices of
                              gate * ((relu(h W_gate) * (h W_up)) W_down)
@@ -576,7 +586,9 @@ def held_experts(h, gates, experts, weights, first=0, use_kernel=None):
     shared (the serving step maps one lane a sequence) the lanes are
     laid side by side and computed as ONE call, so a tick reads a hit
     expert once and not once a lane; `hit` is then the tick's."""
-    return _lanes_as_one_call(int(first), use_kernel)(
+    if activation == ("reglu" if len(weights) == 3 else "relu2"):
+        activation = None  # what the count says: one call for both names
+    return _lanes_as_one_call(int(first), use_kernel, activation)(
         h, gates, experts, *weights)
 
 

@@ -42,8 +42,13 @@ class ServingRequest(object):
 
     Client-facing fields mirror proto GenerateRequest; the rest is
     scheduler state. Events flow through `events` as tuples:
-        ("tokens", [ids], model_version)  new tokens (first event also
-                                          marks TTFT)
+        ("tokens", [ids], model_version, [reveal steps])
+                                          new tokens (first event also
+                                          marks TTFT); the last part is
+                                          empty but for a block-
+                                          diffusion model: the
+                                          denoising pass that revealed
+                                          each token
         ("done", model_version)           completed; all tokens emitted
         ("error", code, message)          terminal failure
 
@@ -83,6 +88,9 @@ class ServingRequest(object):
         self.span = None
         # scheduler-side state
         self.generated = []
+        # parallel to `generated` for a block-diffusion model (the
+        # engine's commit pass fills it), else left empty
+        self.reveal_steps = []
         self.first_token_at = None
         self.seated_at = None  # set when the scheduler seats a slot
         self.model_version = -1
@@ -146,7 +154,7 @@ class RequestQueue(object):
     """
 
     def __init__(self, capacity, seq_len, clock=time.monotonic,
-                 max_cached_tokens=None):
+                 max_cached_tokens=None, refuse=None):
         if capacity < 1:
             raise ValueError("capacity must be >= 1, got %d" % capacity)
         self.capacity = int(capacity)
@@ -155,6 +163,9 @@ class RequestQueue(object):
             int(max_cached_tokens) if max_cached_tokens else None
         )
         self._clock = clock
+        # the engine's own word on a request (engine.refuse_request:
+        # a message, or None): what this model cannot serve at all
+        self._refuse = refuse
         self._q = collections.deque()
         self._cv = threading.Condition()
         self._closed = False
@@ -209,6 +220,9 @@ class RequestQueue(object):
                 "request needs %d KV rows > the pool's total budget of "
                 "%d tokens" % (cached, self.max_cached_tokens),
             )
+        why = self._refuse(request) if self._refuse else None
+        if why:
+            raise AdmissionError("INVALID_ARGUMENT", why)
         if request.expired(self._clock()):
             raise AdmissionError(
                 "DEADLINE_EXCEEDED", "deadline expired before admission"

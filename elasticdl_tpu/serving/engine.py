@@ -85,6 +85,29 @@ committed (a deadline) is skipped there. The speculative tick stays in
 line: its positions advance by what the verify accepts, which only the
 fetch tells.
 
+The BLOCK TICK (a model with `block_causal` B > 1: generation by
+diffusion over blocks) is the same step program at a tile of B
+positions a lane: a lane carries its open block (B tokens, each
+position's reveal step) and its pass index beside its position, which
+is the block's start. A DENOISING pass (pass s < S, `denoise_steps`)
+runs the model over the block against the cache of every earlier
+block, with the mask token wherever nothing is revealed yet, caches
+nothing, reads at each masked position the greedy token and its
+softmax probability from the logits AT that position, and reveals the
+B / S of highest probability; pass S, the COMMIT pass, runs the final
+tokens, scatters the block's B rows and moves the lane on by B
+positions, its next block all masks. S + 1 passes yield B tokens. The
+schedule is static, so the host knows every lane's pass without a
+fetch and one step stays in flight as ever: the book (`_positions`,
+`_passes`) moves at the launch, a lane is freed at the launch of its
+last commit pass, and `_collect` hands a request a block's tokens
+(with the pass that revealed each, `request.reveal_steps`) at its
+commit pass and nothing at the others. The prompt's whole blocks are
+prefilled and written at seating, no token comes of it, and the
+prompt's last `p mod B` tokens open the first block as given
+positions. What cannot hold for such a model refuses to start, by
+name (`_refuse_what_a_block_model_cannot_hold`).
+
 The weights are served in the dtype the programs COMPUTE in, made
 once a load. The state handed in stays what a checkpoint holds (fp32);
 `_load_params` (construction and every hot reload) runs one jitted
@@ -115,6 +138,7 @@ then the int8 tree as handed in.
 """
 
 import collections
+import functools
 import os
 import time
 
@@ -227,8 +251,17 @@ def _tick_counts(sown):
 
 #: the columns of the device's lane state, an int32 [slots, 4 +
 #: max_blocks] array with a row a lane; the temperature is kept as its
-#: float32 bits
+#: float32 bits. A block engine (tile B > 1) keeps 2 B + 1 more columns
+#: behind the table: the open block's tokens, each position's reveal
+#: step, and the lane's pass index (`block_fields`)
 _LANE_POS, _LANE_TOKEN, _LANE_SEED, _LANE_TEMP, _LANE_TABLE = range(5)
+
+#: a position's reveal step in a block lane: not revealed yet (the
+#: model reads the mask token there), or a prompt token that opens the
+#: first generated block; else the denoising pass (0 .. S - 1) that
+#: revealed it. And a free lane's pass index
+MASKED, GIVEN = -1, -2
+_NO_PASS = -1
 
 #: in the MIRROR's token column: the host has not seen this lane's last
 #: token (its step is in flight, or was when the next was launched);
@@ -240,26 +273,67 @@ Lanes = collections.namedtuple(
     "Lanes", "tables positions last_tokens seeds temps")
 
 
-def lane_fields(lanes):
+def _block_columns(tile):
+    return 2 * tile + 1 if tile > 1 else 0
+
+
+def lane_fields(lanes, tile=1):
     """The lane state array (numpy, or jax inside a program) as its
     named parts: block tables [slots, max_blocks], positions, last
-    tokens, seeds (int32) and temperatures (float32)."""
+    tokens, seeds (int32) and temperatures (float32). `tile` B > 1: a
+    block engine's, whose last 2 B + 1 columns are `block_fields`."""
     temps = lanes[:, _LANE_TEMP]
     return Lanes(
-        lanes[:, _LANE_TABLE:], lanes[:, _LANE_POS],
+        lanes[:, _LANE_TABLE:lanes.shape[1] - _block_columns(tile)],
+        lanes[:, _LANE_POS],
         lanes[:, _LANE_TOKEN], lanes[:, _LANE_SEED],
         temps.view(np.float32) if isinstance(temps, np.ndarray)
         else jax.lax.bitcast_convert_type(temps, jnp.float32),
     )
 
 
-def _merge_lanes(sent, carried):
+def block_fields(lanes, tile):
+    """A block engine's lane state beyond `lane_fields`: the open
+    block's tokens [slots, B], each position's reveal step [slots, B]
+    (MASKED, GIVEN, or the pass that revealed it) and the pass index
+    [slots] (0 .. S - 1 a denoising pass, S the commit pass, _NO_PASS
+    a free lane)."""
+    at = lanes.shape[1] - _block_columns(tile)
+    return (lanes[:, at:at + tile], lanes[:, at + tile:at + 2 * tile],
+            lanes[:, at + 2 * tile])
+
+
+def _merge_lanes(sent, carried, tile=1):
     """The mirror as sent, with the token the device carries wherever
     the host's says `_KEEP`: a lane whose last token the host has not
-    seen (its step is in flight) keeps it."""
+    seen (its step is in flight) keeps it. A block lane keeps its open
+    block (tokens, reveal steps) the same way: what a pass revealed
+    only the device knows. Its pass index is the host's to know (the
+    schedule is static) and goes as sent: a lane freed since is free."""
     token = sent[:, _LANE_TOKEN]
-    return sent.at[:, _LANE_TOKEN].set(
-        jnp.where(token == _KEEP, carried[:, _LANE_TOKEN], token))
+    keep = token == _KEEP
+    out = sent.at[:, _LANE_TOKEN].set(
+        jnp.where(keep, carried[:, _LANE_TOKEN], token))
+    if tile > 1:
+        at = sent.shape[1] - _block_columns(tile)
+        out = out.at[:, at:-1].set(
+            jnp.where(keep[:, None], carried[:, at:-1], sent[:, at:-1]))
+    return out
+
+
+def _reveal_by_confidence(prob, masked, count):
+    """[slots, B] bool: of each lane's masked positions the `count` of
+    highest probability, ties to the lower position (the static
+    low-confidence reveal of a block-diffusion model)."""
+    tile = prob.shape[1]
+    p = jnp.where(masked, prob, -1.0)
+    at = jnp.arange(tile)
+    # beats[l, i, j]: masked position j goes before position i
+    beats = ((p[:, None, :] > p[:, :, None])
+             | ((p[:, None, :] == p[:, :, None])
+                & (at[None, None, :] < at[None, :, None])))
+    rank = jnp.sum(beats & masked[:, None, :], axis=-1)
+    return masked & (rank < count)
 
 
 def _at_path(tree, path):
@@ -296,9 +370,12 @@ class _Slot(object):
 
 #: a decode step launched and not yet committed: the array its tokens
 #: (and the tick's counters behind them) come home in, the lanes it ran
-#: as [(slot, _Slot, whether this was the lane's last step)], and the
-#: weights' version it ran under
-_Flight = collections.namedtuple("_Flight", "tokens ran version")
+#: as [(slot, _Slot, whether this was the lane's last step)], the
+#: weights' version it ran under, and which of those lanes' passes
+#: yield tokens: all (None), or, of a block step, the slots at their
+#: commit pass
+_Flight = collections.namedtuple("_Flight", "tokens ran version commits",
+                                 defaults=(None,))
 
 
 class _PrefillJob(object):
@@ -425,7 +502,7 @@ class PagedContinuousBatchingEngine(object):
     def __init__(self, trainer, state, num_slots, top_k=0, top_p=1.0,
                  block_size=16, num_blocks=0, share_prefix=True,
                  draft=None, draft_k=0, host_bytes=None,
-                 prefill_chunk_tokens=None):
+                 prefill_chunk_tokens=None, denoise_steps=0):
         import inspect
 
         model = trainer.model
@@ -502,6 +579,23 @@ class PagedContinuousBatchingEngine(object):
         # read under-reports blocking by at most one prefill, which
         # the attribution tolerates by design.
         self.prefill_busy_ms = 0.0
+        # the tile: positions a lane a tick. 1, or the block length of
+        # a block-diffusion model (the module docstring, BLOCK TICK),
+        # which takes `denoise_steps` S denoising passes a block (0 =
+        # one a position) and then the commit pass
+        self._tile = max(1, int(getattr(model, "block_causal", 0) or 0))
+        self.denoise_steps = int(denoise_steps)
+        self._mask_token = int(getattr(model, "mask_token", -1))
+        if self._tile > 1:
+            self.denoise_steps = self.denoise_steps or self._tile
+            self._refuse_what_a_block_model_cannot_hold(
+                draft, draft_k, share_prefix)
+        elif self.denoise_steps:
+            raise ValueError(
+                "denoise_steps %d is the number of denoising passes a "
+                "block of a block-diffusion model (block_causal > 1) "
+                "takes; this model yields a token a step (--denoise_steps "
+                "0)" % self.denoise_steps)
 
         from elasticdl_tpu.serving.kv_pool import PagedKVPool
 
@@ -520,6 +614,13 @@ class PagedContinuousBatchingEngine(object):
                                   for path in self._state_paths})
         if self._state_layers:
             self._refuse_what_needs_a_state_snapshot(draft, draft_k)
+            if self._tile > 1:
+                raise ValueError(
+                    "a model that denoises a block of positions a step "
+                    "(block_causal) cannot keep a per-sequence state "
+                    "(a state-space layer): a denoising pass would have "
+                    "to leave the state as it found it, which nothing "
+                    "does yet")
         self.kv = PagedKVPool(
             self._kv_shapes, self.seq_len, self.num_slots,
             self.num_blocks, self.block_size,
@@ -543,6 +644,15 @@ class PagedContinuousBatchingEngine(object):
         self._last_tokens = np.zeros(self.num_slots, np.int32)
         self._seeds = np.zeros(self.num_slots, np.int32)
         self._temps = np.zeros(self.num_slots, np.float32)
+        # a block engine's book of each lane's open block: what the
+        # host knows of it (a lane seated since the last launch: the
+        # given positions, the rest masked) and the pass the next
+        # launch runs, which the host always knows
+        self._block_tokens = np.zeros((self.num_slots, self._tile),
+                                      np.int32)
+        self._block_reveal = np.full((self.num_slots, self._tile),
+                                     MASKED, np.int32)
+        self._passes = np.full(self.num_slots, _NO_PASS, np.int32)
         # the lane state on the device as the last step launched hands
         # it back (None: not to be trusted, the next launch sends the
         # mirror), and whether the host has written a lane's scalars
@@ -610,6 +720,88 @@ class PagedContinuousBatchingEngine(object):
                 "chunked prefill (prefill_chunk_tokens)",
                 "--prefill_chunk_tokens 0 / EDL_PREFILL_CHUNK_TOKENS "
                 "unset"))
+
+    def _refuse_what_a_block_model_cannot_hold(self, draft, draft_k,
+                                               share_prefix):
+        """A model that generates by diffusion over blocks of B
+        positions: S must divide B (the static schedule reveals B / S
+        positions a pass), a pool block must hold whole model blocks,
+        and what assumes a token a step, or the open block's rows in
+        the pool, is refused by name."""
+        B, S = self._tile, self.denoise_steps
+        if S < 1 or B % S:
+            raise ValueError(
+                "denoise_steps %d does not divide the model's block of "
+                "%d positions: the static reveal takes B / S positions a "
+                "pass (--denoise_steps one of %s)"
+                % (S, B, [d for d in range(1, B + 1) if B % d == 0]))
+        if self.block_size % B or self.seq_len % B:
+            raise ValueError(
+                "kv_block_size %d and seq_len %d must be whole multiples "
+                "of the model's block of %d positions, which is written "
+                "to the pool as one" % (self.block_size, self.seq_len, B))
+        if self._mask_token < 0:
+            raise ValueError(
+                "a model that generates by diffusion over blocks "
+                "(block_causal) names the id a position not revealed yet "
+                "reads: mask_token is not set")
+        why = ("this model generates by diffusion over blocks of %d "
+               "positions (block_causal), and %s. Start the server "
+               "without it (%s)")
+        if draft is not None and int(draft_k) >= 1:
+            raise ValueError(why % (
+                B, "speculative decode (draft, draft_k) verifies tokens "
+                "a causal step would have produced one by one",
+                "--draft_k 0, no --draft_model_def"))
+        if self.prefill_chunk_tokens:
+            raise ValueError(why % (
+                B, "chunked prefill (prefill_chunk_tokens) samples a "
+                "first token from the prompt's last tile, which a block "
+                "model does not have", "--prefill_chunk_tokens 0 / "
+                "EDL_PREFILL_CHUNK_TOKENS unset"))
+        if share_prefix:
+            raise ValueError(why % (
+                B, "seating on a shared prefix (kv_shared) re-runs the "
+                "prompt's tail for a first token, which a block model "
+                "does not have; seating the given positions behind a "
+                "shared chain is not built yet",
+                "--kv_shared 0 / EDL_KV_SHARED=0"))
+        if self.host_bytes:
+            raise ValueError(why % (
+                B, "the host tier (kv_host_bytes) revives the chains "
+                "that prefix sharing keeps",
+                "--kv_host_bytes 0 / EDL_KV_HOST_BYTES unset"))
+        if self.top_k or self.top_p < 1.0:
+            raise ValueError(why % (
+                B, "the reveal reads each position's greedy token and "
+                "its probability: sampling filters (top_k, top_p) and a "
+                "temperature have no meaning yet",
+                "--top_k 0 --top_p 1.0"))
+
+    def refuse_request(self, request):
+        """Why this engine cannot serve `request` at all (None: it
+        can): asked at admission, so that a request is turned away
+        with INVALID_ARGUMENT and never reaches a lane."""
+        if self._tile == 1:
+            return None
+        if request.temperature > 0.0:
+            return ("this model generates by diffusion over blocks and "
+                    "is served greedy: temperature %g is refused (0)"
+                    % request.temperature)
+        if getattr(request, "prefill_only", False):
+            return ("this model generates by diffusion over blocks: a "
+                    "prefill-only seat (the disagg handoff) is not "
+                    "built for it")
+        return None
+
+    def _rows_cached(self, request):
+        """The KV rows a seated `request` may come to hold: every
+        position but the last token's (nobody attends from beyond it),
+        or, block by block, up to the end of its last block."""
+        total = len(request.prompt) + request.max_new_tokens
+        if self._tile == 1:
+            return total - 1
+        return -(-total // self._tile) * self._tile
 
     def _init_draft(self, draft, draft_k):
         """Seat the draft model for speculative decode: its own dense
@@ -822,12 +1014,11 @@ class PagedContinuousBatchingEngine(object):
         """Whether `request` can be seated RIGHT NOW beyond needing a
         free slot (the scheduler checks slots separately): answered
         from the block budget."""
-        if (request.max_new_tokens <= 1
+        if (request.max_new_tokens <= 1 and self._tile == 1
                 and not getattr(request, "prefill_only", False)):
             return True  # one-token answer; never touches the pool
-        cached = len(request.prompt) + request.max_new_tokens - 1
         return self.kv.can_seat(request.prompt, len(request.prompt),
-                                cached)
+                                self._rows_cached(request))
 
     def max_cached_tokens(self):
         """Largest prompt+decode cache footprint a request may ever
@@ -879,6 +1070,8 @@ class PagedContinuousBatchingEngine(object):
                 "request needs %d positions > seq_len %d"
                 % (total, self.seq_len)
             )
+        if self._tile > 1:
+            return self._insert_block(slot, request)
         prefill_only = getattr(request, "prefill_only", False)
         if prefill_only and self._state_layers:
             raise ValueError(
@@ -894,7 +1087,7 @@ class PagedContinuousBatchingEngine(object):
             revived_before = self.kv.allocator.blocks_revived
             seat_t0 = time.perf_counter()
             shared = self.kv.seat(slot, request.prompt,
-                                  p + request.max_new_tokens - 1)
+                                  self._rows_cached(request))
             revived = (self.kv.allocator.blocks_revived
                        - revived_before)
             if revived and hasattr(request, "trace_event"):
@@ -910,11 +1103,7 @@ class PagedContinuousBatchingEngine(object):
         if decoding and shared:
             first = self._insert_shared(slot, request, shared)
         else:
-            p_pad = _prefill_bucket(p, self.seq_len)
-            fn = self._prefill_fns.get(p_pad)
-            if fn is None:
-                fn = self._build_prefill(p_pad)
-                self._prefill_fns[p_pad] = fn
+            p_pad, fn = self._prefill_for(p)
             buf = np.zeros((1, self.seq_len), np.int32)
             buf[0, :p] = request.prompt
             with tracing.phase("prefill", trace_id=_trace_id(request),
@@ -953,6 +1142,58 @@ class PagedContinuousBatchingEngine(object):
         self._activate(slot, request, first)
         return slot, first, False
 
+    def _prefill_for(self, p):
+        """(bucket, its compiled prefill) for a prompt of `p` tokens."""
+        p_pad = _prefill_bucket(p, self.seq_len)
+        fn = self._prefill_fns.get(p_pad)
+        if fn is None:
+            fn = self._prefill_fns[p_pad] = self._build_prefill(p_pad)
+        return p_pad, fn
+
+    def _insert_block(self, slot, request):
+        """Seat a request of a block-diffusion model: the prompt's
+        whole blocks are prefilled under the block-causal mask and
+        written to the slot's blocks (none for a prompt shorter than a
+        block); no token comes of it. Returns (slot, None, False): the
+        first tokens come with the first block's commit pass."""
+        p = len(request.prompt)
+        whole = p // self._tile * self._tile
+        self.kv.seat(slot, request.prompt, self._rows_cached(request))
+        if whole:
+            p_pad, fn = self._prefill_for(whole)
+            buf = np.zeros((1, self.seq_len), np.int32)
+            buf[0, :whole] = request.prompt[:whole]
+            with tracing.phase("prefill", trace_id=_trace_id(request),
+                               prompt_tokens=whole, bucket=p_pad):
+                with self.trainer.mesh:
+                    kv, _first = fn(
+                        self._exec_variables, jnp.asarray(buf),
+                        jnp.asarray(whole, jnp.int32),
+                        jnp.asarray(request.seed, jnp.int32),
+                        jnp.asarray(0.0, jnp.float32),
+                    )
+                    self.kv.write_prompt(kv, slot, whole)
+            if hasattr(request, "trace_event"):
+                request.trace_event("prefill", bucket=p_pad, slot=slot,
+                                    paged=True)
+        request.model_version = self.model_version
+        self._activate(slot, request, None)
+        return slot, None, False
+
+    def _open_block(self, slot, st):
+        """Write lane `slot`'s open block into the book as the host
+        knows it: at pass 0, the prompt's remainder given where the
+        block is the request's first, every other position masked."""
+        request = st.request
+        p = len(request.prompt)
+        given = ([] if request.generated
+                 else request.prompt[p // self._tile * self._tile:])
+        self._block_tokens[slot] = self._mask_token
+        self._block_tokens[slot, :len(given)] = given
+        self._block_reveal[slot] = MASKED
+        self._block_reveal[slot, :len(given)] = GIVEN
+        self._passes[slot] = 0
+
     def _activate(self, slot, request, first):
         """Start decoding `request` in `slot`, its prompt's rows
         resident and `first` its first generated token: the lane's
@@ -960,9 +1201,13 @@ class PagedContinuousBatchingEngine(object):
         p = len(request.prompt)
         self._slots[slot] = _Slot(request, p + request.max_new_tokens)
         self._positions[slot] = p
-        self._last_tokens[slot] = first
+        self._last_tokens[slot] = first or 0
         self._seeds[slot] = request.seed
         self._temps[slot] = request.temperature
+        if self._tile > 1:
+            # a block lane sits at its open block's start
+            self._positions[slot] = p // self._tile * self._tile
+            self._open_block(slot, self._slots[slot])
         self._lanes_dirty = True
 
     def _insert_shared(self, slot, request, shared):
@@ -1193,6 +1438,7 @@ class PagedContinuousBatchingEngine(object):
         `release`)."""
         self._slots[slot] = None
         self._positions[slot] = 0
+        self._passes[slot] = _NO_PASS
         self._lanes_dirty = True
         self.kv.release(slot)
 
@@ -1262,10 +1508,17 @@ class PagedContinuousBatchingEngine(object):
             if lanes is None:
                 self._last_tokens[:] = 0
                 for slot, st in self._seated():
-                    self._last_tokens[slot] = st.request.generated[-1]
+                    if self._tile > 1:
+                        # what its passes revealed is lost with the
+                        # device's state: the open block starts anew
+                        self._open_block(slot, st)
+                    else:
+                        self._last_tokens[slot] = st.request.generated[-1]
             sent = jax.device_put(np.column_stack(
                 [self._positions, self._last_tokens, self._seeds,
                  self._temps.view(np.int32), self.kv.tables]
+                + ([self._block_tokens, self._block_reveal, self._passes]
+                   if self._tile > 1 else [])
                 + ([] if budgets is None else [budgets])))
             if budgets is None:
                 sent = self._merge_fn(sent, sent if lanes is None else lanes)
@@ -1325,12 +1578,15 @@ class PagedContinuousBatchingEngine(object):
         active = self._seated()
         if not active:
             return False
+        tile, commit_pass = self._tile, self.denoise_steps
         with tracing.phase("tick.ensure"):
             for i, _st in active:
                 # the block this step writes (position = the slot's
-                # pos); drawn from the slot's reservation, so it
-                # cannot fail
-                self.kv.ensure_blocks(i, int(self._positions[i]))
+                # pos; a block lane's B rows, at its commit pass, lie
+                # in one pool block); drawn from the slot's
+                # reservation, so it cannot fail
+                if tile == 1 or self._passes[i] == commit_pass:
+                    self.kv.ensure_blocks(i, int(self._positions[i]))
             # an extend's pop can spill under pressure: keep the
             # telemetry mirror current even on decode-only ticks
             self._sync_host_telemetry()
@@ -1351,20 +1607,30 @@ class PagedContinuousBatchingEngine(object):
                 self._lanes, tokens = self.kv.update(
                     self._step_fn, self._exec_variables, lanes
                 )
-                ran = []
+                ran, commits = [], (None if tile == 1 else set())
                 for slot, st in active:
-                    self._positions[slot] += 1
                     self._last_tokens[slot] = _KEEP
+                    # a block lane moves on at its commit pass alone
+                    commit = tile == 1 or self._passes[slot] == commit_pass
+                    if tile > 1:
+                        self._passes[slot] = (
+                            0 if commit else self._passes[slot] + 1)
+                    if commit:
+                        self._positions[slot] += tile
+                        if tile > 1:
+                            commits.add(slot)
                     # the first token came with the seating, one more
-                    # with every step launched since
-                    last = bool(
-                        self._positions[slot] + 1 >= st.max_total)
+                    # with every step launched since (a block model's
+                    # tokens all come of its commit passes)
+                    last = commit and bool(
+                        self._positions[slot] + (tile == 1)
+                        >= st.max_total)
                     if last:
                         self._free(slot)
                         self._landing += 1
                     ran.append((slot, st, last))
-                self._flights.append(
-                    _Flight(tokens, ran, self.model_version))
+                self._flights.append(_Flight(
+                    tokens, ran, self.model_version, commits))
         return True
 
     def _collect(self):
@@ -1380,20 +1646,47 @@ class PagedContinuousBatchingEngine(object):
             nxt = np.asarray(flight.tokens)  # the host waits here
         self._flights.popleft()
         out = []
+        tile = self._tile
+        # a block step's tokens: every lane's block, then every
+        # position's reveal step
+        n_tokens = self.num_slots * (2 * tile if tile > 1 else 1)
         with tracing.phase("tick.commit"):
-            for name, n in zip(self._tick_counters,
-                               nxt[self.num_slots:]):
+            for name, n in zip(self._tick_counters, nxt[n_tokens:]):
                 tracing.count(name, int(n))
             for slot, st, last in flight.ran:
                 if last:
                     self._landing -= 1
                 if st.evicted:
                     continue
-                token = int(nxt[slot])
-                st.request.generated.append(token)
-                st.request.model_version = flight.version
-                out.append((slot, st.request, [token], last))
+                request = st.request
+                if tile == 1:
+                    tokens = [int(nxt[slot])]
+                elif slot in flight.commits:
+                    tokens = self._block_committed(request, nxt, slot)
+                else:
+                    tokens = []  # a denoising pass yields none
+                request.generated.extend(tokens)
+                request.model_version = flight.version
+                out.append((slot, request, tokens, last))
         return out
+
+    def _block_committed(self, request, fetched, slot):
+        """The tokens a block lane's commit pass hands its request: the
+        block's, less the positions the prompt gave and whatever lies
+        beyond `max_new_tokens` (the last block is trimmed); each
+        token's reveal step goes to `request.reveal_steps` beside
+        it."""
+        tile = self._tile
+        block = fetched[slot * tile:(slot + 1) * tile]
+        reveal = fetched[(self.num_slots + slot) * tile:
+                         (self.num_slots + slot + 1) * tile]
+        room = request.max_new_tokens - len(request.generated)
+        new = np.flatnonzero(reveal != GIVEN)[:room]
+        steps = getattr(request, "reveal_steps", None)
+        if steps is not None:
+            steps.extend(int(x) for x in reveal[new])
+        tracing.count("diffusion.blocks_committed")
+        return [int(x) for x in block[new]]
 
     def _spec_step(self, active):
         """One speculative tick: k drafted tokens per slot, verified
@@ -1504,7 +1797,8 @@ class PagedContinuousBatchingEngine(object):
     def _lanes_spec(self, budgets=False):
         """The shape of what _tick_lanes hands a tick's program, for
         who traces one without running it."""
-        width = _LANE_TABLE + self.kv.max_blocks_per_slot + bool(budgets)
+        width = (_LANE_TABLE + self.kv.max_blocks_per_slot
+                 + _block_columns(self._tile) + bool(budgets))
         return jax.ShapeDtypeStruct((self.num_slots, width), jnp.int32)
 
     def _tjit(self, name, fn, **jit_kwargs):
@@ -1545,11 +1839,23 @@ class PagedContinuousBatchingEngine(object):
         state_paths = self._state_paths
         state_at = [i for i, kind in enumerate(self.kv.kinds)
                     if kind == STATE]
+        # the tile: 1, or a block-diffusion model's block (BLOCK TICK)
+        tile, n_passes = self._tile, self.denoise_steps
+        mask_token = self._mask_token
+        max_blocks = self.kv.max_blocks_per_slot
 
         def step(pools, variables, lanes):
             variables = _maybe_dequantize(variables, qz)
             tables, positions, last_tokens, seeds, temps = lane_fields(
-                lanes)
+                lanes, tile)
+            if tile > 1:
+                # a block lane reads its open block, the mask token
+                # wherever nothing is revealed yet; its pass rides
+                # beside the tokens and says whether it is seated
+                block, reveal, passes = block_fields(lanes, tile)
+                last_tokens = jnp.concatenate(
+                    [jnp.where(reveal == MASKED, mask_token, block),
+                     passes[:, None]], axis=1)
             # the per-slot state arenas ride the lanes' axis: lane i
             # reads and writes slot i (none for a model without state
             # layers)
@@ -1568,24 +1874,42 @@ class PagedContinuousBatchingEngine(object):
                 cache = {"pos": pos}
                 for path, leaf in zip(state_paths, state):
                     _set_path(cache, path, leaf[None])  # a batch of one
+                if tile == 1:
+                    # a lane at position 0 (free, or its prompt still
+                    # being written) carries no sequence
+                    tokens, live = tok[None, None], (pos > 0)[None]
+                else:
+                    # a free block lane has no pass (its first block
+                    # may start at position 0)
+                    tokens, live = tok[None, :tile], (tok[tile] >= 0)[None]
                 with jax.named_scope("paged_slot"):
                     logits, aux = model.apply(
                         dict(variables, cache=cache),
-                        {"tokens": tok[None, None]},
+                        {"tokens": tokens},
                         training=False, decode=True,
                         mutable=["cache", "kv_out", "counters"],
-                        # a lane at position 0 (free, or its prompt
-                        # still being written) carries no sequence
                         paged={"pools": pools, "table": table[None],
-                               "live": (pos > 0)[None]},
+                               "live": live},
                     )
-                nxt = serving_next_token(
-                    logits[0, 0], seed, pos + 1, temp, top_k, top_p
-                )
-                rows = jax.tree.map(
-                    lambda t: t[0][0, :, 0, :], aux.get("kv_out", {}),
-                    is_leaf=lambda x: isinstance(x, tuple),
-                )  # sown [1, hkv, 1, d] -> [hkv, d]
+                if tile == 1:
+                    nxt = serving_next_token(
+                        logits[0, 0], seed, pos + 1, temp, top_k, top_p
+                    )
+                    rows = jax.tree.map(
+                        lambda t: t[0][0, :, 0, :], aux.get("kv_out", {}),
+                        is_leaf=lambda x: isinstance(x, tuple),
+                    )  # sown [1, hkv, 1, d] -> [hkv, d]
+                else:
+                    # each position's greedy token and its softmax
+                    # probability, from the logits AT that position
+                    z = logits[0] - jnp.max(logits[0], -1, keepdims=True)
+                    nxt = (jnp.argmax(logits[0], -1).astype(jnp.int32),
+                           1.0 / jnp.sum(jnp.exp(z), -1))
+                    rows = jax.tree.map(
+                        lambda t: t[0][0].transpose(1, 0, 2),
+                        aux.get("kv_out", {}),
+                        is_leaf=lambda x: isinstance(x, tuple),
+                    )  # sown [1, hkv, B, d] -> [B, hkv, d]
                 state = [_at_path(aux["cache"], path)[0]
                          for path in state_paths]
                 return nxt, rows, aux.get("counters", {}), state
@@ -1599,6 +1923,48 @@ class PagedContinuousBatchingEngine(object):
             # what the model's layers counted this tick rides home
             # behind the tokens, in the one array the host fetches
             counted = _tick_counts(sown)
+            if tile > 1:
+                greedy, prob = nxt
+                seated = passes >= 0
+                denoise = seated & (passes < n_passes)
+                commit = seated & (passes == n_passes)
+                # a denoising pass reveals its B / S most confident
+                # masked positions; a revealed token never changes
+                now = denoise[:, None] & _reveal_by_confidence(
+                    prob, reveal == MASKED, tile // n_passes)
+                block = jnp.where(now, greedy, block)
+                reveal = jnp.where(now, passes[:, None], reveal)
+                counted.update({
+                    "diffusion.lane_passes": jnp.sum(seated),
+                    "diffusion.commit_passes": jnp.sum(commit),
+                    "diffusion.tokens_revealed": jnp.sum(now),
+                })
+                tick_counters[:] = sorted(counted)
+                # what the host fetches: every lane's block and reveal
+                # steps (read at a lane's commit pass, when they are
+                # final), then the counters
+                fetched = jnp.concatenate(
+                    [block.reshape(-1), reveal.reshape(-1)]
+                    + [counted[name][None] for name in tick_counters]
+                ).astype(jnp.int32)
+                # the commit pass writes the block's rows and moves
+                # the lane on, its next block all masks
+                at = lanes.shape[1] - _block_columns(tile)
+                lanes = lanes.at[:, _LANE_POS].add(
+                    jnp.where(commit, tile, 0)
+                ).at[:, at:].set(jnp.concatenate([
+                    jnp.where(commit[:, None], mask_token, block),
+                    jnp.where(commit[:, None], MASKED, reveal),
+                    jnp.where(commit, 0, passes + denoise)[:, None],
+                ], axis=1))
+                wpos = positions[:, None] + jnp.arange(tile)[None, :]
+                bids = jnp.take_along_axis(
+                    tables, jnp.minimum(wpos // block_size, max_blocks - 1),
+                    axis=1)
+                bids = jnp.where(commit[:, None] & (bids >= 0), bids,
+                                 num_blocks)
+                pools = scatter_rows(pools, rows, bids, wpos % block_size)
+                return pools, (lanes, fetched)
             tick_counters[:] = sorted(counted)
             # the state the next tick starts from: a seated lane is
             # one position on and its last token is the one just
@@ -1635,7 +2001,9 @@ class PagedContinuousBatchingEngine(object):
         # with it the small program of the launches that send the
         # mirror: every such launch runs it, the first one too, so it
         # is compiled with the step and never later
-        self._merge_fn = self._tjit("merge_lanes", _merge_lanes)
+        self._merge_fn = self._tjit(
+            "merge_lanes", _merge_lanes if self._tile == 1
+            else functools.partial(_merge_lanes, tile=self._tile))
         return self._tjit("paged_step", self._paged_step_program(),
                           donate_argnums=(0,))
 

@@ -87,6 +87,10 @@ def parse_serving_args(args=None):
                         help="zoo model_def for the draft; empty = "
                              "speculative decode off")
     parser.add_argument("--draft_model_params", default="")
+    # a block-diffusion model (block_causal > 1) only: the denoising
+    # passes a block takes before its commit pass; 0 = the block
+    # length (one position a pass). Must divide the block length
+    parser.add_argument("--denoise_steps", type=int, default=0)
     # pre-READY warmup: generate this many tokens in-process before
     # printing the readiness line, so the jit compile is paid BEFORE a
     # router/autoscaler routes live traffic here (a freshly adopted
@@ -216,6 +220,7 @@ def build_server(args):
             kv_host_bytes=(None if args.kv_host_bytes < 0
                            else args.kv_host_bytes),
             draft_k=draft_k if draft is not None else 0,
+            denoise_steps=args.denoise_steps,
             metrics_port=(None if args.metrics_port < 0
                           else args.metrics_port),
             forensics=(None if args.forensics < 0

@@ -117,6 +117,11 @@ class ServingConfig(object):
     into a speculative draft-verify step committing up to draft_k + 1
     tokens, token-exact with plain decode.
 
+    denoise_steps (a block-diffusion model only, `block_causal` > 1):
+    the denoising passes S a block of B positions takes before its
+    commit pass (0 = B, one position a pass); B % S == 0 or the server
+    refuses to start. A streamed chunk is then a committed block.
+
     kv_host_bytes (None resolves from EDL_KV_HOST_BYTES,
     default 0 = off) bounds the host-RAM spill tier: evicted prefix
     chains demote to host buffers and revive by device upload instead
@@ -139,7 +144,7 @@ class ServingConfig(object):
                  forensics=None, runtime_health=None,
                  stall_after_secs=None, health_reconcile_secs=2.0,
                  health_dir=None, role=None, prefill_chunk_tokens=None,
-                 prefill_budget_ms=None):
+                 prefill_budget_ms=None, denoise_steps=0):
         self.num_slots = int(num_slots)
         self.queue_capacity = int(queue_capacity)
         self.top_k = int(top_k)
@@ -159,6 +164,7 @@ class ServingConfig(object):
             else bool(kv_shared)
         )
         self.draft_k = int(draft_k)
+        self.denoise_steps = int(denoise_steps)
         self.kv_host_bytes = (
             kv_host_bytes_default() if kv_host_bytes is None
             else int(kv_host_bytes)
@@ -376,10 +382,15 @@ class _Scheduler(threading.Thread):
             # getattr keeps bare test engines valid
             seated = getattr(self.engine, "seated_count",
                              self.engine.active_count)
+            # a block engine's tick also says how many lanes' passes
+            # the step it committed ran
+            more = ({"passes": self._tick_passes}
+                    if getattr(self.engine, "denoise_steps", 0) else {})
             tracing.end(tick, active=seated(),
-                        queue_depth=len(self.queue))
+                        queue_depth=len(self.queue), **more)
 
     def _tick(self):
+        self._tick_passes = 0
         self._run_jobs()
         if self.watcher is not None:
             reloaded = self.watcher.poll()
@@ -423,9 +434,10 @@ class _Scheduler(threading.Thread):
             results = self.engine.step()
             dt = self._clock() - t0
             committed = 0
+            self._tick_passes = len(results)
             with tracing.phase("tick.stream"):
                 for _slot, req, tokens, finished in results:
-                    req.push(("tokens", list(tokens), req.model_version))
+                    self._stream(req, tokens)
                     committed += len(tokens)
                     if finished:
                         self._complete(req)
@@ -448,6 +460,21 @@ class _Scheduler(threading.Thread):
         # pending prefills and no decode: loop again immediately —
         # the next tick runs another budget's worth of tiles and
         # still polls admission between them
+
+    def _stream(self, req, tokens):
+        """Push what a step gave `req`: a token (or a speculative
+        step's few), or a block-diffusion model's committed block with
+        the pass that revealed each token; nothing for a denoising
+        pass, which yields none. The first tokens of a request that
+        got none at its seating (a block model's) mark its TTFT."""
+        if not tokens:
+            return
+        if req.first_token_at is None:
+            req.first_token_at = self._clock()
+            ttft_ms = self.telemetry.record_ttft(req)
+            req.trace_event("first_token", ttft_ms=round(ttft_ms, 3))
+        req.push(("tokens", list(tokens), req.model_version,
+                  req.reveal_steps[-len(tokens):]))
 
     def _advance_prefills(self):
         """Run pending chunked-prefill tiles, round-robin, under the
@@ -502,13 +529,14 @@ class _Scheduler(threading.Thread):
         completion for one-shot (max_new_tokens <= 1 / prefill-only)
         requests."""
         req = job.request
+        req.first_token_at = self._clock()
         ttft_ms = self.telemetry.record_ttft(req)
         req.trace_event("first_token", slot=job.slot,
                         ttft_ms=round(ttft_ms, 3))
         # the prefill produced this token; step() only counts the
         # decode-loop tokens
         self.telemetry.count("tokens_generated")
-        req.push(("tokens", [job.first], req.model_version))
+        req.push(("tokens", [job.first], req.model_version, []))
         if job.finished:
             self._complete(req)
 
@@ -611,13 +639,19 @@ class _Scheduler(threading.Thread):
                 else:
                     self._pending_prefills.append(job)
                 continue
+            if first is None:
+                # a block-diffusion model: the prompt's blocks are
+                # cached and no token has come of it; the first come
+                # with its first block's commit pass (_stream)
+                continue
+            req.first_token_at = self._clock()
             ttft_ms = self.telemetry.record_ttft(req)
             req.trace_event("first_token", slot=slot,
                             ttft_ms=round(ttft_ms, 3))
             # the prefill produced this token; step() only counts the
             # decode-loop tokens
             self.telemetry.count("tokens_generated")
-            req.push(("tokens", [first], req.model_version))
+            req.push(("tokens", [first], req.model_version, []))
             if finished:
                 self._complete(req)
 
@@ -650,7 +684,7 @@ class _Scheduler(threading.Thread):
             if not self.engine.active_count():
                 continue
             for _slot, req, tokens, finished in self.engine.step():
-                req.push(("tokens", list(tokens), req.model_version))
+                self._stream(req, tokens)
                 if finished:
                     self._complete(req)
 
@@ -715,7 +749,7 @@ class ServingServicer(object):
 
     def generate(self, request, context=None):
         req = self._admit(request, context)
-        for _chunk, _version in self._events(req, context):
+        for _chunk, _version, _steps in self._events(req, context):
             pass  # unary: accumulate; req.generated holds the tokens
         return pb.GenerateResponse(
             tokens=req.prompt + req.generated,
@@ -726,9 +760,10 @@ class ServingServicer(object):
         req = self._admit(request, context)
 
         def stream():
-            for chunk, version in self._events(req, context):
+            for chunk, version, steps in self._events(req, context):
                 yield pb.TokenChunk(
-                    tokens=chunk, done=False, model_version=version
+                    tokens=chunk, done=False, model_version=version,
+                    reveal_steps=steps,
                 )
             yield pb.TokenChunk(
                 tokens=[], done=True, model_version=req.model_version
@@ -1008,7 +1043,9 @@ class ServingServicer(object):
         return req
 
     def _events(self, req, context):
-        """Yield ("tokens" chunks, version) until done; terminate with a
+        """Yield ("tokens" chunks, version, reveal steps) until done
+        (the last empty but for a block-diffusion model, whose chunk is
+        a committed block); terminate with a
         clean status on error/expiry/scheduler loss. The timeout'd wait
         is the no-hang backstop: even if the scheduler vanishes without
         pushing a terminal event, the handler notices within one poll."""
@@ -1027,7 +1064,7 @@ class ServingServicer(object):
                 continue
             kind = ev[0]
             if kind == "tokens":
-                yield ev[1], ev[2]
+                yield ev[1], ev[2], ev[3]
             elif kind == "done":
                 return
             else:  # ("error", code, message)
@@ -1064,7 +1101,14 @@ class GenerationServer(object):
             draft=draft, draft_k=cfg.draft_k,
             host_bytes=cfg.kv_host_bytes,
             prefill_chunk_tokens=cfg.prefill_chunk_tokens,
+            denoise_steps=cfg.denoise_steps,
         )
+        if self.engine.denoise_steps and cfg.role != "unified":
+            raise ValueError(
+                "this model generates by diffusion over blocks, and a %r "
+                "replica (role) hands a prompt's KV chain to a sibling "
+                "that would decode it a token a step. Start it unified "
+                "(--role unified / EDL_SERVING_ROLE unset)" % (cfg.role,))
         if self.engine.kv.has_state and cfg.role != "unified":
             raise ValueError(
                 "this model keeps a per-sequence state (a state-space "
@@ -1075,6 +1119,7 @@ class GenerationServer(object):
         self.queue = RequestQueue(
             cfg.queue_capacity, self.engine.seq_len,
             max_cached_tokens=self.engine.max_cached_tokens(),
+            refuse=self.engine.refuse_request,
         )
         self.telemetry = ServingTelemetry(
             log_dir=cfg.telemetry_dir or None,

@@ -24,6 +24,7 @@ from elasticdl_tpu.ops.attention import (
     NEG_INF,
     apply_rope,
     blockwise_attention,
+    causal_limit,
     expand_kv,
     flash_attention,
     jax_flash_attention,
@@ -108,6 +109,16 @@ class CausalSelfAttention(nn.Module):
     # attention reads. Write-side rounding costs one quantize per
     # generated token — negligible next to the read stream.
     kv_cache_dtype: str = ""
+    # per-head RMSNorm of q and of k over head_dim, each with its own
+    # learned gain (`q_norm/scale`, `k_norm/scale`), before the rotary
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
+    # block-causal attention (a block-diffusion model): row i sees key
+    # j iff j // block_causal <= i // block_causal, blocks aligned at
+    # absolute position 0: causal across blocks, every position of a
+    # row's own block in both directions. 0 = plain causal. Through
+    # prefill, the decode tile over the paged pool and the dense cache
+    block_causal: int = 0
 
     def _cache_vars(self, b, hkv, d, dtype):
         """The cache buffers in the configured storage format. Returns
@@ -221,6 +232,14 @@ class CausalSelfAttention(nn.Module):
             qkv[..., (h + hkv) * d:]
             .reshape(b, l, hkv, d).transpose(0, 2, 1, 3)
         )  # q: [b, h, l, d]; k/v: [b, hkv, l, d]
+        if self.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = nn.RMSNorm(epsilon=self.qk_norm_eps, dtype=self.dtype,
+                               name="q_norm")(q)
+                k = nn.RMSNorm(epsilon=self.qk_norm_eps, dtype=self.dtype,
+                               name="k_norm")(k)
+        if self.block_causal and not self.causal:
+            raise ValueError("block_causal needs a causal model")
         if decode:
             return self._decode_step(q, k, v, e, decode_pos,
                                      paged=paged)
@@ -282,6 +301,13 @@ class CausalSelfAttention(nn.Module):
             )
         window = self.window or None
         mesh = mesh_lib.current_mesh()
+        if self.block_causal > 1 and (
+                self.attn_impl == "jax_flash"
+                or (mesh is not None and mesh.size > 1)):
+            raise NotImplementedError(
+                "block_causal attention runs on one device through "
+                "attn_impl 'auto' or 'xla'; the sharded, ring, ulysses "
+                "and jax_flash paths have no such mask")
         if mesh is not None and mesh.shape.get(MeshAxis.SP, 1) > 1:
             # ring merges partials per kv rotation and ulysses
             # all-to-alls the head axis over sp — both want the full
@@ -314,7 +340,7 @@ class CausalSelfAttention(nn.Module):
         elif self.attn_impl == "xla":
             out = blockwise_attention(
                 q, k, v, causal=self.causal, window=window,
-                segments=segments,
+                segments=segments, block_causal=self.block_causal,
             )
         elif self.attn_impl == "jax_flash":
             if segments is not None:
@@ -333,7 +359,7 @@ class CausalSelfAttention(nn.Module):
         else:  # "auto" (validated above) on one device
             out = flash_attention(
                 q, k, v, causal=self.causal, window=window,
-                segments=segments,
+                segments=segments, block_causal=self.block_causal,
             )
         out = out.transpose(0, 2, 1, 3).reshape(b, l, h * d)
         return self._proj(out, e)
@@ -422,6 +448,7 @@ class CausalSelfAttention(nn.Module):
                     k_scale_pool=paged["k_scale"],
                     v_scale_pool=paged["v_scale"],
                     k_cur_scale=ksc, v_cur_scale=vsc,
+                    block_causal=self.block_causal,
                 ).astype(dtype)
                 out = out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
                 return self._proj(out, e)
@@ -432,6 +459,7 @@ class CausalSelfAttention(nn.Module):
                 paged["k"], paged["v"], paged["table"],
                 jnp.broadcast_to(idx, (b,)),
                 scale=d ** -0.5, window=self.window or None,
+                block_causal=self.block_causal,
             ).astype(dtype)
             out = out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
             return self._proj(out, e)
@@ -456,7 +484,7 @@ class CausalSelfAttention(nn.Module):
             ).astype(jnp.float32) * ks.value[..., 0][:, :, None, None]
         k_pos = jnp.arange(self.cache_len)[None, :]
         row_pos = (idx + jnp.arange(t))[:, None]
-        valid = k_pos <= row_pos  # [t, L]
+        valid = k_pos <= causal_limit(row_pos, self.block_causal)
         if self.window:
             valid = valid & (k_pos > row_pos - self.window)
         s = jnp.where(valid[None, None, None], s, NEG_INF)
@@ -498,7 +526,8 @@ class ExpertFFN(nn.Module):
     holds them all. What kind of layer it is comes from parameters:
 
     * `activation`: "reglu", gated experts of three matrices,
-      (relu(h W_gate) * (h W_up)) W_down; "relu2", experts of two,
+      (relu(h W_gate) * (h W_up)) W_down; "swiglu", the same with silu
+      in relu's place; "relu2", experts of two,
       relu(h W_up^T)^2 W_down: there is no `w_gate`, and `w_up` is
       [count, hidden, d] like `w_down`, a hidden unit a row, as a
       checkpoint stores an up projection (ops/expert_ffn.py says what
@@ -553,9 +582,9 @@ class ExpertFFN(nn.Module):
             raise ValueError(
                 "experts_held %r is no range of %d experts"
                 % (self.held, self.num_experts))
-        if self.activation not in ("reglu", "relu2"):
+        if self.activation not in ("reglu", "swiglu", "relu2"):
             raise ValueError("Unknown moe_activation %r (valid: 'reglu', "
-                             "'relu2')" % (self.activation,))
+                             "'swiglu', 'relu2')" % (self.activation,))
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError("Unknown moe_scoring %r (valid: 'softmax', "
                              "'sigmoid')" % (self.scoring,))
@@ -573,7 +602,7 @@ class ExpertFFN(nn.Module):
                             (d, self.num_experts), jnp.float32)
         into, back = (count, d, self.hidden), (count, self.hidden, d)
         banks = ((("w_gate", into, d), ("w_up", into, d))
-                 if self.activation == "reglu" else (("w_up", back, d),))
+                 if self.activation != "relu2" else (("w_up", back, d),))
         weights = [
             jnp.asarray(self.param(name, bank(fan_in), shape,
                                    jnp.float32), dtype)
@@ -603,7 +632,8 @@ class ExpertFFN(nn.Module):
             # takes the plain products
             y, held, hit = held_experts(
                 rows, gates, experts, weights, first=first,
-                use_kernel=False if training else None)
+                use_kernel=False if training else None,
+                activation=self.activation)
         if self.shared_hidden:
             with jax.named_scope("moe_shared"):
                 up, down = (
@@ -670,6 +700,12 @@ class Block(nn.Module):
     moe_scoring: str = "softmax"
     moe_route_scale: float = 1.0
     moe_shared_hidden: int = 0
+    # what the router of an attention-then-MLP block reads: "input",
+    # the block's own input (routing known before attention runs);
+    # "mlp", the normed input the experts multiply
+    moe_route_from: str = "input"
+    qk_norm: bool = False
+    block_causal: int = 0
     kind: str = ""  # "" attention then MLP | "*" | "M" | "E"
     ssm: tuple = ()  # Mamba2Mixer's fields, as sorted (name, value)
 
@@ -684,6 +720,8 @@ class Block(nn.Module):
             num_kv_heads=self.num_kv_heads,
             lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
             kv_cache_dtype=self.kv_cache_dtype,
+            qk_norm=self.qk_norm, qk_norm_eps=self.norm_eps,
+            block_causal=self.block_causal,
             name="attn",
         )
 
@@ -729,7 +767,13 @@ class Block(nn.Module):
                 % (self.kind,))
         y = _norm(self.norm, self.dtype, self.norm_eps)(x)
         if self.mlp == "moe_reglu":
-            y = self._experts()(y, block_in, training, live=live)
+            if self.moe_route_from not in ("input", "mlp"):
+                raise ValueError(
+                    "Unknown moe_route_from %r (valid: 'input', 'mlp')"
+                    % (self.moe_route_from,))
+            y = self._experts()(
+                y, block_in if self.moe_route_from == "input" else y,
+                training, live=live)
             return x + y.astype(x.dtype)
         if self.mlp != "gelu":
             raise ValueError(
@@ -869,6 +913,16 @@ class TransformerLM(nn.Module):
     moe_scoring: str = "softmax"
     moe_route_scale: float = 1.0
     moe_shared_hidden: int = 0
+    moe_route_from: str = "input"  # | "mlp" (Block)
+    # per-head RMSNorm of q and k (CausalSelfAttention), and the block
+    # length of block-causal attention (0 = causal): a block-diffusion
+    # model, which the serving engine decodes a block of positions a
+    # lane a tick (serving/engine.py, BLOCK TICK)
+    qk_norm: bool = False
+    block_causal: int = 0
+    # the id such a model reads at a position not revealed yet (the
+    # engine refuses a block model without one)
+    mask_token: int = -1
     # a character a layer, "" = every layer attention then MLP as
     # above: "*" a layer that is attention alone, "M" the Mamba-2
     # mixer alone, "E" the expert layer alone, each x + f(norm(x)).
@@ -1042,6 +1096,8 @@ class TransformerLM(nn.Module):
                 moe_scoring=self.moe_scoring,
                 moe_route_scale=self.moe_route_scale,
                 moe_shared_hidden=self.moe_shared_hidden,
+                moe_route_from=self.moe_route_from,
+                qk_norm=self.qk_norm, block_causal=self.block_causal,
                 kind=kinds[i], ssm=ssm if kinds[i] == "M" else (),
                 name="block_%d" % i,
             )

@@ -82,7 +82,8 @@ def build_engine(cfg):
             trainer, state, num_slots=server["num_slots"],
             block_size=server["kv_block_size"],
             num_blocks=server["kv_num_blocks"],
-            share_prefix=bool(server.get("kv_shared", 1)))
+            share_prefix=bool(server.get("kv_shared", 1)),
+            denoise_steps=server.get("denoise_steps", 0))
     return eng, {"params": state.params, **state.model_state}
 
 

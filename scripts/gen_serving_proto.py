@@ -70,6 +70,10 @@ SERVING_MESSAGES = {
         ("tokens", 1, T.TYPE_INT32, _REP),
         ("done", 2, T.TYPE_BOOL, _OPT),
         ("model_version", 3, T.TYPE_INT32, _OPT),
+        # a block-diffusion model only (a chunk is a committed block):
+        # parallel to `tokens`, the denoising pass that revealed each;
+        # empty for every other model
+        ("reveal_steps", 4, T.TYPE_INT32, _REP),
     ],
     "ServerStatusRequest": [],
     "ServerStatusResponse": [

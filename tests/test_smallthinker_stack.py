@@ -10,7 +10,8 @@ seeded random weights, float32 compute:
     and the same logits against references whose layers all have ONE
     window: they must differ;
 (c) the shares add up: two halves of a layer's experts sum to the
-    uncut layer and to the reference;
+    uncut layer and to the reference, for ReGLU experts and for SwiGLU
+    ones (chipbench/refs/sdar_moe.py: silu in relu's place);
 (d) no token is dropped under a routing skewed onto one expert, and
     the decode path and the prefill path of the layer agree;
 and what the serving step hands back for the counters.
@@ -31,6 +32,7 @@ import pytest
 from flax.core import FrozenDict
 
 from chipbench.drivers.open_loop import _unflatten
+from chipbench.refs import sdar_moe as swiglu_ref
 from chipbench.refs import smallthinker as ref
 from elasticdl_tpu.common.model_utils import load_model_spec_from_module
 from elasticdl_tpu.observability import tracing
@@ -244,15 +246,15 @@ def _layer(seed, t, experts=8, d=32, hidden=16, k=3, skew=None):
     return w, h, x, logits
 
 
-def _share(w, h, logits, first, count, k=3, **kwargs):
+def _share(w, h, logits, first, count, k=3, form="reglu", **kwargs):
     gates, experts = moe.route_top_k(logits, k)
-    return moe.held_experts_reglu(
-        h, gates, experts, *(w[n][first:first + count] for n in
-                             ("moe/w_gate", "moe/w_up", "moe/w_down")),
-        first=first, **kwargs)
+    return moe.held_experts(
+        h, gates, experts, [w[n][first:first + count] for n in
+                            ("moe/w_gate", "moe/w_up", "moe/w_down")],
+        first=first, activation=form, **kwargs)
 
 
-def _reference_layer(w, h, logits, first, count, k=3):
+def _reference_layer(w, h, logits, first, count, k=3, form="reglu"):
     cfg = {"moe_experts": 8, "moe_top_k": k, "experts_held": [first, count]}
     top_v, top_i = jax.lax.top_k(logits, k)
     weights = jnp.sum(jnp.where(
@@ -260,24 +262,31 @@ def _reference_layer(w, h, logits, first, count, k=3):
         jax.nn.softmax(top_v, -1)[..., None], 0.0), axis=-2)
     held = {n: v[first:first + count] for n, v in w.items()
             if n != "moe/router"}
-    return ref.experts(cfg, held, h, weights)
+    family = {"reglu": ref, "swiglu": swiglu_ref}[form]
+    return family.experts(cfg, held, h, weights)
 
 
+@pytest.mark.parametrize("form", ["reglu", "swiglu"])
 @pytest.mark.parametrize("seed", [0, 1])
 @pytest.mark.parametrize("t", [1, 16, 17, 300],
                          ids=["one-row", "decode-rows", "first-prefill",
                               "two-tiles"])
-def test_the_shares_add_up_to_the_whole_layer_and_to_the_reference(seed, t):
+def test_the_shares_add_up_to_the_whole_layer_and_to_the_reference(
+        seed, t, form):
     w, h, _, logits = _layer(seed, t)
-    whole, held_all, hit_all = _share(w, h, logits, 0, 8)
-    low, held_low, hit_low = _share(w, h, logits, 0, 4)
-    high, held_high, hit_high = _share(w, h, logits, 4, 4)
+    whole, held_all, hit_all = _share(w, h, logits, 0, 8, form=form)
+    low, held_low, hit_low = _share(w, h, logits, 0, 4, form=form)
+    high, held_high, hit_high = _share(w, h, logits, 4, 4, form=form)
     # float32 rounding: the halves sum the same products in two parts
     assert float(jnp.max(jnp.abs(low + high - whole))) < 2e-6
     assert float(jnp.max(jnp.abs(
-        whole - _reference_layer(w, h, logits, 0, 8)))) < 2e-6
+        whole - _reference_layer(w, h, logits, 0, 8, form=form)))) < 2e-6
     assert float(jnp.max(jnp.abs(
-        high - _reference_layer(w, h, logits, 4, 4)))) < 2e-6
+        high - _reference_layer(w, h, logits, 4, 4, form=form)))) < 2e-6
+    # the two gated forms are two layers
+    other = {"reglu": "swiglu", "swiglu": "reglu"}[form]
+    assert float(jnp.max(jnp.abs(
+        whole - _reference_layer(w, h, logits, 0, 8, form=other)))) > 1e-3
     assert (held_low + held_high == held_all).all()
     assert (held_all == 3).all()
     assert hit_low.tolist() + hit_high.tolist() == hit_all.tolist()
@@ -566,8 +575,9 @@ def test_lanes_mapped_one_by_one_are_computed_as_one_call():
     assert jaxpr.count("custom_vmap_call") == 1  # one call for all lanes
 
 
+@pytest.mark.parametrize("form", ["reglu", "swiglu"])
 def test_the_kernel_agrees_with_the_plain_tiles_when_interpreted(
-        monkeypatch):
+        monkeypatch, form):
     monkeypatch.setenv("ELASTICDL_TPU_FORCE_INTERPRET", "1")
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
     e, d, hidden = 4, 128, 256
@@ -578,12 +588,19 @@ def test_the_kernel_agrees_with_the_plain_tiles_when_interpreted(
         h = jax.random.normal(ks[3], (t, d))
         gates, experts = moe.route_top_k(
             jax.random.normal(ks[4], (t, 8)), 3)
-        kernel = moe.held_experts_reglu(h, gates, experts, *w, first=2,
-                                        use_kernel=True)
-        plain = moe.held_experts_reglu(h, gates, experts, *w, first=2,
-                                       use_kernel=False)
+        kernel = moe.held_experts(h, gates, experts, w, first=2,
+                                  use_kernel=True, activation=form)
+        plain = moe.held_experts(h, gates, experts, w, first=2,
+                                 use_kernel=False, activation=form)
         assert float(jnp.max(jnp.abs(kernel[0] - plain[0]))) < 1e-5
         assert (kernel[2] == plain[2]).all()
+        if form == "reglu":  # the name it has always had
+            named = moe.held_experts_reglu(h, gates, experts, *w, first=2,
+                                           use_kernel=True)
+            assert (named[0] == kernel[0]).all()
+    with pytest.raises(ValueError, match="activation 'relu2' with 3"):
+        moe.held_experts(h, gates, experts, w, use_kernel=False,
+                         activation="relu2")
 
 
 def test_the_router_is_kept_float32_and_the_experts_are_served_as_bf16():
