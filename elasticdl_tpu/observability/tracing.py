@@ -219,10 +219,13 @@ COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             # back behind the tick's tokens and summed over layers:
             # (row, choice) pairs routed / those whose expert is held
             # here / held experts some lane chose / held experts, all
-            # of SEATED lanes only (a free lane makes no choice); and
-            # the rows the layers were handed / those that chose
+            # of SEATED lanes only (a free lane makes no choice); the
+            # rows the layers were handed / those that chose; and the
+            # rows of the expert tiles that were computed (live tiles x
+            # their height: pairs_held over it is how full they were)
             "moe.pairs_routed", "moe.pairs_held", "moe.experts_hit",
             "moe.expert_slots", "moe.lanes", "moe.lanes_live",
+            "moe.tile_rows",
             # serving/kv_pool.py run_inplace(): calls of a program that
             # takes a KV pool and hands one back, and those of them
             # that consumed the pool they were handed (donation: the

@@ -29,10 +29,12 @@ zeros and move no weight. That one shape serves both ends of serving
   multiplied by its own experts only.
 
 On the TPU it is one Mosaic kernel: grid (tile, slice of the experts'
-hidden width), the expert of a tile read from scalar memory by the
-weights' index maps, so the pipeline fetches `W[expert_of[i]]` slice by
-slice while the previous slice is multiplied; dead tiles repeat the
-last live tile's block index and fetch nothing. Elsewhere (the CPU
+hidden width: one slice where an expert's matrices fit the VMEM twice,
+`hidden_slice`), the expert of a tile read from scalar memory by the
+weights' index maps, so the pipeline fetches `W[expert_of[i + 1]]`
+while tile i is multiplied, and fetches nothing where the next tile is
+the same expert's; dead tiles repeat the last live tile's block index
+and fetch nothing. Elsewhere (the CPU
 tests, kernels switched off) `expert_tiles_reference` computes the same
 thing with a `lax.map` over tiles.
 """
@@ -49,15 +51,25 @@ from elasticdl_tpu.ops.dispatch import interpret_mode, use_pallas
 #: the kernel's name in a device trace (the scope around the call names
 #: its HLO instruction: `moe_expert_tiles.N`)
 KERNEL_SCOPE = "moe_expert_tiles"
-#: Mosaic may use this much VMEM for the kernel: a 256-row tile of a
-#: 2560-wide model double-buffers 18 MB, over the 16 MB default and far
-#: under a v5e core's 128 MiB
+#: Mosaic may use this much VMEM for the kernel: two buffers of an
+#: expert's three matrices whole (23.6 MB at 2560 x 768) beside a
+#: 256-row tile's rows, results and accumulator (13 MB) are over the
+#: 16 MB default and far under a v5e core's 128 MiB
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def hidden_slice(hidden):
-    """Columns of the experts' hidden width a grid step multiplies:
-    the widest of 512, 256, 128 that divides it, else all of it."""
+def hidden_slice(hidden, expert_bytes):
+    """Columns of the experts' hidden width a grid step multiplies.
+    All of it where ONE expert's matrices (`expert_bytes`), twice for
+    the pipeline's two buffers, fit half of the kernel's VMEM: the
+    weights' block index is then the expert alone, so a second tile of
+    the same expert repeats it and fetches nothing (sliced, the slice
+    index runs inside the tile index and every tile streams its
+    expert again: at 16-row tiles over runs of 64 rows the kernel took
+    2.7 times as long, my chip run, PR 43). Else the widest of 512,
+    256, 128 that divides it, else all of it."""
+    if 2 * expert_bytes <= _VMEM_LIMIT // 2:
+        return hidden
     for th in (512, 256, 128):
         if hidden % th == 0 and hidden > th:
             return th
@@ -66,14 +78,13 @@ def hidden_slice(hidden):
 
 def kernel_supported(tm, d, hidden, dtype):
     """Shape gate of the Mosaic kernel: whole (sublane, 128) tiles of
-    the operand dtype; a hidden width that no 128-multiple divides
-    (1856 = 29 x 64) goes through whole, as the full extent of its
-    axis, and has to be whole sublanes of the down projection's rows.
+    the operand dtype. The hidden width goes through in slices that
+    are multiples of 128 or whole, as the full extent of its axis (a
+    width that no 128-multiple divides, 1856 = 29 x 64, always does),
+    so it has to be whole sublanes of the down projection's rows.
     Every other shape takes the reference."""
     sublanes = 32 // jnp.dtype(dtype).itemsize  # 8 f32, 16 bf16
-    th = hidden_slice(hidden)
-    return (tm % sublanes == 0 and d % 128 == 0
-            and (th % 128 == 0 or (th == hidden and th % sublanes == 0)))
+    return tm % sublanes == 0 and d % 128 == 0 and hidden % sublanes == 0
 
 
 #: what a gated expert does to its gate product, by `activation`
@@ -136,7 +147,8 @@ def _expert_tiles_kernel(x_tiles, x_of, gates, expert_of, n_live, *weights,
                          activation):
     n_tiles, tm = gates.shape[:2]
     hidden, d = weights[-1].shape[1:]
-    th = hidden_slice(hidden)
+    th = hidden_slice(hidden, sum(w[0].size * w.dtype.itemsize
+                                  for w in weights))
     n_h = hidden // th
 
     def slice_of(i, j, n_live_ref):

@@ -400,13 +400,37 @@ def moe_reference(params, x, capacity_factor=1.25,
 # tiles there are.
 
 #: up to this many rows the layer reads each HIT expert once for all
-#: rows (a decode tick: the experts' weights are the cost, so the rows
-#: ride along); above it each row is multiplied by its own experts only
-#: (a prefill: the products are the cost). Chosen from T, no knob.
+#: rows (a decode tick of few lanes: the experts' weights are the cost,
+#: so the rows ride along as ONE tile of this height a hit expert);
+#: above it the (row, choice) pairs are sorted by expert and each row
+#: is multiplied by its own experts only, in tiles whose height
+#: follows the call's shapes (`sorted_tile_rows`). Chosen from T, no
+#: knob.
 DECODE_ROWS = 16
-#: rows of a prefill tile: at 256 a tile's products (3 GFLOP at widths
-#: 2560 x 768) take about as long as its expert's weights (11.8 MB)
+#: the tallest sorted tile, a prefill's: at 256 rows a tile's products
+#: (3 GFLOP at widths 2560 x 768) take about as long as its expert's
+#: weights (11.8 MB). A call whose experts' runs are shorter takes a
+#: shorter step of the ladder below
 PREFILL_TILE_ROWS = 256
+#: the heights a sorted tile may have: whole sublane tiles of bf16 (16
+#: rows) and of float32 (8), each twice the one before
+_TILE_LADDER = (16, 32, 64, 128, PREFILL_TILE_ROWS)
+
+
+def sorted_tile_rows(t, k, count):
+    """Rows of a sorted tile, from the call's static shapes alone: the
+    lowest step of the ladder that is not under `t * k / count`, the
+    run a held expert would have on average if every one of `t` rows'
+    `k` choices were one of the `count` experts held here. A tile is
+    multiplied whole and written whole, so one much taller than its
+    expert's run multiplies and writes padding: a decode step of 32
+    rows x 6 choices over 32 held experts takes 16 rows, one of 128
+    rows x 8 choices 32, a prefill of 1,024 rows or more the 256 it
+    always had. A run longer than the tile takes further tiles
+    (`_grouped_tiles`), so the height costs time and never a pair."""
+    run = -(-t * k // count)
+    return next((tm for tm in _TILE_LADDER if tm >= run),
+                PREFILL_TILE_ROWS)
 
 
 def route_top_k(logits, k):
@@ -440,7 +464,8 @@ def _held_choices(experts, first, count):
 
 def _hit_tiles(h, gates, experts, first, *weights_and_use_kernel,
                activation=None):
-    """Few rows: one tile per HIT expert over all the rows."""
+    """Few rows: one tile per HIT expert over all the rows. Returns
+    (y, held, hit, rows of the tiles computed)."""
     *weights, use_kernel = weights_and_use_kernel
     t = h.shape[0]
     count = weights[0].shape[0]
@@ -459,18 +484,21 @@ def _hit_tiles(h, gates, experts, first, *weights_and_use_kernel,
     y = expert_tiles(x, jnp.zeros((count,), jnp.int32),
                      tile_gates[..., None], order, n_live, *weights,
                      use_kernel=use_kernel, activation=activation)
-    return jnp.sum(y, axis=0)[:t], held, hit
+    return jnp.sum(y, axis=0)[:t], held, hit, n_live * tm
 
 
 def _grouped_tiles(h, gates, experts, first, *weights_and_use_kernel,
                    activation=None):
     """Many rows: the (row, choice) pairs sorted by expert, each held
-    expert's run padded to whole tiles; gathers only, no scatter."""
+    expert's run padded to whole tiles of `sorted_tile_rows` rows;
+    gathers only, no scatter. `n_tiles` is the static bound: every
+    pair in a full tile, and a part-filled last tile a held expert.
+    Returns (y, held, hit, rows of the tiles computed)."""
     *weights, use_kernel = weights_and_use_kernel
     t, d = h.shape
     k = experts.shape[1]
     count = weights[0].shape[0]
-    tm = PREFILL_TILE_ROWS
+    tm = sorted_tile_rows(t, k, count)
     n_tiles = -(-t * k // tm) + count
     local, held = _held_choices(experts, first, count)
     # pairs of experts held elsewhere, and pairs that are no choice
@@ -498,8 +526,11 @@ def _grouped_tiles(h, gates, experts, first, *weights_and_use_kernel,
     pair = order[jnp.clip(start[e_row] + offset, 0, t * k - 1)]
     x_tiles = h[pair // k]  # [n_tiles, tm, d]
     tile_gates = jnp.where(live_row, gates.reshape(-1)[pair], 0.0)
-    y = expert_tiles(x_tiles, jnp.arange(n_tiles), tile_gates[..., None],
-                     expert_of, n_live, *weights, use_kernel=use_kernel,
+    # a dead tile names the last live tile's rows, as it names its
+    # expert: the kernel then fetches nothing for it
+    x_of = jnp.minimum(jnp.arange(n_tiles), jnp.maximum(n_live - 1, 0))
+    y = expert_tiles(x_tiles, x_of, tile_gates[..., None], expert_of,
+                     n_live, *weights, use_kernel=use_kernel,
                      activation=activation)
     # each pair's row of the tiles; a pair held elsewhere reads none
     e_pair = jnp.minimum(key, count - 1)
@@ -507,16 +538,16 @@ def _grouped_tiles(h, gates, experts, first, *weights_and_use_kernel,
     rows = y.reshape(n_tiles * tm, d)[jnp.where(key < count, dest, 0)]
     rows = jnp.where((key < count)[:, None], rows, 0.0)
     hit = sizes > 0
-    return jnp.sum(rows.reshape(t, k, d), axis=1), held, hit
+    return jnp.sum(rows.reshape(t, k, d), axis=1), held, hit, n_live * tm
 
 
 def _held_experts(first, use_kernel, activation, h, gates, experts,
                   *weights):
     path = _hit_tiles if h.shape[0] <= DECODE_ROWS else _grouped_tiles
-    y, held, hit = path(h, gates, experts, first, *weights, use_kernel,
-                        activation=activation)
+    y, held, hit, tile_rows = path(h, gates, experts, first, *weights,
+                                   use_kernel, activation=activation)
     return (y, jnp.sum(held, axis=1).astype(jnp.int32),
-            hit.astype(jnp.int32))
+            hit.astype(jnp.int32), tile_rows.astype(jnp.int32))
 
 
 @functools.lru_cache(maxsize=None)
@@ -535,13 +566,14 @@ def _lanes_as_one_call(first, use_kernel, activation=None):
             axes = [0 if b else None for b in in_batched]
             out = jax.vmap(plain, in_axes=axes)(h, gates, experts,
                                                 *weights)
-            return out, (True, True, True)
+            return out, (True, True, True, True)
         t = h.shape[1]
         flat = [a.reshape((axis_size * t,) + a.shape[2:])
                 for a in (h, gates, experts)]
-        y, held, hit = call(*flat, *weights)
+        y, held, hit, tile_rows = call(*flat, *weights)
         return ((y.reshape((axis_size, t) + y.shape[1:]),
-                 held.reshape(axis_size, t), hit), (True, True, False))
+                 held.reshape(axis_size, t), hit, tile_rows),
+                (True, True, False, False))
 
     return call
 
@@ -566,6 +598,10 @@ def held_experts(h, gates, experts, weights, first=0, use_kernel=None,
                              or gate * (relu(h W_up^T)^2 W_down)
         held [T] int32       how many of a row's k choices are held
         hit [count] int32    1 for each held expert some row chose
+        tile_rows int32      the rows of the tiles that were computed
+                             (live tiles x their height): what the
+                             kernel multiplied and wrote for the held
+                             pairs, padding included
 
     No choice is dropped and no expert is computed that no row chose;
     what the experts held elsewhere would add is left out (on one chip
@@ -581,11 +617,17 @@ def held_experts(h, gates, experts, weights, first=0, use_kernel=None,
     bit what it would be had the row chosen like the others. With no
     choice at all the kernel is handed `n_live` 0 and `y` is zeros.
 
-    The path is chosen
-    from T (DECODE_ROWS). Under `jax.vmap` over rows with the weights
-    shared (the serving step maps one lane a sequence) the lanes are
-    laid side by side and computed as ONE call, so a tick reads a hit
-    expert once and not once a lane; `hit` is then the tick's."""
+    The path and the tiles' height are chosen from the call's static
+    shapes, with no option: up to DECODE_ROWS rows every hit expert is
+    one tile over the same rows; above it the pairs are sorted by
+    expert into tiles of `sorted_tile_rows(T, k, count)` rows, the
+    lowest step of 16 .. PREFILL_TILE_ROWS not under the run an expert
+    has on average (a decode step of many lanes takes short tiles, a
+    long prefill the tallest). Under `jax.vmap` over rows with the
+    weights shared (the serving step maps one lane a sequence) the
+    lanes are laid side by side and computed as ONE call, so a tick
+    reads a hit expert once and not once a lane and T is the tick's
+    rows; `hit` and `tile_rows` are then the tick's."""
     if activation == ("reglu" if len(weights) == 3 else "relu2"):
         activation = None  # what the count says: one call for both names
     return _lanes_as_one_call(int(first), use_kernel, activation)(

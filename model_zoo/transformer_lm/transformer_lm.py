@@ -560,9 +560,12 @@ class ExpertFFN(nn.Module):
     handed what the layer did, of LIVE rows only: `moe.pairs_routed`,
     `moe.pairs_held` (scalars: live rows x `top_k`, and those of them
     held here), `moe.experts_hit`, `moe.expert_slots` (a mark an
-    expert held: chosen by a live row; held at all), and `moe.lanes`,
+    expert held: chosen by a live row; held at all), `moe.lanes`,
     `moe.lanes_live` (scalars: this call's rows, and those that
-    chose)."""
+    chose), and `moe.tile_rows` (a mark of one item: the rows of the
+    tiles `held_experts` computed, the tick's and not a lane's where
+    lanes are mapped, so it counts once; `moe.pairs_held` over it is
+    the share of the multiplied rows that are a held pair)."""
 
     num_experts: int
     top_k: int
@@ -630,7 +633,7 @@ class ExpertFFN(nn.Module):
         with jax.named_scope("moe_experts"):
             # the Mosaic kernel has no backward: a training forward
             # takes the plain products
-            y, held, hit = held_experts(
+            y, held, hit, tile_rows = held_experts(
                 rows, gates, experts, weights, first=first,
                 use_kernel=False if training else None,
                 activation=self.activation)
@@ -657,6 +660,7 @@ class ExpertFFN(nn.Module):
                 "moe.expert_slots": jnp.ones_like(hit),
                 "moe.lanes": b * l,
                 "moe.lanes_live": lanes_live,
+                "moe.tile_rows": tile_rows.reshape(1),
             }
             for name, value in counts.items():
                 self.sow("counters", name, jnp.asarray(value, jnp.int32))

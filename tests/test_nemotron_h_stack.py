@@ -424,6 +424,15 @@ def test_whatever_token_a_free_lane_holds_it_hits_no_expert(slots):
         assert after[name] - before.get(name, 0) == counts[name], name
     assert after["moe.lanes"] - before.get("moe.lanes", 0) \
         == slots * counts["moe.lanes_live"]
+    # one seated lane: a tile a hit expert on either path, of 16 rows up
+    # to 16 lanes and of the sorted tile's height above (18 lanes x 3
+    # choices over 4 held experts, a run of 14: 16 too, where the
+    # prefill's 256 were multiplied before)
+    tm = (moe.DECODE_ROWS if slots <= moe.DECODE_ROWS
+          else moe.sorted_tile_rows(slots, 3, 4))
+    assert tm == 16
+    assert after["moe.tile_rows"] - before.get("moe.tile_rows", 0) \
+        == tm * counts["moe.experts_hit"]
 
 
 # ------------------------------ (d) the scans, the kernels, the shares
@@ -570,7 +579,7 @@ def test_the_relu2_tiles_kernel_equals_the_plain_tiles_when_interpreted(
     monkeypatch.setenv("ELASTICDL_TPU_FORCE_INTERPRET", "1")
     ks = jax.random.split(jax.random.PRNGKey(3), 6)
     e, d, hidden = 4, 128, 192  # no 128-multiple divides 192 but itself
-    assert expert_ffn.hidden_slice(hidden) == hidden
+    assert expert_ffn.hidden_slice(hidden, 2 ** 40) == hidden
     assert expert_ffn.kernel_supported(16, d, hidden, jnp.float32)
     w = [jax.random.normal(ks[0], (e, hidden, d)) * d ** -0.5,
          jax.random.normal(ks[1], (e, hidden, d)) * hidden ** -0.5]

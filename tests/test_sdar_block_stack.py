@@ -302,6 +302,8 @@ def test_lanes_seated_and_freed_mid_block_with_a_step_in_flight():
         len(p) % B for p, _ in specs)
     # the expert layers count ROWS: a lane's four are four
     assert count("moe.lanes_live") == blocks * (S + 1) * B * 3
+    # 2 lanes x 4 rows ride the 16-row path: 16 rows a hit expert
+    assert count("moe.tile_rows") == 16 * count("moe.experts_hit") > 0
     assert count("tick.ahead") >= count("diffusion.lane_passes") // 2 - 3
 
 
@@ -548,11 +550,16 @@ def test_the_block_step_compiles_for_a_v5e_at_the_cells_widths(one_chip):
 #: sha1 of the sorted (operation, count) pairs of the lowered paged
 #: step of each family at a tiny size, taken on the parent commit
 #: (27fd91b, jax 0.9.0) by this very function: making the tile a
-#: parameter of the one step program changed no operation of theirs
+#: parameter of the one step program changed no operation of theirs.
+#: The two families with expert layers were taken again when the layer
+#: began to count `moe.tile_rows` (3c28db8 -> the commit after it): ten
+#: small operations an expert layer more (the live tiles times their
+#: height, the mark's reduce and sum: 1,474 -> 1,494 and 1,108 ->
+#: 1,119) and none changed; the dense family is as it was
 _STEP_OPS = {
     "dense": "48da383e02e3f43877968d632397ab62c47b144f",
-    "experts": "95c1c06d71177cb530336d1b85d4cb056d5c7592",
-    "state": "45e859cf58cfe2d9394c17dd85a74a7dbb8b6891",
+    "experts": "6af399f77b7ea1b47b5adc053a65064dc3ddf23e",
+    "state": "b50da8a582432bba7e869e18761c7455b5ea4937",
 }
 _FAMILIES = {
     "dense": {"vocab_size": 64, "seq_len": 32, "embed_dim": 32,

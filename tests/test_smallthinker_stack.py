@@ -36,6 +36,7 @@ from chipbench.refs import sdar_moe as swiglu_ref
 from chipbench.refs import smallthinker as ref
 from elasticdl_tpu.common.model_utils import load_model_spec_from_module
 from elasticdl_tpu.observability import tracing
+from elasticdl_tpu.ops import expert_ffn
 from elasticdl_tpu.parallel import mesh as mesh_lib
 from elasticdl_tpu.parallel import moe
 from elasticdl_tpu.serving.admission import ServingRequest
@@ -203,6 +204,10 @@ def test_the_step_hands_back_what_the_expert_layers_did():
     assert counts["moe.expert_slots"] == ticks * 4 * layers
     # one lane's three choices hit three experts a layer at the most
     assert 0 < counts["moe.experts_hit"] <= ticks * k * layers
+    # two lanes take the 16-row path: a tile of 16 rows a hit expert,
+    # counted once a tick and not once a lane
+    assert counts["moe.tile_rows"] == moe.DECODE_ROWS * counts[
+        "moe.experts_hit"]
     # 3 of 4 layers have a window of 8 = 2 blocks of 4: a lane at 20..31
     # holds 5..8 blocks a layer, the oldest of them dead in those three
     assert counts["kv.blocks_held"] > 0
@@ -274,9 +279,9 @@ def _reference_layer(w, h, logits, first, count, k=3, form="reglu"):
 def test_the_shares_add_up_to_the_whole_layer_and_to_the_reference(
         seed, t, form):
     w, h, _, logits = _layer(seed, t)
-    whole, held_all, hit_all = _share(w, h, logits, 0, 8, form=form)
-    low, held_low, hit_low = _share(w, h, logits, 0, 4, form=form)
-    high, held_high, hit_high = _share(w, h, logits, 4, 4, form=form)
+    whole, held_all, hit_all, _ = _share(w, h, logits, 0, 8, form=form)
+    low, held_low, hit_low, _ = _share(w, h, logits, 0, 4, form=form)
+    high, held_high, hit_high, _ = _share(w, h, logits, 4, 4, form=form)
     # float32 rounding: the halves sum the same products in two parts
     assert float(jnp.max(jnp.abs(low + high - whole))) < 2e-6
     assert float(jnp.max(jnp.abs(
@@ -297,14 +302,14 @@ def test_no_token_is_dropped_when_every_token_chooses_one_expert(t):
     """A capacity of 1.25 would drop most of these rows' first choice;
     here every row reaches expert 2 with its full weight."""
     w, h, _, logits = _layer(5, t, skew=2)
-    got, held, hit = _share(w, h, logits, 0, 8)
+    got, held, hit, _ = _share(w, h, logits, 0, 8)
     assert hit[2] == 1 and (held == 3).all()
     want = _reference_layer(w, h, logits, 0, 8)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6
     gates, experts = moe.route_top_k(logits, 3)
     assert (experts[:, 0] == 2).all() and float(gates[:, 0].min()) > 0.99
     # and the expert alone gives nearly all of every row
-    alone, _, _ = _share(w, h, logits, 2, 1)
+    alone = _share(w, h, logits, 2, 1)[0]
     assert float(jnp.max(jnp.abs(alone - want))) < 0.05 * float(
         jnp.max(jnp.abs(want)))
     assert float(jnp.min(jnp.max(jnp.abs(alone), axis=1))) > 0
@@ -322,6 +327,146 @@ def test_the_decode_path_and_the_prefill_path_agree(first, count):
     assert float(jnp.max(jnp.abs(hit_path[0] - grouped[0]))) < 2e-6
     assert (hit_path[1] == grouped[1]).all()
     assert (hit_path[2] == grouped[2]).all()
+
+
+#: (rows, choices a row, experts held, experts of the layer, form) ->
+#: the sorted tile's height by the rule: the decode step of the
+#: benchmark's expert cells above DECODE_ROWS (nm3n's 32 lanes, sdar's
+#: 32 lanes x 4 rows, a fused pass of twice that), a short prefill
+#: bucket, and a prefill of 2,048 rows
+_TILE_SHAPES = {
+    "nm3n-tick": ((32, 6, 32, 128, "relu2"), 16),
+    "sdar-pass": ((128, 8, 32, 128, "swiglu"), 32),
+    "fused-pass": ((256, 8, 32, 128, "swiglu"), 64),
+    "short-prefill": ((512, 6, 32, 64, "reglu"), 128),
+    "prefill-2048": ((2048, 6, 32, 64, "reglu"), 256),
+}
+
+
+@pytest.mark.parametrize("t,k,count,want", [
+    (32, 6, 32, 16), (128, 8, 32, 32), (256, 8, 32, 64),
+    (17, 6, 32, 16), (64, 6, 32, 16), (128, 6, 32, 32),
+    (512, 8, 32, 128), (1024, 6, 32, 256), (1024, 8, 32, 256),
+    (2048, 6, 32, 256), (2048, 8, 32, 256), (6208, 6, 32, 256),
+    (40, 3, 8, 16), (40, 3, 1, 128), (4096, 8, 128, 256)])
+def test_a_sorted_tile_is_as_tall_as_an_experts_run(t, k, count, want):
+    """The height comes from the static shapes alone: the lowest step
+    of the ladder not under t * k / count, never over the prefill's."""
+    assert moe.sorted_tile_rows(t, k, count) == want
+    assert want in moe._TILE_LADDER and want <= moe.PREFILL_TILE_ROWS
+    assert want >= min(t * k / count, moe.PREFILL_TILE_ROWS)
+    lower = [tm for tm in moe._TILE_LADDER if tm < want]
+    assert all(tm < t * k / count for tm in lower)
+    # more rows never take a shorter tile
+    assert moe.sorted_tile_rows(2 * t, k, count) >= want
+
+
+def _pairs_one_by_one(h, gates, experts, first, weights, form):
+    """Every held (row, choice) pair's product on its own: each expert
+    over every row densely, a row's k results added in order."""
+    count = weights[0].shape[0]
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+    if form == "relu2":
+        dense = [dot(jnp.square(jnp.maximum(dot(h, weights[0][e].T), 0.0)),
+                     weights[1][e]) for e in range(count)]
+    else:
+        act = {"reglu": lambda a: jnp.maximum(a, 0.0),
+               "swiglu": jax.nn.silu}[form]
+        dense = [dot(act(dot(h, weights[0][e])) * dot(h, weights[1][e]),
+                     weights[2][e]) for e in range(count)]
+    dense = jnp.stack(dense)  # [count, t, d]
+    local = np.asarray(experts) - first
+    ok = (local >= 0) & (local < count)
+    rows = np.arange(h.shape[0])
+    y = jnp.zeros_like(h)
+    for j in range(experts.shape[1]):
+        picked = dense[np.clip(local[:, j], 0, count - 1), rows]
+        y = y + jnp.where(ok[:, j, None], gates[:, j, None] * picked, 0.0)
+    return y, ok
+
+
+@pytest.mark.parametrize("skew", [
+    "spread", "long-run", "exact-run", "dead-lanes", "held-elsewhere"])
+@pytest.mark.parametrize("shape", sorted(_TILE_SHAPES))
+def test_sorted_tiles_of_every_height_give_each_pair_its_product(
+        shape, skew, monkeypatch):
+    """`_grouped_tiles` at every height of the ladder against the pairs
+    one by one: choices spread over the layer's experts with one held
+    expert nobody chose; a run longer than the tile (every row's first
+    choice is one expert: further tiles, no pair dropped); a run of
+    exactly the tile; rows of free lanes (-1: no choice); and every
+    choice held elsewhere (no tile at all). The rows the kernel is
+    handed are the tile list's."""
+    (t, k, count, total, form), tm = _TILE_SHAPES[shape]
+    assert moe.sorted_tile_rows(t, k, count) == tm
+    first, d, hidden = 3, 16, 8
+    rng = np.random.default_rng(sum(map(ord, shape + skew)))
+    quiet, busy = first + 5, first + 1  # held experts: nobody's / skewed
+    # each row's k choices: distinct experts, never the two reserved
+    free = np.setdiff1d(np.arange(total), [quiet, busy])
+    experts = np.stack([rng.choice(free, size=k, replace=False)
+                        for _ in range(t)])
+    if skew == "long-run":
+        experts[:, 0] = busy  # a run of t rows: more than one tile
+    elif skew == "exact-run":
+        experts[:tm, 0] = busy  # t >= tm for every shape: one full tile
+    elif skew == "dead-lanes":
+        experts[rng.random(t) < 0.4] = -1
+        experts[0] = -1
+    elif skew == "held-elsewhere":
+        experts = np.where(experts < first + count, experts + count,
+                           experts) % total
+        experts = np.where((experts >= first) & (experts < first + count),
+                           first + count, experts)
+    gates = jax.nn.softmax(jnp.asarray(rng.normal(size=(t, k)),
+                                       jnp.float32), axis=-1)
+    into, back = (count, d, hidden), (count, hidden, d)
+    shapes = [back, back] if form == "relu2" else [into, into, back]
+    weights = [jnp.asarray(rng.normal(size=sh) * sh[1] ** -0.5, jnp.float32)
+               for sh in shapes]
+    h = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    experts = jnp.asarray(experts, jnp.int32)
+    handed = []
+    real = moe.expert_tiles
+
+    def spy(x_tiles, x_of, tile_gates, expert_of, n, *w, **kw):
+        handed.append((x_tiles.shape, int(n), np.asarray(expert_of)))
+        return real(x_tiles, x_of, tile_gates, expert_of, n, *w, **kw)
+
+    with monkeypatch.context() as patch:
+        patch.setattr(moe, "expert_tiles", spy)
+        y, held, hit, tile_rows = moe._grouped_tiles(
+            h, gates, experts, first, *weights, False, activation=form)
+    want, ok = _pairs_one_by_one(h, gates, experts, first, weights, form)
+    assert float(jnp.max(jnp.abs(y - want))) < 2e-5
+    assert (np.asarray(held) == ok).all()
+    sizes = np.bincount(np.asarray(experts)[ok] - first, minlength=count)
+    assert np.asarray(hit).astype(bool).tolist() == (sizes > 0).tolist()
+    assert not hit[quiet - first]
+    # the tile list: the static bound, and whole tiles an expert's run
+    (x_shape, n_live, expert_of), = handed
+    assert x_shape == (-(-t * k // tm) + count, tm, d)
+    tiles_of = -(-sizes // tm)
+    assert n_live == tiles_of.sum() <= x_shape[0]
+    assert expert_of[:n_live].tolist() == np.repeat(
+        np.arange(count), tiles_of).tolist()
+    assert int(tile_rows) == n_live * tm >= ok.sum()
+    if skew == "long-run":
+        assert sizes[busy - first] == t > tm
+        assert tiles_of[busy - first] == -(-t // tm) > 1
+    elif skew == "exact-run":
+        assert sizes[busy - first] == tm and tiles_of[busy - first] == 1
+    elif skew == "dead-lanes":
+        dead = (np.asarray(experts) < 0).all(axis=1)
+        assert dead.any() and not np.asarray(y)[dead].any()
+    elif skew == "held-elsewhere":
+        assert n_live == 0 == int(tile_rows) and not np.asarray(y).any()
+    # the layer itself takes this path and height for these shapes
+    if skew == "spread":
+        whole = moe.held_experts(h, gates, experts, weights, first=first,
+                                 use_kernel=False, activation=form)
+        np.testing.assert_array_equal(np.asarray(whole[0]), np.asarray(y))
+        assert int(whole[3]) == int(tile_rows)
 
 
 def _dead_rows_case(seed, t, dead, form, d=32, hidden=16):
@@ -375,9 +520,10 @@ def test_a_row_that_makes_no_choice_reads_no_expert(
 
     with monkeypatch.context() as patch:
         patch.setattr(moe, "expert_tiles", spy)
-        y_all, held_all, hit_all = path(h, gates, experts, first,
-                                        *weights, False)
-        y, held, hit = path(h, gates, masked, first, *weights, False)
+        y_all, held_all, hit_all, _ = path(h, gates, experts, first,
+                                           *weights, False)
+        y, held, hit, tile_rows = path(h, gates, masked, first, *weights,
+                                       False)
     y, y_all = np.asarray(y), np.asarray(y_all)
     np.testing.assert_array_equal(y[live], y_all[live])
     assert (y[~live] == 0.0).all() and np.isfinite(y).all()
@@ -393,9 +539,13 @@ def test_a_row_that_makes_no_choice_reads_no_expert(
         assert hit_all[-1] and not hit[-1]
     sizes = np.bincount(np.asarray(experts)[live].reshape(-1),
                         minlength=8)[first:first + count]
+    tm = (moe.DECODE_ROWS if t <= moe.DECODE_ROWS
+          else moe.sorted_tile_rows(t, 3, count))
     want_live = (int((sizes > 0).sum()) if t <= moe.DECODE_ROWS
-                 else int((-(-sizes // moe.PREFILL_TILE_ROWS)).sum()))
+                 else int((-(-sizes // tm)).sum()))
     assert n_live[1] == want_live <= n_live[0] - (first + count == 8)
+    # the rows that were multiplied are the tile list's, padding and all
+    assert int(tile_rows) == want_live * tm >= int(held.sum())
     if not live.any():
         assert n_live[1] == 0 and not y.any() and not np.asarray(hit).any()
     # the layer itself (its batching rule takes no new operand), lane
@@ -447,7 +597,7 @@ def _layer_as_it_was(params, h, route_from, form):
         gates, experts = moe.route_sigmoid_top_k(
             logits, 3, params["router_bias"], 2.5)
     rows = h.reshape(b * l, d)
-    y, _, _ = moe.held_experts(rows, gates, experts, weights, first=2)
+    y = moe.held_experts(rows, gates, experts, weights, first=2)[0]
     if form == "relu2":
         act = jnp.square(jnp.maximum(jnp.dot(
             rows, params["shared_up"],
@@ -551,7 +701,7 @@ def test_whatever_token_a_free_lane_holds_it_hits_no_expert():
     alone, counted_alone = run(_engine(PARAMS, w, slots=1), False)
     assert poisoned == alone == generated
     for name in ("moe.pairs_routed", "moe.pairs_held", "moe.experts_hit",
-                 "moe.lanes_live"):
+                 "moe.lanes_live", "moe.tile_rows"):
         assert counted[name] == counted_alone[name] == counts[name], name
     assert counted["moe.lanes"] == 2 * counted_alone["moe.lanes"]
     assert counted_alone["moe.lanes"] == counted_alone["moe.lanes_live"]
@@ -566,28 +716,57 @@ def test_lanes_mapped_one_by_one_are_computed_as_one_call():
     def lane(hh, ll):
         return _share(w, hh, ll, 0, 4)
 
-    y, held, hit = jax.vmap(lane)(h[:, None], logits[:, None])
-    together, held_t, hit_t = _share(w, h, logits, 0, 4)
+    y, held, hit, tile_rows = jax.vmap(lane)(h[:, None], logits[:, None])
+    together, held_t, hit_t, tile_rows_t = _share(w, h, logits, 0, 4)
     assert float(jnp.max(jnp.abs(y[:, 0] - together))) < 2e-6
     assert (held[:, 0] == held_t).all()
     assert (hit == hit_t[None]).all()  # the tick's mark, on every lane
+    assert (tile_rows == tile_rows_t).all()  # and the tick's tiles
+    assert int(tile_rows_t) == moe.DECODE_ROWS * int(hit_t.sum())
     jaxpr = str(jax.make_jaxpr(jax.vmap(lane))(h[:, None], logits[:, None]))
     assert jaxpr.count("custom_vmap_call") == 1  # one call for all lanes
 
 
+@pytest.mark.parametrize("hidden,expert_bytes,want", [
+    (768, 3 * 2048 * 768 * 2, 768), (768, 3 * 2560 * 768 * 2, 768),
+    (1856, 2 * 2688 * 1856 * 2, 1856), (14336, 3 * 4096 * 14336 * 2, 512),
+    (768, 2 ** 40, 256), (256, 2 ** 40, 128), (192, 2 ** 40, 192)])
+def test_the_hidden_width_is_one_slice_where_an_expert_fits_twice(
+        hidden, expert_bytes, want):
+    """One slice makes a second tile of an expert repeat its block
+    index (no second fetch): taken where the pipeline's two buffers of
+    an expert's matrices fit half of the kernel's VMEM, as at the three
+    serve configurations' widths; a larger expert goes by slices."""
+    assert expert_ffn.hidden_slice(hidden, expert_bytes) == want
+    assert hidden % want == 0
+    assert (want == hidden) == (
+        2 * expert_bytes <= expert_ffn._VMEM_LIMIT // 2
+        or not any(hidden % th == 0 and hidden > th
+                   for th in (512, 256, 128)))
+
+
+@pytest.mark.parametrize("slices", [1, 2], ids=["one-slice", "two-slices"])
 @pytest.mark.parametrize("form", ["reglu", "swiglu"])
 def test_the_kernel_agrees_with_the_plain_tiles_when_interpreted(
-        monkeypatch, form):
+        monkeypatch, form, slices):
     monkeypatch.setenv("ELASTICDL_TPU_FORCE_INTERPRET", "1")
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
     e, d, hidden = 4, 128, 256
+    if slices == 2:  # as an expert too large for the VMEM is multiplied
+        monkeypatch.setattr(expert_ffn, "_VMEM_LIMIT", 0)
+    assert expert_ffn.hidden_slice(hidden, 3 * d * hidden * 4) \
+        == hidden // slices
     w = [jax.random.normal(ks[0], (e, d, hidden)) * d ** -0.5,
          jax.random.normal(ks[1], (e, d, hidden)) * d ** -0.5,
          jax.random.normal(ks[2], (e, hidden, d)) * hidden ** -0.5]
-    for t in (5, 40):
+    # 5 rows: a tile a hit expert; 40: sorted tiles of 32 rows; 41:
+    # every row chooses expert 3, whose run takes a second tile (the
+    # weights' block index repeats under one slice and not under two)
+    for t in (5, 40, 41):
         h = jax.random.normal(ks[3], (t, d))
         gates, experts = moe.route_top_k(
-            jax.random.normal(ks[4], (t, 8)), 3)
+            jax.random.normal(ks[4], (t, 8)).at[:, 3].add(
+                50.0 * (t == 41)), 3)
         kernel = moe.held_experts(h, gates, experts, w, first=2,
                                   use_kernel=True, activation=form)
         plain = moe.held_experts(h, gates, experts, w, first=2,
