@@ -206,14 +206,25 @@ _SLOW_EXEMPT = ("idle", "train.checkpoint", "train.eval",
 #: the closed set of `count` names
 COUNTERS = ("prompt_write.launches", "prompt_write.tokens",
             "prompts_prefilled",
+            # serving/kv_pool.py write_prompt(): (block, layer) writes
+            # NOT made, a prompt block behind a window class's reach of
+            # the prompt's end (kv_pool.py, BLOCK CLASSES)
+            "prompt_write.blocks_skipped",
             # serving/engine.py step() / _spec_step(), a decode tick:
             # table slots in reach of the lanes' sequences (what the
             # paged kernel streams a layer) of lanes x table width
             "paged.blocks_streamed", "paged.table_slots",
-            # the same tick, over ALL layers: blocks a seated lane has
-            # written, and those of them wholly behind their layer's
-            # window (held by the pool, never read again)
-            "kv.blocks_held", "kv.window_dead_blocks",
+            # the same tick, in blocks x layers: what the pool's block
+            # classes HOLD of the rows the seated lanes have written,
+            # those of them wholly behind their layer's window (held,
+            # never read again), and what one table for every layer
+            # would hold of the same lanes (every block in every layer:
+            # what `held` is where the pool has one table)
+            "kv.blocks_held", "kv.window_dead_blocks", "kv.blocks_whole",
+            # serving/kv_pool.py ensure_blocks(): blocks x layers a
+            # window class gave back because they fell behind the
+            # window of the position being written
+            "kv.window_blocks_released",
             # what the model's expert layers sow into "counters" in a
             # decode step (model_zoo/transformer_lm ExpertFFN), handed
             # back behind the tick's tokens and summed over layers:

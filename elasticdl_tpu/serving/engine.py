@@ -626,7 +626,21 @@ class PagedContinuousBatchingEngine(object):
             self.num_blocks, self.block_size,
             share_prefix=bool(share_prefix),
             host_bytes=self.host_bytes, kinds=kinds,
+            leaf_windows=self._leaf_windows(share_prefix, draft, draft_k),
         )
+        # [(window, layers that have it)] of the pool's block classes;
+        # a pool with one table for every layer has the model's kinds
+        # of layer all in the one table
+        self._classes = self._window_kinds
+        if self.kv.classed:
+            self._classes = list(zip(self.kv.class_windows,
+                                     self.kv.class_layers))
+            logger.info(
+                "serving: KV blocks in %d classes by attention window: %s",
+                len(self._classes), "; ".join(
+                    "window %d: %d layers, %d blocks" % (
+                        a.window, n, a.num_blocks) for a, n in zip(
+                            self.kv.allocators, self.kv.class_layers)))
         # optional recompile sentry (runtime_health.RecompileSentry;
         # the server attaches it under ServingConfig.runtime_health).
         # Every jit site below compiles through _tjit, which resolves
@@ -700,6 +714,53 @@ class PagedContinuousBatchingEngine(object):
                                     self._exec_variables, d_variables),
                 d_variables,
             )
+
+    def _leaf_windows(self, share_prefix, draft, draft_k):
+        """Each cache leaf's attention window, along the leaves, for
+        the pool to keep its blocks in CLASSES by (kv_pool.py, BLOCK
+        CLASSES), or None: one table for every layer, every sequence
+        charged whole. Classes need that nothing reads a block behind
+        a layer's window: no shared or spilled chain (a later prompt
+        would seat on it), no decode tile over earlier positions (the
+        draft's verify, a chunked prefill's tiles, a block model's);
+        with any of those on the pool is the one it always was, and
+        the server says so once, with what it costs
+        (`classes_given_up`, `kv_stats()["kv_classes_given_up"]`)."""
+        self.classes_given_up = []
+        window_of = getattr(self.model, "cache_leaf_window", None)
+        if window_of is None:
+            return None
+        paths = [
+            tuple(getattr(k, "key", getattr(k, "name", None)) for k in path)
+            for path, _ in jax.tree_util.tree_flatten_with_path(
+                self._kv_shapes)[0]]
+        windows = [int(window_of(path)) for path in paths]
+        if not any(windows):
+            return None
+        self.classes_given_up = [name for name, on in (
+            ("prefix sharing (kv_shared)", share_prefix),
+            ("the host tier (kv_host_bytes)", self.host_bytes),
+            ("chunked prefill (prefill_chunk_tokens)",
+             self.prefill_chunk_tokens),
+            ("a block model's tile (block_causal)", self._tile > 1),
+            ("speculative decode (draft, draft_k)",
+             draft is not None and int(draft_k) >= 1)) if on]
+        if not self.classes_given_up:
+            return windows
+        from elasticdl_tpu.serving.kv_pool import blocks_for
+
+        whole = blocks_for(self.seq_len, self.block_size)
+        logger.warning(
+            "serving: %s keeps the KV pool at ONE table for every layer: "
+            "a lane of %d tokens is charged %d blocks in each of the %d "
+            "window layers, where a block class of window %d would charge "
+            "%d; --kv_num_blocks %d seats %d such lanes",
+            ", ".join(self.classes_given_up), self.seq_len, whole,
+            len({path[0] for path, w in zip(paths, windows) if w}),
+            max(windows),
+            min(whole, blocks_for(max(windows), self.block_size) + 2),
+            self.num_blocks, self.num_blocks // max(whole, 1))
+        return None
 
     def _refuse_what_needs_a_state_snapshot(self, draft, draft_k):
         """A model with state layers (the pool refuses prefix sharing
@@ -1028,7 +1089,8 @@ class PagedContinuousBatchingEngine(object):
 
     def kv_stats(self):
         """KV memory accounting for telemetry / ServerStatus."""
-        return self.kv.stats()
+        return dict(self.kv.stats(),
+                    kv_classes_given_up=list(self.classes_given_up))
 
     def _sync_host_telemetry(self):
         """Forward the pool's monotone spill-tier counters (revival
@@ -1461,26 +1523,34 @@ class PagedContinuousBatchingEngine(object):
         window (`model.layer_windows()`), so the reach is taken a kind
         of layer and averaged over the layers (whole blocks); with one
         window for every layer that is the one layer's count. And what
-        the pool holds in vain: every layer keeps every block of a
-        seated lane (kv_pool.plan charges the whole length, which a
-        layer that sees every key needs), so a block wholly behind a
-        layer's window is `kv.window_dead_blocks`, of the
-        `kv.blocks_held` written so far over all layers."""
+        the pool holds, in blocks x layers: `kv.blocks_whole`, what one
+        table for every layer holds of the seated lanes' rows written
+        so far (every block, in every layer); `kv.blocks_held`, what
+        the pool's classes do hold of them (a window class has released
+        what fell behind its window: kv_pool.py, BLOCK CLASSES; with
+        one table the two are equal); and `kv.window_dead_blocks`, the
+        held blocks wholly behind their layer's window, which nothing
+        reads again."""
         m = self.kv.max_blocks_per_slot
-        held = streamed = dead = 0
-        for window, layers in self._window_kinds:
+        whole = held = streamed = dead = 0
+        for c, (window, layers) in enumerate(self._classes):
             j_lo, j_hi = paged_live_blocks(
                 self._positions, window or None, self.block_size, m,
                 xp=np,
             )
-            held += layers * int(j_hi.sum())
-            dead += layers * int(j_lo.sum())
+            # a lane's leading table entries released behind the window
+            gone = (np.minimum(self.kv.holes[c], j_hi)
+                    if self.kv.classed else 0)
+            whole += layers * int(j_hi.sum())
+            held += layers * int((j_hi - gone).sum())
+            dead += layers * int(np.maximum(j_lo - gone, 0).sum())
             streamed += layers * int((j_hi - j_lo).sum())
         tracing.count("paged.blocks_streamed",
                       streamed // self._window_layers)
         tracing.count("paged.table_slots", self.num_slots * m)
         tracing.count("kv.window_dead_blocks", dead)
         tracing.count("kv.blocks_held", held)
+        tracing.count("kv.blocks_whole", whole)
 
     def _tick_lanes(self, budgets=None):
         """The lane state this launch's program takes, on the device,
@@ -1797,7 +1867,7 @@ class PagedContinuousBatchingEngine(object):
     def _lanes_spec(self, budgets=False):
         """The shape of what _tick_lanes hands a tick's program, for
         who traces one without running it."""
-        width = (_LANE_TABLE + self.kv.max_blocks_per_slot
+        width = (_LANE_TABLE + self.kv.tables.shape[1]
                  + _block_columns(self._tile) + bool(budgets))
         return jax.ShapeDtypeStruct((self.num_slots, width), jnp.int32)
 
@@ -1843,6 +1913,13 @@ class PagedContinuousBatchingEngine(object):
         tile, n_passes = self._tile, self.denoise_steps
         mask_token = self._mask_token
         max_blocks = self.kv.max_blocks_per_slot
+        # the pool's block classes (one, but for a model whose layers
+        # differ in window served without sharing): each class's first
+        # column of the tables and its arenas' blocks, and which class
+        # a leaf of the pool is of
+        table_of, leaf_class = self.kv.table_of, self.kv.leaf_class
+        classes = [(c * max_blocks, alloc.num_blocks)
+                   for c, alloc in enumerate(self.kv.allocators)]
 
         def step(pools, variables, lanes):
             variables = _maybe_dequantize(variables, qz)
@@ -1888,8 +1965,10 @@ class PagedContinuousBatchingEngine(object):
                         {"tokens": tokens},
                         training=False, decode=True,
                         mutable=["cache", "kv_out", "counters"],
-                        paged={"pools": pools, "table": table[None],
-                               "live": live},
+                        paged=dict(
+                            {"pools": pools, "table": table[None],
+                             "live": live},
+                            **({"table_of": table_of} if table_of else {})),
                     )
                 if tile == 1:
                     nxt = serving_next_token(
@@ -1978,16 +2057,27 @@ class PagedContinuousBatchingEngine(object):
             nxt = jnp.concatenate(
                 [nxt] + [counted[name][None] for name in tick_counters]
             ).astype(jnp.int32)
-            bids = jnp.take_along_axis(
-                tables, (positions // block_size)[:, None], axis=1
-            )[:, 0]
-            # free lanes (table row -1), and a lane whose prompt is
-            # still being written tile by tile (its row is there, its
-            # position 0): point past the arena so the scatter's
-            # mode="drop" discards them
-            bids = jnp.where(seated & (bids >= 0), bids, num_blocks)
+            if table_of is None:
+                bids = jnp.take_along_axis(
+                    tables, (positions // block_size)[:, None], axis=1
+                )[:, 0]
+                # free lanes (table row -1), and a lane whose prompt is
+                # still being written tile by tile (its row is there,
+                # its position 0): point past the arena so the
+                # scatter's mode="drop" discards them
+                bids = jnp.where(seated & (bids >= 0), bids, num_blocks)
+                pools = scatter_rows(pools, rows, bids,
+                                     positions % block_size)
+                return pools, (lanes, nxt)
+            # the same a class: its own table columns, its own arenas
+            bids = [
+                jnp.take_along_axis(
+                    tables, (at + positions // block_size)[:, None],
+                    axis=1)[:, 0] for at, _blocks in classes]
+            bids = [jnp.where(seated & (b >= 0), b, blocks)
+                    for b, (_at, blocks) in zip(bids, classes)]
             pools = scatter_rows(pools, rows, bids,
-                                 positions % block_size)
+                                 positions % block_size, leaf_class)
             return pools, (lanes, nxt)
 
         return step

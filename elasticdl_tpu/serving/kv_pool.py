@@ -145,6 +145,39 @@ its rank:
 * SCALAR, the position counter: a zero-d placeholder that keeps the
   tree's structure.
 
+BLOCK CLASSES BY LAYER WINDOW (`leaf_windows`; the engine hands them in
+where prefix sharing, the host tier, the draft and chunked prefill are
+all off): the ROWS leaves are grouped by their layer's attention window
+into classes, each with its own BlockAllocator, its own arenas' block
+count and its own table, a class's columns side by side in `tables`
+(`table_of` names each layer's). A class that sees every key (window 0)
+is what the whole pool is otherwise. A WINDOW class
+
+* is CHARGED `min(blocks_for(tokens), blocks_for(window) + 2)` blocks
+  for a sequence (`plan` / `can_seat` / `alloc` / `extend`): a row at
+  position p sees keys (p - window, p], which lie in at most
+  blocks_for(window) + 1 blocks, and one more is the slack between
+  drawing the block being written and releasing the one behind;
+* RELEASES behind the window: when `ensure_blocks` draws a lane a new
+  block, every block that lies wholly behind the window of the position
+  being written goes back to the class's free list and its table entry
+  to -1 (`kv.window_blocks_released`); the paged attention reads no
+  table entry below its live range (ops.attention.paged_live_blocks,
+  the same arithmetic). Host work only and safe with a step in flight,
+  as `release` is: what next writes the block is a program that takes
+  the pool that step hands back;
+* is seated with the blocks in reach of the PROMPT'S END only:
+  `write_prompt` writes a block below that range to the whole-length
+  classes alone (`prompt_write.blocks_skipped`);
+* has arenas of `num_slots x (blocks_for(window) + 2)` blocks, capped
+  by `num_blocks`, which goes on sizing the whole-length class.
+
+What needs every block of every layer keeps the one-table pool: a
+shared chain, a spilled one, a decode tile over earlier positions (the
+draft's verify, a chunked prefill); the engine warns once, by name of
+the option and with the charge (`engine._leaf_windows`). A pool of more
+than one class refuses a chain export and a copy on write by name.
+
 Block ids enter the compiled decode step as DEVICE arrays (the tables),
 so slot churn and sequence growth never recompile anything. The tables
 the step reads STAY on the device, carried from tick to tick in the
@@ -291,7 +324,7 @@ class BlockAllocator(object):
     touched); steady-state slot churn is O(1) per block."""
 
     def __init__(self, num_blocks, block_size, share_prefix=False,
-                 host_blocks=0):
+                 host_blocks=0, window=0):
         if num_blocks < 1:
             raise ValueError(
                 "num_blocks must be >= 1, got %d" % num_blocks)
@@ -301,12 +334,29 @@ class BlockAllocator(object):
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.share_prefix = bool(share_prefix)
+        # the attention window of the layers whose rows these blocks
+        # hold (module docstring, BLOCK CLASSES); 0 = every earlier
+        # key, and then nothing below differs from a pool without
+        # classes: a sequence is charged its whole length and a table
+        # has no hole
+        self.window = int(window)
+        if self.window and (share_prefix or host_blocks):
+            raise ValueError(
+                "a window class of blocks releases behind its window: "
+                "it can hold no shared or spilled chain")
+        self.window_blocks = (
+            blocks_for(self.window, self.block_size) + 2
+            if self.window else 0)
         # host-spill tier capacity, in blocks (0 = eviction forgets)
         self.host_blocks = int(host_blocks)
         # LIFO: the most recently freed block is reused first (warm
         # reuse; also what the reuse-order tests lock)
         self._free = list(range(self.num_blocks - 1, -1, -1))
-        self._tables = {}     # slot -> [block ids]
+        self._tables = {}     # slot -> [block ids], -1 a released one
+        # slot -> the table's leading entries released behind the
+        # window (a window class; the holes are always a prefix)
+        self._holes = {}
+        self.released = 0     # monotone: blocks released behind it
         self._committed = {}  # slot -> total blocks promised
         self._cow_credit = {}  # slot -> reserved CoW copies (0 or 1)
         self._reserved = 0    # promised-but-unmaterialized, all slots
@@ -386,6 +436,24 @@ class BlockAllocator(object):
     def table(self, slot):
         return list(self._tables.get(slot, ()))
 
+    def holes(self, slot):
+        """The leading table entries of `slot` released behind the
+        window (0 without one)."""
+        return self._holes.get(slot, 0)
+
+    def charge(self, tokens):
+        """Blocks a sequence of `tokens` rows is charged: all of them,
+        or at most the window's reach and its slack."""
+        n = blocks_for(tokens, self.block_size)
+        return min(n, self.window_blocks) if self.window else n
+
+    def live_from(self, pos):
+        """The first table slot a row at position `pos` still sees
+        (ops.attention.paged_live_blocks' j_lo): 0 without a window."""
+        if not self.window:
+            return 0
+        return max(0, int(pos) - self.window + 1) // self.block_size
+
     # ----------------------------------------------------- prefix index
 
     def _full_block_tuples(self, prompt):
@@ -426,10 +494,11 @@ class BlockAllocator(object):
         return chain, needed
 
     def _plan(self, prompt, tokens, commit_tokens=None):
-        now = blocks_for(tokens, self.block_size)
-        commit = max(
-            now, blocks_for(commit_tokens or tokens, self.block_size)
-        )
+        # what is materialized now: the blocks the first decode step
+        # (position `tokens`) has in reach; all of them without a window
+        now = (blocks_for(tokens, self.block_size)
+               - self.live_from(tokens))
+        commit = max(now, self.charge(commit_tokens or tokens))
         chain = self.match_prefix(prompt) if prompt is not None else []
         chain = chain[:now]
         # full-prompt match: the engine must re-run the last prompt
@@ -712,10 +781,9 @@ class BlockAllocator(object):
         Returns the number of SHARED tokens (0 without a match)."""
         if slot in self._tables:
             raise ValueError("slot %r already holds blocks" % (slot,))
-        now = blocks_for(tokens, self.block_size)
-        commit = max(
-            now, blocks_for(commit_tokens or tokens, self.block_size)
-        )
+        holes = self.live_from(tokens)
+        now = blocks_for(tokens, self.block_size) - holes
+        commit = max(now, self.charge(commit_tokens or tokens))
         chain, needed, cow = self._plan(prompt, tokens, commit_tokens)
         if needed > self.available():
             raise OutOfBlocks(
@@ -729,7 +797,7 @@ class BlockAllocator(object):
         # and the remainder draws fresh (the plan charged a fresh
         # block for every spilled entry either way, so accounting is
         # unchanged; only the shared-token count shrinks).
-        table_ids = []
+        table_ids = [-1] * holes  # behind the window of the prompt's end
         shared_blocks = 0
         for node in chain:
             if node >= 0:
@@ -751,11 +819,13 @@ class BlockAllocator(object):
                 self.incref(bid)
                 table_ids.append(bid)
                 break
-        while len(table_ids) < now:
+        while len(table_ids) < holes + now:
             bid = self._pop_block()
             self.incref(bid)
             table_ids.append(bid)
         self._tables[slot] = table_ids
+        if holes:
+            self._holes[slot] = holes
         self._committed[slot] = commit
         self._cow_credit[slot] = cow
         self._reserved += (commit - now) + cow
@@ -773,9 +843,21 @@ class BlockAllocator(object):
         if table is None:
             raise ValueError("slot %r holds no blocks" % (slot,))
         need = blocks_for(total_tokens, self.block_size) - len(table)
+        if need > 0 and self.window:
+            # a window class: the row being written is at
+            # total_tokens - 1, and what lies wholly behind its window
+            # goes back first, to the reservation it was drawn from
+            for j in range(self.holes(slot),
+                           min(self.live_from(total_tokens - 1),
+                               len(table))):
+                self.decref(table[j])
+                table[j] = -1
+                self._holes[slot] = j + 1
+                self._reserved += 1
+                self.released += 1
         added = []
         for _ in range(max(0, need)):
-            if len(table) < self._committed[slot]:
+            if len(table) - self.holes(slot) < self._committed[slot]:
                 self._reserved -= 1  # drawing our own reservation
             elif self.available() < 1:
                 raise OutOfBlocks(
@@ -829,20 +911,21 @@ class BlockAllocator(object):
         table = self._tables.pop(slot, None)
         if table is None:
             return 0
+        held = table[self._holes.pop(slot, 0):]
         self._reserved -= (
-            self._committed.pop(slot) - len(table)
+            self._committed.pop(slot) - len(held)
             + self._cow_credit.pop(slot, 0)
         )
         # decref'd in table order so a fully-private table lands on the
         # free list with the block allocated LAST on top of the stack
         # (LIFO through the whole alloc -> free -> alloc cycle)
-        for bid in table:
+        for bid in held:
             self.decref(bid)
         return len(table)
 
 
 def build_pools(kv_shapes, cache_len, num_blocks, block_size, kinds=None,
-                num_slots=0):
+                num_slots=0, leaf_blocks=None):
     """Device arenas from the model's batch-1 decode-cache template
     (api/generation._kv_shapes_for) and its leaves' declared `kinds` (a
     tree alongside; None = the `kv_row_leaf` convention): a ROWS leaf
@@ -850,20 +933,26 @@ def build_pools(kv_shapes, cache_len, num_blocks, block_size, kinds=None,
     zeros, a STATE leaf `[1, ...]` becomes `[num_slots, ...]` zeros, and
     the position counter stays a zero-d placeholder, so the pool tree
     keeps the cache tree's structure: the model slices its own layer's
-    arenas out of it by name."""
+    arenas out of it by name. `leaf_blocks` (along the leaves) gives
+    each ROWS leaf its own block count: its class's (module docstring,
+    BLOCK CLASSES)."""
     if kinds is None:
         kinds = cache_leaf_kinds(None, kv_shapes, cache_len)
+    flat, treedef = jax.tree.flatten(kv_shapes)
+    if leaf_blocks is None:
+        leaf_blocks = [num_blocks] * len(flat)
 
-    def arena(leaf, kind):
+    def arena(leaf, kind, blocks):
         if kind == ROWS:
             _, hkv, _, d = leaf.shape
-            return jnp.zeros((num_blocks, block_size, hkv, d),
-                             leaf.dtype)
+            return jnp.zeros((blocks, block_size, hkv, d), leaf.dtype)
         if kind == STATE:
             return jnp.zeros((num_slots,) + leaf.shape[1:], leaf.dtype)
         return jnp.zeros(leaf.shape, leaf.dtype)
 
-    return jax.tree.map(arena, kv_shapes, kinds)
+    return jax.tree.unflatten(treedef, [
+        arena(leaf, kind, blocks) for leaf, kind, blocks in zip(
+            flat, jax.tree.leaves(kinds), leaf_blocks)])
 
 
 def _map_kind(fn, which, kinds, pools, *trees):
@@ -879,7 +968,8 @@ def _map_kind(fn, which, kinds, pools, *trees):
         for pool, kind, *rest in zip(flat, kinds, *others)])
 
 
-def write_prompt_block(pools, kv, j, bid, block_size, kinds):
+def write_prompt_block(pools, kv, j, bid, block_size, kinds,
+                       leaf_class=None, classes=()):
     """Insert block `j` of a freshly prefilled batch-1 cache tree into
     the arenas at block id `bid` — ONE `dynamic_update_slice` per ROWS
     leaf (`kinds`, static, along the pool's leaves) at a TRACED (j,
@@ -888,17 +978,28 @@ def write_prompt_block(pools, kv, j, bid, block_size, kinds):
     last block are prefill junk; the paged attention masks `k_pos <
     length` so they are never read before the decode scatter
     overwrites them. A leaf of another kind is handed back as it is,
-    whatever its rank."""
-    def upd(pool, leaf):
+    whatever its rank. A pool of several block classes (`leaf_class`,
+    static, along the leaves) hands `bid` as a vector, an id a class,
+    and names the `classes` this launch writes: a leaf of another
+    class is handed back as it is too (a block behind a window class's
+    reach has no block there)."""
+    def upd(pool, leaf, at):
         rows = jax.lax.dynamic_slice_in_dim(
             leaf[0], j * block_size, block_size, axis=1
         )  # [hkv, block_size, d]
         rows = rows.transpose(1, 0, 2)  # [block_size, hkv, d]
         return jax.lax.dynamic_update_slice(
-            pool, rows[None], (bid, 0, 0, 0)
+            pool, rows[None], (at, 0, 0, 0)
         )
 
-    return _map_kind(upd, ROWS, kinds, pools, kv)
+    if leaf_class is None:
+        return _map_kind(lambda pool, leaf: upd(pool, leaf, bid), ROWS,
+                         kinds, pools, kv)
+    flat, treedef = jax.tree.flatten(pools)
+    return jax.tree.unflatten(treedef, [
+        upd(pool, leaf, bid[c]) if kind == ROWS and c in classes else pool
+        for pool, leaf, kind, c in zip(
+            flat, treedef.flatten_up_to(kv), kinds, leaf_class)])
 
 
 def write_state(pools, kv, slot, kinds):
@@ -928,7 +1029,7 @@ def copy_block(pools, src, dst, kinds):
     return _map_kind(upd, ROWS, kinds, pools)
 
 
-def scatter_rows(pools, rows, bids, offs):
+def scatter_rows(pools, rows, bids, offs, leaf_class=None):
     """Write decode rows into the arenas: `rows` is a tree whose
     structure is a SUBSET of `pools` (the model's "kv_out" sown
     collection) with leaves `[..., hkv, d]` — one row per leading
@@ -938,20 +1039,24 @@ def scatter_rows(pools, rows, bids, offs):
     rows, pad rows) carry an out-of-bounds bid and are discarded by the
     scatter — they never touch a block a live sequence owns. Distinct
     live rows target distinct (block, offset) pairs, so the scatter
-    indices never collide."""
+    indices never collide. A pool of several block classes hands
+    `bids` as a list, an array a class, and `leaf_class` (along the
+    pool's leaves) says which a leaf takes."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(pools)
+    if leaf_class is None:
+        bids, leaf_class = [bids], [0] * len(flat)
     rmap = {
         jax.tree_util.keystr(p): leaf
         for p, leaf in jax.tree_util.tree_flatten_with_path(rows)[0]
     }
     out = []
     with jax.named_scope("row_scatter"):  # op_name, for a device trace
-        for path, pool in flat:
+        for (path, pool), c in zip(flat, leaf_class):
             row = rmap.get(jax.tree_util.keystr(path))
             if row is None:
                 out.append(pool)
             else:
-                out.append(pool.at[bids, offs].set(row, mode="drop"))
+                out.append(pool.at[bids[c], offs].set(row, mode="drop"))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -996,7 +1101,7 @@ class PagedKVPool(object):
 
     def __init__(self, kv_shapes, cache_len, num_slots, num_blocks,
                  block_size, share_prefix=False, host_bytes=0,
-                 kinds=None):
+                 kinds=None, leaf_windows=None):
         cache_len = int(cache_len)
         block_size = int(block_size)
         if cache_len % block_size:
@@ -1008,8 +1113,6 @@ class PagedKVPool(object):
         self.block_size = block_size
         self.num_blocks = int(num_blocks)
         self.max_blocks_per_slot = cache_len // block_size
-        self.allocator = BlockAllocator(num_blocks, block_size,
-                                        share_prefix=share_prefix)
         # each leaf's declared kind (module docstring, LEAVES BY KIND),
         # as a tree alongside the template's and, for the programs that
         # take the pool, static, along jax.tree.leaves(self.pools)
@@ -1017,6 +1120,56 @@ class PagedKVPool(object):
             kinds = cache_leaf_kinds(None, kv_shapes, cache_len)
         self.kinds = tuple(jax.tree.leaves(kinds))
         self.has_state = STATE in self.kinds
+        # the block CLASSES (module docstring): the ROWS leaves grouped
+        # by `leaf_windows` (along the leaves; None = one class that is
+        # charged every sequence whole), the whole-length class first.
+        # `leaf_class` is static, along the leaves (0 where a leaf is
+        # no row leaf); `allocator` is the first class's, the one a
+        # pool without classes has
+        if leaf_windows is not None and (share_prefix
+                                         or int(host_bytes) > 0):
+            raise ValueError(
+                "block classes by window cannot hold a shared or a "
+                "spilled chain: leaf_windows with share_prefix / "
+                "host_bytes")
+        windows = [int(w) if kind == ROWS else 0 for w, kind in zip(
+            leaf_windows or [0] * len(self.kinds), self.kinds)]
+        self.class_windows = sorted(
+            {w for w, kind in zip(windows, self.kinds) if kind == ROWS}
+        ) or [0]
+        self.leaf_class = tuple(
+            self.class_windows.index(w) if kind == ROWS else 0
+            for w, kind in zip(windows, self.kinds))
+        # the layers of a class: its row leaves' top-level names
+        paths = [path for path, _ in
+                 jax.tree_util.tree_flatten_with_path(kv_shapes)[0]]
+        self.class_layers = [
+            max(1, len({path[0] for path, c, kind in zip(
+                paths, self.leaf_class, self.kinds)
+                if kind == ROWS and c == mine}))
+            for mine in range(len(self.class_windows))]
+        self.allocators = [
+            BlockAllocator(
+                min(self.num_blocks, int(num_slots) * (
+                    blocks_for(w, block_size) + 2)) if w
+                else self.num_blocks,
+                block_size, share_prefix=share_prefix and not w,
+                window=w)
+            for w in self.class_windows]
+        self.allocator = self.allocators[0]
+        # whether a table may have holes and a write takes an id a
+        # class: any class but the one whole-length one
+        self.classed = len(self.allocators) > 1 or bool(
+            self.allocator.window)
+        m = self.max_blocks_per_slot
+        # {top-level name of a layer's leaves: its class's columns of
+        # `tables`}, for the model to slice (None: one class, the whole)
+        self.table_of = None
+        if len(self.allocators) > 1:
+            self.table_of = {
+                path[0].key: (c * m, (c + 1) * m)
+                for path, c, kind in zip(paths, self.leaf_class,
+                                         self.kinds) if kind == ROWS}
         if self.has_state and (share_prefix or int(host_bytes) > 0):
             raise ValueError(
                 "this model keeps a per-sequence state (a state-space "
@@ -1027,11 +1180,20 @@ class PagedKVPool(object):
                     else "the host spill tier (host_bytes)",
                     "--kv_shared 0 / EDL_KV_SHARED=0" if share_prefix
                     else "--kv_host_bytes 0 / EDL_KV_HOST_BYTES unset"))
-        self.pools = build_pools(kv_shapes, cache_len, num_blocks,
-                                 block_size, kinds, int(num_slots))
+        self.pools = build_pools(
+            kv_shapes, cache_len, num_blocks, block_size, kinds,
+            int(num_slots),
+            leaf_blocks=[self.allocators[c].num_blocks
+                         for c in self.leaf_class])
+        # a class's table beside the next: class c is columns
+        # [c * m, (c + 1) * m)
         self.tables = np.full(
-            (int(num_slots), self.max_blocks_per_slot), -1, np.int32
+            (int(num_slots), len(self.allocators) * m), -1, np.int32
         )
+        # [class, slot]: a lane's leading table entries released
+        # behind the window (the engine's counters read them)
+        self.holes = np.zeros((len(self.allocators), int(num_slots)),
+                              np.int32)
         # a row of `tables` was written since the engine last sent
         # them to the device (it clears this when it does)
         self.tables_dirty = False
@@ -1041,7 +1203,14 @@ class PagedKVPool(object):
         # kv_bytes_in_use / bytes-per-generated-token report.
         row_leaves = self._of_kind(ROWS)
         self.bytes_total = sum(_leaf_bytes(leaf) for leaf in row_leaves)
-        self.block_bytes = self.bytes_total // max(1, self.num_blocks)
+        # one block of every layer of a class, and of all the classes
+        # together (what a block of a pool without classes is)
+        self.class_block_bytes = [
+            sum(_leaf_bytes(leaf) for leaf, k, cls in zip(
+                jax.tree.leaves(self.pools), self.kinds, self.leaf_class)
+                if k == ROWS and cls == c) // alloc.num_blocks
+            for c, alloc in enumerate(self.allocators)]
+        self.block_bytes = sum(self.class_block_bytes)
         # the per-slot state arenas beside them: fixed, whatever is
         # seated, and no part of a block
         self.state_bytes = sum(_leaf_bytes(leaf)
@@ -1130,8 +1299,8 @@ class PagedKVPool(object):
     # ----------------------------------------------------------- lifecycle
 
     def can_seat(self, prompt, prompt_tokens, commit_tokens):
-        return self.allocator.can_seat(prompt, prompt_tokens,
-                                       commit_tokens)
+        return all(alloc.can_seat(prompt, prompt_tokens, commit_tokens)
+                   for alloc in self.allocators)
 
     def seat(self, slot, prompt, commit_tokens):
         """Reserve the request's full block budget and materialize the
@@ -1140,10 +1309,22 @@ class PagedKVPool(object):
         OutOfBlocks with nothing taken. Returns the shared token count
         (0 without a match; revived tokens count as shared — they are
         seated without re-running prefill either way)."""
+        for alloc in self.allocators[1:]:
+            # every class or none: asked before anything is taken
+            _chain, needed = alloc.plan(prompt, len(prompt),
+                                        commit_tokens)
+            if needed > alloc.available():
+                raise OutOfBlocks(
+                    "the class of blocks of window %d needs %d new "
+                    "blocks, %d available"
+                    % (alloc.window, needed, alloc.available()))
         shared = self.allocator.alloc(
             slot, len(prompt), commit_tokens=commit_tokens,
             prompt=prompt,
         )
+        for c in range(1, len(self.allocators)):  # asked above: it fits
+            self.allocators[c].alloc(slot, len(prompt),
+                                     commit_tokens=commit_tokens)
         self._apply_revivals()
         self._sync_row(slot)
         return shared
@@ -1250,7 +1431,8 @@ class PagedKVPool(object):
         if self._write_fn is None:
             self._write_fn = _pool_tjit(
                 self, "kv_prompt_write", write_prompt_block,
-                static_argnames=("block_size", "kinds"),
+                static_argnames=("block_size", "kinds", "leaf_class",
+                                 "classes"),
                 donate_argnums=(0,),
             )
         return self._write_fn
@@ -1303,6 +1485,7 @@ class PagedKVPool(object):
                 "chain export (the disagg handoff) ships a prompt's KV "
                 "blocks, and this model keeps a per-sequence state "
                 "beside them that no chain carries yet")
+        self._refuse_on_classes("chain export (the disagg handoff)")
         alloc = self.allocator
         chain = alloc.match_prefix(prompt)
         tuples = alloc._full_block_tuples(prompt)[:len(chain)]
@@ -1409,6 +1592,16 @@ class PagedKVPool(object):
             self.chain_import_tokens += added * self.block_size
         return added, added * self.block_size
 
+    def _refuse_on_classes(self, what):
+        if len(self.allocators) > 1:
+            raise ValueError(
+                "%s needs every block of every layer, and this pool "
+                "keeps its layers' blocks in %d classes by attention "
+                "window (%s), a window class only what its window "
+                "reaches. Start the server with prefix sharing on "
+                "(--kv_shared 1), which keeps one table for every layer"
+                % (what, len(self.allocators), self.class_windows))
+
     def host_bytes_in_use(self):
         """True host-tier bytes: spilled blocks hold every row leaf of
         one block at its own dtype, i.e. exactly block_bytes each."""
@@ -1427,17 +1620,37 @@ class PagedKVPool(object):
         keeps a per-sequence state, seat that in the slot: one more
         launch for every state leaf together (`write_state`)."""
         write = self._write_program()
-        table = self.allocator.table(slot)
         blocks = range(start_block,
                        blocks_for(prompt_tokens, self.block_size))
+        tables = [alloc.table(slot) for alloc in self.allocators]
+        launches = skipped = 0
         with tracing.phase("prompt_write", blocks=len(blocks)):
             for j in blocks:
                 # `kv` is NOT donated: every block's launch reads it
-                self.update(
-                    write, kv, jnp.asarray(j, jnp.int32),
-                    jnp.asarray(table[j], jnp.int32),
-                    block_size=self.block_size, kinds=self.kinds,
-                )
+                if not self.classed:
+                    self.update(
+                        write, kv, jnp.asarray(j, jnp.int32),
+                        jnp.asarray(tables[0][j], jnp.int32),
+                        block_size=self.block_size, kinds=self.kinds,
+                    )
+                    launches += 1
+                    continue
+                # a block behind a window class's reach of the
+                # prompt's end has no block there: the other classes'
+                # leaves alone are written
+                bids = [table[j] for table in tables]
+                classes = tuple(c for c, bid in enumerate(bids)
+                                if bid >= 0)
+                skipped += sum(layers for layers, bid in zip(
+                    self.class_layers, bids) if bid < 0)
+                if classes:
+                    self.update(
+                        write, kv, jnp.asarray(j, jnp.int32),
+                        jnp.asarray(np.maximum(bids, 0), jnp.int32),
+                        block_size=self.block_size, kinds=self.kinds,
+                        leaf_class=self.leaf_class, classes=classes,
+                    )
+                    launches += 1
         if self.has_state:
             with tracing.phase("state_write", slot=int(slot)):
                 self.update(self._state_program(), kv,
@@ -1446,7 +1659,9 @@ class PagedKVPool(object):
             tracing.count("state_write.launches")
         # work done, counted where it happens: one launch per block,
         # and the prompt tokens those blocks now hold
-        tracing.count("prompt_write.launches", len(blocks))
+        tracing.count("prompt_write.launches", launches)
+        if skipped:  # a (block, layer) no window class keeps
+            tracing.count("prompt_write.blocks_skipped", skipped)
         tracing.count("prompt_write.tokens",
                       prompt_tokens - start_block * self.block_size)
         tracing.count("prompts_prefilled")
@@ -1455,7 +1670,14 @@ class PagedKVPool(object):
         """Make sure the block covering cache position `pos` exists
         (the decode step writes up to there this iteration); draws the
         slot's reservation, so it cannot fail for a seated request."""
-        if self.allocator.extend(slot, pos + 1):
+        grew = False
+        for layers, alloc in zip(self.class_layers, self.allocators):
+            before = alloc.released
+            grew = bool(alloc.extend(slot, pos + 1)) or grew
+            if alloc.released > before:  # in blocks x layers, as held
+                tracing.count("kv.window_blocks_released",
+                              layers * (alloc.released - before))
+        if grew:
             self._sync_row(slot)
 
     # back-compat spelling (single position)
@@ -1465,6 +1687,7 @@ class PagedKVPool(object):
         """Copy-on-write guard before `slot` writes cache position
         `pos`: if the covering block is shared, copy it (device) and
         repoint the table. Returns the (old, new) ids or None."""
+        self._refuse_on_classes("a copy on write")
         moved = self.allocator.cow(slot, pos // self.block_size)
         if moved is None:
             return None
@@ -1492,9 +1715,10 @@ class PagedKVPool(object):
         overwritten before it is read. The table row is marked
         written, so the next launch sends the mirror and no later step
         carries the lane."""
-        freed = self.allocator.free(slot)
+        freed = [alloc.free(slot) for alloc in self.allocators][0]
         if freed:
             self.tables[slot, :] = -1
+            self.holes[:, slot] = 0
             self.tables_dirty = True
         return freed
 
@@ -1506,16 +1730,20 @@ class PagedKVPool(object):
         self.allocator.flush_index()
 
     def _sync_row(self, slot):
-        table = self.allocator.table(slot)
-        row = np.full(self.max_blocks_per_slot, -1, np.int32)
-        row[: len(table)] = table
+        m = self.max_blocks_per_slot
+        row = np.full(self.tables.shape[1], -1, np.int32)
+        for c, alloc in enumerate(self.allocators):
+            table = alloc.table(slot)
+            row[c * m: c * m + len(table)] = table
+            self.holes[c, slot] = alloc.holes(slot)
         self.tables[slot] = row
         self.tables_dirty = True
 
     # ------------------------------------------------------------- stats
 
     def bytes_in_use(self):
-        return self.allocator.blocks_in_use() * self.block_bytes
+        return sum(alloc.blocks_in_use() * each for alloc, each in zip(
+            self.allocators, self.class_block_bytes))
 
     def stats(self):
         return {
@@ -1523,11 +1751,19 @@ class PagedKVPool(object):
             "kv_shared": self.allocator.share_prefix,
             "kv_cache_dtype": self.kv_cache_dtype,
             "kv_block_size": self.block_size,
-            "kv_blocks_total": self.num_blocks,
+            "kv_blocks_total": sum(a.num_blocks for a in self.allocators),
             # capacity available to new work: free + reclaimable —
             # cached prefixes are not "in use", they are a warm cache
-            "kv_blocks_free": (self.allocator.num_free()
-                               + self.allocator.num_cached()),
+            "kv_blocks_free": sum(a.num_free() + a.num_cached()
+                                  for a in self.allocators),
+            # the block classes by attention window (one, of window 0,
+            # where every layer keeps every block): [window, layers,
+            # blocks, blocks free]
+            "kv_classes": [
+                [a.window, layers, a.num_blocks,
+                 a.num_free() + a.num_cached()]
+                for a, layers in zip(self.allocators,
+                                     self.class_layers)],
             "kv_blocks_cached": self.allocator.num_cached(),
             "kv_blocks_shared": self.allocator.shared_blocks(),
             "kv_bytes_total": self.bytes_total,

@@ -119,6 +119,10 @@ class CausalSelfAttention(nn.Module):
     # row's own block in both directions. 0 = plain causal. Through
     # prefill, the decode tile over the paged pool and the dense cache
     block_causal: int = 0
+    # an output GATE: a fifth projection `gate` (hidden -> heads x
+    # head_dim, no bias) of the layer's input beside q, k and v, whose
+    # sigmoid multiplies the heads' output before the output projection
+    attn_gate: bool = False
 
     def _cache_vars(self, b, hkv, d, dtype):
         """The cache buffers in the configured storage format. Returns
@@ -240,9 +244,18 @@ class CausalSelfAttention(nn.Module):
                                name="k_norm")(k)
         if self.block_causal and not self.causal:
             raise ValueError("block_causal needs a causal model")
+        gate = None
+        if self.attn_gate:
+            gate = nn.Dense(
+                h * d, use_bias=False, dtype=self.dtype, name="gate",
+                kernel_init=(
+                    _tp_dense_init(1) if self.tp_shard
+                    else nn.initializers.lecun_normal()
+                ),
+            )(x)
         if decode:
             return self._decode_step(q, k, v, e, decode_pos,
-                                     paged=paged)
+                                     paged=paged, gate=gate)
         if self.use_rope:
             pos = jnp.arange(l) if positions is None else positions
             q = apply_rope(q, pos, self.rope_theta)
@@ -362,9 +375,12 @@ class CausalSelfAttention(nn.Module):
                 segments=segments, block_causal=self.block_causal,
             )
         out = out.transpose(0, 2, 1, 3).reshape(b, l, h * d)
-        return self._proj(out, e)
+        return self._proj(out, e, gate)
 
-    def _proj(self, out, e):
+    def _proj(self, out, e, gate=None):
+        if gate is not None:
+            with jax.named_scope("attn_gate"):
+                out = out * jax.nn.sigmoid(gate).astype(out.dtype)
         y = nn.Dense(
             e, use_bias=False, dtype=self.dtype, name="proj",
             kernel_init=(
@@ -376,7 +392,8 @@ class CausalSelfAttention(nn.Module):
             y = y + self._lora_branch(out, e, "proj")
         return y
 
-    def _decode_step(self, q, k, v, e, decode_pos, paged=None):
+    def _decode_step(self, q, k, v, e, decode_pos, paged=None,
+                     gate=None):
         """Chunked decode against the KV cache: q is [b, h, t, d],
         k/v [b, hkv, t, d] for a chunk of t >= 1 tokens at absolute
         positions [decode_pos, decode_pos + t) — t = 1 is the classic
@@ -451,7 +468,7 @@ class CausalSelfAttention(nn.Module):
                     block_causal=self.block_causal,
                 ).astype(dtype)
                 out = out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
-                return self._proj(out, e)
+                return self._proj(out, e, gate)
             self.sow("kv_out", "k", k)  # [b, hkv, t, d] for the
             self.sow("kv_out", "v", v)  # engine's pool scatter
             out = paged_decode_attention(
@@ -462,7 +479,7 @@ class CausalSelfAttention(nn.Module):
                 block_causal=self.block_causal,
             ).astype(dtype)
             out = out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
-            return self._proj(out, e)
+            return self._proj(out, e, gate)
         cvars = self._cache_vars(b, hkv, d, dtype)
         self._cache_write(cvars, k, v, idx)
         scale = d ** -0.5
@@ -502,7 +519,7 @@ class CausalSelfAttention(nn.Module):
             )
         # (hkv, group) flattens back to h in q's head order
         out = out.transpose(0, 3, 1, 2, 4).reshape(b, t, h * d)
-        return self._proj(out, e)
+        return self._proj(out, e, gate)
 
 
 def _norm(kind, dtype, eps, name=None):
@@ -537,9 +554,10 @@ class ExpertFFN(nn.Module):
       + `router_bias` (a parameter that only selects), weights
       `route_scale` * score / the chosen scores' sum;
     * `shared_hidden` > 0: one more expert of that width and the
-      experts' activation that every token passes through, unweighted
-      (`shared_up`, `shared_down`): a plain dense product that every
-      chip of a deployment computes alike, counted once.
+      experts' form that every token passes through, unweighted
+      (`shared_up`, `shared_down`, and `shared_gate` where the experts
+      are gated): a plain dense product that every chip of a
+      deployment computes alike, counted once.
 
     `route_from` is what the router reads (a block with attention
     hands its own input, so that routing is known before attention
@@ -591,9 +609,6 @@ class ExpertFFN(nn.Module):
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError("Unknown moe_scoring %r (valid: 'softmax', "
                              "'sigmoid')" % (self.scoring,))
-        if self.shared_hidden and self.activation != "relu2":
-            raise ValueError(
-                "a shared expert is built for relu2 experts only")
         dtype = self.dtype or h.dtype
 
         def bank(fan_in):
@@ -639,15 +654,24 @@ class ExpertFFN(nn.Module):
                 activation=self.activation)
         if self.shared_hidden:
             with jax.named_scope("moe_shared"):
-                up, down = (
+                into = (d, self.shared_hidden)
+                *gate, up, down = (
                     jnp.asarray(self.param(
                         name, nn.initializers.normal(shape[0] ** -0.5),
                         shape, jnp.float32), dtype)
                     for name, shape in (
-                        ("shared_up", (d, self.shared_hidden)),
+                        (("shared_gate", into),)
+                        if self.activation != "relu2" else ()) + (
+                        ("shared_up", into),
                         ("shared_down", (self.shared_hidden, d))))
-                act = jnp.square(jnp.maximum(jnp.dot(
-                    rows, up, preferred_element_type=jnp.float32), 0.0))
+                act = jnp.dot(rows, up, preferred_element_type=jnp.float32)
+                if gate:  # gated like the experts: act(h W_gate) * (h W_up)
+                    act = (jax.nn.silu if self.activation == "swiglu"
+                           else jax.nn.relu)(jnp.dot(
+                               rows, gate[0],
+                               preferred_element_type=jnp.float32)) * act
+                else:
+                    act = jnp.square(jnp.maximum(act, 0.0))
                 y = y + jnp.dot(act.astype(dtype), down,
                                 preferred_element_type=jnp.float32)
         if (self.is_mutable_collection("counters")
@@ -670,8 +694,10 @@ class ExpertFFN(nn.Module):
 class Block(nn.Module):
     """THE block of the stack, configured per layer: normalisation,
     rotary on or off (and its theta), this layer's window, and what
-    sits in the MLP slot (the dense GELU MLP, or the expert layer fed
-    by the block's own input). `kind` "" is that block, attention then
+    sits in the MLP slot (the dense GELU MLP, the dense gated "swiglu"
+    MLP of `dense_hidden`, or the expert layer fed by the block's own
+    input). `sandwich_norm` norms each sublayer's OUTPUT too, before
+    the residual add. `kind` "" is that block, attention then
     MLP. A stack that names its layers' kinds (`layer_kinds`) makes a
     layer ONE mixer behind one norm with one residual, x + f(norm(x)):
     "*" attention alone, "M" the Mamba-2 mixer alone (`ssm`), "E" the
@@ -695,7 +721,10 @@ class Block(nn.Module):
     kv_cache_dtype: str = ""  # "" | "int8" (see CausalSelfAttention)
     norm: str = "layer"  # "layer" | "rms"
     norm_eps: float = 1e-6
-    mlp: str = "gelu"  # "gelu" dense MLP | "moe_reglu" expert layer
+    # "gelu" dense MLP | "swiglu" dense gated MLP | "moe_reglu" expert
+    # layer
+    mlp: str = "gelu"
+    dense_hidden: int = 0  # the "swiglu" MLP's width
     moe_experts: int = 0
     moe_top_k: int = 0
     moe_hidden: int = 0
@@ -710,6 +739,10 @@ class Block(nn.Module):
     moe_route_from: str = "input"
     qk_norm: bool = False
     block_causal: int = 0
+    attn_gate: bool = False  # CausalSelfAttention
+    # a norm on each sublayer's output before the residual add
+    # (`post_attn_norm`, `post_mlp_norm`), beside the two on its input
+    sandwich_norm: bool = False
     kind: str = ""  # "" attention then MLP | "*" | "M" | "E"
     ssm: tuple = ()  # Mamba2Mixer's fields, as sorted (name, value)
 
@@ -726,6 +759,7 @@ class Block(nn.Module):
             kv_cache_dtype=self.kv_cache_dtype,
             qk_norm=self.qk_norm, qk_norm_eps=self.norm_eps,
             block_causal=self.block_causal,
+            attn_gate=self.attn_gate,
             name="attn",
         )
 
@@ -746,6 +780,19 @@ class Block(nn.Module):
         # `live`: which rows carry a sequence (ExpertFFN), None = all
         e = x.shape[-1]
         block_in = x
+        if self.sandwich_norm and self.kind:
+            raise ValueError(
+                "sandwich_norm is built for the attention-then-MLP "
+                "block, not for a layer of one mixer (layer_kinds)")
+
+        def post(y, name):
+            # the sublayer's output under a norm of its own
+            if not self.sandwich_norm:
+                return y
+            with jax.named_scope("post_norm"):
+                return _norm(self.norm, self.dtype, self.norm_eps,
+                             name=name)(y)
+
         y = _norm(self.norm, self.dtype, self.norm_eps)(x)
         if self.kind == "M":
             if segments is not None:
@@ -759,10 +806,10 @@ class Block(nn.Module):
         if self.kind == "E":
             return x + self._experts()(
                 y, y, training, live=live).astype(x.dtype)
-        x = x + self._attention()(
+        x = x + post(self._attention()(
             y, training, decode=decode, decode_pos=decode_pos,
             prefill=prefill, segments=segments, positions=positions,
-            paged=paged)
+            paged=paged), "post_attn_norm")
         if self.kind == "*":
             return x
         if self.kind:
@@ -778,10 +825,10 @@ class Block(nn.Module):
             y = self._experts()(
                 y, block_in if self.moe_route_from == "input" else y,
                 training, live=live)
-            return x + y.astype(x.dtype)
-        if self.mlp != "gelu":
+            return x + post(y.astype(x.dtype), "post_mlp_norm")
+        if self.mlp not in ("gelu", "swiglu"):
             raise ValueError(
-                "Unknown mlp %r (valid: 'gelu', 'moe_reglu')"
+                "Unknown mlp %r (valid: 'gelu', 'swiglu', 'moe_reglu')"
                 % (self.mlp,))
         up_init = (
             _tp_dense_init(1) if self.tp_shard
@@ -791,6 +838,22 @@ class Block(nn.Module):
             _tp_dense_init(0) if self.tp_shard
             else nn.initializers.lecun_normal()
         )
+        if self.mlp == "swiglu":
+            # the dense gated MLP, no bias: (silu(y W_gate) * (y W_up))
+            # W_down, of width `dense_hidden`
+            if self.dense_hidden < 1:
+                raise ValueError("mlp 'swiglu' needs dense_hidden")
+            with jax.named_scope("dense_mlp"):
+                gate, up = (
+                    nn.Dense(self.dense_hidden, use_bias=False,
+                             dtype=self.dtype, kernel_init=up_init,
+                             name=name)(y)
+                    for name in ("mlp_gate", "mlp_up"))
+                y = nn.Dense(
+                    e, use_bias=False, dtype=self.dtype,
+                    kernel_init=down_init, name="mlp_down",
+                )(nn.silu(gate) * up)
+            return x + post(y, "post_mlp_norm")
         y = nn.Dense(
             self.mlp_ratio * e, dtype=self.dtype, kernel_init=up_init,
             name="mlp_up",
@@ -799,7 +862,7 @@ class Block(nn.Module):
         y = nn.Dense(
             e, dtype=self.dtype, kernel_init=down_init, name="mlp_down"
         )(y)
-        return x + y
+        return x + post(y, "post_mlp_norm")
 
 
 class LMHead(nn.Module):
@@ -898,12 +961,18 @@ class TransformerLM(nn.Module):
     head_dim: int = 0  # 0 = embed_dim // num_heads
     norm: str = "layer"  # "layer" LayerNorm | "rms" RMSNorm
     norm_eps: float = 1e-6
-    # the MLP slot: "gelu" = the dense 4x GELU MLP; "moe_reglu" = the
-    # drop-free gated expert layer (ExpertFFN): a router over
+    # the MLP slot: "gelu" = the dense 4x GELU MLP; "swiglu" = the
+    # dense gated MLP of width `dense_hidden`, no bias; "moe_reglu" =
+    # the drop-free gated expert layer (ExpertFFN): a router over
     # `moe_experts`, `moe_top_k` a token, experts of width
     # `moe_hidden`, of which this chip holds `experts_held = (first,
-    # count)` (() = all)
+    # count)` (() = all). `mlp_layout`, a 0/1 entry a layer like the
+    # rotary and window layouts: a 0 is a layer whose slot holds the
+    # dense gated MLP instead of `mlp` (leading dense layers before
+    # expert layers)
     mlp: str = "gelu"
+    mlp_layout: tuple = ()
+    dense_hidden: int = 0
     moe_experts: int = 0
     moe_top_k: int = 0
     moe_hidden: int = 0
@@ -927,6 +996,12 @@ class TransformerLM(nn.Module):
     # the id such a model reads at a position not revealed yet (the
     # engine refuses a block model without one)
     mask_token: int = -1
+    # an output gate on attention (CausalSelfAttention), a norm on
+    # each sublayer's output before the residual add (Block), and the
+    # embedding multiplied by sqrt(embed_dim)
+    attn_gate: bool = False
+    sandwich_norm: bool = False
+    embed_scale: bool = False
     # a character a layer, "" = every layer attention then MLP as
     # above: "*" a layer that is attention alone, "M" the Mamba-2
     # mixer alone, "E" the expert layer alone, each x + f(norm(x)).
@@ -984,6 +1059,16 @@ class TransformerLM(nn.Module):
             for on, kind in zip(self._layout("window_layout"),
                                 self._kinds()) if kind in ("", "*"))
 
+    def cache_leaf_window(self, path):
+        """The attention window (0 = every earlier key) of the layer a
+        leaf of the decode cache belongs to, by its path: what the
+        serving pool groups its block classes by."""
+        layer = str(path[0])
+        if not layer.startswith("block_"):
+            return 0
+        on = self._layout("window_layout")[int(layer[len("block_"):])]
+        return self.attn_window if on else 0
+
     def cache_leaf_kind(self, path):
         """What a leaf of this model's decode cache is, by its path
         (api/generation.cache_leaf_kinds): the "rows" of an attention
@@ -1000,7 +1085,9 @@ class TransformerLM(nn.Module):
         # pool — {"pools": tree mirroring this model's cache collection
         # with per-layer [num_blocks, block_size, hkv, d] arenas,
         # "table": [b, m] int32 block table}. Each block slices out its
-        # own layer's arenas below; see serving/kv_pool.py. The decode
+        # own layer's arenas below; see serving/kv_pool.py. A pool of
+        # several block classes adds "table_of": {block name: (first,
+        # end) columns of "table"} (static), its class's table. The decode
         # step adds "live": [b] bool, which rows carry a sequence (a
         # free lane does not): the expert layers read no expert for a
         # row that carries none (ExpertFFN).
@@ -1026,6 +1113,8 @@ class TransformerLM(nn.Module):
         x = nn.Embed(
             self.vocab_size, self.embed_dim, dtype=self.dtype, name="wte"
         )(tokens)
+        if self.embed_scale:
+            x = x * jnp.asarray(self.embed_dim ** 0.5, x.dtype)
         # shared decode-counter convention (setup_decode_positions):
         # the counter drives every layer's cache write, RoPE rotation
         # and the wpe lookup
@@ -1052,6 +1141,8 @@ class TransformerLM(nn.Module):
                         for on in self._layout("window_layout"))
         rotary = [self.pos_emb == "rope" and bool(on)
                   for on in self._layout("rope_layout")]
+        mlps = [self.mlp if on else "swiglu"
+                for on in self._layout("mlp_layout")]
         ssm = tuple(sorted({
             "num_heads": self.ssm_heads, "head_dim": self.ssm_head_dim,
             "groups": self.ssm_groups, "state_dim": self.ssm_state,
@@ -1093,7 +1184,8 @@ class TransformerLM(nn.Module):
                 lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
                 kv_cache_dtype=self.kv_cache_dtype,
                 norm=self.norm, norm_eps=self.norm_eps,
-                mlp=self.mlp, moe_experts=self.moe_experts,
+                mlp=mlps[i], dense_hidden=self.dense_hidden,
+                moe_experts=self.moe_experts,
                 moe_top_k=self.moe_top_k, moe_hidden=self.moe_hidden,
                 experts_held=tuple(self.experts_held),
                 moe_activation=self.moe_activation,
@@ -1102,15 +1194,23 @@ class TransformerLM(nn.Module):
                 moe_shared_hidden=self.moe_shared_hidden,
                 moe_route_from=self.moe_route_from,
                 qk_norm=self.qk_norm, block_causal=self.block_causal,
+                attn_gate=self.attn_gate,
+                sandwich_norm=self.sandwich_norm,
                 kind=kinds[i], ssm=ssm if kinds[i] == "M" else (),
                 name="block_%d" % i,
             )
             blk_paged = None
             if paged is not None and kinds[i] in ("", "*"):
                 arena = paged["pools"]["block_%d" % i]["attn"]
+                table = paged["table"]
+                # a pool of several block CLASSES (serving/kv_pool.py)
+                # lays a table a class side by side and names each
+                # layer's columns
+                span = (paged.get("table_of") or {}).get("block_%d" % i)
+                if span is not None:
+                    table = table[:, span[0]:span[1]]
                 blk_paged = {
-                    "k": arena["k"], "v": arena["v"],
-                    "table": paged["table"],
+                    "k": arena["k"], "v": arena["v"], "table": table,
                 }
                 if "k_scale" in arena:  # int8 arenas carry scale leaves
                     blk_paged["k_scale"] = arena["k_scale"]

@@ -76,7 +76,8 @@ def build_engine(cfg):
     # dtype) is the one it runs while it is built: shapes there too
     with mock.patch.object(
             kv_pool, "build_pools",
-            lambda *a: jax.eval_shape(lambda: build_pools(*a))), \
+            lambda *a, **k: jax.eval_shape(
+                lambda: build_pools(*a, **k))), \
             mock.patch.object(engine_mod, "tracked_jit", shapes_only):
         eng = engine_mod.PagedContinuousBatchingEngine(
             trainer, state, num_slots=server["num_slots"],
@@ -92,7 +93,12 @@ def programs(eng, tile, upload_blocks):
     A pool with per-slot state leaves (a model with state-space layers)
     has the state write beside the step and the prompt write, and none
     of the programs such a model refuses to start with (copy on write,
-    the decode tile, the host tier's upload)."""
+    the decode tile, the host tier's upload). A pool that keeps its
+    blocks in classes by attention window (kv_pool.py, BLOCK CLASSES:
+    served without prefix sharing) has the step and the prompt write
+    in its two forms, every class written and the whole-length classes
+    alone, a block id a class; what needs one table for every layer
+    never runs on it."""
     import jax
     import jax.numpy as jnp
 
@@ -112,6 +118,18 @@ def programs(eng, tile, upload_blocks):
     if kv.has_state:
         out["state_write"] = (kv._state_program(), [eng._kv_shapes, i32],
                               {"kinds": kv.kinds})
+        return out
+    if kv.classed:
+        bids = spec((len(kv.allocators),), jnp.int32)
+        every = tuple(range(len(kv.allocators)))
+        whole = tuple(c for c in every if not kv.allocators[c].window)
+        for name, classes in (("prompt_write", every),
+                              ("prompt_write[whole]", whole)):
+            if classes:
+                out[name] = (
+                    kv._write_program(), [eng._kv_shapes, i32, bids],
+                    {"block_size": kv.block_size, "kinds": kv.kinds,
+                     "leaf_class": kv.leaf_class, "classes": classes})
         return out
     out.update({
         "cow_copy": (kv._copy_program(), [i32, i32], {"kinds": kv.kinds}),

@@ -63,9 +63,10 @@ def _cfg(**over):
     return dict(PARAMS, **WEIGHTS, **over)
 
 
-def _engine(params, leaves, slots=2):
+def _engine(params, leaves, slots=2, **kwargs):
     """The paged engine over `leaves` (the reference's, by path),
-    `slots` lanes, blocks of four."""
+    `slots` lanes, blocks of four; prefix sharing on, as the engine's
+    default has it: one table for every layer."""
     trainer = trainer_mod.Trainer(
         load_model_spec_from_module(zoo),
         mesh=mesh_lib.build_mesh({"dp": 1}, devices=jax.devices()[:1]),
@@ -76,7 +77,7 @@ def _engine(params, leaves, slots=2):
         opt_state=(), model_state=FrozenDict({}),
         rng=jax.random.PRNGKey(0))
     return PagedContinuousBatchingEngine(trainer, state, slots,
-                                         block_size=4)
+                                         block_size=4, **kwargs)
 
 
 def _model(cfg):
@@ -213,6 +214,39 @@ def test_the_step_hands_back_what_the_expert_layers_did():
     assert counts["kv.blocks_held"] > 0
     dead = counts["kv.window_dead_blocks"] / counts["kv.blocks_held"]
     assert 0.3 < dead < 0.75
+    # one table for every layer (the engine shares prefixes): what it
+    # holds is what it would hold, and it gives nothing back
+    assert counts["kv.blocks_whole"] == counts["kv.blocks_held"]
+    assert counts.get("kv.window_blocks_released", 0) == 0
+
+
+def test_without_sharing_the_window_layers_hold_their_window_only():
+    """The same request through an engine that shares no prefix: the
+    three window layers' blocks are a class of their own
+    (serving/kv_pool.py, BLOCK CLASSES), which holds at most one dead
+    block a lane a layer between two releases, and the tokens are the
+    same."""
+    _, w, prompt, generated, _, shared = _served()
+    eng = _engine(PARAMS, w, share_prefix=False)
+    assert eng.kv.class_windows == [0, 8] and eng.kv.class_layers == [1, 3]
+    request = ServingRequest(prompt, len(generated))
+    before = dict(tracing.recorder().counts())
+    eng.insert(request)
+    while eng.active_count():
+        assert eng._launch() and eng._collect()
+    after = tracing.recorder().counts()
+    counts = {k: after[k] - before.get(k, 0) for k in after}
+    assert request.generated == generated
+    ticks = len(generated) - 1
+    assert counts["kv.blocks_whole"] == shared["kv.blocks_held"]
+    assert counts["kv.blocks_held"] < 0.75 * counts["kv.blocks_whole"]
+    assert counts["kv.window_dead_blocks"] <= 3 * ticks  # one a layer
+    assert (counts["kv.window_dead_blocks"]
+            < 0.2 * counts["kv.blocks_held"])
+    assert counts["kv.window_blocks_released"] > 0
+    assert counts["prompt_write.blocks_skipped"] == 3 * 3  # blocks 0-2
+    assert counts["paged.blocks_streamed"] == shared[
+        "paged.blocks_streamed"]
     assert counts["paged.blocks_streamed"] > 0
 
 
